@@ -113,12 +113,6 @@ class RunConfig:
     remat: Optional[bool] = None             # per-block rematerialization
     prefetch_depth: int = 2                  # host pipeline look-ahead (0=off)
     accum_steps: int = 1                     # microbatches per optimizer step
-    # JAX persistent compilation cache (ROADMAP item 5): a directory all
-    # roles point jax_compilation_cache_dir at, so a role RESTART (and
-    # the PR-4 warm rounds) deserializes yesterday's XLA executables
-    # instead of recompiling them — compile.ms then measures cache-load
-    # time, not compile time. None disables (in-memory jit cache only).
-    compile_cache_dir: Optional[str] = None
 
     # -- serving plane (engine/serve.py; neurons/server.py) -----------------
     serve_port: int = 0                      # HTTP /generate port (0 = off)
@@ -459,8 +453,8 @@ def build_parser(role: str) -> argparse.ArgumentParser:
     g.add_argument("--mu-dtype", dest="mu_dtype",
                    choices=("float32", "bfloat16"), default=d.mu_dtype,
                    help="AdamW first-moment storage dtype; bfloat16 halves "
-                        "its HBM footprint (7B/8B configs) at ~no "
-                        "throughput cost (scripts/opt_dtype_probe.py)")
+                        "its HBM footprint (7B/8B configs); its "
+                        "throughput cost is not measured")
     g.add_argument("--lora-rank", dest="lora_rank", type=int,
                    default=d.lora_rank,
                    help=">0 switches the miner to LoRA-delta training; "
@@ -559,14 +553,6 @@ def build_parser(role: str) -> argparse.ArgumentParser:
                         "Wire artifacts (bases, deltas, adapters) stay in "
                         "the universal unrolled layout, so roles can flip "
                         "this independently")
-    g.add_argument("--compile-cache-dir", dest="compile_cache_dir",
-                   default=d.compile_cache_dir,
-                   help="JAX persistent compilation cache directory "
-                        "(created if missing): role restarts deserialize "
-                        "previously-compiled XLA executables instead of "
-                        "recompiling — point every role of a deployment "
-                        "at the same path. Unset = in-memory jit cache "
-                        "only (every restart recompiles)")
 
     if role == "server":
         g = p.add_argument_group("serving")

@@ -22,6 +22,7 @@ mesh, the merge runs as local partial sums + ICI all-reduce
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import os
@@ -363,17 +364,24 @@ class ParameterizedMerge:
             delta_lib.miner_axis_size(stacked))
         opt_state = tx.init(w)
         last = None
-        for epoch in range(self.meta_epochs):
-            for batch in val_batches():
-                batch = engine.place_batch(batch)
-                # `last` stays a device array inside the batch loop so the
-                # host never blocks on an individual meta-step; one float()
-                # per epoch (the log line) is the only sync point.
-                w, opt_state, last = meta_step(w, opt_state, base, stacked,
-                                               batch)
-            logger.info("meta-learning epoch %d/%d loss=%.4f",
-                        epoch + 1, self.meta_epochs,
-                        float("nan") if last is None else float(last))
+        # traced under the engine's mesh, like the engine's own loss: the
+        # in-model Pallas attention reads the ambient mesh to shard_map
+        # itself (GSPMD refuses to partition a Mosaic call)
+        mesh = getattr(engine, "mesh", None)
+        mesh_ctx = mesh if mesh is not None else contextlib.nullcontext()
+        with mesh_ctx:
+            for epoch in range(self.meta_epochs):
+                for batch in val_batches():
+                    batch = engine.place_batch(batch)
+                    # `last` stays a device array inside the batch loop so
+                    # the host never blocks on an individual meta-step; one
+                    # float() per epoch (the log line) is the only sync
+                    # point.
+                    w, opt_state, last = meta_step(w, opt_state, base,
+                                                   stacked, batch)
+                logger.info("meta-learning epoch %d/%d loss=%.4f",
+                            epoch + 1, self.meta_epochs,
+                            float("nan") if last is None else float(last))
         merged = mixture(w, base, stacked)   # pre-jitted (_build_step cache)
         return merged, w
 

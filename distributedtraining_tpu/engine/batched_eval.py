@@ -207,11 +207,8 @@ class BatchedCohortEvaluator:
                             bucket=_cohort_bucket)
 
     def _build_mesh(self, mesh) -> Callable:
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-        try:  # jax >= 0.8 top-level API, experimental path as fallback
-            from jax import shard_map as _shard_map
-        except ImportError:  # pragma: no cover
-            from jax.experimental.shard_map import shard_map as _shard_map
 
         axis = self._axis(mesh)
         vmapped = self._candidate_eval()
@@ -225,16 +222,10 @@ class BatchedCohortEvaluator:
             return (jax.lax.all_gather(ls, axis, tiled=True),
                     jax.lax.all_gather(ts, axis, tiled=True))
 
-        specs = dict(mesh=mesh, in_specs=(P(), P(axis), P()),
-                     out_specs=(P(), P()))
-        try:
-            # the replication the trailing all-gather establishes is not
-            # statically inferable, so the rep check must be off (the
-            # kwarg is check_rep on jax<=0.4.x, check_vma after the
-            # shard_map promotion to the top-level API)
-            fn = _shard_map(local_eval, check_rep=False, **specs)
-        except TypeError:  # pragma: no cover — newer jax spelling
-            fn = _shard_map(local_eval, check_vma=False, **specs)
+        # the replication the trailing all-gather establishes is not
+        # statically inferable, so the vma check must be off
+        fn = shard_map(local_eval, mesh=mesh, in_specs=(P(), P(axis), P()),
+                       out_specs=(P(), P()), check_vma=False)
         return devprof.wrap("eval.cohort", jax.jit(fn),
                             bucket=_cohort_bucket)
 

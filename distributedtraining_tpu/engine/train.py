@@ -55,10 +55,10 @@ def default_optimizer(learning_rate: float = 5e-4,
     Gradient clipping is off by default for parity (the reference has none in
     its live path) but first-class because real runs want it.
 
-    ``mu_dtype="bfloat16"`` stores the first moment in bf16 — throughput is a
-    wash on v5e at 124M (measured ±1%, scripts/opt_dtype_probe.py) but it
-    halves the first-moment HBM footprint, which is what lets the 7B/8B
-    full-delta configs keep params+AdamW resident per chip."""
+    ``mu_dtype="bfloat16"`` stores the first moment in bf16 — it halves the
+    first-moment HBM footprint, which is what lets the 7B/8B full-delta
+    configs keep params+AdamW resident per chip (throughput effect not
+    measured)."""
     tx = optax.adamw(learning_rate, weight_decay=weight_decay,
                      mu_dtype=mu_dtype)
     if grad_clip is not None:

@@ -49,10 +49,10 @@ class GPT2Config:
     dtype: str = "bfloat16"        # activation/compute dtype
     param_dtype: str = "float32"   # storage dtype
     remat: bool = False
-    # flash is the TPU default: the Pallas kernel declines off-TPU (and for
-    # short/ragged shapes) and the dense XLA path takes over transparently.
-    # Measured on v5e, GPT-2-124M fwd+bwd: +16% tokens/sec at T=1024,
-    # +45% at 2048, 3.1x at 4096 vs dense (see ops/flash_attention.py).
+    # flash is the TPU default: ops/flash_attention.py's rule selects the
+    # Pallas kernel on TPU at block-aligned T without a padding mask, and
+    # the XLA paths otherwise. Its gain over dense is not measured on the
+    # current chip.
     attention_impl: str = "flash"  # "dense" | "flash" | "ring"
     vocab_multiple: int = 128      # pad vocab to a lane-aligned multiple
     # lax.scan over the block stack: one block traced/compiled once instead
@@ -64,7 +64,7 @@ class GPT2Config:
     # f32 either way (preferred_element_type); "bfloat16" halves the single
     # largest activation tensor's HBM round-trips at a small CE-input
     # precision cost (the loss still reduces in f32). Opt-in pending an
-    # on-chip measurement (docs/perf.md).
+    # on-chip measurement.
     logits_dtype: str = "float32"
 
     @property

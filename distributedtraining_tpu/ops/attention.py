@@ -250,8 +250,9 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      impl: str = "dense") -> jax.Array:
     """Causal self-attention entry point used by the models.
 
-    impl: "dense" (XLA), "flash" (Pallas kernel when available, falls back
-    to blockwise at long T / dense at short T on non-TPU backends),
+    impl: "dense" (XLA), "flash" (the Pallas kernel where
+    flash_attention's rule selects it — TPU, no padding mask, aligned T —
+    else blockwise at long T / dense at short T),
     "blockwise" (portable lax flash — O(block^2) temps everywhere), "ring"
     (sequence-parallel over the sp mesh axis; needs set_ring_mesh and
     unmasked/unpacked inputs).
@@ -268,17 +269,18 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                    segment_ids=segment_ids)
     if impl == "flash":
         from . import flash_attention
+        # None = the selection rule said no (off-TPU, padding mask,
+        # short or unaligned T); a selected kernel that fails raises
         out = flash_attention.flash_attention(
             q, k, v, attention_mask=attention_mask, segment_ids=segment_ids)
         if out is not None:
             return out
         if T >= BLOCKWISE_FALLBACK_LEN:
-            # kernel declined (CPU backend): at long T the dense [T, T]
-            # fallback would blow temp memory the TPU path never pays —
-            # stream blocks instead
+            # at long T the dense [T, T] scores would blow temp memory
+            # the kernel path never pays — stream blocks instead
             return blockwise_attention(
                 q, k, v, attention_mask=attention_mask,
                 segment_ids=segment_ids)
-        # short T: dense is faster off-TPU and the temps are tiny
+        # short T: dense is faster and the temps are tiny
     mask = combine_masks(make_causal_mask(T), attention_mask, segment_ids)
     return dot_product_attention(q, k, v, mask)

@@ -30,41 +30,23 @@ import jax.numpy as jnp
 import numpy as np
 
 
-_warned_no_thread_resources = False
+def ambient_mesh():
+    """The multi-device mesh of the enclosing ``with mesh:`` — the train
+    engine traces the model under one (engine/train.py) — or None."""
+    # jax 0.9.0 has no public reader for the legacy mesh context
+    # (jax.interpreters.pxla.thread_resources was deprecated in 0.8.2)
+    from jax._src.mesh import thread_resources
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty or mesh.size == 1 else mesh
 
 
 def _ambient_mesh_needs_matmul_bwd() -> bool:
     """True when the mesh active during tracing has both dp>1 and fsdp>1 —
     the configuration whose gather-backward reshard GSPMD cannot express
     (see module docstring)."""
-    try:
-        try:
-            # the `with mesh:` context reader; public spelling
-            # (jax.interpreters.pxla.thread_resources) deprecated in 0.8.2
-            # with no public replacement for the legacy context
-            from jax._src.mesh import thread_resources
-        except ImportError:  # pragma: no cover — older jax
-            from jax.interpreters.pxla import thread_resources
-    except ImportError:  # pragma: no cover — future jax relocation
-        # both private spellings gone: degrade to the default scatter
-        # backward (correct everywhere, slower on dp x fsdp meshes)
-        # instead of raising out of every embedding TRACE — an import
-        # error here would take down single-device runs that never
-        # needed the probe at all
-        global _warned_no_thread_resources
-        if not _warned_no_thread_resources:
-            _warned_no_thread_resources = True
-            import logging
-            logging.getLogger(__name__).warning(
-                "jax no longer exposes thread_resources at either known "
-                "path; embedding backward keeps the scatter spelling "
-                "(involuntary-remat risk returns on dp x fsdp meshes)")
-        return False
-    mesh = thread_resources.env.physical_mesh
-    if mesh.empty:
-        return False
-    shape = dict(mesh.shape)
-    return shape.get("dp", 1) > 1 and shape.get("fsdp", 1) > 1
+    mesh = ambient_mesh()
+    return (mesh is not None and mesh.shape.get("dp", 1) > 1
+            and mesh.shape.get("fsdp", 1) > 1)
 
 
 import functools

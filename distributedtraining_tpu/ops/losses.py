@@ -64,8 +64,8 @@ def fused_linear_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
     materializing the [N, V] logits tensor.
 
     The standard path materializes f32 logits (GPT-2-124M at B8/T1024:
-    ~1.6 GB per traversal, several traversals per step — the single largest
-    non-matmul HBM cost, docs/perf.md). Two spellings of the fix:
+    ~1.6 GB per traversal, several traversals per step). Two spellings of
+    the fix:
 
     - ``impl="pallas"`` (ops/pallas_ce.py): hand-written forward/backward
       kernels with the logits tiles living in VMEM only — the preferred

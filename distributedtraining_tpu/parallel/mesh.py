@@ -14,12 +14,15 @@ axes ride the fastest ICI links; tp should be innermost, dp outermost
 from __future__ import annotations
 
 import dataclasses
+import logging
 import math
 
 import jax
 import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
+
+logger = logging.getLogger(__name__)
 
 AXES = ("dp", "fsdp", "sp", "tp")
 
@@ -55,9 +58,15 @@ def make_mesh(cfg: MeshConfig | None = None, *, devices=None) -> Mesh:
     devices = devices[: cfg.n_devices]
     try:
         dev_array = mesh_utils.create_device_mesh(cfg.shape, devices=devices)
-    except Exception:
-        # CPU virtual devices or odd topologies: plain reshape is fine
+        layout = "topology-aware (mesh_utils.create_device_mesh)"
+    except (ValueError, NotImplementedError, AssertionError) as e:
+        # CPU virtual devices or a shape mesh_utils has no assignment for:
+        # device order as listed. Correct, but axes may not follow the
+        # ICI rings — say so rather than hide it.
         dev_array = np.asarray(devices).reshape(cfg.shape)
+        layout = f"plain reshape of the device list ({e})"
+    logger.info("mesh %s over %d %s devices: %s", dict(zip(AXES, cfg.shape)),
+                len(devices), devices[0].platform, layout)
     return Mesh(dev_array, AXES)
 
 

@@ -47,31 +47,45 @@ _MULTIPROCESS_ENV_VARS = (
 )
 
 
-def _gce_tpu_worker_count() -> int:
+def _gce_tpu_worker_count(deadline_s: float = 2.0) -> int:
     """Worker count from the GCE metadata server — plain Cloud TPU pod
     slices launched via gcloud export no env vars; JAX's own cluster
-    auto-detect queries this same endpoint. Returns 1 on any failure."""
-    if os.environ.get("TPU_SKIP_MDS_QUERY"):
-        return 1
+    auto-detect queries this same endpoint. Returns 1 when the server
+    gives no usable answer within ``deadline_s``: the query runs on a
+    daemon thread because urlopen's timeout bounds the socket, not a
+    stalled name lookup."""
+    import threading
     import urllib.request
-    req = urllib.request.Request(
-        "http://metadata.google.internal/computeMetadata/v1/instance/"
-        "attributes/worker-network-endpoints",
-        headers={"Metadata-Flavor": "Google"})
-    try:
-        with urllib.request.urlopen(req, timeout=1.0) as r:
-            return len([e for e in r.read().decode().split(",") if e])
-    except Exception:  # malformed responses included — never crash startup
-        return 1
+
+    answer: list[int] = []
+
+    def query():
+        req = urllib.request.Request(
+            "http://metadata.google.internal/computeMetadata/v1/instance/"
+            "attributes/worker-network-endpoints",
+            headers={"Metadata-Flavor": "Google"})
+        try:
+            with urllib.request.urlopen(req, timeout=1.0) as r:
+                answer.append(
+                    len([e for e in r.read().decode().split(",") if e]))
+        except (OSError, ValueError):   # unreachable, or a malformed body
+            pass
+
+    t = threading.Thread(target=query, name="gce-mds-query", daemon=True)
+    t.start()
+    t.join(deadline_s)
+    return answer[0] if answer else 1
 
 
 def _multiprocess_env() -> bool:
     env = os.environ
     if any(env.get(k) for k in _MULTIPROCESS_ENV_VARS):
         return True
-    # TPU pod metadata: single-host TPU VMs also set this (one hostname), so
-    # it only signals multi-process when several workers are listed
-    if len([h for h in env.get("TPU_WORKER_HOSTNAMES", "").split(",") if h]) > 1:
+    # the TPU runtime's own description of the slice: single-host TPU VMs
+    # set one hostname, a pod lists several. Where it is set it DECIDES —
+    # a single host is then never sent to the network to ask
+    tpu_hosts = env.get("TPU_WORKER_HOSTNAMES")
+    if tpu_hosts and len([h for h in tpu_hosts.split(",") if h]) > 1:
         return True
     for k in ("SLURM_NTASKS", "SLURM_NPROCS", "OMPI_COMM_WORLD_SIZE"):
         try:
@@ -79,12 +93,14 @@ def _multiprocess_env() -> bool:
                 return True
         except ValueError:
             pass
-    # last resort, only when this looks like a TPU VM: /dev/accel* (v4 and
-    # earlier) or /dev/vfio WITH libtpu importable (v5e+ use vfio, but bare
-    # /dev/vfio also exists on non-GCE GPU-passthrough hosts where a
-    # metadata.google.internal lookup would stall in DNS — jax's own
-    # cloud_tpu detection gates on libtpu the same way). Then ask the
-    # metadata server like jax's cloud_tpu_cluster does.
+    if tpu_hosts or env.get("TPU_SKIP_MDS_QUERY"):
+        return False
+    # last resort, only when this looks like a TPU VM that describes its
+    # slice nowhere in the environment: /dev/accel* (v4 and earlier) or
+    # /dev/vfio WITH libtpu importable (v5e+ use vfio, but bare /dev/vfio
+    # also exists on non-GCE GPU-passthrough hosts). Then ask the
+    # metadata server like jax's cloud_tpu_cluster does, under a hard
+    # deadline.
     import glob
     import importlib.util
     if glob.glob("/dev/accel*") or (
@@ -113,6 +129,8 @@ def initialize(coordinator_address: Optional[str] = None,
                 or process_id is not None)
     if not explicit and not _multiprocess_env():
         # single-process launch; nothing to initialize
+        logger.info("multihost: single host (decided from the "
+                    "environment); jax.distributed not initialized")
         _initialized = True
         return
     jax.distributed.initialize(coordinator_address=coordinator_address,

@@ -4,7 +4,7 @@ The reference's de-facto system test is its Local* twins running a
 miner -> validator -> averager round offline (SURVEY.md §4.1); this is that
 round as a minimal, readable script. Run from the repo root:
 
-    DT_FORCE_PLATFORM=cpu python examples/local_round.py
+    JAX_PLATFORMS=cpu python examples/local_round.py
 
 Everything here is the same machinery the real roles compose
 (neurons/common.py) — swap InMemoryTransport/LocalChain for
@@ -16,11 +16,6 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from distributedtraining_tpu.utils.platform import (  # noqa: E402
-    force_platform_from_env)
-
-force_platform_from_env()
 
 from distributedtraining_tpu.chain import LocalChain  # noqa: E402
 from distributedtraining_tpu.data import (ByteTokenizer,  # noqa: E402

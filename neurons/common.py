@@ -25,6 +25,7 @@ from distributedtraining_tpu.parallel import make_mesh, resolve_mesh_config
 from distributedtraining_tpu.transport import (InMemoryTransport,
                                                LocalFSTransport)
 from distributedtraining_tpu.utils import JSONLSink, multi_sink
+from distributedtraining_tpu.utils.platform import enable_compile_cache
 
 logger = logging.getLogger(__name__)
 
@@ -240,39 +241,6 @@ def build_base_fetcher(cfg: RunConfig, c: Components):
                        store_bytes=cfg.base_store_mb * (1 << 20))
 
 
-def enable_compile_cache(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path`` (ROADMAP
-    item 5, first half): every role applies this at build, so a role
-    RESTART — and a supervised respawn, and the averager failover
-    standby — deserializes the previous process's XLA executables
-    instead of recompiling the bucket ladders from scratch. The
-    ``compile.ms`` histogram then measures cache-load time (tens of ms)
-    instead of compile time (seconds); tests/test_serve.py pins the
-    restart behavior. The threshold knobs are best-effort: names drift
-    across JAX versions, and a missing knob only means the default
-    threshold applies."""
-    import jax
-
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, val)
-        except (AttributeError, ValueError):  # pragma: no cover — jax drift
-            logger.debug("compile cache knob %s unavailable", knob)
-    # the cache module memoizes "disabled" the first time ANY compile
-    # runs without a dir configured (platform probes compile tiny
-    # programs well before build()); reset so the new dir takes effect
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover — private-API drift
-        logger.debug("compilation_cache.reset_cache unavailable",
-                     exc_info=True)
-    logger.info("persistent compilation cache at %s", path)
-
-
 def build(cfg: RunConfig) -> Components:
     import jax
 
@@ -285,9 +253,8 @@ def build(cfg: RunConfig) -> Components:
                          num_processes=cfg.multihost_processes,
                          process_id=cfg.multihost_id)
 
-    if cfg.compile_cache_dir:
-        # before ANY jit dispatch so the whole build benefits
-        enable_compile_cache(cfg.compile_cache_dir)
+    # before ANY jit dispatch so the whole build benefits
+    enable_compile_cache()
 
     import dataclasses as _dc
 

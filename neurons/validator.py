@@ -17,12 +17,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-# platform override BEFORE any backend touch (see utils/platform.py)
-from distributedtraining_tpu.utils.platform import (  # noqa: E402
-    force_platform_from_env)
-
-force_platform_from_env()
-
 from distributedtraining_tpu.config import RunConfig   # noqa: E402
 from distributedtraining_tpu.engine import Validator   # noqa: E402
 from neurons.common import (build, build_base_fetcher,  # noqa: E402

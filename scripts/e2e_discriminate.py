@@ -41,11 +41,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distributedtraining_tpu.utils.platform import (  # noqa: E402
-    force_platform_from_env)
-
-force_platform_from_env()
-
 
 def run(work_dir: str, *, model: str = "gpt2-124m",
         steps: tuple[int, int, int] = (60, 25, 8),

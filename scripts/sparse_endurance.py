@@ -40,11 +40,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distributedtraining_tpu.utils.platform import (  # noqa: E402
-    force_platform_from_env)
-
-force_platform_from_env()
-
 
 def _fleet(work_dir: str, wire: str, *, rounds: int, steps: int,
            model: str, dataset: str, density: float) -> list[dict]:

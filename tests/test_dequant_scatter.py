@@ -73,17 +73,23 @@ def test_kernel_matches_xla_scatter_int8_f32_duplicates():
                                           np.asarray(ref))
 
 
-def test_kernel_declines_oversize_and_empty():
-    flat_big = jnp.zeros((dsc.MAX_ACC_ELEMS + 1,), jnp.float32)
+def test_shape_rule_and_selection():
+    """Selection is a shape rule plus the backend, never a probe: leaves
+    too big for VMEM, not whole 128-lane rows, empty, or with more
+    entries than SMEM holds are not the kernel's; calling it on one
+    anyway is a ValueError, not a silent decline."""
+    assert dsc.kernel_supports(2048, 96)
+    assert not dsc.kernel_supports(dsc.MAX_ACC_ELEMS + 128, 1)
+    assert not dsc.kernel_supports(2048 + 64, 1)        # ragged rows
+    assert not dsc.kernel_supports(2048, 0)
+    assert not dsc.kernel_supports(dsc.MAX_ACC_ELEMS, dsc.MAX_ENTRIES + 1)
     idx = jnp.asarray([0], jnp.int32)
     q = jnp.asarray([1], jnp.int8)
-    assert dsc.dequant_scatter_add(flat_big, idx, q, 1.0,
-                                   interpret=True) is None
-    flat = jnp.zeros((128,), jnp.float32)
-    assert dsc.dequant_scatter_add(flat, idx[:0], q[:0], 1.0,
-                                   interpret=True) is None
-    # and production CPU (no interpret override, no TPU): declined
-    assert dsc.dequant_scatter_add(flat, idx, q, 1.0) is None
+    with pytest.raises(ValueError, match="unsupported shapes"):
+        dsc.dequant_scatter_add(jnp.zeros((100,), jnp.float32), idx, q,
+                                1.0, interpret=True)
+    # production CPU (no interpret hook, no TPU): accumulate paths do
+    # not route here
     assert not dsc.enabled()
 
 

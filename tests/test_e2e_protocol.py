@@ -4,7 +4,7 @@ The committed artifact E2E_r03.json is produced by the full GPT-2-124M
 run (~10 min CPU); this test exercises the identical harness — real
 checkpoint format, --init-from conversion, files: corpus, word
 tokenizer, all three CLIs, the three protocol assertions — at a scale CI
-can afford. Set DT_RUN_SLOW=1 to run the full 124M spelling here too.
+can afford. `-m slow` runs the full 124M spelling here too.
 
 Reference flow being reproduced: /root/reference/neurons/miner.py:54-106.
 """
@@ -60,9 +60,7 @@ def test_checkpoint_is_idempotent_and_bit_real(tmp_path):
     assert "wte" in params
 
 
-@pytest.mark.skipif(not os.environ.get("DT_RUN_SLOW"),
-                    reason="full 124M protocol round (~10 min CPU); "
-                           "set DT_RUN_SLOW=1")
+@pytest.mark.slow   # full 124M protocol round, ~10 min on CPU
 def test_protocol_round_gpt2_124m(tmp_path):
     summary = run(str(tmp_path), steps=30, model="gpt2-124m",
                   eval_batches=2)

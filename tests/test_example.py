@@ -8,7 +8,7 @@ import sys
 
 def test_local_round_example():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, DT_FORCE_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(repo, "examples", "local_round.py")],

@@ -78,7 +78,7 @@ def test_two_process_miner_cli(tmp_path):
     addr = f"127.0.0.1:{_free_port()}"
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    env["DT_FORCE_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     miner = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "neurons", "miner.py")
     args = [

@@ -21,7 +21,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run(role, *args):
     env = dict(os.environ)
-    env["DT_FORCE_PLATFORM"] = "cpu"  # subprocesses must not grab the TPU
+    # concurrent role processes cannot share a chip (one process per
+    # chip): this lane is CPU by construction
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)        # no virtual-device forcing needed
     return subprocess.Popen(
         [sys.executable, os.path.join(REPO, "neurons", f"{role}.py"), *args],

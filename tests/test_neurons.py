@@ -380,8 +380,8 @@ def test_round4_flags_parse_into_config():
 
 def test_sparse8_delta_round(tmp_path):
     """--delta-dtype sparse8: top-k int8 wire — the artifact shrinks well
-    past the dense int8 form (>=8x beyond int8 at the default density,
-    VERDICT r3 #5), the validator auto-detects the self-describing format
+    past the dense int8 form (>=8x beyond int8 at the default density),
+    the validator auto-detects the self-describing format
     and scores it, the averager merges it."""
     q_dir, sp_dir = tmp_path / "int8", tmp_path / "sparse8"
     for d, extra in ((q_dir, ["--delta-dtype", "int8"]),

@@ -541,7 +541,7 @@ def test_every_exporter_metric_name_is_documented():
       by their documented ``<rule>``-style placeholder rows and are
       not enumerable statically);
     - span names: every literal obs.span(...) name (rendered as
-      ``span.<name>_ms`` / the span taxonomy table);
+      ``span.<name>_ms`` / the span vocabulary table);
     - labeled families: the _FLEET_SERIES ledger series, the SLO
       breach family, and every literal dt_* family in
       utils/devprof.py + utils/obs_http.py.

@@ -100,16 +100,27 @@ def test_kernel_under_jit():
                                atol=1e-6)
 
 
-def test_kernel_declines_cleanly():
-    """Off-TPU with no interpret override the kernel declines (tier-1
-    production path is the XLA reference); multi-token queries decline
-    everywhere (decode is one token per step)."""
+def test_selection_is_by_backend_and_shape():
+    """The model-facing entry selects from what it can observe: off-TPU
+    it IS the XLA reference (tier-1 production path); the kernel itself
+    never declines — a shape its layout cannot hold is a ValueError,
+    and multi-token queries are such a shape (decode is one token per
+    step)."""
     args = _case(2, 4, 2, 64, 8, 4, [13, 27])
-    assert pa.paged_decode_attention(*args) is None      # CPU backend
+    np.testing.assert_array_equal(
+        np.asarray(pa.paged_attention(*args)),           # CPU backend
+        np.asarray(pa.paged_decode_reference(*args)))
     q, kp, vp, pt, sl, kn, vn = args
+    assert pa.kernel_supports(q, kp)
     q3 = jnp.concatenate([q, q, q], axis=1)
-    assert pa.paged_decode_attention(q3, kp, vp, pt, sl, kn, vn,
-                                     interpret=True) is None
+    assert not pa.kernel_supports(q3, kp)
+    with pytest.raises(ValueError, match="unsupported shapes"):
+        pa.paged_decode_attention(q3, kp, vp, pt, sl, kn, vn,
+                                  interpret=True)
+    # Hkv*D must fill whole 128-lane tiles (the tiny preset's 4x16 does
+    # not): selected away from the kernel, never probed
+    q_s, kp_s = q[..., :16], kp[..., :16]
+    assert not pa.kernel_supports(q_s, kp_s)
 
 
 # ---------------------------------------------------------------------------

@@ -139,8 +139,8 @@ def test_averager_round_on_mesh_matches_host(strategy_name, devices, tmp_path):
             return WeightedAverage()
         # sgd for host-vs-mesh PARITY: adam steps are ~lr*sign(g), so a
         # reduction-order sign flip on a near-zero meta-gradient becomes
-        # a full-lr weight divergence (the round-4 on-chip lesson,
-        # TUNNEL_r04.md); adam behavior itself is covered by the
+        # a full-lr weight divergence (the round-4 on-chip lesson);
+        # adam behavior itself is covered by the
         # discrimination tests in test_engines.py
         return ParameterizedMerge(model, meta_epochs=2, meta_lr=0.3,
                                   per_tensor=True, meta_optimizer="sgd")
@@ -213,9 +213,16 @@ def test_multihost_env_detection(monkeypatch):
     monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
     assert not multihost._multiprocess_env()
 
-    # single-host TPU VMs set one hostname; only several workers signal a pod
+    # single-host TPU VMs set one hostname; only several workers signal a
+    # pod — and one hostname DECIDES: the metadata server is never asked
+    # (the chip tool's machine has no network)
+    monkeypatch.delenv("TPU_SKIP_MDS_QUERY")
+    monkeypatch.setattr(multihost, "_gce_tpu_worker_count",
+                        lambda: pytest.fail("single host went to the "
+                                            "network to ask"))
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
     assert not multihost._multiprocess_env()
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "w0,w1,w2,w3")
     assert multihost._multiprocess_env()
     monkeypatch.delenv("TPU_WORKER_HOSTNAMES")
@@ -227,6 +234,25 @@ def test_multihost_env_detection(monkeypatch):
     monkeypatch.delenv("SLURM_NTASKS")
     monkeypatch.setenv("JAX_COORDINATOR_ADDRESS", "10.0.0.1:1234")
     assert multihost._multiprocess_env()
+
+
+def test_metadata_query_is_bounded_by_a_hard_deadline(monkeypatch):
+    """A name lookup that stalls (urlopen's timeout does not bound it)
+    cannot hold start-up: the query is abandoned at the deadline and the
+    answer is "one host"."""
+    import time
+    import urllib.request
+
+    from distributedtraining_tpu.parallel import multihost
+
+    def stalled(*a, **kw):
+        time.sleep(5.0)
+        raise OSError("never answered")
+
+    monkeypatch.setattr(urllib.request, "urlopen", stalled)
+    t0 = time.monotonic()
+    assert multihost._gce_tpu_worker_count(deadline_s=0.2) == 1
+    assert time.monotonic() - t0 < 2.0
 
 
 def test_resolve_mesh_config():

@@ -193,7 +193,11 @@ def test_call_with_retry_recovers_then_gives_up():
 def test_async_artifacts_byte_identical_to_sync(setup):
     model, cfg, batch = setup
     t_sync, l_sync = _run_miner(model, batch, push_async=False)
-    t_async, l_async = _run_miner(model, batch, push_async=True)
+    # room for every push to wait its turn: at depth 1 a fast train loop
+    # (warm compile cache) supersedes a pending push by design, and the
+    # push COUNT then depends on machine speed — parity is the subject
+    t_async, l_async = _run_miner(model, batch, push_async=True,
+                                  push_queue_depth=4)
     assert l_sync.report.pushes == l_async.report.pushes >= 2
     assert t_sync._deltas["m0"] == t_async._deltas["m0"]
 

@@ -17,7 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_scale_aot_configs_fit(tmp_path):
     out = tmp_path / "scale.json"
-    env = dict(os.environ, DT_FORCE_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     # the script sets its own xla_force_host_platform_device_count
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(

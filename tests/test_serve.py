@@ -529,17 +529,24 @@ def test_http_frontend_round_trip(setup):
 # Persistent compilation cache (ROADMAP item 5, first half)
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_restart(tmp_path, setup, sink):
-    """--compile-cache-dir: a restarted serving process re-traces but
-    deserializes yesterday's executables — the cache directory gains NO
-    new entries for the identical bucket programs, and decode output
+def test_compile_cache_restart(tmp_path, setup, sink, monkeypatch):
+    """The persistent compile cache, placed from OUTSIDE through
+    ``JAX_COMPILATION_CACHE_DIR``: a restarted serving process re-traces
+    but deserializes yesterday's executables — the cache directory gains
+    NO new entries for the identical bucket programs, and decode output
     stays pinned. (In-memory jit caches are cleared to simulate the
     restart; compile.ms still counts the re-dispatches, now measuring
     cache-load cost.)"""
-    from neurons.common import enable_compile_cache
+    from distributedtraining_tpu.utils.platform import enable_compile_cache
     model, cfg, params, _, prompts = setup
     cache_dir = str(tmp_path / "xla-cache")
     refs = refs_for(model, params, prompts[:2], 6)
+    suite_dir = jax.config.jax_compilation_cache_dir
+    # a process started with the variable set has it mirrored into
+    # jax.config at import; this process is already running, so mirror
+    # it by hand — enable_compile_cache itself sets no directory then
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     try:
         def bucket_entries():
             # the serving programs proper (incidental one-op jit_<prim>
@@ -548,7 +555,8 @@ def test_compile_cache_restart(tmp_path, setup, sink):
                     if f.endswith("-cache")
                     and ("jit_prefill" in f or "jit_step" in f)}
 
-        enable_compile_cache(cache_dir)
+        assert enable_compile_cache() == cache_dir
+        assert jax.config.jax_compilation_cache_dir == cache_dir
         eng = GenerationEngine(model, params, max_slots=2, page_size=8)
         assert eng.generate(prompts[:2], 6) == refs
         eng.close()
@@ -568,7 +576,30 @@ def test_compile_cache_restart(tmp_path, setup, sink):
             f"restart recompiled fresh bucket programs: "
             f"{sorted(bucket_entries() - entries)}")
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
+        # back to the suite's cache (tests/conftest.py)
+        monkeypatch.undo()
+        jax.config.update("jax_compilation_cache_dir", suite_dir)
+        from jax._src import compilation_cache
+        compilation_cache.reset_cache()
+
+
+def test_compile_cache_default_is_fixed_repo_path(monkeypatch):
+    """Unset, every entry point lands on ONE fixed path —
+    ``<repo>/.jax_cache`` — never a temp name, pid or time (a cache that
+    moves never hits)."""
+    from distributedtraining_tpu.utils import platform
+    suite_dir = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert platform.enable_compile_cache() == os.path.join(
+            repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", suite_dir)
+        from jax._src import compilation_cache
+        compilation_cache.reset_cache()
 
 
 def test_run_config_serving_flags():
@@ -576,7 +607,7 @@ def test_run_config_serving_flags():
     cfg = RunConfig.from_args("server", [
         "--serve-port", "8123", "--serve-slots", "4", "--page-size", "8",
         "--kv-pages", "64", "--max-new-tokens", "32", "--swap-policy",
-        "restart", "--swap-poll", "2.5", "--compile-cache-dir", "/tmp/cc",
+        "restart", "--swap-poll", "2.5",
         "--model", "tiny", "--backend", "memory"])
     assert cfg.role == "server"
     assert cfg.serve_port == 8123
@@ -586,12 +617,9 @@ def test_run_config_serving_flags():
     assert cfg.serve_max_new == 32
     assert cfg.swap_policy == "restart"
     assert cfg.swap_poll == 2.5
-    assert cfg.compile_cache_dir == "/tmp/cc"
-    # every role grows the cache flag (restarts of ALL roles skip
-    # recompiles)
-    for role in ("miner", "validator", "averager"):
-        c = RunConfig.from_args(role, ["--compile-cache-dir", "/tmp/cc"])
-        assert c.compile_cache_dir == "/tmp/cc"
+    # the compile cache has one placement knob and it is not a flag
+    # (JAX_COMPILATION_CACHE_DIR, utils/platform.enable_compile_cache)
+    assert not hasattr(cfg, "compile_cache_dir")
 
 
 # ---------------------------------------------------------------------------

@@ -554,8 +554,8 @@ def test_signed_transport_signs_manifest_and_passes_shards(tmp_path):
     from distributedtraining_tpu.transport.signed import SignedTransport
     from distributedtraining_tpu.utils.identity import Identity
 
-    ident = Identity.generate("m0")
-    keys = {"m0": ident.public_bytes()}
+    ident = Identity.generate()
+    keys = {"m0": ident.public_bytes}
     inner = CountingFS(str(tmp_path / "fs"))
     signed = SignedTransport(inner, identity=ident,
                              pubkey_resolver=keys.get, my_hotkey="m0")

@@ -1,10 +1,9 @@
 """Flash-attention numerics on the real chip: forward AND grad parity vs the
 dense oracle at T in {256, 1024}, packed segments included.
 
-This is the on-device half of tests/test_flash_attention.py (whose kernel
-parity cases skip under the CPU-forcing conftest). The +14%/+16% train-path
-claims (models/gpt2.py) and the custom _block_sizes schedule
-(ops/flash_attention.py) rest on these numerics.
+This is the on-device half of tests/test_flash_attention.py (which pins
+the selection rule on CPU). The custom _block_sizes schedule
+(ops/flash_attention.py) rests on these numerics.
 """
 
 import jax
