@@ -361,6 +361,12 @@ def _time_gather_deltas(*, n_miners: int = 4, latency_s: float = 0.05,
       ingest_speedup_cold/warm    serial / pooled wall-clock
       ingest_warm_downloads       artifact fetches in the warm round
                                   (acceptance: exactly 0)
+      ingest_inflight_serial/cold the most artifact fetches in flight at
+                                  one instant, from the start and end
+                                  stamps every fetch records (serial:
+                                  exactly 1; pooled: the overlap itself,
+                                  whatever the box's load does to the
+                                  wall-clock ratio)
       ingest_parity               accepted ids + delta bytes identical in
                                   both modes
 
@@ -384,12 +390,26 @@ def _time_gather_deltas(*, n_miners: int = 4, latency_s: float = 0.05,
     tmp = tempfile.mkdtemp(prefix="ingest_bench_")
     try:
         downloads = []
+        fetch_stamps: list = []     # (start, end) of every artifact fetch
 
         class SlowFS(LocalFSTransport):
             def fetch_delta_bytes(self, miner_id):
+                t_in = time.perf_counter()
                 time.sleep(latency_s)   # simulated network pull
                 downloads.append(miner_id)
-                return super().fetch_delta_bytes(miner_id)
+                try:
+                    return super().fetch_delta_bytes(miner_id)
+                finally:
+                    fetch_stamps.append((t_in, time.perf_counter()))
+
+        def most_in_flight(stamps) -> int:
+            edges = sorted([(a, 1) for a, _ in stamps]
+                           + [(b, -1) for _, b in stamps])
+            most = level = 0
+            for _, step in edges:
+                level += step
+                most = max(most, level)
+            return most
 
         transport = SlowFS(tmp)
         hotkeys = [f"m{i}" for i in range(n_miners)]
@@ -424,11 +444,18 @@ def _time_gather_deltas(*, n_miners: int = 4, latency_s: float = 0.05,
             t_serial, t_cold, t_warm = [], [], []
             staged_serial = staged_cold = staged_warm = None
             warm_downloads = 0
+            inflight_serial = inflight_cold = 0
             for _ in range(trials):
+                fetch_stamps.clear()
                 dt, staged_serial = timed(serial, clear=True)
                 t_serial.append(dt)
+                inflight_serial = max(inflight_serial,
+                                      most_in_flight(fetch_stamps))
+                fetch_stamps.clear()
                 dt, staged_cold = timed(pooled, clear=True)
                 t_cold.append(dt)
+                inflight_cold = max(inflight_cold,
+                                    most_in_flight(fetch_stamps))
                 downloads.clear()
                 dt, staged_warm = timed(pooled, clear=False)
                 t_warm.append(dt)
@@ -454,6 +481,8 @@ def _time_gather_deltas(*, n_miners: int = 4, latency_s: float = 0.05,
                 "ingest_speedup_warm": round(ser_ms / max(warm_ms, 1e-9),
                                              3),
                 "ingest_warm_downloads": warm_downloads,
+                "ingest_inflight_serial": inflight_serial,
+                "ingest_inflight_cold": inflight_cold,
                 "ingest_parity": bool(parity),
             }
         finally:
@@ -1009,7 +1038,6 @@ def _time_serve(*, n_requests: int = 8, prompt_len: int = 16,
         naive_tps = total / naive_s
         engine_tps = total / engine_s
         step_p = reg.histogram("serve.step_ms").percentiles((50.0, 95.0))
-        tok_p = reg.histogram("serve.token_ms").percentiles((50.0, 95.0))
         # hot swap: stage off-line (as the watcher thread would), then one
         # idle-engine step installs it; the stall is what the decode loop
         # actually paused for
@@ -1156,8 +1184,7 @@ def _time_serve(*, n_requests: int = 8, prompt_len: int = 16,
             "serve_batched_tokens_per_sec": round(engine_tps, 1),
             "serve_speedup": round(engine_tps / naive_tps, 3),
             "serve_batch": n_requests,
-            "serve_token_ms_p50": round(tok_p["p50"], 3),
-            "serve_token_ms_p95": round(tok_p["p95"], 3),
+            "serve_step_ms_p50": round(step_p["p50"], 3),
             "serve_step_ms_p95": round(step_p["p95"], 3),
             "serve_swap_stall_ms": round(swap_ms, 3),
             "serve_swap_under_step_p95": bool(swap_ms < step_p["p95"]),
@@ -1719,6 +1746,7 @@ def _time_devprof_overhead(*, steps: int = 100, trials: int = 2,
                 observed["prog_achieved"] = devprof.achieved_fractions()
                 for r in recs:
                     if r.prog == "train.step":
+                        observed["devprof_train_step_calls"] = r.calls
                         observed["devprof_train_step_flops"] = r.flops
                         observed["devprof_train_step_bytes"] = \
                             r.bytes_accessed

@@ -167,7 +167,15 @@ class ServeRequest:
     # the bit-identity anchor of the cross-worker contract.
     kv_ref: str | None = None
     first_token: int | None = None
+    # wall clock, for what is printed (reqtrace, the HTTP layer); every
+    # latency is computed on the monotonic pair below
     submitted_t: float = dataclasses.field(default_factory=time.time)
+    submitted_pc: float = dataclasses.field(
+        default_factory=time.perf_counter)
+    # perf_counter at each token's emit, one per entry of ``tokens``: two
+    # tokens a caller receives from ONE step() still carry their own
+    # stamps (the first from the prefill, the second from the decode)
+    emit_t: list = dataclasses.field(default_factory=list)
     done_evt: threading.Event = dataclasses.field(
         default_factory=threading.Event)
 
@@ -182,8 +190,6 @@ class _Slot:
     seq_len: int         # tokens currently in the KV cache
     last_tok: int        # next input token (already emitted to req.tokens)
     order: int           # admission order (preemption picks the youngest)
-    last_emit_t: float = 0.0   # perf_counter at the last emitted token
-    #                            (drives the per-token serve.tpot_ms)
     spec_window: int = 0  # drafts allowed THIS step (set by _grow: the
     #                       pages for seq_len..seq_len+spec_window are
     #                       owned exclusively; 0 = plain-decode lane)
@@ -918,6 +924,7 @@ class GenerationEngine:
 
     def _requeue_front(self, req: ServeRequest) -> None:
         req.tokens.clear()
+        req.emit_t.clear()
         req.status = "queued"
         with self._qlock:
             self._queue.appendleft(req)
@@ -1022,7 +1029,8 @@ class GenerationEngine:
         mp = t_bucket // P
         stack_kv = self._stack_kv
 
-        def prefill(params, tokens, prompt_len, k_pages, v_pages, page_row):
+        def serve_prefill(params, tokens, prompt_len, k_pages, v_pages,
+                          page_row):
             amask = (jnp.arange(t_bucket)[None, :]
                      < prompt_len).astype(jnp.int32)
             logits, muts = model.apply(
@@ -1042,7 +1050,7 @@ class GenerationEngine:
 
         prog = devprof.wrap(
             "serve.prefill",
-            jax.jit(prefill,
+            jax.jit(serve_prefill,
                     donate_argnums=(3, 4) if self._donate else ()),
             bucket=t_bucket)
         self._prefill_progs[t_bucket] = prog
@@ -1056,7 +1064,8 @@ class GenerationEngine:
         L = len(self._layers)
         stack_kv = self._stack_kv
 
-        def step(params, k_pages, v_pages, page_tables, seq_lens, tokens):
+        def serve_decode(params, k_pages, v_pages, page_tables, seq_lens,
+                         tokens):
             # paged attention: each block reads its OWN page-pool slice
             # directly through the table (ops/paged_attention.py — the
             # fused gather+attend kernel on TPU, its XLA twin off-TPU).
@@ -1080,7 +1089,8 @@ class GenerationEngine:
 
         prog = devprof.wrap(
             "serve.decode",
-            jax.jit(step, donate_argnums=(1, 2) if self._donate else ()),
+            jax.jit(serve_decode,
+                    donate_argnums=(1, 2) if self._donate else ()),
             bucket=f"{n_slots}x{n_pages}")
         self._decode_progs[(n_slots, n_pages)] = prog
         return prog
@@ -1098,8 +1108,9 @@ class GenerationEngine:
         L = len(self._layers)
         stack_kv = self._stack_kv
 
-        def step_sample(params, k_pages, v_pages, page_tables, seq_lens,
-                        tokens, temps, top_ps, seeds, tok_idx):
+        def serve_decode_sample(params, k_pages, v_pages, page_tables,
+                                seq_lens, tokens, temps, top_ps, seeds,
+                                tok_idx):
             kv_pages = tuple((k_pages[i], v_pages[i]) for i in range(L))
             logits, muts = model.apply(
                 {"params": params}, tokens[:, None],
@@ -1119,7 +1130,7 @@ class GenerationEngine:
 
         prog = devprof.wrap(
             "serve.decode_sample",
-            jax.jit(step_sample,
+            jax.jit(serve_decode_sample,
                     donate_argnums=(1, 2) if self._donate else ()),
             bucket=f"{n_slots}x{n_pages}")
         self._decode_sample_progs[(n_slots, n_pages)] = prog
@@ -1141,8 +1152,8 @@ class GenerationEngine:
         cap = self.max_seq_len
         stack_kv = self._stack_kv
 
-        def prefill_ctx(params, tokens, ctx_len, suffix_len,
-                        k_pages, v_pages, page_table):
+        def serve_prefill_ctx(params, tokens, ctx_len, suffix_len,
+                              k_pages, v_pages, page_table):
             kv_pages = tuple((k_pages[i], v_pages[i]) for i in range(L))
             pos = ctx_len + jnp.arange(t_bucket)
             logits, muts = model.apply(
@@ -1164,7 +1175,7 @@ class GenerationEngine:
 
         prog = devprof.wrap(
             "serve.prefill_ctx",
-            jax.jit(prefill_ctx,
+            jax.jit(serve_prefill_ctx,
                     donate_argnums=(4, 5) if self._donate else ()),
             bucket=f"{t_bucket}x{pb}")
         self._prefill_ctx_progs[(t_bucket, pb)] = prog
@@ -1199,8 +1210,8 @@ class GenerationEngine:
         cap = self.max_seq_len
         stack_kv = self._stack_kv
 
-        def verify(params, k_pages, v_pages, page_tables, seq_lens,
-                   tokens, n_input, temps, top_ps, seeds, tok_idx0):
+        def serve_verify(params, k_pages, v_pages, page_tables, seq_lens,
+                         tokens, n_input, temps, top_ps, seeds, tok_idx0):
             kv_pages = tuple((k_pages[i], v_pages[i]) for i in range(L))
             pos = seq_lens[:, None] + jnp.arange(W)[None, :]   # [B, W]
             logits, muts = model.apply(
@@ -1230,7 +1241,7 @@ class GenerationEngine:
 
         prog = devprof.wrap(
             "serve.verify",
-            jax.jit(verify,
+            jax.jit(serve_verify,
                     donate_argnums=(1, 2) if self._donate else ()),
             bucket=f"{n_slots}x{n_pages}")
         self._verify_progs[(n_slots, n_pages)] = prog
@@ -1242,12 +1253,13 @@ class GenerationEngine:
         compiled once at the first sampled admission)."""
         prog = self._sample_tok_prog_
         if prog is None:
-            def sample_tok(row, temp, top_p, seed, tok_idx):
+            def serve_sample_tok(row, temp, top_p, seed, tok_idx):
                 return _sample_from_logits(
                     row[None, :], temp[None], top_p[None], seed[None],
                     tok_idx[None])[0]
 
-            prog = devprof.wrap("serve.sample_tok", jax.jit(sample_tok),
+            prog = devprof.wrap("serve.sample_tok",
+                                jax.jit(serve_sample_tok),
                                 bucket=1)
             self._sample_tok_prog_ = prog
         args = (row, np.float32(req.temperature), np.float32(req.top_p),
@@ -1263,13 +1275,13 @@ class GenerationEngine:
         they stay masked behind ``kv_lens`` until overwritten."""
         prog = self._page_copy_prog_
         if prog is None:
-            def page_copy(k_pages, v_pages, src, dst):
+            def serve_page_copy(k_pages, v_pages, src, dst):
                 return (k_pages.at[:, dst].set(k_pages[:, src]),
                         v_pages.at[:, dst].set(v_pages[:, src]))
 
             prog = devprof.wrap(
                 "serve.page_copy",
-                jax.jit(page_copy,
+                jax.jit(serve_page_copy,
                         donate_argnums=(0, 1) if self._donate else ()),
                 bucket=1)
             self._page_copy_prog_ = prog
@@ -1485,7 +1497,7 @@ class GenerationEngine:
         # queue age (submit -> admission attempt): the "how long did
         # this request wait" half of TTFT — exported for fleet_report's
         # q_age95 column whether or not per-request tracing is on
-        queue_age_ms = max(0.0, (time.time() - req.submitted_t) * 1e3)
+        queue_age_ms = (time.perf_counter() - req.submitted_pc) * 1e3
         if req.kv_ref is not None and self._kv_adopter is not None:
             verdict = self._try_adopt(req, queue_age_ms)
             if verdict == "ok":
@@ -1545,10 +1557,21 @@ class GenerationEngine:
             else:
                 self.trace.stage(req.rid, "admit",
                                  queue_age_ms=queue_age_ms)
+        # to completion: both return the first token ON THE HOST, and the
+        # `serve.prefill` span's time around it
         if matched:
-            self._prefill_shared(req, pages, matched)
+            tok, dur_ms = self._prefill_shared(req, pages, matched)
         else:
-            self._prefill(req, pages)
+            tok, dur_ms = self._prefill(req, pages)
+        obs.count("serve.prefills")
+        self.prefills_done += 1
+        if self.trace is not None:
+            self.trace.stage(req.rid, "prefill", pfx_hit=int(matched > 0),
+                             pfx_tokens=matched, prompt_tokens=plen,
+                             dur_ms=round(dur_ms, 3))
+        if self._cache is not None and not matched:
+            self._cache.register(list(req.prompt), pages)
+        self._activate(req, pages, tok)
         return True
 
     def _try_adopt(self, req: ServeRequest, queue_age_ms: float) -> str:
@@ -1670,9 +1693,11 @@ class GenerationEngine:
             req.kv_ref = kv_ref
         req.first_token = int(nxt)
         req.tokens.append(int(nxt))
+        now = time.perf_counter()
+        req.emit_t.append(now)
         self.tokens_emitted += 1
         obs.count("serve.tokens")
-        ttft_ms = max(0.0, (time.time() - req.submitted_t) * 1e3)
+        ttft_ms = (now - req.submitted_pc) * 1e3
         obs.observe("serve.ttft_ms", ttft_ms)
         for p in pages:
             self.pool.decref(p)
@@ -1686,83 +1711,76 @@ class GenerationEngine:
         req.done_evt.set()
         self._admit_hold = False
 
-    def _prefill(self, req: ServeRequest, pages: list) -> None:
+    def _prefill(self, req: ServeRequest, pages: list) -> tuple[int, float]:
+        """Full prefill. Returns the first token and the milliseconds of
+        its `serve.prefill` span: input build, dispatch and the token's
+        arrival on the host."""
         P = self.page_size
         plen = len(req.prompt)
         t_bucket = self._prefill_ladder.bucket_for(
             (plen + P - 1) // P) * P
-        mp = t_bucket // P
-        toks = np.zeros((1, t_bucket), np.int32)
-        toks[0, :plen] = req.prompt
-        page_row = np.zeros((mp,), np.int32)
-        row = pages[:mp]
-        page_row[:len(row)] = row
-        prog = self._prefill_prog(t_bucket)
-        k_pages, v_pages = self._kv
-        t0 = time.perf_counter()
-        if self._prefill_ladder.mark(t_bucket // P):
-            obs.count("serve.prefill_bucket_compiles")
-            nxt, logit_row, k_pages, v_pages = _timed_compile(
-                prog, self._params, toks, np.int32(plen),
-                k_pages, v_pages, page_row)
-        else:
-            nxt, logit_row, k_pages, v_pages = prog(
-                self._params, toks, np.int32(plen), k_pages, v_pages,
-                page_row)
-        self._kv = (k_pages, v_pages)
-        dur_ms = (time.perf_counter() - t0) * 1e3
-        obs.observe("serve.prefill_ms", dur_ms)
-        obs.count("serve.prefills")
-        self.prefills_done += 1
-        if self.trace is not None:
-            self.trace.stage(req.rid, "prefill", pfx_hit=0, pfx_tokens=0,
-                             prompt_tokens=plen, dur_ms=round(dur_ms, 3))
-        if self._cache is not None:
-            self._cache.register(list(req.prompt), pages)
-        self._activate(req, pages, self._first_token(req, nxt, logit_row))
+        with obs.phase("serve.prefill", timed=True, rid=req.rid,
+                       bucket=t_bucket) as ph:
+            mp = t_bucket // P
+            toks = np.zeros((1, t_bucket), np.int32)
+            toks[0, :plen] = req.prompt
+            page_row = np.zeros((mp,), np.int32)
+            row = pages[:mp]
+            page_row[:len(row)] = row
+            prog = self._prefill_prog(t_bucket)
+            k_pages, v_pages = self._kv
+            if self._prefill_ladder.mark(t_bucket // P):
+                obs.count("serve.prefill_bucket_compiles")
+                nxt, logit_row, k_pages, v_pages = _timed_compile(
+                    prog, self._params, toks, np.int32(plen),
+                    k_pages, v_pages, page_row)
+            else:
+                nxt, logit_row, k_pages, v_pages = prog(
+                    self._params, toks, np.int32(plen), k_pages, v_pages,
+                    page_row)
+            self._kv = (k_pages, v_pages)
+            tok = self._first_token(req, nxt, logit_row)
+        return tok, ph.dur_ms
 
     def _prefill_shared(self, req: ServeRequest, pages: list,
-                        ctx_len: int) -> None:
+                        ctx_len: int) -> tuple[int, float]:
         """Suffix prefill: ``ctx_len`` prompt tokens already live in
-        shared cache pages; only the tail runs the model."""
+        shared cache pages; only the tail runs the model. Returns what
+        ``_prefill`` does."""
         P = self.page_size
         plen = len(req.prompt)
         suffix = plen - ctx_len
         t_bucket = self._pctx_t_ladder.bucket_for(
             (suffix + P - 1) // P) * P
         pb = self._pctx_p_ladder.bucket_for(plen // P + 1)
-        toks = np.zeros((1, t_bucket), np.int32)
-        toks[0, :suffix] = req.prompt[ctx_len:]
-        table = np.zeros((1, pb), np.int32)
-        table[0, :len(pages)] = pages
-        prog = self._prefill_ctx_prog(t_bucket, pb)
-        k_pages, v_pages = self._kv
-        t0 = time.perf_counter()
-        key = (t_bucket, pb)
-        self._pctx_t_ladder.mark(t_bucket // P)
-        self._pctx_p_ladder.mark(pb)
-        if key not in self._pctx_seen:
-            self._pctx_seen.add(key)
-            obs.count("serve.prefill_bucket_compiles")
-            nxt, logit_row, k_pages, v_pages = _timed_compile(
-                prog, self._params, toks, np.int32(ctx_len),
-                np.int32(suffix), k_pages, v_pages, table)
-        else:
-            nxt, logit_row, k_pages, v_pages = prog(
-                self._params, toks, np.int32(ctx_len), np.int32(suffix),
-                k_pages, v_pages, table)
-        self._kv = (k_pages, v_pages)
-        dur_ms = (time.perf_counter() - t0) * 1e3
-        obs.observe("serve.prefill_ms", dur_ms)
-        obs.count("serve.prefills")
-        self.prefills_done += 1
-        if self.trace is not None:
-            self.trace.stage(req.rid, "prefill", pfx_hit=1,
-                             pfx_tokens=ctx_len, prompt_tokens=plen,
-                             dur_ms=round(dur_ms, 3))
-        self._activate(req, pages, self._first_token(req, nxt, logit_row))
+        with obs.phase("serve.prefill", timed=True, rid=req.rid,
+                       bucket=t_bucket, ctx_pages=pb) as ph:
+            toks = np.zeros((1, t_bucket), np.int32)
+            toks[0, :suffix] = req.prompt[ctx_len:]
+            table = np.zeros((1, pb), np.int32)
+            table[0, :len(pages)] = pages
+            prog = self._prefill_ctx_prog(t_bucket, pb)
+            k_pages, v_pages = self._kv
+            key = (t_bucket, pb)
+            self._pctx_t_ladder.mark(t_bucket // P)
+            self._pctx_p_ladder.mark(pb)
+            if key not in self._pctx_seen:
+                self._pctx_seen.add(key)
+                obs.count("serve.prefill_bucket_compiles")
+                nxt, logit_row, k_pages, v_pages = _timed_compile(
+                    prog, self._params, toks, np.int32(ctx_len),
+                    np.int32(suffix), k_pages, v_pages, table)
+            else:
+                nxt, logit_row, k_pages, v_pages = prog(
+                    self._params, toks, np.int32(ctx_len), np.int32(suffix),
+                    k_pages, v_pages, table)
+            self._kv = (k_pages, v_pages)
+            tok = self._first_token(req, nxt, logit_row)
+        return tok, ph.dur_ms
 
     def _first_token(self, req: ServeRequest, nxt, logit_row) -> int:
+        """The prefill's pick on the host: the host waits for the device
+        HERE, which is why `serve.prefill` ends after it."""
         if req.temperature > 0.0:
             return self._sample_tok(logit_row, req, 0)
         return int(nxt)
@@ -1784,28 +1802,29 @@ class GenerationEngine:
         slot.req.tokens.append(tok)
         self.tokens_emitted += 1
         obs.count("serve.tokens")
-        # request-level latency attribution: TTFT = queue admit (submit
-        # wall clock) -> first token, including queue wait — the number a
+        # request-level latency attribution: TTFT = submit -> first
+        # token (both on perf_counter), including queue wait — the number a
         # CALLER experiences, which tokens/sec alone cannot show; TPOT =
         # the wall gap between this slot's consecutive tokens (decode
         # step + scheduler overhead as one per-token figure). Both export
         # as dt_serve_ttft_ms_* / dt_serve_tpot_ms_* gauges and ride the
         # server heartbeat into fleet_report's ttft95/tpot95 columns.
         now = time.perf_counter()
-        if len(slot.req.tokens) == 1:
-            ttft_ms = max(0.0, (time.time() - slot.req.submitted_t) * 1e3)
+        stamps = slot.req.emit_t
+        stamps.append(now)
+        if len(stamps) == 1:
+            ttft_ms = (now - slot.req.submitted_pc) * 1e3
             obs.observe("serve.ttft_ms", ttft_ms)
             if self.trace is not None:
                 self.trace.note_latency(slot.req.rid, ttft_ms=ttft_ms)
-        elif slot.last_emit_t:
-            tpot_ms = (now - slot.last_emit_t) * 1e3
+        else:
+            tpot_ms = (now - stamps[-2]) * 1e3
             obs.observe("serve.tpot_ms", tpot_ms)
             if self.trace is not None:
                 # lazy: fold into the slot; _trace_flush hands the
                 # weighted sum to note_latency in one call per run
                 slot.tr_tpot_sum += tpot_ms
                 slot.tr_tpot_n += 1
-        slot.last_emit_t = now
         if (self.eos_id is not None and tok == self.eos_id) or \
                 len(slot.req.tokens) >= slot.req.max_new_tokens:
             self._finish(slot, "done")
@@ -1898,67 +1917,85 @@ class GenerationEngine:
         draft = self._draft
         t0 = time.perf_counter()
         proposals: dict[int, list] = {}
-        if any(s.spec_window > 0 for s in active):
-            try:
-                proposals = draft.propose(active) or {}
-            except Exception:
-                # a broken drafter must never break serving: this round
-                # verifies an empty window (= plain decode)
-                logger.exception("draft propose failed; "
-                                 "plain-decoding this step")
-                obs.count("serve.spec_fallbacks")
-                proposals = {}
+        # the four decode phases of the plain path; the drafter's own
+        # dispatches and fetches count as dispatch
+        with obs.phase("serve.decode.dispatch", live=len(active)):
+            if any(s.spec_window > 0 for s in active):
+                try:
+                    proposals = draft.propose(active) or {}
+                except Exception:
+                    # a broken drafter must never break serving: this
+                    # round verifies an empty window (= plain decode)
+                    logger.exception("draft propose failed; "
+                                     "plain-decoding this step")
+                    obs.count("serve.spec_fallbacks")
+                    proposals = {}
         obs.observe("serve.spec_draft_ms",
                     (time.perf_counter() - t0) * 1e3)
-        plan = {s.req.rid: [int(t) for t in
-                            proposals.get(s.req.rid, [])][:s.spec_window]
-                for s in active}
-        W = self.draft_k + 1
         t1 = time.perf_counter()
-        P = self.page_size
-        need_pages = max(
-            (s.seq_len + len(plan[s.req.rid])) // P + 1 for s in active)
-        sb, pb = self._decode_bucket(len(active), need_pages,
-                                     self._verify_progs)
-        tables = np.zeros((sb, pb), np.int32)
-        seq_lens = np.zeros((sb,), np.int32)
-        tokens = np.zeros((sb, W), np.int32)
-        n_input = np.zeros((sb,), np.int32)
-        temps = np.zeros((sb,), np.float32)
-        top_ps = np.ones((sb,), np.float32)
-        seeds = np.zeros((sb,), np.int32)
-        tok_idx0 = np.zeros((sb,), np.int32)
-        for i, slot in enumerate(active):
-            props = plan[slot.req.rid]
-            row = slot.pages[:pb]
-            tables[i, :len(row)] = row
-            seq_lens[i] = slot.seq_len
-            tokens[i, 0] = slot.last_tok
-            if props:
-                tokens[i, 1:1 + len(props)] = props
-            n_input[i] = 1 + len(props)
-            temps[i] = slot.req.temperature
-            top_ps[i] = slot.req.top_p
-            seeds[i] = slot.req.seed & 0x7FFFFFFF
-            tok_idx0[i] = len(slot.req.tokens)
-        prog = self._verify_prog(sb, pb)
-        k_pages, v_pages = self._kv
-        self._slot_ladder.mark(sb)
-        self._page_ladder.mark(pb)
-        args = (self._params, k_pages, v_pages, tables, seq_lens, tokens,
-                n_input, temps, top_ps, seeds, tok_idx0)
-        if (sb, pb) not in self._verify_seen:
-            self._verify_seen.add((sb, pb))
-            obs.count("serve.decode_bucket_compiles")
-            picks, k_pages, v_pages = _timed_compile(prog, *args)
-        else:
-            picks, k_pages, v_pages = prog(*args)
-        self._kv = (k_pages, v_pages)
-        picks = np.asarray(jax.device_get(picks))
+        with obs.phase("serve.decode.build"):
+            plan = {s.req.rid: [int(t) for t in
+                                proposals.get(s.req.rid, [])][:s.spec_window]
+                    for s in active}
+            W = self.draft_k + 1
+            P = self.page_size
+            need_pages = max((s.seq_len + len(plan[s.req.rid])) // P + 1
+                             for s in active)
+            sb, pb = self._decode_bucket(len(active), need_pages,
+                                         self._verify_progs)
+            tables = np.zeros((sb, pb), np.int32)
+            seq_lens = np.zeros((sb,), np.int32)
+            tokens = np.zeros((sb, W), np.int32)
+            n_input = np.zeros((sb,), np.int32)
+            temps = np.zeros((sb,), np.float32)
+            top_ps = np.ones((sb,), np.float32)
+            seeds = np.zeros((sb,), np.int32)
+            tok_idx0 = np.zeros((sb,), np.int32)
+            for i, slot in enumerate(active):
+                props = plan[slot.req.rid]
+                row = slot.pages[:pb]
+                tables[i, :len(row)] = row
+                seq_lens[i] = slot.seq_len
+                tokens[i, 0] = slot.last_tok
+                if props:
+                    tokens[i, 1:1 + len(props)] = props
+                n_input[i] = 1 + len(props)
+                temps[i] = slot.req.temperature
+                top_ps[i] = slot.req.top_p
+                seeds[i] = slot.req.seed & 0x7FFFFFFF
+                tok_idx0[i] = len(slot.req.tokens)
+            prog = self._verify_prog(sb, pb)
+            k_pages, v_pages = self._kv
+            self._slot_ladder.mark(sb)
+            self._page_ladder.mark(pb)
+            args = (self._params, k_pages, v_pages, tables, seq_lens,
+                    tokens, n_input, temps, top_ps, seeds, tok_idx0)
+        with obs.phase("serve.decode.dispatch", slots=sb, pages=pb,
+                       live=len(active)):
+            if (sb, pb) not in self._verify_seen:
+                self._verify_seen.add((sb, pb))
+                obs.count("serve.decode_bucket_compiles")
+                picks, k_pages, v_pages = _timed_compile(prog, *args)
+            else:
+                picks, k_pages, v_pages = prog(*args)
+            self._kv = (k_pages, v_pages)
+        with obs.phase("serve.decode.fetch"):
+            picks = np.asarray(jax.device_get(picks))
         obs.observe("serve.spec_verify_ms",
                     (time.perf_counter() - t1) * 1e3)
+        with obs.phase("serve.decode.emit"):
+            emitted = self._commit_spec(plan, picks)
+        self._spec_rounds += 1
+        if self._spec_proposed:
+            obs.gauge("serve.spec_accept_rate", self.spec_accept_rate)
+        return emitted
+
+    def _commit_spec(self, plan: dict, picks) -> int:
+        """Each slot commits the longest prefix of its proposals that
+        matches the target's picks, plus the pick at the divergence."""
+        draft = self._draft
         emitted = 0
-        for i, slot in enumerate(list(active)):
+        for i, slot in enumerate(list(self._active)):
             props = plan[slot.req.rid]
             j = 0
             while j < len(props) and props[j] == int(picks[i, j]):
@@ -1986,81 +2023,81 @@ class GenerationEngine:
             if slot.req.status == "active":
                 draft.commit(slot.req.rid,
                              list(slot.req.prompt) + list(slot.req.tokens))
-        self._spec_rounds += 1
-        if self._spec_proposed:
-            obs.gauge("serve.spec_accept_rate", self.spec_accept_rate)
         return emitted
 
     def _decode_plain(self) -> int:
         active = self._active
         if not active:
             return 0
-        sampled = any(s.req.temperature > 0.0 for s in active)
-        progs = self._decode_sample_progs if sampled else self._decode_progs
-        need_pages = max(s.seq_len // self.page_size + 1 for s in active)
-        sb, pb = self._decode_bucket(len(active), need_pages, progs)
-        tables = np.zeros((sb, pb), np.int32)
-        seq_lens = np.zeros((sb,), np.int32)
-        tokens = np.zeros((sb,), np.int32)
-        for i, slot in enumerate(active):
-            row = slot.pages[:pb]
-            tables[i, :len(row)] = row
-            seq_lens[i] = slot.seq_len
-            tokens[i] = slot.last_tok
-        k_pages, v_pages = self._kv
-        self._slot_ladder.mark(sb)
-        self._page_ladder.mark(pb)
-        if sampled:
-            # one program serves any greedy/sampled mix: temperature 0
-            # lanes argmax inside the jitted sampler, so batch
-            # composition never forces a recompile
-            temps = np.zeros((sb,), np.float32)
-            top_ps = np.ones((sb,), np.float32)
-            seeds = np.zeros((sb,), np.int32)
-            tok_idx = np.zeros((sb,), np.int32)
+        with obs.phase("serve.decode.build"):
+            sampled = any(s.req.temperature > 0.0 for s in active)
+            progs = (self._decode_sample_progs if sampled
+                     else self._decode_progs)
+            need_pages = max(s.seq_len // self.page_size + 1
+                             for s in active)
+            sb, pb = self._decode_bucket(len(active), need_pages, progs)
+            tables = np.zeros((sb, pb), np.int32)
+            seq_lens = np.zeros((sb,), np.int32)
+            tokens = np.zeros((sb,), np.int32)
             for i, slot in enumerate(active):
-                temps[i] = slot.req.temperature
-                top_ps[i] = slot.req.top_p
-                seeds[i] = slot.req.seed & 0x7FFFFFFF
-                tok_idx[i] = len(slot.req.tokens)
-            prog = self._decode_sample_prog(sb, pb)
-            args = (self._params, k_pages, v_pages, tables, seq_lens,
-                    tokens, temps, top_ps, seeds, tok_idx)
-            if (sb, pb) not in self._decode_sample_seen:
-                self._decode_sample_seen.add((sb, pb))
+                row = slot.pages[:pb]
+                tables[i, :len(row)] = row
+                seq_lens[i] = slot.seq_len
+                tokens[i] = slot.last_tok
+            k_pages, v_pages = self._kv
+            self._slot_ladder.mark(sb)
+            self._page_ladder.mark(pb)
+            if sampled:
+                # one program serves any greedy/sampled mix: temperature
+                # 0 lanes argmax inside the jitted sampler, so batch
+                # composition never forces a recompile
+                temps = np.zeros((sb,), np.float32)
+                top_ps = np.ones((sb,), np.float32)
+                seeds = np.zeros((sb,), np.int32)
+                tok_idx = np.zeros((sb,), np.int32)
+                for i, slot in enumerate(active):
+                    temps[i] = slot.req.temperature
+                    top_ps[i] = slot.req.top_p
+                    seeds[i] = slot.req.seed & 0x7FFFFFFF
+                    tok_idx[i] = len(slot.req.tokens)
+                prog = self._decode_sample_prog(sb, pb)
+                seen = self._decode_sample_seen
+                args = (self._params, k_pages, v_pages, tables, seq_lens,
+                        tokens, temps, top_ps, seeds, tok_idx)
+            else:
+                prog = self._decode_prog(sb, pb)
+                seen = self._decode_seen
+                args = (self._params, k_pages, v_pages, tables, seq_lens,
+                        tokens)
+        with obs.phase("serve.decode.dispatch", slots=sb, pages=pb,
+                       live=len(active)):
+            if (sb, pb) not in seen:
+                seen.add((sb, pb))
                 obs.count("serve.decode_bucket_compiles")
                 nxt, k_pages, v_pages = _timed_compile(prog, *args)
             else:
                 nxt, k_pages, v_pages = prog(*args)
-        else:
-            prog = self._decode_prog(sb, pb)
-            if (sb, pb) not in self._decode_seen:
-                self._decode_seen.add((sb, pb))
-                obs.count("serve.decode_bucket_compiles")
-                nxt, k_pages, v_pages = _timed_compile(
-                    prog, self._params, k_pages, v_pages, tables, seq_lens,
-                    tokens)
-            else:
-                nxt, k_pages, v_pages = prog(self._params, k_pages, v_pages,
-                                             tables, seq_lens, tokens)
-        self._kv = (k_pages, v_pages)
-        nxt = np.asarray(jax.device_get(nxt))
-        emitted = 0
-        trace_t = self.trace.clock() if self.trace is not None else 0.0
-        for i, slot in enumerate(list(active)):
-            slot.seq_len += 1
-            slot.last_tok = int(nxt[i])
-            if self.trace is not None:
-                # lazy per-slot accumulation: the hot path is three
-                # scalar bumps against one hoisted clock read — the
-                # timeline gets one coalesced span at _trace_flush
-                # (spec/cow/preempt/finish), zero device work
-                if slot.tr_decode_n == 0:
-                    slot.tr_decode_t0 = trace_t
-                slot.tr_decode_n += 1
-                slot.tr_decode_t1 = trace_t
-            self._emit(slot, int(nxt[i]))
-            emitted += 1
+            self._kv = (k_pages, v_pages)
+        with obs.phase("serve.decode.fetch"):
+            # the host waits for the device here
+            nxt = np.asarray(jax.device_get(nxt))
+        with obs.phase("serve.decode.emit"):
+            emitted = 0
+            trace_t = self.trace.clock() if self.trace is not None else 0.0
+            for i, slot in enumerate(list(active)):
+                slot.seq_len += 1
+                slot.last_tok = int(nxt[i])
+                if self.trace is not None:
+                    # lazy per-slot accumulation: the hot path is three
+                    # scalar bumps against one hoisted clock read — the
+                    # timeline gets one coalesced span at _trace_flush
+                    # (spec/cow/preempt/finish), zero device work
+                    if slot.tr_decode_n == 0:
+                        slot.tr_decode_t0 = trace_t
+                    slot.tr_decode_n += 1
+                    slot.tr_decode_t1 = trace_t
+                self._emit(slot, int(nxt[i]))
+                emitted += 1
         return emitted
 
     def step(self) -> dict:
@@ -2069,17 +2106,20 @@ class GenerationEngine:
         if self._params is None:
             raise RuntimeError("no base installed; call install_params "
                                "(or attach a watcher and publish a base)")
-        t0 = time.perf_counter()
-        self._maybe_swap()
-        self._admit()
-        self._grow()
-        emitted = self._decode()
-        dur = time.perf_counter() - t0
+        # the phases below are host spans on the profiler's clock and
+        # feed serve.<phase>_ms (utils/obs.phase; docs/observability.md
+        # has the table); off, each is one branch (serve.step is timed
+        # either way: the caller gets step_ms)
+        with obs.phase("serve.step", timed=True) as whole:
+            self._maybe_swap()
+            with obs.phase("serve.admit"):
+                self._admit()
+            with obs.phase("serve.grow"):
+                self._grow()
+            emitted = self._decode()
+        dur = whole.dur_ms / 1e3
         self.steps += 1
-        obs.observe("serve.step_ms", dur * 1e3)
         if emitted:
-            # one decode step IS each emitted token's latency
-            obs.observe("serve.token_ms", dur * 1e3)
             rate = emitted / max(dur, 1e-9)
             self._tok_rate_ema = rate if self._tok_rate_ema is None else (
                 self._tok_rate_ema + 0.2 * (rate - self._tok_rate_ema))
@@ -2090,7 +2130,7 @@ class GenerationEngine:
         if self.debug_invariants:
             self._check_invariants()
         return {"emitted": emitted, "active": len(self._active),
-                "queued": self.queue_depth, "step_ms": dur * 1e3,
+                "queued": self.queue_depth, "step_ms": whole.dur_ms,
                 "revision": self.revision}
 
     def _check_invariants(self) -> None:

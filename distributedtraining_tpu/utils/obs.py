@@ -11,6 +11,10 @@ This module is the one home of the structured layer every role emits:
   through the process's configured :class:`MetricsSink` (the same JSONL
   file the scalar metrics land in) and feed a latency histogram per span
   name. Spans nest; each record carries its parent and depth.
+- ``phase("serve.admit")`` is the hot-loop primitive beside ``span``: a
+  host span on the profiler's clock (``jax.profiler.TraceAnnotation``)
+  plus one histogram observation, no record. The serve engine's step is
+  timed with it.
 - a process-wide :class:`Registry` of counters and latency histograms
   (p50/p95/p99 from bounded ring reservoirs) with name linting —
   ``[a-z0-9_.]`` only, and one name cannot be both a counter and a
@@ -43,6 +47,7 @@ thread-local, so a worker thread must re-enter its artifact's id via
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import math
 import re
@@ -548,6 +553,98 @@ def fetch_cid(transport, miner_id: str) -> str | None:
         return rider_delta_id(fm(miner_id))
     except Exception:
         return None
+
+
+# ---------------------------------------------------------------------------
+# Phases
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=1024)
+def _phase_hist(name: str, hist: str | None) -> str:
+    """The histogram a phase feeds. Both names are linted here, once per
+    (name, hist) pair, not at every entry of a hot loop."""
+    check_metric_name(name)
+    return check_metric_name(hist or f"{name}_ms")
+
+
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported at the first enabled
+    phase; None in a process without jax (the histogram still fills)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+class _NoPhase:
+    """What ``phase`` returns while obs is off: one object for every call
+    site, so a disabled phase is one branch and no allocation."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_PHASE = _NoPhase()
+
+
+class _Phase:
+    __slots__ = ("_hist", "_annot", "_t0", "dur_ms")
+
+    def __init__(self, registry: Registry | None, name: str,
+                 hist: str | None, args: dict):
+        if registry is None:  # obs off, ``timed``: the clock pair alone
+            self._hist = self._annot = None
+            return
+        # the histogram of the registry that was live at entry: a phase
+        # that straddles reset() must not dirty the fresh state
+        self._hist = registry.histogram(_phase_hist(name, hist))
+        annot = _trace_annotation()
+        self._annot = None if annot is None else annot(name, **args)
+
+    def __enter__(self) -> "_Phase":
+        if self._annot is not None:
+            self._annot.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur_ms = (time.perf_counter() - self._t0) * 1e3
+        if self._annot is not None:
+            self._annot.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(self.dur_ms)
+        return False
+
+
+def phase(name: str, hist: str | None = None, *, timed: bool = False,
+          **args):
+    """Time one phase of a hot loop (``with obs.phase("serve.admit"):``).
+
+    Enabled, the phase is a ``jax.profiler.TraceAnnotation(name, **args)``
+    — a host span on the profiler's own clock, so a device trace can put
+    an idle gap down to it; start, end and nesting are kept per thread by
+    the profiler, not here — and one observation of the histogram
+    ``hist`` (default ``<name>_ms``). ``args`` are what ties the span to a
+    request or a shape (a request id, a bucket): pass values the site
+    already holds, they are formatted only inside a running profiler.
+    Nothing reaches the sink per close: the registry holds the histogram
+    and ``flush()`` writes it at the role's cadence. Disabled (no sink),
+    every call returns the same no-op object; a site that needs the time
+    with obs off too (``with obs.phase(..., timed=True) as ph`` and then
+    ``ph.dur_ms``) gets the clock pair alone, so no site times one
+    interval twice. For a round-scale phase that needs a record, a
+    correlation id and the flight mirror, use ``span``."""
+    st = _STATE
+    if st.sink is None:
+        return _Phase(None, name, hist, args) if timed else _NO_PHASE
+    return _Phase(st.registry, name, hist, args)
 
 
 # ---------------------------------------------------------------------------

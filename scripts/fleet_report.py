@@ -318,7 +318,7 @@ def format_table(rep: dict) -> str:
                    "health.beats", "fleet.heartbeats",
                    "device.mem_peak_bytes",
                    "serve.tokens", "serve.tokens_per_sec",
-                   "serve.token_ms.p95", "serve.ttft_ms.p95",
+                   "serve.step_ms.p95", "serve.ttft_ms.p95",
                    "serve.tpot_ms.p95", "serve.swap_stall_ms.p95",
                    "serve.swaps", "flight.bundles")
     for role, snap in sorted(reg.items()):

@@ -195,6 +195,166 @@ def test_span_records_error_flag():
     assert rec["error"] is True
 
 
+# ---------------------------------------------------------------------------
+# phase: the hot-loop primitive beside span
+# ---------------------------------------------------------------------------
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: keeps, per thread, the
+    order in which names were entered and left."""
+
+    log: list = []
+    made: list = []     # (name, arguments) of every annotation built
+
+    def __init__(self, name, **args):
+        self.name = name
+        _FakeAnnotation.made.append((name, args))
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(
+            (threading.get_ident(), "enter", self.name))
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(
+            (threading.get_ident(), "exit", self.name))
+
+
+@pytest.fixture()
+def fake_annotation(monkeypatch):
+    _FakeAnnotation.log = []
+    _FakeAnnotation.made = []
+    monkeypatch.setattr(obs, "_trace_annotation", lambda: _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_phase_disabled_is_one_shared_noop(fake_annotation):
+    a, b = obs.phase("serve.admit"), obs.phase("serve.step", hist="x_ms",
+                                               rid="rq-1")
+    assert a is b                       # no allocation per call site
+    with a as got:
+        assert got is None
+    assert not obs.dirty() and len(obs.registry()) == 0
+    assert fake_annotation == []
+    # disabled, not even the name is looked at
+    with obs.phase("Not A Name"):
+        pass
+
+
+def test_phase_timed_gives_the_time_with_obs_off_and_on(fake_annotation):
+    """A site that needs the time either way (`step()`'s `step_ms`, the
+    request trace's prefill stage) reads it off the phase: off, the clock
+    pair alone; on, the number the histogram was fed."""
+    with obs.phase("serve.prefill", timed=True) as off:
+        pass
+    assert off is not obs.phase("serve.prefill")     # not the shared no-op
+    assert off.dur_ms >= 0.0
+    assert not obs.dirty() and len(obs.registry()) == 0
+    assert fake_annotation == []
+    reg = obs.configure(InMemorySink(), role="server")
+    with obs.phase("serve.prefill", timed=True) as on:
+        pass
+    assert reg.peek("serve.prefill_ms").total == pytest.approx(on.dur_ms)
+    assert [e[1:] for e in fake_annotation] == [
+        ("enter", "serve.prefill"), ("exit", "serve.prefill")]
+
+
+def test_phase_enabled_feeds_its_histogram_and_nothing_else(fake_annotation):
+    sink = InMemorySink()
+    reg = obs.configure(sink, role="server")
+    with obs.phase("serve.admit"):
+        with obs.phase("serve.prefill"):
+            pass
+    with obs.phase("serve.decode.fetch", hist="serve.wait_ms"):
+        pass
+    assert reg.names() == ["serve.admit_ms", "serve.prefill_ms",
+                           "serve.wait_ms"]
+    assert all(reg.peek(n).count == 1 for n in reg.names())
+    assert reg.peek("serve.admit_ms").total >= \
+        reg.peek("serve.prefill_ms").total
+    assert sink.records == []           # nothing per close
+    obs.flush()                         # the registry at the role's cadence
+    assert sink.records[0]["serve.admit_ms.count"] == 1.0
+    me = threading.get_ident()
+    assert fake_annotation == [
+        (me, "enter", "serve.admit"), (me, "enter", "serve.prefill"),
+        (me, "exit", "serve.prefill"), (me, "exit", "serve.admit"),
+        (me, "enter", "serve.decode.fetch"),
+        (me, "exit", "serve.decode.fetch")]
+
+
+def test_phase_hands_its_arguments_to_the_annotation(fake_annotation):
+    """What ties a span to a request or a shape rides as annotation
+    arguments: built into the annotation when enabled, dropped unread
+    when disabled (a timed phase included)."""
+    with obs.phase("serve.prefill", timed=True, rid="rq-1", bucket=128):
+        pass
+    assert _FakeAnnotation.made == []
+    obs.configure(InMemorySink(), role="server")
+    with obs.phase("serve.prefill", timed=True, rid="rq-1", bucket=128):
+        pass
+    with obs.phase("serve.grow"):
+        pass
+    assert _FakeAnnotation.made == [
+        ("serve.prefill", {"rid": "rq-1", "bucket": 128}),
+        ("serve.grow", {})]
+
+
+def test_phase_nests_per_thread(fake_annotation):
+    """Two threads inside the same phases at once: every thread's enters
+    and exits pair up last-in-first-out, and the shared histograms count
+    both."""
+    reg = obs.configure(InMemorySink(), role="server")
+    inside = threading.Barrier(2, timeout=10)
+
+    def work():
+        with obs.phase("serve.step"):
+            with obs.phase("serve.decode.fetch"):
+                inside.wait()           # both threads are in both phases
+
+    threads = [threading.Thread(target=work) for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+        assert not t.is_alive()
+    assert reg.peek("serve.step_ms").count == 2
+    assert reg.peek("serve.decode.fetch_ms").count == 2
+    for ident in {e[0] for e in fake_annotation}:
+        mine = [e[1:] for e in fake_annotation if e[0] == ident]
+        assert mine == [("enter", "serve.step"),
+                        ("enter", "serve.decode.fetch"),
+                        ("exit", "serve.decode.fetch"),
+                        ("exit", "serve.step")]
+
+
+def test_phase_lints_a_name_once_and_survives_without_jax(monkeypatch):
+    obs.configure(InMemorySink(), role="server")
+    with pytest.raises(ValueError):
+        obs.phase("Bad Name")
+    with pytest.raises(ValueError):
+        obs.phase("ok.name", hist="Bad Hist")
+    seen = []
+    real = obs.check_metric_name
+    monkeypatch.setattr(obs, "check_metric_name",
+                        lambda n: seen.append(n) or real(n))
+    obs._phase_hist.cache_clear()
+    monkeypatch.setattr(obs, "_trace_annotation", lambda: None)  # no jax
+    for _ in range(3):
+        with obs.phase("serve.grow"):
+            pass
+    assert seen.count("serve.grow") == 1    # Histogram() lints its own too
+    assert obs.registry().peek("serve.grow_ms").count == 3
+
+
+def test_phase_observes_when_its_body_raises(fake_annotation):
+    reg = obs.configure(InMemorySink(), role="server")
+    with pytest.raises(StopIteration):
+        with obs.phase("serve.admit"):
+            next(iter(()))
+    assert reg.peek("serve.admit_ms").count == 1
+    assert [e[1] for e in fake_annotation] == ["enter", "exit"]
+
+
 def test_correlate_is_thread_local():
     sink = InMemorySink()
     obs.configure(sink)
@@ -541,7 +701,8 @@ def test_every_exporter_metric_name_is_documented():
       by their documented ``<rule>``-style placeholder rows and are
       not enumerable statically);
     - span names: every literal obs.span(...) name (rendered as
-      ``span.<name>_ms`` / the span vocabulary table);
+      ``span.<name>_ms`` / the span vocabulary table), and every
+      literal obs.phase(...) name with the histogram it feeds;
     - labeled families: the _FLEET_SERIES ledger series, the SLO
       breach family, and every literal dt_* family in
       utils/devprof.py + utils/obs_http.py.
@@ -578,6 +739,13 @@ def test_every_exporter_metric_name_is_documented():
                 counter_names.add(node.args[0].value)
             elif node.func.attr == "span":
                 span_names.add(node.args[0].value)
+            elif node.func.attr == "phase":
+                # the span's name, and the histogram it feeds
+                name = node.args[0].value
+                hist = [k.value.value for k in node.keywords
+                        if k.arg == "hist"]
+                span_names.add(name)
+                counter_names.add(hist[0] if hist else f"{name}_ms")
 
     families = {"dt_" + suffix for _, suffix, _ in obs_http._FLEET_SERIES}
     families.add("dt_fleet_slo_breached")

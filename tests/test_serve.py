@@ -553,7 +553,8 @@ def test_compile_cache_restart(tmp_path, setup, sink, monkeypatch):
             # helpers may come and go; they cost microseconds)
             return {f for f in os.listdir(cache_dir)
                     if f.endswith("-cache")
-                    and ("jit_prefill" in f or "jit_step" in f)}
+                    and ("jit_serve_prefill" in f
+                         or "jit_serve_decode" in f)}
 
         assert enable_compile_cache() == cache_dir
         assert jax.config.jax_compilation_cache_dir == cache_dir
@@ -908,5 +909,191 @@ def test_prefix_cache_flushed_on_hot_swap(setup, sink):
         assert eng.generate(prompts, GEN) == refs_for(model, params2,
                                                       prompts)
         assert eng.prefix_hits >= 2         # cache rebuilt and hit again
+    finally:
+        eng.close()
+
+
+# ---------------------------------------------------------------------------
+# Phase spans, emit stamps and program names (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+STEP_PHASES = ("serve.step", "serve.admit", "serve.prefill", "serve.grow",
+               "serve.decode.build", "serve.decode.dispatch",
+               "serve.decode.fetch", "serve.decode.emit")
+
+
+def test_step_phases_fill_their_histograms_and_nest(setup, sink):
+    """With obs on, one step() that admits and decodes feeds all eight
+    phase histograms; the children lie inside `serve.step`, the prefill
+    inside `serve.admit`; nothing is written to the sink per close."""
+    model, cfg, params, _, prompts = setup
+    eng = GenerationEngine(model, params, max_slots=2, page_size=8)
+    try:
+        eng.submit(prompts[0], GEN)
+        eng.step()
+        reg = obs.registry()
+        hists = {p: reg.peek(f"{p}_ms") for p in STEP_PHASES}
+        assert all(h is not None and h.count == 1 for h in hists.values()), {
+            p: getattr(h, "count", None) for p, h in hists.items()}
+        total = {p: h.total for p, h in hists.items()}
+        inside = sum(total[p] for p in STEP_PHASES
+                     if p not in ("serve.step", "serve.prefill"))
+        assert total["serve.step"] >= inside
+        assert total["serve.admit"] >= total["serve.prefill"]
+        assert not [r for r in sink.records if "span" in r]
+        # the per-token twin of serve.step_ms is gone; `serve.tokens`, the
+        # counter, is not it
+        assert not [n for n in reg.names() if n.startswith("serve.token_")]
+    finally:
+        eng.close()
+
+
+def test_prefill_ms_is_timed_to_completion(setup, sink):
+    """`serve.prefill_ms` (and the request trace's prefill stage) end
+    after the first token reached the host, not after the dispatch: a
+    prefill whose result takes 50 ms to arrive reads at least 50 ms."""
+    import time as _time
+    model, cfg, params, _, prompts = setup
+    eng = GenerationEngine(model, params, max_slots=2, page_size=8)
+    block_s = 0.05
+
+    class _Late:
+        """The prefill's first token, arriving `block_s` after the
+        dispatch returned (what `int()` of a device array waits for)."""
+
+        def __init__(self, value):
+            self.value = value
+
+        def __int__(self):
+            _time.sleep(block_s)
+            return int(self.value)
+
+    real = eng._prefill_prog
+
+    def late_prog(t_bucket):
+        prog = real(t_bucket)
+
+        def run(*args):
+            nxt, row, k_pages, v_pages = prog(*args)
+            return _Late(nxt), row, k_pages, v_pages
+        return run
+
+    eng._prefill_prog = late_prog
+    try:
+        req = eng.submit(prompts[0], GEN)
+        eng.step()
+        h = obs.registry().peek("serve.prefill_ms")
+        assert h.count == 1 and h.total >= block_s * 1e3
+        stage = [s for s in eng.trace.get(req.rid).stages
+                 if s["stage"] == "prefill"]
+        assert stage and stage[0]["dur_ms"] >= block_s * 1e3
+    finally:
+        eng.close()
+
+
+def test_emit_stamps_one_per_token_and_two_in_one_step(setup):
+    """Always on, obs or not: a request prefilled and decoded in ONE
+    step() hands its caller two tokens with two different stamps, and a
+    finished request has one non-decreasing stamp per token on the clock
+    `submitted_pc` was taken from."""
+    model, cfg, params, _, prompts = setup
+    eng = GenerationEngine(model, params, max_slots=2, page_size=8)
+    try:
+        req = eng.submit(prompts[1], GEN)
+        eng.step()
+        assert len(req.tokens) == 2 and len(req.emit_t) == 2
+        assert req.submitted_pc <= req.emit_t[0] < req.emit_t[1]
+        while not req.done_evt.is_set():
+            eng.step()
+        assert len(req.emit_t) == len(req.tokens) == GEN
+        assert req.emit_t == sorted(req.emit_t)
+    finally:
+        eng.close()
+
+
+def test_requeue_clears_the_emit_stamps(setup):
+    """A preempted request regenerates from its prompt: its stamps start
+    over with its tokens."""
+    model, cfg, params, _, prompts = setup
+    eng = GenerationEngine(model, params, max_slots=2, page_size=8)
+    try:
+        req = eng.submit(prompts[0], GEN)
+        eng.step()
+        assert req.emit_t
+        assert eng._preempt_one()
+        assert req.tokens == [] and req.emit_t == []
+        while not req.done_evt.is_set():
+            eng.step()
+        assert len(req.emit_t) == len(req.tokens) == GEN
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("family", ["serve_decode", "serve_prefill"])
+def test_programs_carry_stable_names(setup, family):
+    """The device's `XLA Modules` line shows `jit_<function>`: the jitted
+    functions are named after devprof's vocabulary."""
+    model, cfg, params, _, prompts = setup
+    eng = GenerationEngine(model, params, max_slots=2, page_size=8)
+    try:
+        k_pages, v_pages = eng._kv
+        if family == "serve_decode":
+            lowered = eng._decode_prog(2, 2).lower(
+                eng._params, k_pages, v_pages, np.zeros((2, 2), np.int32),
+                np.zeros((2,), np.int32), np.zeros((2,), np.int32))
+        else:
+            lowered = eng._prefill_prog(8).lower(
+                eng._params, np.zeros((1, 8), np.int32), np.int32(3),
+                k_pages, v_pages, np.zeros((1,), np.int32))
+        assert f"@jit_{family} " in lowered.as_text()
+    finally:
+        eng.close()
+
+
+def test_spans_carry_the_request_and_the_shape(setup, sink, monkeypatch):
+    """`serve.prefill` names its request (the id request traces key on)
+    and its token bucket, a shared-prefix prefill its context pages too;
+    `serve.decode.dispatch` names the program's bucket and the live slot
+    count. Each prefill, full or shared, feeds `serve.prefill_ms` and
+    hands its time to the request trace's stage."""
+    made = []
+
+    class _Annotation:
+        def __init__(self, name, **args):
+            made.append((name, args))
+
+        def __enter__(self):
+            pass
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(obs, "_trace_annotation", lambda: _Annotation)
+    model, cfg, params, _, _ = setup
+    rng = np.random.RandomState(11)
+    sysp = [int(t) for t in rng.randint(0, cfg.vocab_size, size=16)]
+    prompts = [sysp + [int(t) for t in rng.randint(0, cfg.vocab_size,
+                                                   size=4)]
+               for _ in range(2)]
+    eng = GenerationEngine(model, params, max_slots=2, page_size=8,
+                           prefix_cache=True)
+    try:
+        full = eng.submit(prompts[0], GEN)
+        eng.step()
+        shared = eng.submit(prompts[1], GEN)
+        eng.step()
+        assert eng.prefix_hits == 1
+        prefills = [a for n, a in made if n == "serve.prefill"]
+        assert prefills == [{"rid": full.rid, "bucket": 32},
+                            {"rid": shared.rid, "bucket": 8,
+                             "ctx_pages": 4}]
+        dispatches = [a for n, a in made if n == "serve.decode.dispatch"]
+        assert dispatches[0] == {"slots": 1, "pages": 4, "live": 1}
+        assert dispatches[1]["live"] == 2
+        assert obs.registry().peek("serve.prefill_ms").count == 2
+        for req in (full, shared):
+            stage = [s for s in eng.trace.get(req.rid).stages
+                     if s["stage"] == "prefill"]
+            assert len(stage) == 1 and stage[0]["dur_ms"] > 0.0
     finally:
         eng.close()

@@ -429,3 +429,28 @@ def test_router_backend_speed_factor():
     pol = RouterPolicy()
     assert pol.score(spec) < pol.score(plain)
     assert pol.choose([plain, spec]) is spec
+
+
+def test_speculative_step_feeds_the_four_decode_phases(setup, sink):
+    """A speculative step is timed by the same four decode phases as a
+    plain one (the drafter's propose is a second dispatch of that step),
+    beside the two spec histograms it had."""
+    model, cfg, params, _, prompts = setup
+    draft = DraftEngine(model, params, max_slots=4, page_size=8)
+    eng = spec_engine(model, params, draft)
+    try:
+        eng.submit(prompts[0], GEN)
+        eng.step()
+        assert eng.spec_rounds == 1
+        reg = obs.registry()
+        count = {n: reg.peek(f"serve.{n}_ms").count for n in (
+            "step", "decode.build", "decode.dispatch", "decode.fetch",
+            "decode.emit", "spec_draft", "spec_verify")}
+        assert count == {"step": 1, "decode.build": 1, "decode.dispatch": 2,
+                         "decode.fetch": 1, "decode.emit": 1,
+                         "spec_draft": 1, "spec_verify": 1}
+        total = {n: reg.peek(f"serve.{n}_ms").total for n in count}
+        assert total["step"] >= sum(total[f"decode.{p}"] for p in (
+            "build", "dispatch", "fetch", "emit"))
+    finally:
+        eng.close()
