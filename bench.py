@@ -1506,9 +1506,9 @@ def _time_decode_attn_kernel(*, B: int = 4, Hq: int = 4, Hkv: int = 2,
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), jnp.float32)
     k_pages = jnp.asarray(
-        rng.standard_normal((pool, P, Hkv, D)), jnp.float32)
+        rng.standard_normal((pool, P, Hkv * D)), jnp.float32)
     v_pages = jnp.asarray(
-        rng.standard_normal((pool, P, Hkv, D)), jnp.float32)
+        rng.standard_normal((pool, P, Hkv * D)), jnp.float32)
     k_new = jnp.asarray(rng.standard_normal((B, 1, Hkv, D)), jnp.float32)
     v_new = jnp.asarray(rng.standard_normal((B, 1, Hkv, D)), jnp.float32)
     tables = jnp.asarray(

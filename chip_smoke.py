@@ -347,7 +347,11 @@ def serve_leg(common, work_dir, *, prompts, max_new, expect_kernels) -> dict:
     placement = {"params": _placement(eng._params),
                  "kv_pool": _placement(eng._kv)}
     buckets = {}
+    # the pool is a pair of per-layer tuples (engine/kv_pool.py); `lower`
+    # takes them as it takes any pytree
     k_pages, v_pages = eng._kv
+    _check(placement["kv_pool"]["leaves"] == 2 * len(eng._layers),
+           f"KV pool is not one K and one V array per layer: {placement}")
     for (slots, pages), prog in eng._decode_progs.items():
         text = prog.lower(
             eng._params, k_pages, v_pages,

@@ -1,0 +1,114 @@
+"""The serving KV pool's device layout, and every way it is written.
+
+A pool is a pair ``(k_pages, v_pages)``; each half is a tuple of one
+array PER LAYER, stored in the shape the paged-attention kernel DMAs
+from: lane-dense ``[pool_pages, P, Hkv*D]`` rows
+(ops/paged_attention.py). Why per layer and not one ``[L, ...]`` array:
+a Pallas custom call takes a buffer of its own, so a layer sliced out of
+a stacked array is copied once per layer per step; a separate array is
+passed as it lies. Why ``Hkv*D`` and not ``[..., Hkv, D]``: under TPU
+tiling a head of 64 is half a lane row, and re-laying the pool for the
+kernel is a copy of the pool.
+
+Every serve program (engine/serve.py, engine/speculative.py,
+engine/kv_transfer.py) takes all ``2L`` arrays donated, hands layer *i*
+its own pair, writes layer *i*'s fresh rows into layer *i*'s own arrays
+through the helpers here, and returns all ``2L``. Nothing stacks across
+layers on the device: the fresh ``[B, T, Hkv, D]`` tensors are reshaped
+to ``Hkv*D`` (small); the pool never is. The transfer plane's wire
+format stays ``[L, P, Hkv, D]`` per page — :func:`read_pages` and
+:func:`adopt_page` convert a few pages at the edge.
+"""
+
+from __future__ import annotations
+
+from typing import Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+Pool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
+
+
+def make_pool(n_layers: int, pool_pages: int, page_size: int,
+              kv_heads: int, head_dim: int, dtype) -> Pool:
+    shape = (pool_pages, page_size, kv_heads * head_dim)
+    return (tuple(jnp.zeros(shape, dtype) for _ in range(n_layers)),
+            tuple(jnp.zeros(shape, dtype) for _ in range(n_layers)))
+
+
+def _sown(inter, layers: Sequence[str]) -> tuple[list, list]:
+    """Every layer's fresh k and v out of a ``sow_kv=True`` forward's
+    intermediates, as lane-dense ``[B, T, Hkv*D]`` rows."""
+    fresh = [inter[name]["kv_cache"][0] for name in layers]
+    return tuple([x.reshape(*x.shape[:2], -1) for x in half]   # [B,T,Hkv,D]
+                 for half in zip(*fresh))
+
+
+def write_rows(k_pages, v_pages, inter, layers, page_idx, off) -> Pool:
+    """Scatter a forward's fresh token rows: layer *i*'s sown
+    ``[B, T, Hkv*D]`` row ``[b, t]`` lands at ``(page_idx[b, t],
+    off[b, t])`` of layer *i*'s own arrays (verify, suffix prefill)."""
+    def put(pages, rows):
+        return tuple(p.at[page_idx, off].set(x)
+                     for p, x in zip(pages, rows))
+
+    k_new, v_new = _sown(inter, layers)
+    return put(k_pages, k_new), put(v_pages, v_new)
+
+
+def write_next_row(k_pages, v_pages, inter, layers, page_tables,
+                   seq_lens) -> Pool:
+    """A decode step's write: slot *b*'s one fresh row goes to position
+    ``seq_lens[b]`` of the sequence its ``page_tables`` row names."""
+    P = k_pages[0].shape[1]
+    page_idx = jnp.take_along_axis(
+        page_tables, (seq_lens // P)[:, None], axis=1)
+    return write_rows(k_pages, v_pages, inter, layers, page_idx,
+                      (seq_lens % P)[:, None])
+
+
+def write_pages(k_pages, v_pages, inter, layers, page_row) -> Pool:
+    """Scatter a full prefill's whole pages: layer *i*'s sown
+    ``[1, len(page_row) * P, Hkv*D]`` rows, page by page."""
+    def put(pages, rows):
+        return tuple(
+            p.at[page_row].set(x.reshape(page_row.shape[0], -1,
+                                         x.shape[-1]))
+            for p, x in zip(pages, rows))
+
+    k_new, v_new = _sown(inter, layers)
+    return put(k_pages, k_new), put(v_pages, v_new)
+
+
+def copy_page(k_pages, v_pages, src, dst) -> Pool:
+    """Copy page ``src`` onto page ``dst`` in every layer (the
+    copy-on-write primitive)."""
+    return tuple(tuple(p.at[dst].set(p[src]) for p in half)
+                 for half in (k_pages, v_pages))
+
+
+def adopt_page(k_pages, v_pages, k_new, v_new, dst) -> Pool:
+    """Write one wire-format ``[L, P, Hkv, D]`` K/V page into page
+    ``dst``, layer by layer."""
+    def put(pages, new):
+        return tuple(p.at[dst].set(new[i].reshape(new.shape[1], -1))
+                     for i, p in enumerate(pages))
+
+    return put(k_pages, k_new), put(v_pages, v_new)
+
+
+def read_pages(pool: Pool, idx, kv_heads: int) -> tuple[np.ndarray,
+                                                        np.ndarray]:
+    """Pages ``idx`` of every layer on the host, in the wire format's
+    ``[L, n, P, Hkv, D]``: gathered per layer, so only those few pages
+    are stacked and moved."""
+    idx = jnp.asarray(idx, jnp.int32)
+    k_host, v_host = jax.device_get(
+        tuple(jnp.stack([x[idx] for x in half]) for half in pool))
+
+    def heads(x):
+        return np.asarray(x).reshape(*x.shape[:3], kv_heads, -1)
+
+    return heads(k_host), heads(v_host)

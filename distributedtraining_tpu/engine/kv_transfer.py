@@ -9,9 +9,10 @@ the finished request's KV pages; a DECODE worker adopts those pages
 into its own ``PagePool`` and runs the paged-attention decode kernel
 flat-out. This module is the transfer plane between them.
 
-The wire unit is the page pool's natural layout: one page is the
-``[L, P, Hkv, D]`` slice of the ``[L, pages, P, Hkv, D]`` pool across
-both K and V. Pages travel as content-addressed shards
+The wire unit is one page across all layers, ``[L, P, Hkv, D]`` for K
+and for V: the engine gathers it from (and adoption writes it into) the
+per-layer ``[pages, P, Hkv*D]`` pool arrays of engine/kv_pool.py, a few
+pages at a time. Pages travel as content-addressed shards
 (``__kv__.s.<sha256>``) and a per-request manifest
 (``__kv__.<request-slug>``) lists the page digests in page-table order
 plus the geometry and the BASE REVISION the pages were prefillied on —
@@ -48,6 +49,7 @@ from flax import serialization as flax_ser
 from .. import serialization as ser
 from ..transport import base as tbase
 from ..utils import devprof, obs
+from . import kv_pool
 
 logger = logging.getLogger(__name__)
 
@@ -377,14 +379,14 @@ class KVAdopter:
 
 def make_adopt_prog(donate: bool) -> Callable:
     """One jitted page write: scatter a fetched ``[L, P, Hkv, D]`` K/V
-    pair into pool slot ``dst``. Bucket-free (page geometry is static
+    pair into pool slot ``dst``, layer by layer (engine/kv_pool.py).
+    Bucket-free (page geometry is static
     per engine), compiled ONCE at the first adoption and warm forever —
     the decode worker's zero-steady-state-compiles pin covers it. The
     serve engine owns the ``_timed_compile`` first-call accounting,
     exactly like its ``serve.page_copy`` twin."""
     def kv_adopt(k_pages, v_pages, k_new, v_new, dst):
-        return (k_pages.at[:, dst].set(k_new),
-                v_pages.at[:, dst].set(v_new))
+        return kv_pool.adopt_page(k_pages, v_pages, k_new, v_new, dst)
 
     return devprof.wrap(
         "serve.kv_adopt",

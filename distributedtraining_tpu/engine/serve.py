@@ -114,6 +114,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import devprof, flight, obs, reqtrace
+from . import kv_pool
 from .batched_eval import _timed_compile
 
 logger = logging.getLogger(__name__)
@@ -797,7 +798,8 @@ class GenerationEngine:
         self._params: Params | None = None
         self.revision: str | None = None
         self._layers: list[str] | None = None
-        self._kv: tuple[jax.Array, jax.Array] | None = None
+        self._kv: kv_pool.Pool | None = None
+        self._kv_heads = getattr(cfg, "n_kv_head", None) or cfg.n_head
         self.pool: PagePool | None = None
         self._prefix_cache = prefix_cache
         self._cache: PrefixCache | None = None
@@ -857,11 +859,9 @@ class GenerationEngine:
 
     def _init_kv(self) -> None:
         cfg = self.cfg
-        hkv = getattr(cfg, "n_kv_head", None) or cfg.n_head
-        shape = (len(self._layers), self.pool_pages, self.page_size,
-                 hkv, cfg.head_dim)
-        dt = cfg.compute_dtype()
-        self._kv = (jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+        self._kv = kv_pool.make_pool(
+            len(self._layers), self.pool_pages, self.page_size,
+            self._kv_heads, cfg.head_dim, cfg.compute_dtype())
         self.pool = PagePool(self.pool_pages)
         if self._prefix_cache:
             self._cache = PrefixCache(self.pool, self.page_size)
@@ -1013,21 +1013,12 @@ class GenerationEngine:
         return got
 
     # -- programs -----------------------------------------------------------
-    def _stack_kv(self, inter) -> tuple[jax.Array, jax.Array]:
-        ks, vs = [], []
-        for name in self._layers:
-            k, v = inter[name]["kv_cache"][0]
-            ks.append(k)
-            vs.append(v)
-        return jnp.stack(ks), jnp.stack(vs)   # [L, B, T, Hkv, D]
-
     def _prefill_prog(self, t_bucket: int) -> Callable:
         prog = self._prefill_progs.get(t_bucket)
         if prog is not None:
             return prog
-        model, P, vocab = self.model, self.page_size, self.cfg.vocab_size
-        mp = t_bucket // P
-        stack_kv = self._stack_kv
+        model, vocab = self.model, self.cfg.vocab_size
+        layers = self._layers
 
         def serve_prefill(params, tokens, prompt_len, k_pages, v_pages,
                           page_row):
@@ -1036,11 +1027,8 @@ class GenerationEngine:
             logits, muts = model.apply(
                 {"params": params}, tokens, attention_mask=amask,
                 sow_kv=True, mutable=["intermediates"])
-            k, v = stack_kv(muts["intermediates"])      # [L, 1, T, Hkv, D]
-            k = k[:, 0].reshape(k.shape[0], mp, P, *k.shape[-2:])
-            v = v[:, 0].reshape(v.shape[0], mp, P, *v.shape[-2:])
-            k_pages = k_pages.at[:, page_row].set(k)
-            v_pages = v_pages.at[:, page_row].set(v)
+            k_pages, v_pages = kv_pool.write_pages(
+                k_pages, v_pages, muts["intermediates"], layers, page_row)
             row = logits[0, prompt_len - 1, :vocab]
             nxt = jnp.argmax(row)
             # the logits row rides out so sampled requests can draw
@@ -1060,9 +1048,8 @@ class GenerationEngine:
         prog = self._decode_progs.get((n_slots, n_pages))
         if prog is not None:
             return prog
-        model, P, vocab = self.model, self.page_size, self.cfg.vocab_size
-        L = len(self._layers)
-        stack_kv = self._stack_kv
+        model, vocab = self.model, self.cfg.vocab_size
+        layers = self._layers
 
         def serve_decode(params, k_pages, v_pages, page_tables, seq_lens,
                          tokens):
@@ -1071,19 +1058,16 @@ class GenerationEngine:
             # fused gather+attend kernel on TPU, its XLA twin off-TPU).
             # The dense [L, B, S, H, D] gathered context the pre-kernel
             # spelling materialized here per token no longer exists.
-            kv_pages = tuple((k_pages[i], v_pages[i]) for i in range(L))
+            kv_pages = tuple(zip(k_pages, v_pages))
             logits, muts = model.apply(
                 {"params": params}, tokens[:, None],
                 position_ids=seq_lens[:, None],
                 kv_pages=kv_pages, page_tables=page_tables,
                 kv_lens=seq_lens,
                 sow_kv=True, mutable=["intermediates"])
-            new_k, new_v = stack_kv(muts["intermediates"])  # [L, B, 1, H, D]
-            page_idx = jnp.take_along_axis(
-                page_tables, (seq_lens // P)[:, None], axis=1)[:, 0]
-            off = seq_lens % P
-            k_pages = k_pages.at[:, page_idx, off].set(new_k[:, :, 0])
-            v_pages = v_pages.at[:, page_idx, off].set(new_v[:, :, 0])
+            k_pages, v_pages = kv_pool.write_next_row(
+                k_pages, v_pages, muts["intermediates"], layers,
+                page_tables, seq_lens)
             nxt = jnp.argmax(logits[:, -1, :vocab], axis=-1)
             return nxt.astype(jnp.int32), k_pages, v_pages
 
@@ -1104,26 +1088,22 @@ class GenerationEngine:
         prog = self._decode_sample_progs.get((n_slots, n_pages))
         if prog is not None:
             return prog
-        model, P, vocab = self.model, self.page_size, self.cfg.vocab_size
-        L = len(self._layers)
-        stack_kv = self._stack_kv
+        model, vocab = self.model, self.cfg.vocab_size
+        layers = self._layers
 
         def serve_decode_sample(params, k_pages, v_pages, page_tables,
                                 seq_lens, tokens, temps, top_ps, seeds,
                                 tok_idx):
-            kv_pages = tuple((k_pages[i], v_pages[i]) for i in range(L))
+            kv_pages = tuple(zip(k_pages, v_pages))
             logits, muts = model.apply(
                 {"params": params}, tokens[:, None],
                 position_ids=seq_lens[:, None],
                 kv_pages=kv_pages, page_tables=page_tables,
                 kv_lens=seq_lens,
                 sow_kv=True, mutable=["intermediates"])
-            new_k, new_v = stack_kv(muts["intermediates"])
-            page_idx = jnp.take_along_axis(
-                page_tables, (seq_lens // P)[:, None], axis=1)[:, 0]
-            off = seq_lens % P
-            k_pages = k_pages.at[:, page_idx, off].set(new_k[:, :, 0])
-            v_pages = v_pages.at[:, page_idx, off].set(new_v[:, :, 0])
+            k_pages, v_pages = kv_pool.write_next_row(
+                k_pages, v_pages, muts["intermediates"], layers,
+                page_tables, seq_lens)
             nxt = _sample_from_logits(logits[:, -1, :vocab], temps,
                                       top_ps, seeds, tok_idx)
             return nxt, k_pages, v_pages
@@ -1148,13 +1128,12 @@ class GenerationEngine:
         if prog is not None:
             return prog
         model, P, vocab = self.model, self.page_size, self.cfg.vocab_size
-        L = len(self._layers)
+        layers = self._layers
         cap = self.max_seq_len
-        stack_kv = self._stack_kv
 
         def serve_prefill_ctx(params, tokens, ctx_len, suffix_len,
                               k_pages, v_pages, page_table):
-            kv_pages = tuple((k_pages[i], v_pages[i]) for i in range(L))
+            kv_pages = tuple(zip(k_pages, v_pages))
             pos = ctx_len + jnp.arange(t_bucket)
             logits, muts = model.apply(
                 {"params": params}, tokens,
@@ -1162,13 +1141,12 @@ class GenerationEngine:
                 kv_pages=kv_pages, page_tables=page_table,
                 kv_lens=jnp.reshape(ctx_len, (1,)),
                 sow_kv=True, mutable=["intermediates"])
-            k, v = stack_kv(muts["intermediates"])      # [L, 1, T, H, D]
             valid = jnp.arange(t_bucket) < suffix_len
             page_idx = jnp.where(
                 valid, page_table[0, jnp.minimum(pos // P, pb - 1)], 0)
-            off = pos % P
-            k_pages = k_pages.at[:, page_idx, off].set(k[:, 0])
-            v_pages = v_pages.at[:, page_idx, off].set(v[:, 0])
+            k_pages, v_pages = kv_pool.write_rows(
+                k_pages, v_pages, muts["intermediates"], layers,
+                page_idx[None, :], (pos % P)[None, :])
             row = logits[0, suffix_len - 1, :vocab]
             nxt = jnp.argmax(row)
             return nxt.astype(jnp.int32), row, k_pages, v_pages
@@ -1205,14 +1183,13 @@ class GenerationEngine:
         if prog is not None:
             return prog
         model, P, vocab = self.model, self.page_size, self.cfg.vocab_size
-        L = len(self._layers)
+        layers = self._layers
         W = self.draft_k + 1
         cap = self.max_seq_len
-        stack_kv = self._stack_kv
 
         def serve_verify(params, k_pages, v_pages, page_tables, seq_lens,
                          tokens, n_input, temps, top_ps, seeds, tok_idx0):
-            kv_pages = tuple((k_pages[i], v_pages[i]) for i in range(L))
+            kv_pages = tuple(zip(k_pages, v_pages))
             pos = seq_lens[:, None] + jnp.arange(W)[None, :]   # [B, W]
             logits, muts = model.apply(
                 {"params": params}, tokens,
@@ -1220,7 +1197,6 @@ class GenerationEngine:
                 kv_pages=kv_pages, page_tables=page_tables,
                 kv_lens=seq_lens,
                 sow_kv=True, mutable=["intermediates"])
-            new_k, new_v = stack_kv(muts["intermediates"])  # [L,B,W,H,D]
             valid = jnp.arange(W)[None, :] < n_input[:, None]
             page_idx = jnp.where(
                 valid,
@@ -1228,9 +1204,9 @@ class GenerationEngine:
                     page_tables, jnp.minimum(pos // P, n_pages - 1),
                     axis=1),
                 0)                                          # [B, W]
-            off = pos % P
-            k_pages = k_pages.at[:, page_idx, off].set(new_k)
-            v_pages = v_pages.at[:, page_idx, off].set(new_v)
+            k_pages, v_pages = kv_pool.write_rows(
+                k_pages, v_pages, muts["intermediates"], layers,
+                page_idx, pos % P)
             flat = logits[:, :, :vocab].reshape(n_slots * W, vocab)
             tok_idx = (tok_idx0[:, None]
                        + jnp.arange(W)[None, :]).reshape(-1)
@@ -1269,15 +1245,11 @@ class GenerationEngine:
             return int(_timed_compile(prog, *args))
         return int(prog(*args))
 
-    def _copy_page(self, src: int, dst: int) -> None:
-        """Whole-page KV copy (``serve.page_copy``) — the copy-on-write
-        primitive: garbage rows beyond the valid length copy too, but
-        they stay masked behind ``kv_lens`` until overwritten."""
+    def _page_copy_prog(self) -> Callable:
         prog = self._page_copy_prog_
         if prog is None:
             def serve_page_copy(k_pages, v_pages, src, dst):
-                return (k_pages.at[:, dst].set(k_pages[:, src]),
-                        v_pages.at[:, dst].set(v_pages[:, src]))
+                return kv_pool.copy_page(k_pages, v_pages, src, dst)
 
             prog = devprof.wrap(
                 "serve.page_copy",
@@ -1285,6 +1257,13 @@ class GenerationEngine:
                         donate_argnums=(0, 1) if self._donate else ()),
                 bucket=1)
             self._page_copy_prog_ = prog
+        return prog
+
+    def _copy_page(self, src: int, dst: int) -> None:
+        """Whole-page KV copy (``serve.page_copy``) — the copy-on-write
+        primitive: garbage rows beyond the valid length copy too, but
+        they stay masked behind ``kv_lens`` until overwritten."""
+        prog = self._page_copy_prog()
         k_pages, v_pages = self._kv
         if not self._page_copy_warm:
             self._page_copy_warm = True
@@ -1603,11 +1582,10 @@ class GenerationEngine:
             return "degrade"
         P = self.page_size
         plen = len(req.prompt)
-        k_pages, _ = self._kv
-        want = {"layers": k_pages.shape[0], "page_size": P,
-                "kv_heads": k_pages.shape[3],
-                "head_dim": k_pages.shape[4],
-                "dtype": str(k_pages.dtype)}
+        want = {"layers": len(self._layers), "page_size": P,
+                "kv_heads": self._kv_heads,
+                "head_dim": self.cfg.head_dim,
+                "dtype": str(jnp.dtype(self.cfg.compute_dtype()))}
         if got["geometry"] != want or got["prompt_len"] != plen \
                 or len(got["pages"]) != (plen + P - 1) // P:
             obs.count("serve.kv_adopt_failures")
@@ -1678,10 +1656,8 @@ class GenerationEngine:
         plen = len(req.prompt)
         ncontent = (plen + P - 1) // P
         t0 = time.perf_counter()
-        k_pages, v_pages = self._kv
-        idx = np.asarray(pages[:ncontent], np.int32)
-        k_host = np.asarray(jax.device_get(k_pages[:, idx]))
-        v_host = np.asarray(jax.device_get(v_pages[:, idx]))
+        k_host, v_host = kv_pool.read_pages(
+            self._kv, pages[:ncontent], self._kv_heads)
         kv_ref = req.request_id or f"rq-rid{req.rid}"
         ok = self._kv_exporter.export(
             request_id=kv_ref, revision=self.revision or "",

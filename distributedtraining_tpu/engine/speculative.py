@@ -68,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import devprof, obs
+from . import kv_pool
 from .batched_eval import _timed_compile
 from .serve import (DEFAULT_PAGE_SIZE, BucketLadder, PagePool,
                     _layer_keys, _sample_from_logits)
@@ -154,7 +155,7 @@ class DraftEngine:
         self._params: Params | None = None
         self.revision: str | None = None
         self._layers: list[str] | None = None
-        self._kv: tuple[jax.Array, jax.Array] | None = None
+        self._kv: kv_pool.Pool | None = None
         self.pool: PagePool | None = None
         self._states: dict[int, _DraftState] = {}
         self.flush_count = 0
@@ -186,11 +187,10 @@ class DraftEngine:
 
     def _init_kv(self) -> None:
         cfg = self.cfg
-        hkv = getattr(cfg, "n_kv_head", None) or cfg.n_head
-        shape = (len(self._layers), self.pool_pages, self.page_size,
-                 hkv, cfg.head_dim)
-        dt = cfg.compute_dtype()
-        self._kv = (jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+        self._kv = kv_pool.make_pool(
+            len(self._layers), self.pool_pages, self.page_size,
+            getattr(cfg, "n_kv_head", None) or cfg.n_head, cfg.head_dim,
+            cfg.compute_dtype())
         self.pool = PagePool(self.pool_pages)
 
     # -- state lifecycle ----------------------------------------------------
@@ -240,14 +240,6 @@ class DraftEngine:
         self.flush()
 
     # -- programs -----------------------------------------------------------
-    def _stack_kv(self, inter) -> tuple[jax.Array, jax.Array]:
-        ks, vs = [], []
-        for name in self._layers:
-            k, v = inter[name]["kv_cache"][0]
-            ks.append(k)
-            vs.append(v)
-        return jnp.stack(ks), jnp.stack(vs)
-
     def _step_prog(self, n_slots: int, n_pages: int) -> Callable:
         """One draft decode step: identical shape discipline to the
         target's ``serve.decode_sample`` (paged attention through the
@@ -259,25 +251,21 @@ class DraftEngine:
         prog = self._step_progs.get((n_slots, n_pages))
         if prog is not None:
             return prog
-        model, P, vocab = self.model, self.page_size, self.cfg.vocab_size
-        L = len(self._layers)
-        stack_kv = self._stack_kv
+        model, vocab = self.model, self.cfg.vocab_size
+        layers = self._layers
 
         def draft_step(params, k_pages, v_pages, page_tables, seq_lens,
                        tokens, temps, top_ps, seeds, tok_idx):
-            kv_pages = tuple((k_pages[i], v_pages[i]) for i in range(L))
+            kv_pages = tuple(zip(k_pages, v_pages))
             logits, muts = model.apply(
                 {"params": params}, tokens[:, None],
                 position_ids=seq_lens[:, None],
                 kv_pages=kv_pages, page_tables=page_tables,
                 kv_lens=seq_lens,
                 sow_kv=True, mutable=["intermediates"])
-            new_k, new_v = stack_kv(muts["intermediates"])
-            page_idx = jnp.take_along_axis(
-                page_tables, (seq_lens // P)[:, None], axis=1)[:, 0]
-            off = seq_lens % P
-            k_pages = k_pages.at[:, page_idx, off].set(new_k[:, :, 0])
-            v_pages = v_pages.at[:, page_idx, off].set(new_v[:, :, 0])
+            k_pages, v_pages = kv_pool.write_next_row(
+                k_pages, v_pages, muts["intermediates"], layers,
+                page_tables, seq_lens)
             nxt = _sample_from_logits(logits[:, -1, :vocab], temps,
                                       top_ps, seeds, tok_idx)
             return nxt, k_pages, v_pages
@@ -300,7 +288,7 @@ class DraftEngine:
             return prog
         model, P = self.model, self.page_size
         mp = t_bucket // P
-        stack_kv = self._stack_kv
+        layers = self._layers
 
         def draft_prefill(params, tokens, n_tok, k_pages, v_pages,
                           page_row):
@@ -309,12 +297,8 @@ class DraftEngine:
             _, muts = model.apply(
                 {"params": params}, tokens, attention_mask=amask,
                 sow_kv=True, mutable=["intermediates"])
-            k, v = stack_kv(muts["intermediates"])
-            k = k[:, 0].reshape(k.shape[0], mp, P, *k.shape[-2:])
-            v = v[:, 0].reshape(v.shape[0], mp, P, *v.shape[-2:])
-            k_pages = k_pages.at[:, page_row].set(k)
-            v_pages = v_pages.at[:, page_row].set(v)
-            return k_pages, v_pages
+            return kv_pool.write_pages(
+                k_pages, v_pages, muts["intermediates"], layers, page_row)
 
         prog = devprof.wrap(
             "serve.draft",

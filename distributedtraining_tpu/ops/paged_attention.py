@@ -34,17 +34,22 @@ its own.
 
 Kernel layout: Mosaic tiles the two minor dims of every buffer to
 (8|16, 128), so a ``[P, Hkv, D]`` page with D=64 cannot be DMA'd or
-sliced as stored. The wrapper therefore views the pool as lane-dense
-``[pages, P, Hkv*D]`` rows (a free reshape) and the kernel never splits
-the ``Hkv*D`` lane axis: per-head reductions and broadcasts go through a
-0/1 segment matrix ``seg[Hkv*D, 128]`` on the MXU (``(q*k) @ seg`` sums
-each head's D lanes into one score lane; ``p @ seg.T`` spreads a
-probability back over its head's lanes) at HIGHEST precision, so the
-f32 parity contract survives.
+sliced as stored, and re-laying a ``[pages, P, Hkv, D]`` array as
+lane-dense rows is a copy of the whole array under that tiling, not a
+view. The pool is therefore STORED the way the kernel DMAs it — one
+array per layer of lane-dense ``[pages, P, Hkv*D]`` rows
+(engine/kv_pool.py) — and passed to the ``pallas_call`` as it lies. The
+kernel never splits the ``Hkv*D`` lane axis: per-head reductions and
+broadcasts go through a 0/1 segment matrix ``seg[Hkv*D, 128]`` on the
+MXU (``(q*k) @ seg`` sums each head's D lanes into one score lane;
+``p @ seg.T`` spreads a probability back over its head's lanes) at
+HIGHEST precision, so the f32 parity contract survives. The XLA twin
+gathers the table's pages from the same stored shape and splits
+``Hkv*D`` into heads on the gathered context only.
 
-Layouts: q / k_new / v_new are ``[B, 1, H(kv), D]`` (decode is one
-token per slot per step); the page pool is one layer's
-``[pages, P, Hkv, D]`` slice; ``page_tables`` is ``[B, MP]`` int32 into
+Layouts: q / k_new / v_new are ``[B, Tq, H(kv), D]`` (decode is one
+token per slot per step); the page pool is one layer's stored
+``[pages, P, Hkv*D]`` array; ``page_tables`` is ``[B, MP]`` int32 into
 the pool (padded rows point at trash page 0); ``seq_lens`` ``[B]`` is
 each slot's REAL context length (the fresh token sits at position
 ``seq_lens[b]``, always visible to itself).
@@ -89,10 +94,11 @@ def kernel_supports(q: jax.Array, k_pages: jax.Array) -> bool:
     kv head, ``Hkv*D`` a whole number of 128-lane tiles, and pages that
     are whole sublane tiles of the pool dtype (8 rows f32, 16 bf16)."""
     B, Tq, Hq, D = q.shape
-    _, P, Hkv, Dk = k_pages.shape
+    _, P, HD = k_pages.shape
+    Hkv = HD // D
     sublanes = 8 * 4 // jnp.dtype(k_pages.dtype).itemsize
-    return (Tq == 1 and Dk == D and Hq % Hkv == 0 and Hkv <= _LANES
-            and (Hkv * D) % _LANES == 0 and P % sublanes == 0)
+    return (Tq == 1 and Hkv * D == HD and 0 < Hkv <= _LANES
+            and Hq % Hkv == 0 and HD % _LANES == 0 and P % sublanes == 0)
 
 
 def _seg(hd: int, d: int, *, transpose: bool = False) -> jax.Array:
@@ -266,8 +272,9 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     :func:`kernel_supports` rejects; build and compile errors propagate.
 
     q/k_new/v_new: ``[B, 1, Hq/Hkv/Hkv, D]``; k_pages/v_pages: one
-    layer's ``[pages, P, Hkv, D]`` pool; page_tables ``[B, MP]`` int32;
-    seq_lens ``[B]`` int32. Returns ``[B, 1, Hq, D]``.
+    layer's stored ``[pages, P, Hkv*D]`` pool, handed to the kernel as
+    it lies; page_tables ``[B, MP]`` int32; seq_lens ``[B]`` int32.
+    Returns ``[B, 1, Hq, D]``.
 
     ``interpret=True`` runs the Pallas interpreter so the KERNEL math is
     pinned on CPU (tier-1 tests); only a caller passes it.
@@ -277,15 +284,16 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             f"paged_decode_attention: unsupported shapes q={q.shape} "
             f"pages={k_pages.shape} {k_pages.dtype}")
     B, _, Hq, D = q.shape
-    pool, P, Hkv, _ = k_pages.shape
-    G, HD = Hq // Hkv, Hkv * D
+    _, P, HD = k_pages.shape
+    Hkv = HD // D
+    G = Hq // Hkv
     MP = page_tables.shape[1]
     # query head hq = h * G + g  ->  row g, head-h lanes
     qg = q.reshape(B, Hkv, G, D).transpose(0, 2, 1, 3).reshape(B, G, HD)
     call = _build_call(B, G, HD, D, P, MP, q.dtype, k_pages.dtype,
                        interpret)
     out = call(page_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-               qg, k_pages.reshape(pool, P, HD), v_pages.reshape(pool, P, HD),
+               qg, k_pages, v_pages,
                k_new.reshape(B, 1, HD), v_new.reshape(B, 1, HD))
     out = out.reshape(B, G, Hkv, D).transpose(0, 2, 1, 3)
     return out.reshape(B, 1, Hq, D)
@@ -296,13 +304,16 @@ def paged_decode_reference(q: jax.Array, k_pages: jax.Array,
                            seq_lens: jax.Array, k_new: jax.Array,
                            v_new: jax.Array) -> jax.Array:
     """The XLA spelling the kernel replaces — gather the table's pages
-    into a padded context, append the fresh column, broadcast GQA heads,
+    from the stored ``[pages, P, Hkv*D]`` pool into a padded context
+    (only the GATHERED rows are split into heads; the pool never is),
+    append the fresh column, broadcast GQA heads,
     and run :func:`ops.attention.cached_attention` (whose context-length
     mask is an iota compare fused into the scores, not a materialized
     boolean buffer). This is the production CPU path AND the parity
     oracle the kernel is pinned against."""
     B, Tq, Hq, D = q.shape
-    pool, P, Hkv, _ = k_pages.shape
+    _, P, HD = k_pages.shape
+    Hkv = HD // D
     MP = page_tables.shape[1]
     k_ctx = k_pages[page_tables].reshape(B, MP * P, Hkv, D)
     v_ctx = v_pages[page_tables].reshape(B, MP * P, Hkv, D)

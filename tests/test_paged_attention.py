@@ -24,8 +24,9 @@ def _case(B, Hq, Hkv, D, P, MP, lens, *, pool=None, seed=0,
     rng = np.random.default_rng(seed)
     pool = pool or (1 + B * MP)
     q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((pool, P, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((pool, P, Hkv, D)), jnp.float32)
+    # one layer of the pool in its STORED shape: lane-dense Hkv*D rows
+    kp = jnp.asarray(rng.standard_normal((pool, P, Hkv * D)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((pool, P, Hkv * D)), jnp.float32)
     kn = jnp.asarray(rng.standard_normal((B, 1, Hkv, D)), jnp.float32)
     vn = jnp.asarray(rng.standard_normal((B, 1, Hkv, D)), jnp.float32)
     if tables is None:
@@ -119,7 +120,7 @@ def test_selection_is_by_backend_and_shape():
                                   interpret=True)
     # Hkv*D must fill whole 128-lane tiles (the tiny preset's 4x16 does
     # not): selected away from the kernel, never probed
-    q_s, kp_s = q[..., :16], kp[..., :16]
+    q_s, kp_s = q[..., :16], kp[..., :2 * 16]
     assert not pa.kernel_supports(q_s, kp_s)
 
 
@@ -199,7 +200,8 @@ def test_model_kv_pages_matches_kv_ctx_gpt2():
 
     paged, muts_p = model.apply(
         {"params": params}, tokens, position_ids=seq_lens[:, None],
-        kv_pages=tuple((kp[i], vp[i]) for i in range(L)),
+        kv_pages=tuple((kp[i].reshape(pool, P, -1),
+                        vp[i].reshape(pool, P, -1)) for i in range(L)),
         page_tables=tables, kv_lens=seq_lens,
         sow_kv=True, mutable=["intermediates"])
     k_ctx = kp[:, tables].reshape(L, B, MP * P, cfg.n_head, cfg.head_dim)

@@ -130,8 +130,12 @@ def test_llama_gqa_parity():
     eng = GenerationEngine(model, params, max_slots=2, page_size=8)
     try:
         assert eng.generate(prompts, 6) == refs_for(model, params, prompts, 6)
-        # the cache really is GQA-narrow
-        assert eng._kv[0].shape[-2] == cfg.n_kv_head
+        # the cache really is GQA-narrow: one array per layer, stored
+        # as lane-dense [pages, P, n_kv_head * head_dim] rows
+        k_pages, v_pages = eng._kv
+        assert len(k_pages) == len(v_pages) == cfg.n_layer
+        assert {x.shape for x in k_pages + v_pages} == {
+            (eng.pool_pages, 8, cfg.n_kv_head * cfg.head_dim)}
     finally:
         eng.close()
 

@@ -20,8 +20,9 @@ def _case(B, Hq, Hkv, D, P, MP, lens, seed=0, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     pool = 1 + B * MP
     q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), dtype)
-    kp = jnp.asarray(rng.standard_normal((pool, P, Hkv, D)), dtype)
-    vp = jnp.asarray(rng.standard_normal((pool, P, Hkv, D)), dtype)
+    # one layer of the pool as the engine stores it: [pages, P, Hkv*D]
+    kp = jnp.asarray(rng.standard_normal((pool, P, Hkv * D)), dtype)
+    vp = jnp.asarray(rng.standard_normal((pool, P, Hkv * D)), dtype)
     kn = jnp.asarray(rng.standard_normal((B, 1, Hkv, D)), dtype)
     vn = jnp.asarray(rng.standard_normal((B, 1, Hkv, D)), dtype)
     pt = jnp.asarray(rng.integers(1, pool, (B, MP)), jnp.int32)
