@@ -18,6 +18,14 @@ layers on the device: the fresh ``[B, T, Hkv, D]`` tensors are reshaped
 to ``Hkv*D`` (small); the pool never is. The transfer plane's wire
 format stays ``[L, P, Hkv, D]`` per page — :func:`read_pages` and
 :func:`adopt_page` convert a few pages at the edge.
+
+What one layer caches a token is the MODEL's to state
+(:func:`row_widths`): a K/V pair of heads gives ``(Hkv*D, Hkv*D)``; a
+latent-attention model (models/deepseek_v3.py) gives ``(kv_lora_rank,
+qk_rope_head_dim)`` — the normed latent ``c`` in the first half of the
+pair, the one shared rotary key ``k_r`` in the second. The pair, the
+per-layer arrays, donation and every write below are the same for both:
+"k" and "v" name the halves, not what is in them.
 """
 
 from __future__ import annotations
@@ -29,13 +37,45 @@ import jax.numpy as jnp
 import numpy as np
 
 Pool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
+LANES = 128
+LATENT_CACHE_REASON = (
+    "this model caches a latent row and one shared rotary key per token "
+    "(models/deepseek_v3.py), not a K/V pair of heads: the speculative "
+    "lane and the KV transfer plane mirror the K/V geometry and are not "
+    "ported to it")
+
+
+def row_widths(cfg) -> tuple[int, int]:
+    """The widths of the two rows one layer caches a token: what the
+    model's config states (``cache_row_widths``), else one K/V pair of
+    ``n_kv_head`` (or ``n_head``) heads of ``head_dim``."""
+    stated = getattr(cfg, "cache_row_widths", None)
+    if stated is not None:
+        # stored in whole 128-lane tiles, the pad lanes zero for ever: a
+        # 64-wide row alone in its array makes XLA:TPU lay the PAGES axis
+        # minor-most and re-lay the array around every gather, and Mosaic
+        # cannot slice a page out of it (AOT for v5e, PERF.md PR 27)
+        return tuple(-(-w // LANES) * LANES for w in stated)
+    width = (getattr(cfg, "n_kv_head", None) or cfg.n_head) * cfg.head_dim
+    return width, width
+
+
+def kv_head_geometry(cfg) -> tuple[int, int]:
+    """``(kv_heads, head_dim)`` for the planes that mirror a K/V pair of
+    heads (the transfer wire's ``[L, P, Hkv, D]`` pages, the drafter's
+    pool). A model that caches anything else is refused with the
+    reason."""
+    if getattr(cfg, "cache_row_widths", None) is not None:
+        raise ValueError(LATENT_CACHE_REASON)
+    return getattr(cfg, "n_kv_head", None) or cfg.n_head, cfg.head_dim
 
 
 def make_pool(n_layers: int, pool_pages: int, page_size: int,
-              kv_heads: int, head_dim: int, dtype) -> Pool:
-    shape = (pool_pages, page_size, kv_heads * head_dim)
-    return (tuple(jnp.zeros(shape, dtype) for _ in range(n_layers)),
-            tuple(jnp.zeros(shape, dtype) for _ in range(n_layers)))
+              widths: tuple[int, int], dtype) -> Pool:
+    return tuple(
+        tuple(jnp.zeros((pool_pages, page_size, width), dtype)
+              for _ in range(n_layers))
+        for width in widths)
 
 
 def _sown(inter, layers: Sequence[str]) -> tuple[list, list]:
@@ -46,12 +86,19 @@ def _sown(inter, layers: Sequence[str]) -> tuple[list, list]:
                  for half in zip(*fresh))
 
 
+def _to_width(x, pages):
+    """A fresh row narrower than its pool's rows (a stated width stored
+    in whole lane tiles) is zero-padded to them."""
+    pad = pages.shape[-1] - x.shape[-1]
+    return jnp.pad(x, ((0, 0), (0, 0), (0, pad))) if pad else x
+
+
 def write_rows(k_pages, v_pages, inter, layers, page_idx, off) -> Pool:
     """Scatter a forward's fresh token rows: layer *i*'s sown
     ``[B, T, Hkv*D]`` row ``[b, t]`` lands at ``(page_idx[b, t],
     off[b, t])`` of layer *i*'s own arrays (verify, suffix prefill)."""
     def put(pages, rows):
-        return tuple(p.at[page_idx, off].set(x)
+        return tuple(p.at[page_idx, off].set(_to_width(x, p))
                      for p, x in zip(pages, rows))
 
     k_new, v_new = _sown(inter, layers)
@@ -74,8 +121,8 @@ def write_pages(k_pages, v_pages, inter, layers, page_row) -> Pool:
     ``[1, len(page_row) * P, Hkv*D]`` rows, page by page."""
     def put(pages, rows):
         return tuple(
-            p.at[page_row].set(x.reshape(page_row.shape[0], -1,
-                                         x.shape[-1]))
+            p.at[page_row].set(_to_width(x, p).reshape(
+                page_row.shape[0], -1, p.shape[-1]))
             for p, x in zip(pages, rows))
 
     k_new, v_new = _sown(inter, layers)

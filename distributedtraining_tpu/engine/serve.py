@@ -538,10 +538,43 @@ def host_param_template(model) -> Params:
         lambda a: np.zeros(a.shape, a.dtype), abstract)
 
 
+def _with_stats(pick, inter, layers):
+    """A serve program's token pick, and beside it, in the same fetch,
+    the counts the model's layers sowed under ``serve_stats`` (a
+    routed-expert layer's rows and touched experts: ops/moe.py), summed
+    over layers. A model that sows none returns the pick alone: its
+    programs are what they were."""
+    stats: dict = {}
+    for name in layers:
+        for sown in inter.get(name, {}).get("serve_stats", ()):
+            for key, val in sown.items():
+                stats[key] = stats[key] + val if key in stats else val
+    return (pick, stats) if stats else pick
+
+
+def _split_pick(out) -> tuple:
+    """(pick, sown counts or None) of what :func:`_with_stats` made."""
+    return out if isinstance(out, tuple) else (out, None)
+
+
+def _count_moe(stats: dict, per_step: bool = False) -> None:
+    """Feed one program run's routed rows and touched experts (summed
+    over layers) to the registry; off, or for a dense model, one
+    branch."""
+    if not stats or not obs.enabled():
+        return
+    rows = int(stats["moe_rows"])
+    touched = int(stats["moe_experts_touched"])
+    obs.count("serve.moe.rows", rows)
+    obs.count("serve.moe.experts_touched", touched)
+    if per_step and touched:
+        obs.observe("serve.moe.rows_per_expert", rows / touched)
+
+
 def _layer_keys(params) -> list[str]:
     """Transformer block keys of an UNROLLED param tree, in layer order
-    (``h_0..`` for GPT-2, ``layer_0..`` for Llama) — the same keys the
-    ``intermediates`` collection uses for sown (k, v)."""
+    (``h_0..`` for GPT-2, ``layer_0..`` for Llama and DeepSeek-V3) — the
+    same keys the ``intermediates`` collection uses for sown cache rows."""
     found = []
     for k in params:
         m = re.fullmatch(r"(h_|layer_)(\d+)", k)
@@ -799,7 +832,11 @@ class GenerationEngine:
         self.revision: str | None = None
         self._layers: list[str] | None = None
         self._kv: kv_pool.Pool | None = None
-        self._kv_heads = getattr(cfg, "n_kv_head", None) or cfg.n_head
+        # the transfer plane's wire is a K/V pair of heads: a model that
+        # caches anything else is refused here, with the reason
+        self._kv_geom = (kv_pool.kv_head_geometry(cfg)
+                         if kv_exporter is not None or kv_adopter is not None
+                         else None)
         self.pool: PagePool | None = None
         self._prefix_cache = prefix_cache
         self._cache: PrefixCache | None = None
@@ -861,7 +898,7 @@ class GenerationEngine:
         cfg = self.cfg
         self._kv = kv_pool.make_pool(
             len(self._layers), self.pool_pages, self.page_size,
-            self._kv_heads, cfg.head_dim, cfg.compute_dtype())
+            kv_pool.row_widths(cfg), cfg.compute_dtype())
         self.pool = PagePool(self.pool_pages)
         if self._prefix_cache:
             self._cache = PrefixCache(self.pool, self.page_size)
@@ -1034,7 +1071,9 @@ class GenerationEngine:
             # the logits row rides out so sampled requests can draw
             # their FIRST token through serve.sample_tok (greedy ones
             # take nxt and never touch it)
-            return nxt.astype(jnp.int32), row, k_pages, v_pages
+            return (_with_stats(nxt.astype(jnp.int32),
+                                muts["intermediates"], layers),
+                    row, k_pages, v_pages)
 
         prog = devprof.wrap(
             "serve.prefill",
@@ -1069,7 +1108,9 @@ class GenerationEngine:
                 k_pages, v_pages, muts["intermediates"], layers,
                 page_tables, seq_lens)
             nxt = jnp.argmax(logits[:, -1, :vocab], axis=-1)
-            return nxt.astype(jnp.int32), k_pages, v_pages
+            return (_with_stats(nxt.astype(jnp.int32),
+                                muts["intermediates"], layers),
+                    k_pages, v_pages)
 
         prog = devprof.wrap(
             "serve.decode",
@@ -1106,7 +1147,8 @@ class GenerationEngine:
                 page_tables, seq_lens)
             nxt = _sample_from_logits(logits[:, -1, :vocab], temps,
                                       top_ps, seeds, tok_idx)
-            return nxt, k_pages, v_pages
+            return (_with_stats(nxt, muts["intermediates"], layers),
+                    k_pages, v_pages)
 
         prog = devprof.wrap(
             "serve.decode_sample",
@@ -1149,7 +1191,9 @@ class GenerationEngine:
                 page_idx[None, :], (pos % P)[None, :])
             row = logits[0, suffix_len - 1, :vocab]
             nxt = jnp.argmax(row)
-            return nxt.astype(jnp.int32), row, k_pages, v_pages
+            return (_with_stats(nxt.astype(jnp.int32),
+                                muts["intermediates"], layers),
+                    row, k_pages, v_pages)
 
         prog = devprof.wrap(
             "serve.prefill_ctx",
@@ -1583,8 +1627,8 @@ class GenerationEngine:
         P = self.page_size
         plen = len(req.prompt)
         want = {"layers": len(self._layers), "page_size": P,
-                "kv_heads": self._kv_heads,
-                "head_dim": self.cfg.head_dim,
+                "kv_heads": self._kv_geom[0],
+                "head_dim": self._kv_geom[1],
                 "dtype": str(jnp.dtype(self.cfg.compute_dtype()))}
         if got["geometry"] != want or got["prompt_len"] != plen \
                 or len(got["pages"]) != (plen + P - 1) // P:
@@ -1657,7 +1701,7 @@ class GenerationEngine:
         ncontent = (plen + P - 1) // P
         t0 = time.perf_counter()
         k_host, v_host = kv_pool.read_pages(
-            self._kv, pages[:ncontent], self._kv_heads)
+            self._kv, pages[:ncontent], self._kv_geom[0])
         kv_ref = req.request_id or f"rq-rid{req.rid}"
         ok = self._kv_exporter.export(
             request_id=kv_ref, revision=self.revision or "",
@@ -1757,6 +1801,9 @@ class GenerationEngine:
     def _first_token(self, req: ServeRequest, nxt, logit_row) -> int:
         """The prefill's pick on the host: the host waits for the device
         HERE, which is why `serve.prefill` ends after it."""
+        nxt, stats = _split_pick(nxt)
+        if stats is not None:
+            _count_moe(jax.device_get(stats))
         if req.temperature > 0.0:
             return self._sample_tok(logit_row, req, 0)
         return int(nxt)
@@ -2056,7 +2103,9 @@ class GenerationEngine:
             self._kv = (k_pages, v_pages)
         with obs.phase("serve.decode.fetch"):
             # the host waits for the device here
-            nxt = np.asarray(jax.device_get(nxt))
+            nxt, stats = jax.device_get(_split_pick(nxt))
+            nxt = np.asarray(nxt)
+            _count_moe(stats, per_step=True)
         with obs.phase("serve.decode.emit"):
             emitted = 0
             trace_t = self.trace.clock() if self.trace is not None else 0.0

@@ -84,6 +84,9 @@ def compat_reason(draft_model, target_cfg) -> str | None:
     hook (models/gpt2.py, models/llama.py) — the load-bearing check is
     shared REAL vocabulary: draft proposals are token ids the target
     scores verbatim, so the id spaces must mean the same thing."""
+    for cfg in (draft_model.cfg, target_cfg):
+        if getattr(cfg, "cache_row_widths", None) is not None:
+            return kv_pool.LATENT_CACHE_REASON
     mod = sys.modules.get(type(draft_model).__module__)
     fn = getattr(mod, "draft_compat", None)
     if fn is None:
@@ -189,8 +192,7 @@ class DraftEngine:
         cfg = self.cfg
         self._kv = kv_pool.make_pool(
             len(self._layers), self.pool_pages, self.page_size,
-            getattr(cfg, "n_kv_head", None) or cfg.n_head, cfg.head_dim,
-            cfg.compute_dtype())
+            kv_pool.row_widths(cfg), cfg.compute_dtype())
         self.pool = PagePool(self.pool_pages)
 
     # -- state lifecycle ----------------------------------------------------

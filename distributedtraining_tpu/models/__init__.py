@@ -4,6 +4,8 @@
   neurons/miner.py:60), in 124M and 355M presets plus tiny test configs.
 - llama: Llama-2-7B / Llama-3-8B presets for the LoRA-delta and multi-host
   configs in BASELINE.json.
+- deepseek_v3: latent attention + routed/shared experts (the kanana-2
+  row), on the serving path.
 - lora: low-rank adapter trees whose *parameters are the delta*.
 """
 
@@ -12,5 +14,17 @@ from .llama import Llama, LlamaConfig
 from .toy import FeedforwardNet, SimpleCNN, ToyConfig
 from . import lora
 
+
+def family_of(preset: str):
+    """The family (its module: ``PRESETS``, ``make_model``) that owns a
+    preset's name; GPT-2's, whose lookup then names the unknown preset,
+    where none does."""
+    from . import deepseek_v3, gpt2, llama
+    for family in (llama, deepseek_v3):
+        if preset in family.PRESETS:
+            return family
+    return gpt2
+
+
 __all__ = ["GPT2", "GPT2Config", "Llama", "LlamaConfig",
-           "FeedforwardNet", "SimpleCNN", "ToyConfig", "lora"]
+           "FeedforwardNet", "SimpleCNN", "ToyConfig", "lora", "family_of"]

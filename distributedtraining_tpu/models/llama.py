@@ -79,14 +79,22 @@ PRESETS: dict[str, LlamaConfig] = {
 
 
 def rotary_embedding(x: jax.Array, position_ids: jax.Array,
-                     theta: float) -> jax.Array:
-    """Apply RoPE to [B, T, H, D] given positions [B, T]."""
+                     theta: float, *, interleaved: bool = False) -> jax.Array:
+    """Apply RoPE to [B, T, H, D] given positions [B, T]. Pair i is the
+    lanes ``(i, i + D/2)`` (Llama's halves) or, ``interleaved``, the
+    lanes ``(2i, 2i + 1)`` (DeepSeek-V3's ``rope_interleave``)."""
     D = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
     angles = position_ids[..., None].astype(jnp.float32) * inv_freq  # [B,T,D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = x32[..., 0::2], x32[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(x.shape)
+        return out.astype(x.dtype)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 

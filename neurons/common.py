@@ -20,7 +20,7 @@ from distributedtraining_tpu.data import (ByteTokenizer, batch_iterator,
                                           load_tokenizer, text_corpus)
 from distributedtraining_tpu.data.datasets import shuffle_seed_for
 from distributedtraining_tpu.engine import TrainEngine, default_optimizer
-from distributedtraining_tpu.models import gpt2, llama
+from distributedtraining_tpu.models import family_of
 from distributedtraining_tpu.parallel import make_mesh, resolve_mesh_config
 from distributedtraining_tpu.transport import (InMemoryTransport,
                                                LocalFSTransport)
@@ -258,7 +258,7 @@ def build(cfg: RunConfig) -> Components:
 
     import dataclasses as _dc
 
-    family = llama if cfg.model in llama.PRESETS else gpt2
+    family = family_of(cfg.model)
     model_cfg = family.PRESETS[cfg.model]
     if cfg.scan_blocks:
         model_cfg = _dc.replace(model_cfg, scan_blocks=True)

@@ -77,11 +77,11 @@ def _build_drafter(cfg: RunConfig, c):
     if not cfg.serve_speculative:
         return None
     from distributedtraining_tpu.engine import speculative as _spec
-    from distributedtraining_tpu.models import gpt2, llama
+    from distributedtraining_tpu.models import family_of
     try:
         if cfg.serve_draft_repo:
             preset, _, work_dir = cfg.serve_draft_repo.partition("@")
-            family = llama if preset in llama.PRESETS else gpt2
+            family = family_of(preset)
             if preset not in family.PRESETS:
                 raise ValueError(f"unknown draft preset {preset!r}")
             dmodel, _ = family.make_model(preset)

@@ -178,7 +178,7 @@ def test_read_pages_and_adopt_page_round_trip_the_wire_format():
     `read_pages` reads back, bit for bit, and other pages stay."""
     L, pages, hkv, d = 3, 6, 5, 40
     rng = np.random.default_rng(0)
-    pool = kv_pool.make_pool(L, pages, P, hkv, d, jnp.float32)
+    pool = kv_pool.make_pool(L, pages, P, (hkv * d, hkv * d), jnp.float32)
     assert len(pool[0]) == len(pool[1]) == L
     assert pool[0][0].shape == (pages, P, hkv * d)
     k_new = rng.standard_normal((L, P, hkv, d)).astype(np.float32)
