@@ -29,8 +29,9 @@ Design, in the order it matters on TPU:
   Pallas kernel on TPU, its XLA twin elsewhere; dead page slots are
   masked by real lengths, and the dense gathered context the
   pre-round-20 spelling materialized per token no longer exists).
-  Long prompts prefill through the standard model forward, i.e. through
-  ops/flash_attention.py wherever the model's ``attention_impl`` does.
+  Long prompts prefill through the standard model forward with the
+  bucket's padding mask, which ops/flash_attention.py's rule declines:
+  prefill attention is the XLA path.
   Page exhaustion preempts the youngest sequence back to the queue
   (deterministic under greedy decode) instead of OOMing the pool.
 - **Continuous batching.** The scheduler admits queued requests into

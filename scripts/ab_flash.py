@@ -1,0 +1,343 @@
+#!/usr/bin/env python3
+"""A/B of the causal attention kernels, on the chip, before one is wired in.
+
+Two families, both in the installed jax:
+
+  lib     jax.experimental.pallas.ops.tpu.flash_attention, re-blocked: the
+          forward, dK/dV and dQ kernels each with their own blocks
+  splash  jax.experimental.pallas.ops.tpu.splash_attention: the mask is
+          read at trace time, a fully masked block is no kernel step, the
+          backward is one fused kernel (or two, `unfused`)
+
+and `landed`, whatever `ops/flash_attention.py` does on this tree. Every
+candidate is timed at the train cell's shape (B=4, H=20, T=1024, D=64,
+bfloat16, packed segment ids off the 128 grid) forward alone and forward +
+gradient, and checked there against `causal_attention(impl="dense")`
+(forward and the three gradients, packed and unpacked); every forward
+candidate is timed and checked at the six forward-only shapes
+(1, {20, 25}, {256, 512, 1024}, 64) with its blocks clipped to T. The
+candidates that lost live only here.
+
+    chiprun -- python scripts/ab_flash.py          # the table, on the chip
+    JAX_PLATFORMS=cpu python scripts/ab_flash.py --aot
+                                  # compile every candidate for a described
+                                  # v5e; nothing runs, no chip needed
+
+A time is the device's: `reps` calls chained inside ONE jitted loop (each
+call's output is the next one's query), the wall clock around it with
+`block_until_ready`, the fastest of `--trials`, over `reps`. One compile
+serves the timing (reps = n) and the check (reps = 1).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas.ops.tpu import flash_attention as fa
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as sk, splash_attention_mask as sm)
+
+from distributedtraining_tpu.ops import flash_attention as landed_mod
+from distributedtraining_tpu.ops.attention import causal_attention
+
+TRAIN = (4, 20, 1024, 64)
+FORWARD_ONLY = [(1, h, t, 64) for h in (20, 25) for t in (256, 512, 1024)]
+# atol of tests_tpu/test_flash_attention_tpu.py: forward, gradients
+ATOL = (3e-2, 1e-1)
+
+
+# -- the two families, in the framework's [B, T, H, D] layout ---------------
+
+def lib(fwd, dkv=None, dq=None):
+    """fwd = (block_q, block_k_major, block_k); dkv = (block_q_major,
+    block_k_major, block_q, block_k); dq = (block_q, block_k_major,
+    block_k). Blocks are clipped to T."""
+    dkv = dkv or (fwd[0], fwd[1], fwd[0], fwd[2])
+    dq = dq or fwd
+
+    def fn(q, k, v, seg):
+        T, D = q.shape[1], q.shape[3]
+        c = lambda xs: tuple(min(x, T) for x in xs)
+        f, kv, dq_ = c(fwd), c(dkv), c(dq)
+        blocks = fa.BlockSizes(
+            block_q=f[0], block_k_major=f[1], block_k=f[2], block_b=1,
+            block_q_major_dkv=kv[0], block_k_major_dkv=kv[1],
+            block_q_dkv=kv[2], block_k_dkv=kv[3],
+            block_q_dq=dq_[0], block_k_major_dq=dq_[1], block_k_dq=dq_[2])
+        s = None if seg is None else fa.SegmentIds(q=seg, kv=seg)
+        out = fa.flash_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), segment_ids=s, causal=True,
+            sm_scale=D ** -0.5, block_sizes=blocks)
+        return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    return fn
+
+
+def splash(fwd, dkv=None, dq=None):
+    """fwd = (block_q, block_kv, block_kv_compute); dkv likewise for the
+    backward; dq = (block_q_dq, block_kv_dq) asks for the two-kernel
+    backward, None for the fused one. Blocks are clipped to T."""
+    dkv = dkv or fwd
+
+    def fn(q, k, v, seg):
+        T, H, D = q.shape[1:]
+        c = lambda xs: tuple(min(x, T) for x in xs)
+        f, b = c(fwd), c(dkv)
+        blocks = sk.BlockSizes(
+            block_q=f[0], block_kv=f[1], block_kv_compute=f[2],
+            block_q_dkv=b[0], block_kv_dkv=b[1], block_kv_dkv_compute=b[2],
+            block_q_dq=None if dq is None else min(dq[0], T),
+            block_kv_dq=None if dq is None else min(dq[1], T),
+            use_fused_bwd_kernel=dq is None)
+        kernel = sk.make_splash_mha(
+            sm.MultiHeadMask([sm.CausalMask((T, T))] * H),
+            block_sizes=blocks, head_shards=1, q_seq_shards=1)
+        qs = (q * D ** -0.5).astype(q.dtype).transpose(0, 2, 1, 3)
+        ks, vs = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        if seg is None:
+            out = jax.vmap(lambda a, b_, c_: kernel(a, b_, c_))(qs, ks, vs)
+        else:
+            out = jax.vmap(lambda a, b_, c_, s: kernel(
+                a, b_, c_, segment_ids=sk.SegmentIds(q=s, kv=s)))(
+                    qs, ks, vs, seg)
+        return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    return fn
+
+
+def landed(q, k, v, seg):
+    out = landed_mod.flash_attention(q, k, v, segment_ids=seg)
+    if out is None:
+        raise SystemExit("ops/flash_attention.py declined the shape "
+                         f"{q.shape}: no TPU backend?")
+    return out
+
+
+def dense(q, k, v, seg):
+    return causal_attention(q, k, v, segment_ids=seg, impl="dense")
+
+
+def candidates():
+    """(name, fn, role): role says which sweep a candidate belongs to —
+    'fwd' ones are also run at the forward-only shapes."""
+    out = [("landed", landed, "fwd"),
+           # the parent's rule, spelled out: one q block of 1,024 rows
+           ("lib parent 1024/256", lib((1024, 256, 256)), "fwd")]
+    sizes = (128, 256, 512)
+    for bq in sizes + (1024,):
+        for bk in sizes:
+            if (bq, bk) != (1024, 256):
+                out.append((f"lib fwd {bq}/{bk}", lib((bq, bk, bk)), "fwd"))
+    mid = (256, 256, 256)
+    for bq in sizes:
+        for bk in sizes:
+            out.append((f"lib dkv {bq}/{bk}",
+                        lib(mid, (bq, bk, bq, bk), mid), "bwd"))
+            out.append((f"lib dq {bq}/{bk}",
+                        lib(mid, None, (bq, bk, bk)), "bwd"))
+    grid = [(bq, bkv, c) for bq in (256, 512) for bkv in (256, 512)
+            for c in (128, 256, 512) if c <= bkv]
+    grid += [(128, 128, 128), (1024, 512, 512), (512, 1024, 512),
+             (1024, 1024, 512), (1024, 1024, 1024)]
+    for g in grid:
+        tag = "/".join(map(str, g))
+        out.append((f"splash fwd {tag}", splash(g, (512, 512, 512)), "fwd"))
+        out.append((f"splash fused {tag}", splash((512, 512, 512), g), "bwd"))
+    for g in ((256, 256, 256), (512, 512, 512)):
+        tag = "/".join(map(str, g))
+        out.append((f"splash unfused {tag}", splash(g, g, g[:2]), "bwd"))
+    return out
+
+
+# -- inputs -----------------------------------------------------------------
+
+def make_inputs(shape, seed, packed):
+    B, H, T, D = shape
+    rng = np.random.default_rng(seed)
+    q, k, v, do = (rng.standard_normal((B, T, H, D)).astype(np.float32)
+                   for _ in range(4))
+    seg = None
+    if packed:
+        # documents of Pareto lengths from 64 (shape 1.2), packed with no
+        # padding: the boundaries fall off every block grid (T // 64
+        # documents of at least 64 tokens always cover the row)
+        lens = (64 * (1.0 + rng.pareto(1.2, (B, T // 64)))).astype(np.int64)
+        seg = np.stack([np.repeat(np.arange(row.size), row)[:T]
+                        for row in lens]).astype(np.int32)
+    return q, k, v, do, seg
+
+
+def programs(fn, grad):
+    """(q, k, v, do, seg, reps) -> the LAST call's outputs, `reps` calls
+    chained through q. reps is traced: one compile for any count."""
+    def fwd(q, k, v, do, seg, reps):
+        del do
+        return jax.lax.fori_loop(
+            0, reps, lambda _, o: fn(o, k, v, seg), q),
+
+    def fwd_bwd(q, k, v, do, seg, reps):
+        def body(_, c):
+            out, vjp = jax.vjp(lambda a, b, c_: fn(a, b, c_, seg),
+                               c[1], k, v)
+            return (out,) + vjp(do)
+        z = jnp.zeros_like(q)
+        return jax.lax.fori_loop(0, reps, body, (z, q, z, z))
+    return jax.jit(fwd_bwd if grad else fwd)
+
+
+def on_device(arrays, sharding=None):
+    q, k, v, do, seg = arrays
+    put = lambda x, dt: None if x is None else jnp.asarray(x, dt)
+    if sharding is not None:        # --aot: shapes on the described device
+        put = lambda x, dt: None if x is None else jax.ShapeDtypeStruct(
+            x.shape, dt, sharding=sharding)
+    return (put(q, jnp.bfloat16), put(k, jnp.bfloat16), put(v, jnp.bfloat16),
+            put(do, jnp.bfloat16), put(seg, jnp.int32))
+
+
+def worst(got, ref):
+    return [float(jnp.max(jnp.abs(g.astype(jnp.float32)
+                                  - r.astype(jnp.float32))))
+            for g, r in zip(got, ref)]
+
+
+def time_ms(prog, args, reps, trials):
+    jax.block_until_ready(prog(*args, reps))
+    best = float("inf")
+    for _ in range(trials):
+        t = time.perf_counter()
+        jax.block_until_ready(prog(*args, reps))
+        best = min(best, time.perf_counter() - t)
+    return 1e3 * best / reps
+
+
+# -- the run ----------------------------------------------------------------
+
+def run_shape(shape, cands, args, oracle_cache, sharding):
+    """One row per candidate at one shape: times and worst differences."""
+    grad = shape == TRAIN
+    rows = []
+    cases = [True, False] if grad else [False]     # packed, unpacked
+    inputs = {p: on_device(make_inputs(shape, args.seed, p), sharding)
+              for p in cases}
+    if sharding is None:
+        for p in cases:
+            oracle_cache[shape, p] = programs(dense, grad)(*inputs[p], 1)
+    reps = args.reps if grad else 10 * args.reps
+    for name, fn, role in cands:
+        row = {"shape": list(shape), "candidate": name}
+        try:
+            if sharding is not None:
+                for p in cases:
+                    programs(fn, grad).trace(*inputs[p], 1).lower(
+                        lowering_platforms=("tpu",)).compile()
+                if grad and role == "fwd":
+                    programs(fn, False).trace(*inputs[True], 1).lower(
+                        lowering_platforms=("tpu",)).compile()
+                row["compiled"] = True
+            else:
+                timed = cases[0]
+                if role == "fwd":
+                    row["fwd_ms"] = time_ms(programs(fn, False),
+                                            inputs[timed], reps, args.trials)
+                for p in cases if grad else []:
+                    prog = programs(fn, True)
+                    key = "packed" if p else "unpacked"
+                    row[f"err_{key}"] = worst(prog(*inputs[p], 1),
+                                              oracle_cache[shape, p])
+                    if p == timed:
+                        row["fwd_bwd_ms"] = time_ms(prog, inputs[p], reps,
+                                                    args.trials)
+                if not grad:
+                    row["err_unpacked"] = worst(
+                        programs(fn, False)(*inputs[False], 1),
+                        oracle_cache[shape, False])
+                errs = [e for k_, e in row.items() if k_.startswith("err_")]
+                row["ok"] = all(e[0] <= ATOL[0] and max(e[1:] or [0])
+                                <= ATOL[1] for e in errs)
+        except Exception as e:  # a candidate the compiler refuses is a row
+            row["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
+def table(rows):
+    lines = ["| shape B,H,T,D | candidate | fwd ms | fwd+bwd ms | worst |out|, "
+             "|dq|, |dk|, |dv| difference from dense (packed; unpacked) | ok |",
+             "| --- | --- | --- | --- | --- | --- |"]
+    ms = lambda x: "" if x is None else f"{x:.4f}"
+    er = lambda e: "" if e is None else ", ".join(f"{x:.3g}" for x in e)
+    for r in rows:
+        if "error" in r:
+            note = r["error"]
+        elif "compiled" in r:
+            note = "compiled"
+        else:
+            note = f"{er(r.get('err_packed'))}; {er(r.get('err_unpacked'))}"
+        lines.append(f"| {','.join(map(str, r['shape']))} | {r['candidate']} "
+                     f"| {ms(r.get('fwd_ms'))} | {ms(r.get('fwd_bwd_ms'))} "
+                     f"| {note} | {r.get('ok', '')} |")
+    return "\n".join(lines)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--aot", action="store_true",
+                    help="compile for a described v5e; nothing runs")
+    ap.add_argument("--seed", type=int, default=2147483659)
+    ap.add_argument("--reps", type=int, default=20)
+    ap.add_argument("--trials", type=int, default=5)
+    ap.add_argument("--only", default="",
+                    help="substring a candidate's name must hold")
+    ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
+                                                  "ab_flash.json"))
+    args = ap.parse_args(argv)
+
+    sharding = None
+    if args.aot:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+        jax.config.update("jax_enable_compilation_cache", False)
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+        sharding = SingleDeviceSharding(topo.devices[0])
+        landed_mod._on_tpu = lambda: True
+    elif jax.default_backend() != "tpu":
+        print(f"ab_flash: needs a TPU, found {jax.default_backend()} "
+              "(use --aot to compile without one)", file=sys.stderr)
+        return 3
+    dev = jax.devices()[0]
+    print(f"ab_flash: {dev.platform} {dev.device_kind} x{jax.device_count()} "
+          f"jax {jax.__version__} aot={args.aot}", flush=True)
+
+    cands = [c for c in candidates() if args.only in c[0]]
+    rows, oracle = [], {}
+    rows += run_shape(TRAIN, cands, args, oracle, sharding)
+    fwd_only = [c for c in cands if c[2] == "fwd"]
+    for shape in FORWARD_ONLY:
+        rows += run_shape(shape, fwd_only, args, oracle, sharding)
+    text = table(rows)
+    print(text)
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump({"device": dev.device_kind, "jax": jax.__version__,
+                   "aot": args.aot, "seed": args.seed, "reps": args.reps,
+                   "rows": rows}, f, indent=1)
+    with open(os.path.splitext(args.out)[0] + ".md", "w") as f:
+        f.write(text + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

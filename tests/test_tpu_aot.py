@@ -1,5 +1,6 @@
 """Compile for the chip without one: the DeepSeek-V3 family's two Pallas
-kernels at kanana-2's published widths and the decode bucket's shapes,
+kernels at kanana-2's published widths and the decode bucket's shapes, and
+the train step's causal attention kernels at the train cell's shape,
 through the TPU's own compiler against a DESCRIBED v5e (nothing runs, no
 chip is needed). Interpret mode cannot show what this does: Mosaic refused
 the first latent kernel here for a 64-lane page slice, which is why the
@@ -7,6 +8,8 @@ pool stores the rotary key in a whole lane tile (engine/kv_pool.py).
 
 All such compiles live in this one file: only the worker that runs it
 loads the TPU's library, inside a fixture, after collection."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,3 +86,38 @@ def test_grouped_expert_product_compiles_at_published_widths(one_chip, rows,
     # gate+up fused and down: two Mosaic calls, named for the trace reader
     assert text.count("tpu_custom_call") == 2
     assert "%gmm" in text
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_causal_attention_gradient_compiles_at_the_train_cells_shape(
+        one_chip, monkeypatch, packed):
+    """`train-large-t1024`: [4, 1024, 20, 64] bfloat16, packed documents.
+    The gradient holds two Mosaic calls (the forward that keeps its
+    log-sum-exp, and ONE fused backward), and the softmax statistics never
+    lie in HBM broadcast 128 or more lanes wide per (row, head)."""
+    from distributedtraining_tpu.ops import flash_attention as fl
+    monkeypatch.setattr(fl, "_on_tpu", lambda: True)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, w, seg):
+        out = fl.flash_attention(q, k, v, segment_ids=seg)
+        return jnp.sum(out.astype(jnp.float32) * w)
+
+    qkv = sds((4, 1024, 20, 64))
+    seg = sds((4, 1024), jnp.int32) if packed else None
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv,
+                        sds((4, 1024, 20, 64), jnp.float32), seg)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    tag = "_segmented" if packed else ""
+    # the names the device-trace readers find the kernels by
+    assert f"%flash_mha_fwd{tag}_residuals" in text
+    assert f"%flash_mha_dkv{tag}_no_residuals" in text
+    wide = re.findall(r"= f32\[4,20,1024,(\d+)\]\S* broadcast\(", text)
+    assert not [n for n in wide if int(n) >= 128], wide
+    # forward alone (the validator's eval, remat's first pass): one call
+    fwd = _compile(lambda q, k, v, seg: fl.flash_attention(
+        q, k, v, segment_ids=seg), qkv, qkv, qkv, seg)
+    assert fwd.as_text().count("tpu_custom_call") == 1

@@ -1,8 +1,9 @@
 """Flash-attention numerics on the real chip: forward AND grad parity vs the
-dense oracle at T in {256, 1024}, packed segments included.
+dense oracle at T in {256, 1024}, packed segments included, and at the
+train cell's own shape with document boundaries off every block grid.
 
 This is the on-device half of tests/test_flash_attention.py (which pins
-the selection rule on CPU). The custom _block_sizes schedule
+the selection rule and the block schedule on CPU). The schedule
 (ops/flash_attention.py) rests on these numerics.
 """
 
@@ -26,6 +27,18 @@ def _segments(B, T, seed=1):
     rng = np.random.default_rng(seed)
     seg = np.repeat(rng.integers(0, 3, (B, T // 128)), 128, axis=1)
     return jnp.asarray(np.sort(seg, axis=1), jnp.int32)  # monotone per row
+
+
+def _documents(B, T, seed=1):
+    """Packing ids of documents with Pareto lengths 64..T (shape 1.2) laid
+    end to end, as the train cell's traffic packs them: the boundaries
+    fall anywhere, not on a 128 grid."""
+    rng = np.random.default_rng(seed)
+    # T // 64 documents of at least 64 tokens always cover the row
+    lens = (64 * (1.0 + rng.pareto(1.2, (B, T // 64)))).astype(np.int64)
+    seg = np.stack([np.repeat(np.arange(row.size), row)[:T] for row in lens])
+    assert ((np.flatnonzero(np.diff(seg[0])) + 1) % 128 != 0).any()
+    return jnp.asarray(seg, jnp.int32)
 
 
 @pytest.mark.parametrize("T", [256, 1024])
@@ -70,6 +83,35 @@ def test_grads_match_dense(T, packed):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             atol=1e-1, err_msg=f"d{name} mismatch (T={T}, packed={packed})")
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+def test_train_cell_shape_unaligned_documents(seed):
+    """B=4, T=1024, H=20, D=64 (`train-large-t1024`), forward and the
+    three gradients against the dense oracle, with document boundaries
+    that cut through blocks: a block the causal mask leaves whole may
+    still be cut by a segment edge."""
+    q, k, v = _qkv(B=4, T=1024, H=20, D=64, seed=seed)
+    seg = _documents(4, 1024, seed=seed)
+    w = _qkv(B=4, T=1024, H=20, D=64, seed=seed + 10)[0]
+
+    def loss(impl):
+        def f(q, k, v):
+            out = (flash_attention(q, k, v, segment_ids=seg)
+                   if impl == "flash" else
+                   causal_attention(q, k, v, segment_ids=seg, impl="dense"))
+            return jnp.sum(out.astype(jnp.float32)
+                           * w.astype(jnp.float32)), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, out_f), gf = jax.jit(loss("flash"))(q, k, v)
+    (_, out_d), gd = jax.jit(loss("dense"))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out_f, np.float32),
+                               np.asarray(out_d, np.float32), atol=3e-2)
+    for name, a, b in zip("qkv", gf, gd):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            atol=1e-1, err_msg=f"d{name} mismatch at the cell's shape")
 
 
 def test_train_step_flash_vs_dense_loss():
