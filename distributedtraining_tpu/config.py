@@ -263,15 +263,17 @@ class RunConfig:
     # and roofline achieved-fraction gauges for every registered hot
     # path; exposed via obs_http dt_prog_* series, heartbeat anat.*
     # fields, and the {"devprof": ...} JSONL record perf_report joins.
-    # On by default wherever a metrics sink is configured (measured
-    # < 2% overhead, bench._time_devprof_overhead).
+    # On by default wherever a metrics sink is configured (its cost on
+    # the chip: not measured; tests/test_planes.py holds that it sees
+    # every train-step dispatch and changes no result).
     devprof: bool = True
     # lineage/provenance plane (engine/lineage.py): the averager (and
     # every sub-averager) freezes a content-addressed __lineage__ record
     # per landed merge — parent revision, the exact contribution set and
     # weights — and runs the EWMA/CUSUM quality-drift detector over the
-    # merged held-out loss. Records are KBs; measured < 2% at soak
-    # cadence (bench._time_lineage_overhead).
+    # merged held-out loss. Records are KBs beside a full-model base
+    # publish (one record per merged round, the published base
+    # unchanged: tests/test_planes.py).
     lineage: bool = True
     mlflow_uri: Optional[str] = None
     profile_dir: Optional[str] = None        # jax.profiler trace capture

@@ -490,7 +490,7 @@ def weighted_merge_flat(base: Params, stacked_deltas: Params,
     Transient-memory cost: the ``jnp.concatenate`` materializes a second
     full [M, N] buffer (plus the f32 upcast of each row), roughly DOUBLING
     peak HBM during the merge versus the leafwise spelling. Fine at the
-    124M bench scale it serves; do not promote it into the averager for
+    124M scale it serves; do not promote it into the averager for
     7B/8B full-delta merges without a per-leaf-group variant.
     """
     from jax.flatten_util import ravel_pytree

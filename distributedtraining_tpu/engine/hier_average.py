@@ -36,8 +36,8 @@ declares ``C_j`` (its clamped consensus mass; miner count when the
 subtree has no scores — the uniform spelling). The root mixes with
 ``C_j / sum_j C_j``, so the tree telescopes to the flat merge
 ``sum_i (c_i / C) d_i`` exactly in real arithmetic and to fp tolerance
-on hardware (pinned in tests/test_hier_average.py and reported by
-``bench._time_hier_average``). A dead or torn sub-averager stages as
+on hardware (pinned in tests/test_hier_average.py). A dead or torn
+sub-averager stages as
 absent/stale at the root, which degrades to the surviving subtrees —
 the same per-miner isolation the flat gather already had.
 """
@@ -314,8 +314,8 @@ class SubAverager:
         # the PR-5 peak-bytes gauge is the production assert that the
         # packed merge stayed O(params): a fold that secretly stacked
         # M x params would jump this high-water mark by the stack size
-        # (empty on stat-less backends — CPU; bench._time_hier_average
-        # and the structural test pin it there)
+        # (empty on stat-less backends — CPU; the structural test in
+        # tests/test_hier_average.py pins it there)
         from ..utils.metrics import device_memory_watermarks
         for k, v in device_memory_watermarks().items():
             obs.gauge(f"subavg.{k}", v)

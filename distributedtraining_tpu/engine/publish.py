@@ -452,8 +452,7 @@ class DeltaPublisher:
 
         ``publish.submit_ms`` is the TRAINING THREAD's whole cost of a
         push in async mode — the number the pipeline exists to keep near
-        zero (bench._time_push_overlap measures the same thing end to
-        end)."""
+        zero."""
         t0 = time.perf_counter()
         dropped = self._worker.submit(
             lambda: self.publish_now(payload, finite, base_revision, cid,

@@ -149,8 +149,7 @@ class RemediationEngine:
     ``observe_round(breaches)`` right after ``fleet.evaluate_slos()``.
     The staging exclude hook (:meth:`is_excluded`) and score decay
     (:meth:`decay_scores`) read the current case files; both are cheap
-    dict lookups — the filter-hook cost per round is O(hotkeys), which
-    ``bench._time_remediation_overhead`` pins under 2%.
+    dict lookups — the filter-hook cost per round is O(hotkeys).
     """
 
     def __init__(self, fleet: FleetMonitor, *,

@@ -500,8 +500,7 @@ def reference_generate(model, params, prompt: Sequence[int],
     """The O(T^2) correctness oracle: greedy argmax over a FULL model
     forward of the growing sequence per token — no cache, nothing shared
     with the engine's decode path. The engine's output is pinned
-    token-identical to this loop (tests/test_serve.py); it is also the
-    "naive sequential" spelling bench._time_serve A/Bs against."""
+    token-identical to this loop (tests/test_serve.py)."""
     cfg = model.cfg
     toks = [int(t) for t in prompt]
     total = len(toks) + max_new_tokens
@@ -515,7 +514,7 @@ def reference_generate(model, params, prompt: Sequence[int],
             return jnp.argmax(
                 logits[0, cur - 1, :cfg.vocab_size]).astype(jnp.int32)
 
-        prog = _REF_PROGS[key] = jax.jit(fwd)  # devprof: exempt (bench reference path, not a production program)
+        prog = _REF_PROGS[key] = jax.jit(fwd)  # devprof: exempt (the tests' reference path, not a production program)
     buf = np.zeros((1, t_pad), np.int32)
     buf[0, :len(toks)] = toks
     cur = len(toks)
@@ -2181,7 +2180,7 @@ class GenerationEngine:
                  *, max_steps: int = 100_000, temperature: float = 0.0,
                  top_p: float = 1.0, seed: int = 0) -> list[list[int]]:
         """Submit a batch and drive the scheduler to completion (tests,
-        bench, one-shot CLI use)."""
+        one-shot CLI use)."""
         reqs = [self.submit(p, max_new_tokens, temperature=temperature,
                             top_p=top_p, seed=seed) for p in prompts]
         for _ in range(max_steps):

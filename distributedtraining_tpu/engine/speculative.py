@@ -44,10 +44,8 @@ Two drafter flavors share one duck-typed protocol (``ready`` /
   cache under a target swap.
 - :class:`ScriptedDraftSource` — a host-side drafter with no model and
   no KV: proposals come from a pure function of the request's known
-  tokens. Tests use it to force exact 0-accept / all-accept rounds, and
-  ``bench._time_serve``'s degraded-CPU lane uses it as the tiny toy
-  drafter so the ≥1.3× A/B never wedges on a host where running a real
-  draft model would cost more than it saves.
+  tokens. Tests use it to force exact 0-accept / all-accept rounds;
+  fleetsim's load points draft through it.
 
 The engine integration (engine/serve.py ``draft=`` / ``draft_k=``)
 treats either one identically; a drafter that is not ``ready`` (missing
@@ -469,12 +467,11 @@ class DraftEngine:
 
 class ScriptedDraftSource:
     """Host-side drafter: proposals come from ``fn(req, k) -> tokens``
-    with no model, no KV, and no device dispatch. Two production-ish
-    uses: the bench's degraded-CPU lane (a toy oracle drafter keeps the
-    speculative A/B meaningful on hosts where a real draft forward costs
-    more than it saves) and tests that need exact 0-accept or all-accept
-    rounds. ``commit``/``drop``/``flush`` are bookkeeping no-ops —
-    nothing to roll back."""
+    with no model, no KV, and no device dispatch. Two uses: fleetsim's
+    load points (an oracle drafter on the virtual clock) and tests that
+    need exact 0-accept or all-accept rounds.
+    ``commit``/``drop``/``flush`` are bookkeeping no-ops — nothing to
+    roll back."""
 
     def __init__(self, fn: Callable[[Any, int], Sequence[int]], *,
                  revision: str | None = "scripted"):

@@ -1451,9 +1451,7 @@ class MinerLoop:
                 self._pull_action.poll()
                 # step-time attribution: dispatch-side wall time per step
                 # (the host's view — what pipeline stalls actually cost).
-                # Two perf_counter reads + one gated histogram observe; the
-                # <2% overhead budget is pinned by
-                # bench._time_metrics_overhead.
+                # Two perf_counter reads + one gated histogram observe.
                 t0 = _time.perf_counter()
                 m = self._train_one(batch)
                 step_ms = (_time.perf_counter() - t0) * 1e3

@@ -50,14 +50,14 @@ MAX_ENTRIES = 32 * 1024
 
 _LANES = 128
 
-# test/bench hook: force the interpreter so CPU lanes exercise the
+# test hook: force the interpreter so CPU lanes exercise the
 # KERNEL math instead of the XLA fallback (set via use_interpret)
 _FORCE_INTERPRET = False
 
 
 def use_interpret(on: bool) -> None:
     """Route :func:`enabled` callers through the interpreter (CPU test
-    and bench lanes). Production never sets this."""
+    lanes). Production never sets this."""
     global _FORCE_INTERPRET
     _FORCE_INTERPRET = bool(on)
 
@@ -108,8 +108,8 @@ def _build_call(n: int, interpret: bool):
 
 def enabled() -> bool:
     """True when accumulate paths should route packed entries through
-    the kernel: a TPU backend, or the CPU interpreter when a test/bench
-    lane forced it."""
+    the kernel: a TPU backend, or the CPU interpreter when a test lane
+    forced it."""
     return _FORCE_INTERPRET or _on_tpu()
 
 

@@ -252,7 +252,7 @@ def _build_call(B, G, HD, D, P, MP, q_dtype, page_dtype, interpret: bool):
     kernel = functools.partial(
         _decode_kernel, pages_per_chunk=ppc, page_size=P,
         n_chunks=n_chunks, group=G, head_dim=D, scale=D ** -0.5)
-    return pl.pallas_call(  # devprof: exempt (attributed under serve.decode in-step; standalone A/Bs wrap it as serve.decode_attn in bench._time_decode_attn_kernel)
+    return pl.pallas_call(  # devprof: exempt (attributed under serve.decode in-step)
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, HD), q_dtype),

@@ -297,8 +297,7 @@ def _resolve_blocks(block_n: int, block_v: int) -> tuple[int, int]:
     NOTE: read at TRACE time — they bind at the first compile of a given
     jitted program; changing them in-process later does not retrace
     (bn/bv are not part of the program's avals). Set them before the
-    first step, or construct a fresh engine per setting (the tuning
-    sweep in bench.py does the latter).
+    first step, or construct a fresh engine per setting.
 
     BN is a sublane dim (16 covers the strictest bf16 tiling); BV is the
     MINORMOST dim of the logits tiles — sub-128 lanes are the narrow-lane

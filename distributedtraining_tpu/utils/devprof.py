@@ -42,8 +42,9 @@ programs here:
 
 Everything is off until :func:`enable` runs (the utils/obs.py
 contract): a wrapped program costs ONE module-flag branch when
-disabled, and bench._time_devprof_overhead pins the enabled cost at
-< 2% of step time. Exposure: ``obs.flush`` mirrors :func:`snapshot`
+disabled; the enabled cost is not measured on the chip, and
+tests/test_planes.py holds that it attributes every dispatch of the
+train step and changes no result. Exposure: ``obs.flush`` mirrors :func:`snapshot`
 into the role's JSONL sink as a ``{"devprof": ...}`` record
 (scripts/perf_report.py joins those into the where-the-time-goes
 table), utils/obs_http.py renders :func:`prom_lines`
@@ -96,9 +97,6 @@ PROGRAMS: dict[str, str] = {
     "serve.prefill": "per-T-bucket prefill program (engine/serve.py)",
     "serve.decode": "per-(slot,page)-bucket decode step "
                     "(engine/serve.py)",
-    "serve.decode_attn": "standalone fused paged-attention decode "
-                         "program (ops/paged_attention.py; the in-step "
-                         "copy is attributed under serve.decode)",
     "serve.decode_sample": "sampled (temperature/top-p, seeded PRNG) "
                            "twin of serve.decode — same forward, "
                            "scatter, and (slot,page) buckets "
@@ -276,7 +274,7 @@ class _DevprofState:
         self.roofline: Roofline | None = None
         # resolved lazily on the first observed call (enable() must not
         # force backend init inside a role that probes the backend with
-        # its own timeout discipline, bench._require_backend)
+        # its own discipline)
         self.block: bool | None = None
         self.annotate: bool | None = None
         self.probe_costs = True
@@ -616,16 +614,3 @@ def prom_lines() -> list[str]:
         lines.append("# TYPE dt_prog_dropped gauge")
         lines.append(f"dt_prog_dropped {float(_STATE.dropped)!r}")
     return lines
-
-
-def achieved_fractions() -> dict[str, float]:
-    """prog -> best achieved-FLOPs fraction across buckets — the compact
-    per-program utilization summary bench records carry so ``--baseline``
-    can gate utilization regressions, not just headline tokens/sec."""
-    rl = current_roofline()
-    out: dict[str, float] = {}
-    for r in records():
-        ff, _ = r.achieved(rl)
-        if ff is not None:
-            out[r.prog] = max(out.get(r.prog, 0.0), round(ff, 6))
-    return out

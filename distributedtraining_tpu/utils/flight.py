@@ -17,8 +17,9 @@ frozen the moment something goes wrong:
   arm/fire, lease transitions, serving hot-swap outcomes, publish
   outcomes (including torn wire-v2 shard sets), last heartbeats sent
   and observed, and the role's sanitized boot config. Recording is one
-  lock-guarded deque append — ``bench._time_flight_overhead`` pins the
-  cost on the miner step loop under 2%.
+  lock-guarded deque append (its cost on the miner step loop: not
+  measured on the chip; tests/test_planes.py holds that it changes no
+  result).
 - on an SLO breach, a remediation action, a lease flip, or a crash
   (``sys.excepthook`` / ``threading.excepthook`` / ``atexit``), the
   ring **freezes** into a content-addressed postmortem bundle — a JSON

@@ -34,8 +34,8 @@ This module is the one home of the structured layer every role emits:
 Everything here is off unless a sink is configured (``configure``): the
 module-level ``count``/``observe`` helpers and ``span`` are single-branch
 no-ops when disabled, so instrumentation costs nothing in tests and
-tight benches that never opt in (bench._time_metrics_overhead pins the
-enabled cost: < 2% of step time).
+tight loops that never opt in (one ``obs.phase``: 0.48 us off, 2.58 us
+on, PERF.md §6, PR 25).
 
 Thread discipline: the registry and the span emitter are lock-protected
 (the publish worker spans from its background thread while the train

@@ -22,7 +22,7 @@ DEFAULT_COMPILE_CACHE = os.path.join(_REPO_ROOT, ".jax_cache")
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for this process and
     return its directory. Every entry point (the four roles,
-    chip_smoke.py, bench.py, both conftests) calls this before its first
+    chip_smoke.py, both conftests) calls this before its first
     compile, so a restarted role — or the next command on the same
     machine — deserializes the previous process's XLA executables
     instead of recompiling them.

@@ -25,8 +25,8 @@ is the request-scoped layer (the TPU serving anatomy in PAPERS.md
   program vocabulary — a lint test walks the wired modules' call
   sites). Recording is host-side only: one dict merge per slot per
   decode step, zero device work, no new jit programs — steady-state
-  fresh compiles stay 0 and ``bench._time_serve`` A/Bs the overhead
-  under 2%. Per-step stages (``decode``/``spec``/``cow``) COALESCE
+  fresh compiles stay 0 (tests/test_serve.py runs with tracing on).
+  Per-step stages (``decode``/``spec``/``cow``) COALESCE
   into batched entries so a 1000-token generation holds a bounded
   timeline, not a thousand rows.
 - a **tail-exemplar reservoir** keeps the K slowest ttft/tpot requests

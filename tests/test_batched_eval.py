@@ -30,6 +30,7 @@ from distributedtraining_tpu.engine import (
     BatchedCohortEvaluator, FakeClock, TrainEngine, Validator, stage_cohorts)
 from distributedtraining_tpu.models import gpt2
 from distributedtraining_tpu.transport import InMemoryTransport
+from distributedtraining_tpu.utils import devprof
 
 SEQ = 32
 BATCH = 4
@@ -85,6 +86,32 @@ def test_cohort_matches_sequential_with_padding(setup):
     for (gl, gp), (wl, wp) in zip(got, want):
         assert gl == pytest.approx(wl, rel=2e-4, abs=1e-6)
         assert gp == pytest.approx(wp, rel=2e-4, abs=1e-6)
+
+
+def test_cohort_round_dispatch_counts(setup):
+    """The K-fold dispatch reduction, counted on the programs' own
+    names by the device observatory: K=4 candidates over 3 eval batches
+    cost 12 eval-step dispatches one candidate at a time and 3 cohort
+    dispatches batched."""
+    model, cfg, engine, val_batches, base = setup
+    deltas = _make_deltas(base, 4)
+    batches = val_batches()
+    assert len(batches) == 3
+    ev = BatchedCohortEvaluator(engine)
+
+    def calls():
+        return {r.prog: r.calls for r in devprof.records()
+                if r.prog in ("train.eval", "eval.cohort")}
+
+    devprof.enable()
+    try:
+        for d in deltas:
+            engine.evaluate(delta.apply_delta(base, d), batches)
+        assert calls() == {"train.eval": 12}
+        ev.evaluate_cohort(base, deltas, batches)
+        assert calls() == {"train.eval": 12, "eval.cohort": 3}
+    finally:
+        devprof.reset()
 
 
 def test_bucket_ladder():

@@ -77,8 +77,19 @@ def test_achieved_fractions_on_known_roofline():
     assert bf == pytest.approx(1.0)
     rec = stats.as_record(rl)
     assert rec["achieved_flops_frac"] == pytest.approx(0.5)
+    assert rec["achieved_bw_frac"] == pytest.approx(1.0)
     assert rec["arith_intensity"] == pytest.approx(
         stats.flops / stats.bytes_accessed, rel=1e-3)
+    # the same fractions in the registry's export, which the JSONL record,
+    # perf_report and /metrics read
+    devprof.enable()
+    devprof._STATE.roofline = rl
+    devprof._STATE.records[(stats.prog, stats.bucket)] = stats
+    snap = devprof.snapshot()
+    assert snap["roofline"]["peak_flops"] == 197e12
+    assert snap["programs"] == [rec]
+    assert any(line.startswith("dt_prog_achieved_flops_frac{")
+               and line.endswith(" 0.5") for line in devprof.prom_lines())
 
 
 # ---------------------------------------------------------------------------

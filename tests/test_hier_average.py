@@ -466,6 +466,46 @@ def test_sub_averager_lease_standdown(tmp_path):
         sub.close()
 
 
+def test_root_ingress_is_one_artifact_per_subtree(tmp_path):
+    """What the tree is for, as a count: under a fan-out the root's
+    ingress is ONE artifact per sub-averager, whatever the fleet's size
+    (a flat averager fetches one per miner), and every miner's artifact
+    crosses the wire once, to its own sub-averager."""
+    fetched = []
+
+    class CountingFS(LocalFSTransport):
+        def fetch_delta_bytes(self, miner_id):
+            fetched.append(miner_id)
+            return super().fetch_delta_bytes(miner_id)
+
+    transport = CountingFS(str(tmp_path))
+    transport.publish_base(_tree(100))
+    template = _template()
+    hotkeys = [f"m{i}" for i in range(8)]
+    for i, h in enumerate(hotkeys):
+        transport.publish_delta(h, _tree(i + 1))
+    plan = plan_fanout(hotkeys, fanout=4)
+    nodes = sorted(plan)
+    assert len(nodes) == 2
+    subs = [_sub(transport, n, template, plan[n]) for n in nodes]
+    root = DeltaIngestor(transport, template, workers=1, cache_bytes=0,
+                         max_delta_abs=1e3)
+    try:
+        for sub in subs:
+            assert sub.run_round() is True
+        assert sorted(fetched) == hotkeys            # each miner, once
+        fetched.clear()
+        staged = root.stage([tbase.agg_id(n) for n in nodes])
+        assert all(s.ok for s in staged)
+        assert sorted(fetched) == sorted(tbase.agg_id(n) for n in nodes)
+        # uniform consensus: an aggregate's weight is its subtree's size
+        assert sum(s.agg_weight for s in staged) == len(hotkeys)
+    finally:
+        root.close()
+        for sub in subs:
+            sub.close()
+
+
 # ---------------------------------------------------------------------------
 # Root round: hierarchy == flat, and degradation under chaos
 # ---------------------------------------------------------------------------

@@ -153,12 +153,17 @@ def test_screen_deltas_chunking_covers_long_cohorts(base):
 
 def test_pool_preserves_order_and_parallelizes():
     pool = IngestPool(4)
+    # every call waits here for the other three: the map returns only
+    # if all four are in flight at once (the timeout is a hang guard: a
+    # pool that ran them one after another breaks the barrier and fails)
+    together = threading.Barrier(4)
+
+    def job(x):
+        together.wait(timeout=30)
+        return x * 2
+
     try:
-        t0 = time.perf_counter()
-        out = pool.map(lambda x: (time.sleep(0.1), x * 2)[1], list(range(4)))
-        dt = time.perf_counter() - t0
-        assert out == [0, 2, 4, 6]
-        assert dt < 0.35, f"4x0.1s of sleep took {dt:.2f}s — not concurrent"
+        assert pool.map(job, list(range(4))) == [0, 2, 4, 6]
     finally:
         pool.close()
 
@@ -248,13 +253,28 @@ class _CountingFS(LocalFSTransport):
         super().__init__(root)
         self.latency = latency
         self.downloads = []
+        self.stamps = []        # (start, end) of every artifact fetch
         self.probes = 0
 
     def fetch_delta_bytes(self, miner_id):
-        if self.latency:
-            time.sleep(self.latency)
-        self.downloads.append(miner_id)
-        return super().fetch_delta_bytes(miner_id)
+        t_in = time.perf_counter()
+        try:
+            if self.latency:
+                time.sleep(self.latency)
+            self.downloads.append(miner_id)
+            return super().fetch_delta_bytes(miner_id)
+        finally:
+            self.stamps.append((t_in, time.perf_counter()))
+
+    def most_in_flight(self) -> int:
+        """The most fetches open at one instant, by their stamps' order."""
+        edges = sorted([(a, 1) for a, _ in self.stamps]
+                       + [(b, -1) for _, b in self.stamps])
+        most = level = 0
+        for _, step in edges:
+            level += step
+            most = max(most, level)
+        return most
 
     def delta_revision(self, miner_id):
         self.probes += 1
@@ -280,16 +300,13 @@ def test_concurrent_localfs_round_trip_downloads_once_per_revision(
     ing = DeltaIngestor(transport, host, workers=4, max_delta_abs=1e3)
     try:
         hotkeys = [f"m{i}" for i in range(4)] + ["ghost"]
-        t0 = time.perf_counter()
         staged = ing.stage(hotkeys, base_revision="base-r1")
-        cold = time.perf_counter() - t0
         assert [s.hotkey for s in staged] == hotkeys          # input order
         assert [s.reason for s in staged] == ["ok"] * 4 + ["no_delta"]
         assert all(s.cid == f"m{i}-000001"
                    for i, s in enumerate(staged[:4]))
         assert sorted(transport.downloads) == ["m0", "m1", "m2", "m3"]
-        assert cold < 4 * 0.05 + 0.1, \
-            f"cold stage not concurrent: {cold:.2f}s"
+        assert transport.most_in_flight() >= 2, "cold stage not concurrent"
         # -- warm round: revisions unchanged -> ZERO artifact downloads ---
         transport.downloads.clear()
         warm = ing.stage(hotkeys, base_revision="base-r1")
@@ -310,6 +327,41 @@ def test_concurrent_localfs_round_trip_downloads_once_per_revision(
         assert all(s.reason == "ok" for s in third[:4])
     finally:
         ing.close()
+
+
+def test_pool_overlaps_fetches_by_stamps(base, tmp_path):
+    """Serial ingest (one worker, no cache: the shape of a plain gather
+    loop) has at most ONE artifact fetch open at any instant; the pooled
+    ingestor has several open together on a cold round, and stages the
+    same accepted deltas byte for byte. Read from the start and end
+    stamps every fetch records, not from a race of two wall clocks."""
+    from distributedtraining_tpu import serialization as ser
+
+    host = _host_template(base)
+    transport = _CountingFS(str(tmp_path), latency=0.03)
+    _publish_fleet(transport, base, n=4)
+    hotkeys = [f"m{i}" for i in range(4)]
+    serial = DeltaIngestor(transport, host, workers=1, cache_bytes=0,
+                           max_delta_abs=1e3)
+    pooled = DeltaIngestor(transport, host, workers=4, max_delta_abs=1e3)
+    try:
+        staged_serial = serial.stage(hotkeys)
+        assert len(transport.stamps) == 4
+        assert transport.most_in_flight() == 1
+        transport.stamps.clear()
+        staged_pooled = pooled.stage(hotkeys)
+        assert len(transport.stamps) == 4
+        assert transport.most_in_flight() >= 2
+
+        def accepted(staged):
+            return [(s.hotkey, ser.to_msgpack(s.delta)) for s in staged
+                    if s.delta is not None]
+
+        assert len(accepted(staged_serial)) == 4
+        assert accepted(staged_serial) == accepted(staged_pooled)
+    finally:
+        serial.close()
+        pooled.close()
 
 
 def test_stale_skip_avoids_download_and_recovers(base, tmp_path):

@@ -228,6 +228,33 @@ def test_speculative_decode_on_adopted_pages(setup, sink):
         de.close()
 
 
+def test_zero_steady_state_fresh_compiles(setup, sink):
+    """Two identical waves of exports and adoptions, greedy and sampled
+    lanes mixed: the second adds ZERO fresh compiles on either worker
+    class (the adopt program compiled once in wave 1, the bucket ladders
+    are warm after it), every request is still adopted, and the output
+    is unchanged."""
+    model, cfg, params, prompts = setup
+    tr, pe, de = disagg_pair(model, params)
+
+    def wave():
+        return [hop(pe, de, prompts, sampling=sampling)[1]
+                for sampling in (None, {"temperature": 0.8, "top_p": 0.9,
+                                        "seed": 23})]
+
+    try:
+        first = wave()
+        reg = obs.registry()
+        before = reg.histogram("compile.ms").count
+        assert before > 0
+        assert wave() == first
+        assert reg.histogram("compile.ms").count == before
+        assert de.kv_adopted == 4 * len(prompts) and de.kv_reprefills == 0
+    finally:
+        pe.close()
+        de.close()
+
+
 # ---------------------------------------------------------------------------
 # Degrades (every defect -> local prefill, counted, output-identical)
 # ---------------------------------------------------------------------------

@@ -71,26 +71,27 @@ def test_native_empty_and_degenerate():
 
 
 @requires_native
-def test_native_packer_is_faster():
-    """Not a benchmark assertion in CI spirit — a sanity floor that the
-    native path actually beats the Python loop on a realistic workload."""
-    import time
+def test_native_packer_is_taken_on_array_docs(monkeypatch):
+    """On a realistic workload of array documents (what HF tokenizers hand
+    back: the zero-conversion path) ``native=True`` really runs the C++
+    packer, ``native=False`` never touches it, and both pack the same
+    sequences. How much faster the native path is belongs to a host
+    benchmark, not to a test under load."""
     rng = np.random.default_rng(2)
-    # array docs: the zero-conversion fast path (HF tokenizers hand back
-    # arrays; list docs spend ~95% of wall time in np.asarray either way)
     docs = [rng.integers(1, 50000, 700).astype(np.int32)
             for _ in range(400)]
+    calls = []
+    real = packing._pack_documents_native
 
-    def best_of(native, runs=3):
-        times, n = [], None
-        for _ in range(runs):  # best-of: a loaded test machine spikes singles
-            t0 = time.perf_counter()
-            n = sum(1 for _ in packing.pack_documents(docs, 1024,
-                                                      native=native))
-            times.append(time.perf_counter() - t0)
-        return n, min(times)
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
 
-    n_py, t_py = best_of(False)
-    n_nat, t_nat = best_of(True)
-    assert n_py == n_nat
-    assert t_nat < t_py, (t_nat, t_py)
+    monkeypatch.setattr(packing, "_pack_documents_native", counted)
+    py = _collect(packing.pack_documents(docs, 1024, native=False))
+    assert calls == []
+    nat = _collect(packing.pack_documents(docs, 1024, native=True))
+    assert calls == [1]
+    assert py.keys() == nat.keys()
+    for k in py:
+        np.testing.assert_array_equal(py[k], nat[k], err_msg=k)
