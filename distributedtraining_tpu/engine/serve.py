@@ -97,6 +97,7 @@ scraped by the PR-5 exporter as ``dt_serve_*`` gauges.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import itertools
 import json
@@ -115,7 +116,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import devprof, flight, obs, reqtrace
-from . import kv_pool
+from . import kv_pool, serve_weights
 from .batched_eval import _timed_compile
 
 logger = logging.getLogger(__name__)
@@ -613,6 +614,12 @@ class BaseRevisionWatcher:
         # (the same contract the ChaosTransport round pins for the
         # monolithic path). None = monolithic pulls.
         self.fetcher = fetcher
+        # what places a fetched base on the device. The engine that takes
+        # this watcher sets its serving-tree maker (serve_weights.make)
+        # here, so a revision is rounded on THIS thread and the swap is
+        # a rebind; what was staged before that is a placed base, and
+        # ``install_params`` takes either.
+        self.prepare: Callable[[Params], Params] = jax.device_put
         self.poll_s = poll_s
         self._last_seen = start_revision
         self._pending: tuple[str | None, Params] | None = None
@@ -661,7 +668,7 @@ class BaseRevisionWatcher:
             flight.record("swap", outcome="torn_fetch", revision=rev or "")
             return False
         base, fetched_rev = got
-        placed = jax.device_put(base)
+        placed = self.prepare(base)
         jax.block_until_ready(placed)   # stage fully OFF the decode thread
         with self._lock:
             self._pending = (fetched_rev, placed)
@@ -742,6 +749,8 @@ class GenerationEngine:
         self.eos_id = eos_id
         self.swap_policy = swap_policy
         self.watcher = watcher
+        if watcher is not None:
+            watcher.prepare = functools.partial(serve_weights.make, cfg)
         cap = getattr(cfg, "n_positions", None) or getattr(
             cfg, "max_seq_len", 0)
         # page-align DOWN so no prefill bucket can exceed the model's
@@ -831,7 +840,7 @@ class GenerationEngine:
         self._params: Params | None = None
         self.revision: str | None = None
         self._layers: list[str] | None = None
-        self._kv: kv_pool.Pool | None = None
+        self._kv_arrays: kv_pool.Pool | None = None
         # the transfer plane's wire is a K/V pair of heads: a model that
         # caches anything else is refused here, with the reason
         self._kv_geom = (kv_pool.kv_head_geometry(cfg)
@@ -883,11 +892,14 @@ class GenerationEngine:
     # -- weights ------------------------------------------------------------
     def install_params(self, params: Params, *,
                        revision: str | None = None) -> None:
-        """Bind a base revision as the serving weights (boot path and the
-        swap path). Params are jit ARGUMENTS (never donated), so a swap
-        cannot invalidate an in-flight program's buffers — the old tree
-        simply drops its last reference."""
-        placed = jax.device_put(params)
+        """Bind a base revision as the serving weights: ``params`` is a
+        base as published (host or device) or a serving tree the watcher
+        staged; what is bound, and what every serve program takes, is
+        its serving tree (engine/serve_weights.py). Params are jit
+        ARGUMENTS (never donated), so a swap cannot invalidate an
+        in-flight program's buffers — the old tree simply drops its last
+        reference."""
+        placed = serve_weights.make(self.cfg, params)
         if self._layers is None:
             self._layers = _layer_keys(placed)
             self._init_kv()
@@ -895,13 +907,26 @@ class GenerationEngine:
         self.revision = revision
 
     def _init_kv(self) -> None:
-        cfg = self.cfg
-        self._kv = kv_pool.make_pool(
-            len(self._layers), self.pool_pages, self.page_size,
-            kv_pool.row_widths(cfg), cfg.compute_dtype())
         self.pool = PagePool(self.pool_pages)
         if self._prefix_cache:
             self._cache = PrefixCache(self.pool, self.page_size)
+
+    @property
+    def _kv(self) -> kv_pool.Pool:
+        """The pool's device arrays, made when a program first takes
+        them: a caller that handed ``install_params`` a float32 base
+        lying on the device has let go of it by then, so that base, the
+        serving tree and the pool never stand there together."""
+        if self._kv_arrays is None:
+            cfg = self.cfg
+            self._kv_arrays = kv_pool.make_pool(
+                len(self._layers), self.pool_pages, self.page_size,
+                kv_pool.row_widths(cfg), cfg.compute_dtype())
+        return self._kv_arrays
+
+    @_kv.setter
+    def _kv(self, pool: kv_pool.Pool) -> None:
+        self._kv_arrays = pool
 
     # -- submission ---------------------------------------------------------
     def submit(self, prompt: Sequence[int],

@@ -56,6 +56,7 @@ wrong output.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import sys
 import time
@@ -66,7 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import devprof, obs
-from . import kv_pool
+from . import kv_pool, serve_weights
 from .batched_eval import _timed_compile
 from .serve import (DEFAULT_PAGE_SIZE, BucketLadder, PagePool,
                     _layer_keys, _sample_from_logits)
@@ -130,6 +131,8 @@ class DraftEngine:
         self.page_size = page_size
         self.max_slots = max_slots
         self.watcher = watcher
+        if watcher is not None:
+            watcher.prepare = functools.partial(serve_weights.make, cfg)
         cap = getattr(cfg, "n_positions", None) or getattr(
             cfg, "max_seq_len", 0)
         self.max_seq_len = (min(max_seq_len or cap, cap)
@@ -177,8 +180,9 @@ class DraftEngine:
         """Bind a draft revision. Draft KV is a pure function of (draft
         params, tokens), so every cached state is stale the instant a
         new revision lands — flush, exactly like the prefix cache under
-        a target-base swap."""
-        placed = jax.device_put(params)
+        a target-base swap. What is bound is the draft's serving tree
+        (engine/serve_weights.py), as for the target."""
+        placed = serve_weights.make(self.cfg, params)
         if self._layers is None:
             self._layers = _layer_keys(placed)
             self._init_kv()

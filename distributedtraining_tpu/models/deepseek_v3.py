@@ -135,6 +135,17 @@ class DeepseekV3Config:
     def storage_dtype(self):
         return jnp.dtype(self.param_dtype)
 
+    def rounds_first(self, path: tuple[str, ...]) -> bool:
+        """See ``GPT2Config.rounds_first``. Cast before every use: the
+        ``nn.Dense`` kernels, ``kv_b_proj``, the experts' two stacks and
+        the head; the lookup's rows straight after the gather. Not the
+        router and its selection bias (float32 scores, ops/moe.route),
+        not an RMSNorm's scale."""
+        return path[-1] in _CAST_FIRST
+
+
+_CAST_FIRST = ("kernel", "kv_b_proj", "experts_gate_up", "experts_down",
+               "lm_head", "embed_tokens")
 
 PRESETS: dict[str, DeepseekV3Config] = {
     # the published sizes: 30.7B parameters, never built on one chip

@@ -81,6 +81,24 @@ class GPT2Config:
     def storage_dtype(self):
         return jnp.dtype(self.param_dtype)
 
+    def rounds_first(self, path: tuple[str, ...]) -> bool:
+        """Whether EVERY use of the leaf at ``path`` of an unrolled base
+        in the serving forward casts it to the compute dtype first, so
+        that the serving tree (engine/serve_weights.py) may hold it
+        rounded, once per revision: a block's four ``nn.Dense`` (which
+        promotes kernel AND bias). Not ``wte`` / ``wpe``, which the
+        lookup reads as stored (``wte[id] + wpe[pos]`` is a float32 sum,
+        rounded after); not a LayerNorm, which multiplies by its float32
+        scale."""
+        return len(path) == 3 and path[1] in _DENSE
+
+    # The tied head multiplies by ``wte`` ROUNDED, the lookup reads it as
+    # stored: the serving tree holds the head's operand as a leaf of its
+    # own under the first name, rounded from the second.
+    serving_head = ("lm_head", "wte")
+
+
+_DENSE = ("c_attn", "c_proj", "c_fc", "mlp_proj")
 
 # Preset registry; "tiny" is the test model (fast CPU init/step).
 PRESETS: dict[str, GPT2Config] = {
@@ -313,7 +331,14 @@ class GPT2(nn.Module):
         # hybrid (dcn_dp) meshes that reshard is inexpressible and falls
         # back to involuntary full rematerialization. No-op without an
         # ambient logical_axis_rules context (single-device paths).
-        logits = jnp.einsum("bte,ve->btv", x, wte.astype(cfg.compute_dtype()),
+        if self.has_variable("params", "lm_head"):
+            # a serving tree (engine/serve_weights.py): the head's operand
+            # was rounded when the revision was installed. No base and no
+            # training tree has this leaf: they trace the line below.
+            head = self.get_variable("params", "lm_head")
+        else:
+            head = wte.astype(cfg.compute_dtype())
+        logits = jnp.einsum("bte,ve->btv", x, head,
                             preferred_element_type=jnp.float32)
         logits = nn.with_logical_constraint(logits, ("batch", None, "vocab"))
         # the astype fuses into the matmul epilogue, so "bfloat16" means the

@@ -66,6 +66,14 @@ class LlamaConfig:
     def storage_dtype(self):
         return jnp.dtype(self.param_dtype)
 
+    def rounds_first(self, path: tuple[str, ...]) -> bool:
+        """See ``GPT2Config.rounds_first``. Every matrix is a bias-free
+        ``nn.Dense`` kernel or the untied head, cast before its product;
+        the lookup's rows are cast straight after the gather, which
+        rounds the same values. Not an RMSNorm's scale: it multiplies in
+        float32."""
+        return path[-1] in ("kernel", "lm_head", "wte")
+
 
 PRESETS: dict[str, LlamaConfig] = {
     "llama2-7b": LlamaConfig(),

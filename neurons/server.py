@@ -132,11 +132,6 @@ def main(argv=None) -> int:
     watcher = BaseRevisionWatcher(
         c.transport, lambda: host_param_template(c.model),
         poll_s=max(cfg.swap_poll, 0.1), fetcher=base_fetcher)
-    params, revision = _await_base(cfg, c, watcher)
-    if base_fetcher is not None and revision is None and params is not None:
-        # --init-from boot: seed the shard store from the weights we
-        # serve, so the FIRST published base pulls only what differs
-        base_fetcher.seed(params)
     # SLO burn-rate alerting over the request-trace stream
     # (engine/health.py): every finished/shed request the TraceBook
     # records feeds the monitor; multi-window rules fire the standard
@@ -155,8 +150,7 @@ def main(argv=None) -> int:
     kv_adopter = (_kvt.KVAdopter(c.transport)
                   if cfg.serve_phase == "decode" else None)
     engine = GenerationEngine(
-        c.model, params, revision=revision,
-        max_slots=cfg.serve_slots, page_size=cfg.serve_page_size,
+        c.model, max_slots=cfg.serve_slots, page_size=cfg.serve_page_size,
         pool_pages=cfg.serve_kv_pages, max_seq_len=cfg.serve_max_seq,
         max_new_tokens=cfg.serve_max_new,
         eos_id=getattr(c.tokenizer, "eos_id", None),
@@ -171,6 +165,21 @@ def main(argv=None) -> int:
         trace_window_s=cfg.serve_trace_window or 30.0,
         burn=burn, phase=cfg.serve_phase,
         kv_exporter=kv_exporter, kv_adopter=kv_adopter)
+    # the engine holds the watcher now: what the boot poll stages is the
+    # serving tree already (engine/serve_weights.py), made leaf by leaf
+    # from the host base, and the float32 base never lies on the device
+    try:
+        params, revision = _await_base(cfg, c, watcher)
+    except BaseException:
+        engine.close()
+        raise
+    if base_fetcher is not None and revision is None:
+        # --init-from boot: seed the shard store from the weights we
+        # serve (the host's float32 base, as published bases are), so
+        # the FIRST published base pulls only what differs
+        base_fetcher.seed(params)
+    engine.install_params(params, revision=revision)
+    del params   # the engine's to drop at the first swap
     watcher.start()
 
     # health plane: the server heartbeats its SERVED revision (the

@@ -121,3 +121,64 @@ def test_causal_attention_gradient_compiles_at_the_train_cells_shape(
     fwd = _compile(lambda q, k, v, seg: fl.flash_attention(
         q, k, v, segment_ids=seg), qkv, qkv, qkv, seg)
     assert fwd.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("preset, mosaic_calls", [("gpt2-774m", 36),
+                                                  ("gpt2-1.5b", 0)])
+def test_decode_program_reads_each_matrix_in_the_dtype_it_multiplies_in(
+        one_chip, monkeypatch, preset, mosaic_calls):
+    """`serve-large-chat` / `serve-xl-chat`, the 8-slot x 16-page decode
+    program over the serving tree (engine/serve_weights.py): the only
+    float32 matrices among its arguments are the lookup's two tables; no
+    matrix is converted to bfloat16 inside the step; the weights'
+    argument bytes are at most 0.6 of the float32 base's; the paged
+    kernel runs once a layer where it ran (heads of 64 x 20 = 1280
+    lanes) and nowhere where it did not (25 x 64 = 1600). What stays,
+    and is held here so that the PR that removes it sees this fail: the
+    TPU lays a table 1,600 wide (no multiple of 128 lanes) column-major,
+    the head reads it as it lies, and the LOOKUP copies all of
+    `f32[50304,1600]` to gather 8 rows of it, on every step (PERF.md,
+    PR 30: the copy is the lookup's, not the head's)."""
+    from distributedtraining_tpu.engine import serve, serve_weights
+    from distributedtraining_tpu.models import gpt2
+    from distributedtraining_tpu.ops import paged_attention as pa
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    slots, pages, P = 8, 16, 16
+
+    model, cfg = gpt2.make_model(gpt2.PRESETS[preset])
+    base = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0))))
+    tree = serve_weights.abstract(cfg, base)
+    eng = serve.GenerationEngine(model, None, max_slots=slots, page_size=P,
+                                 max_seq_len=1024)
+    try:
+        eng._layers, eng._donate = serve._layer_keys(base), True
+
+        def sds(shape, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        half = (sds((eng.pool_pages, P, cfg.n_embd), jnp.bfloat16),
+                ) * cfg.n_layer
+        compiled = eng._decode_prog(slots, pages).__wrapped__.trace(
+            tree, half, half, sds((slots, pages)), sds((slots,)),
+            sds((slots,))).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        eng.close()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    f32_matrices = set(re.findall(
+        r"= f32\[(\d+),(\d+)\]\S* parameter\(", entry))
+    V, E = str(cfg.padded_vocab), str(cfg.n_embd)
+    assert f32_matrices == {(V, E), (str(cfg.n_positions), E)}
+    big = [m for m in re.findall(r"= bf16\[(\d+),(\d+)\]\S* convert\(", text)
+           if int(m[0]) * int(m[1]) >= 2 ** 20]
+    assert not big, big
+    pool_bytes = 2 * cfg.n_layer * eng.pool_pages * P * cfg.n_embd * 2
+    base_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(base))
+    weights = compiled.memory_analysis().argument_size_in_bytes - pool_bytes
+    assert 0.5 * base_bytes < weights <= 0.6 * base_bytes
+    assert text.count("tpu_custom_call") == mosaic_calls
+    table_copies = re.findall(rf"= f32\[{V},{E}\]\S* copy\(", text)
+    assert len(table_copies) == (int(E) % 128 != 0)

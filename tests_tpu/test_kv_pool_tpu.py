@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributedtraining_tpu.engine import serve
+from distributedtraining_tpu.engine import serve, serve_weights
 from distributedtraining_tpu.models import gpt2
 
 SLOTS, P, SEQ = 32, 16, 1024
@@ -24,8 +24,9 @@ SLOTS, P, SEQ = 32, 16, 1024
 @pytest.fixture(scope="module")
 def large():
     model, cfg = gpt2.make_model(gpt2.PRESETS["gpt2-774m"])
-    params = jax.eval_shape(
-        lambda: model.init_params(jax.random.PRNGKey(0), seq_len=8))
+    # what the programs take: the float32 base's serving tree
+    params = serve_weights.abstract(cfg, jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), seq_len=8)))
     eng = serve.GenerationEngine(model, None, max_slots=SLOTS, page_size=P,
                                  max_seq_len=SEQ)
     assert eng._donate, "not a TPU backend"
