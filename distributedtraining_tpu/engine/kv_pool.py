@@ -26,6 +26,21 @@ qk_rope_head_dim)`` — the normed latent ``c`` in the first half of the
 pair, the one shared rotary key ``k_r`` in the second. The pair, the
 per-layer arrays, donation and every write below are the same for both:
 "k" and "v" name the halves, not what is in them.
+
+A second KIND of cache lives beside the pages, for a family whose layers
+do not all cache per token (models/nemotron_h.py): the family states per
+layer what it keeps (``cfg.layer_caches``: ``"kv"`` the paged pair above,
+``"ssm"`` a recurrent state, None nothing), the paged pool holds arrays
+for the ``"kv"`` layers only, and each ``"ssm"`` layer has one float32
+state ``[slots + 1, H, P, N]`` and one convolution tail ``[slots + 1,
+K - 1, C]`` addressed by SLOT, not by position (:func:`make_state_pool`).
+They are donated and returned like the pages. A slot's row is written
+whole by its prefill (:func:`write_slot_state`) and moved on in place by
+every decode step (the model's layer does that itself and sows the pools
+back: :func:`sown_state`); the last row belongs to no request and takes a
+decode bucket's padding rows, as page 0 takes their page writes. Nothing
+of it can be shared by prefix, rolled back after a refused draft or
+shipped page by page: :data:`RECURRENT_STATE_REASON`.
 """
 
 from __future__ import annotations
@@ -43,6 +58,24 @@ LATENT_CACHE_REASON = (
     "(models/deepseek_v3.py), not a K/V pair of heads: the speculative "
     "lane and the KV transfer plane mirror the K/V geometry and are not "
     "ported to it")
+RECURRENT_STATE_REASON = (
+    "this model keeps a recurrent state per slot (models/nemotron_h.py), "
+    "which is not addressed by position: a shared prefix has no state to "
+    "map, a refused draft cannot be rolled back and there are no pages to "
+    "ship, so the prefix cache, the speculative lane and the KV transfer "
+    "plane are not ported to it")
+StatePool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
+
+
+def layer_caches(cfg, n_layers: int) -> tuple[str | None, ...]:
+    """What each layer keeps for a sequence: what the model's config
+    states (``layer_caches``), else the paged pair in every layer."""
+    stated = getattr(cfg, "layer_caches", None)
+    return ("kv",) * n_layers if stated is None else tuple(stated)
+
+
+def has_recurrent_state(cfg) -> bool:
+    return "ssm" in (getattr(cfg, "layer_caches", None) or ())
 
 
 def row_widths(cfg) -> tuple[int, int]:
@@ -67,6 +100,8 @@ def kv_head_geometry(cfg) -> tuple[int, int]:
     reason."""
     if getattr(cfg, "cache_row_widths", None) is not None:
         raise ValueError(LATENT_CACHE_REASON)
+    if has_recurrent_state(cfg):
+        raise ValueError(RECURRENT_STATE_REASON)
     return getattr(cfg, "n_kv_head", None) or cfg.n_head, cfg.head_dim
 
 
@@ -76,6 +111,32 @@ def make_pool(n_layers: int, pool_pages: int, page_size: int,
         tuple(jnp.zeros((pool_pages, page_size, width), dtype)
               for _ in range(n_layers))
         for width in widths)
+
+
+def make_state_pool(cfg, n_layers: int, slots: int) -> StatePool:
+    """One float32 state and one tail (in the compute dtype) for each
+    ``"ssm"`` layer, ``slots`` rows and one spare."""
+    return (tuple(jnp.zeros((slots + 1, *cfg.ssm_state_shape), jnp.float32)
+                  for _ in range(n_layers)),
+            tuple(jnp.zeros((slots + 1, *cfg.ssm_tail_shape),
+                            cfg.compute_dtype()) for _ in range(n_layers)))
+
+
+def sown_state(inter, layers: Sequence[str]) -> StatePool:
+    """What the ``"ssm"`` layers of a forward sowed under ``ssm_cache``,
+    layer by layer: a decode step's moved pools, or a prefill's one state
+    and tail."""
+    return tuple(zip(*(inter[name]["ssm_cache"][0] for name in layers)))
+
+
+def write_slot_state(states, tails, inter, layers, slot) -> StatePool:
+    """A prefill's write: the state after the prompt's last token and the
+    tail before it, of batch row 0, over row ``slot`` of every layer's
+    pool. Whatever the row held is gone: a slot is never zeroed."""
+    new_states, new_tails = sown_state(inter, layers)
+    return (tuple(p.at[slot].set(x[0]) for p, x in zip(states, new_states)),
+            tuple(p.at[slot].set(x[0].astype(p.dtype))
+                  for p, x in zip(tails, new_tails)))
 
 
 def _sown(inter, layers: Sequence[str]) -> tuple[list, list]:

@@ -90,6 +90,17 @@ bit-identical to spec-off streams, for greedy and sampled lanes alike.
 Rollback is length bookkeeping, the drafter has its own hot-swap lane,
 and a missing/stale/broken drafter degrades to plain decode.
 
+A family whose layers do not all cache per token (models/nemotron_h.py:
+state-space layers beside attention) states per layer what it keeps
+(``cfg.layer_caches``): the page pool then holds the attention layers
+only, and beside it lives one fixed-size recurrent state per SLOT for
+each state-space layer (engine/kv_pool.py), written whole by a slot's
+prefill, moved on in place by every decode step, handed back at
+``_release``. The pools ride behind the programs' other arguments; a
+family without them passes nothing there. The prefix cache, the
+speculative lane and KV transfer address state by position and refuse
+such a family (``kv_pool.RECURRENT_STATE_REASON``; docs/serving.md).
+
 Everything is exposed through the PR-3 obs registry as ``serve.*`` and
 scraped by the PR-5 exporter as ``dt_serve_*`` gauges.
 """
@@ -553,23 +564,41 @@ def _with_stats(pick, inter, layers):
     return (pick, stats) if stats else pick
 
 
+def _state_kwargs(slot_state: tuple) -> dict:
+    """The model arguments of a decode program's per-slot state tail
+    ``(states, tails, slots)``; none for a family that keeps none."""
+    if not slot_state:
+        return {}
+    states, tails, slots = slot_state
+    return {"ssm_pools": tuple(zip(states, tails)), "slots": slots}
+
+
 def _split_pick(out) -> tuple:
     """(pick, sown counts or None) of what :func:`_with_stats` made."""
     return out if isinstance(out, tuple) else (out, None)
 
 
-def _count_moe(stats: dict, per_step: bool = False) -> None:
-    """Feed one program run's routed rows and touched experts (summed
-    over layers) to the registry; off, or for a dense model, one
-    branch."""
+_SOWN_COUNTERS = {
+    "moe_rows": "serve.moe.rows",
+    "moe_experts_touched": "serve.moe.experts_touched",
+    "moe_rows_elsewhere": "serve.moe.rows_elsewhere",
+    "ssm_slot_steps": "serve.ssm.slot_steps",
+}
+
+
+def _count_sown(stats: dict, per_step: bool = False) -> None:
+    """Feed what one program run's layers counted (summed over layers) to
+    the registry: a routed-expert layer's rows computed here, rows left
+    to other chips and touched experts, a state-space layer's live slots;
+    off, or for a model that sows none, one branch."""
     if not stats or not obs.enabled():
         return
-    rows = int(stats["moe_rows"])
-    touched = int(stats["moe_experts_touched"])
-    obs.count("serve.moe.rows", rows)
-    obs.count("serve.moe.experts_touched", touched)
+    for key, val in stats.items():
+        obs.count(_SOWN_COUNTERS[key], int(val))
+    touched = int(stats.get("moe_experts_touched", 0))
     if per_step and touched:
-        obs.observe("serve.moe.rows_per_expert", rows / touched)
+        obs.observe("serve.moe.rows_per_expert",
+                    int(stats["moe_rows"]) / touched)
 
 
 def _layer_keys(params) -> list[str]:
@@ -741,6 +770,12 @@ class GenerationEngine:
             raise ValueError("max_slots and page_size must be >= 1")
         cfg = model.cfg
         cfg = dataclasses.replace(cfg, remat=False, scan_blocks=False)
+        # a recurrent state is not addressed by position: what rests on
+        # positions refuses the family, with the reason
+        if kv_pool.has_recurrent_state(cfg) and (
+                prefix_cache or draft is not None or kv_exporter is not None
+                or kv_adopter is not None):
+            raise ValueError(kv_pool.RECURRENT_STATE_REASON)
         self.model = type(model)(cfg)
         self.cfg = cfg
         self.page_size = page_size
@@ -841,6 +876,15 @@ class GenerationEngine:
         self.revision: str | None = None
         self._layers: list[str] | None = None
         self._kv_arrays: kv_pool.Pool | None = None
+        # whether some layer keeps a state per slot (cfg.layer_caches),
+        # those layers' pools (states, tails), and which of their rows
+        # each admitted request owns; all stay empty for a family that
+        # caches per token only
+        self._recurrent = kv_pool.has_recurrent_state(cfg)
+        self._ssm: kv_pool.StatePool = ((), ())
+        self._ssm_bytes = 0.0
+        self._state_free: list[int] = []
+        self._state_of: dict[int, int] = {}
         # the transfer plane's wire is a K/V pair of heads: a model that
         # caches anything else is refused here, with the reason
         self._kv_geom = (kv_pool.kv_head_geometry(cfg)
@@ -906,10 +950,27 @@ class GenerationEngine:
         self._params = placed
         self.revision = revision
 
+    def _layers_keeping(self, kind: str) -> list[str]:
+        """The layers that keep ``kind`` for a sequence, in layer order:
+        ``"kv"`` pages (every layer, unless the family states otherwise),
+        ``"ssm"`` a per-slot state."""
+        caches = kv_pool.layer_caches(self.cfg, len(self._layers))
+        return [n for n, c in zip(self._layers, caches) if c == kind]
+
+    @property
+    def _kv_layers(self) -> list[str]:
+        return self._layers_keeping("kv")
+
+    @property
+    def _ssm_layers(self) -> list[str]:
+        return self._layers_keeping("ssm")
+
     def _init_kv(self) -> None:
         self.pool = PagePool(self.pool_pages)
         if self._prefix_cache:
             self._cache = PrefixCache(self.pool, self.page_size)
+        if self._recurrent:
+            self._state_free = list(range(self.max_slots))
 
     @property
     def _kv(self) -> kv_pool.Pool:
@@ -920,8 +981,14 @@ class GenerationEngine:
         if self._kv_arrays is None:
             cfg = self.cfg
             self._kv_arrays = kv_pool.make_pool(
-                len(self._layers), self.pool_pages, self.page_size,
+                len(self._kv_layers), self.pool_pages, self.page_size,
                 kv_pool.row_widths(cfg), cfg.compute_dtype())
+            if self._recurrent:
+                self._ssm = kv_pool.make_state_pool(
+                    cfg, len(self._ssm_layers), self.max_slots)
+                self._ssm_bytes = float(sum(
+                    x.nbytes for half in self._ssm for x in half))
+                obs.gauge("serve.ssm.state_bytes", self._ssm_bytes)
         return self._kv_arrays
 
     @_kv.setter
@@ -1075,22 +1142,43 @@ class GenerationEngine:
         return got
 
     # -- programs -----------------------------------------------------------
+    def _donated(self, pages_at: int, state_at: int) -> tuple:
+        """The argument numbers a serve program updates in place: the two
+        halves of the page pool and, for a family that keeps them, the
+        two halves of the per-slot state behind the other arguments."""
+        if not self._donate:
+            return ()
+        state = (state_at, state_at + 1) if self._recurrent else ()
+        return (pages_at, pages_at + 1, *state)
+
+    def _slot_state(self, rows) -> tuple:
+        """A serve program's per-slot state tail: the pools and the
+        row(s) it writes; nothing for a family that keeps none. Read
+        after ``self._kv``, which makes the pools."""
+        return (*self._ssm, rows) if self._recurrent else ()
+
     def _prefill_prog(self, t_bucket: int) -> Callable:
         prog = self._prefill_progs.get(t_bucket)
         if prog is not None:
             return prog
         model, vocab = self.model, self.cfg.vocab_size
-        layers = self._layers
+        layers, kv_layers, ssm_layers = (self._layers, self._kv_layers,
+                                         self._ssm_layers)
 
         def serve_prefill(params, tokens, prompt_len, k_pages, v_pages,
-                          page_row):
+                          page_row, *slot_state):
+            # slot_state: a family with per-slot state passes (states,
+            # tails, slot) behind and gets the written pools back behind
             amask = (jnp.arange(t_bucket)[None, :]
                      < prompt_len).astype(jnp.int32)
             logits, muts = model.apply(
                 {"params": params}, tokens, attention_mask=amask,
                 sow_kv=True, mutable=["intermediates"])
             k_pages, v_pages = kv_pool.write_pages(
-                k_pages, v_pages, muts["intermediates"], layers, page_row)
+                k_pages, v_pages, muts["intermediates"], kv_layers, page_row)
+            moved = (kv_pool.write_slot_state(
+                *slot_state[:2], muts["intermediates"], ssm_layers,
+                slot_state[2]),) if slot_state else ()
             row = logits[0, prompt_len - 1, :vocab]
             nxt = jnp.argmax(row)
             # the logits row rides out so sampled requests can draw
@@ -1098,12 +1186,11 @@ class GenerationEngine:
             # take nxt and never touch it)
             return (_with_stats(nxt.astype(jnp.int32),
                                 muts["intermediates"], layers),
-                    row, k_pages, v_pages)
+                    row, k_pages, v_pages, *moved)
 
         prog = devprof.wrap(
             "serve.prefill",
-            jax.jit(serve_prefill,
-                    donate_argnums=(3, 4) if self._donate else ()),
+            jax.jit(serve_prefill, donate_argnums=self._donated(3, 6)),
             bucket=t_bucket)
         self._prefill_progs[t_bucket] = prog
         return prog
@@ -1113,10 +1200,11 @@ class GenerationEngine:
         if prog is not None:
             return prog
         model, vocab = self.model, self.cfg.vocab_size
-        layers = self._layers
+        layers, kv_layers, ssm_layers = (self._layers, self._kv_layers,
+                                         self._ssm_layers)
 
         def serve_decode(params, k_pages, v_pages, page_tables, seq_lens,
-                         tokens):
+                         tokens, *slot_state):
             # paged attention: each block reads its OWN page-pool slice
             # directly through the table (ops/paged_attention.py — the
             # fused gather+attend kernel on TPU, its XLA twin off-TPU).
@@ -1128,19 +1216,22 @@ class GenerationEngine:
                 position_ids=seq_lens[:, None],
                 kv_pages=kv_pages, page_tables=page_tables,
                 kv_lens=seq_lens,
-                sow_kv=True, mutable=["intermediates"])
+                sow_kv=True, mutable=["intermediates"],
+                **_state_kwargs(slot_state))
             k_pages, v_pages = kv_pool.write_next_row(
-                k_pages, v_pages, muts["intermediates"], layers,
+                k_pages, v_pages, muts["intermediates"], kv_layers,
                 page_tables, seq_lens)
+            # the state pools come back moved on by the layers themselves
+            moved = (kv_pool.sown_state(muts["intermediates"], ssm_layers),
+                     ) if slot_state else ()
             nxt = jnp.argmax(logits[:, -1, :vocab], axis=-1)
             return (_with_stats(nxt.astype(jnp.int32),
                                 muts["intermediates"], layers),
-                    k_pages, v_pages)
+                    k_pages, v_pages, *moved)
 
         prog = devprof.wrap(
             "serve.decode",
-            jax.jit(serve_decode,
-                    donate_argnums=(1, 2) if self._donate else ()),
+            jax.jit(serve_decode, donate_argnums=self._donated(1, 6)),
             bucket=f"{n_slots}x{n_pages}")
         self._decode_progs[(n_slots, n_pages)] = prog
         return prog
@@ -1155,30 +1246,34 @@ class GenerationEngine:
         if prog is not None:
             return prog
         model, vocab = self.model, self.cfg.vocab_size
-        layers = self._layers
+        layers, kv_layers, ssm_layers = (self._layers, self._kv_layers,
+                                         self._ssm_layers)
 
         def serve_decode_sample(params, k_pages, v_pages, page_tables,
                                 seq_lens, tokens, temps, top_ps, seeds,
-                                tok_idx):
+                                tok_idx, *slot_state):
             kv_pages = tuple(zip(k_pages, v_pages))
             logits, muts = model.apply(
                 {"params": params}, tokens[:, None],
                 position_ids=seq_lens[:, None],
                 kv_pages=kv_pages, page_tables=page_tables,
                 kv_lens=seq_lens,
-                sow_kv=True, mutable=["intermediates"])
+                sow_kv=True, mutable=["intermediates"],
+                **_state_kwargs(slot_state))
             k_pages, v_pages = kv_pool.write_next_row(
-                k_pages, v_pages, muts["intermediates"], layers,
+                k_pages, v_pages, muts["intermediates"], kv_layers,
                 page_tables, seq_lens)
+            moved = (kv_pool.sown_state(muts["intermediates"], ssm_layers),
+                     ) if slot_state else ()
             nxt = _sample_from_logits(logits[:, -1, :vocab], temps,
                                       top_ps, seeds, tok_idx)
             return (_with_stats(nxt, muts["intermediates"], layers),
-                    k_pages, v_pages)
+                    k_pages, v_pages, *moved)
 
         prog = devprof.wrap(
             "serve.decode_sample",
             jax.jit(serve_decode_sample,
-                    donate_argnums=(1, 2) if self._donate else ()),
+                    donate_argnums=self._donated(1, 10)),
             bucket=f"{n_slots}x{n_pages}")
         self._decode_sample_progs[(n_slots, n_pages)] = prog
         return prog
@@ -1375,6 +1470,9 @@ class GenerationEngine:
         for p in slot.pages:
             self.pool.decref(p)
         slot.pages = []
+        if slot.req.rid in self._state_of:
+            # the row is free as it lies: the next prefill overwrites it
+            self._state_free.append(self._state_of.pop(slot.req.rid))
         if self._draft is not None:
             # every slot exit — finish, preemption, restart-swap
             # requeue — drops the drafter's per-request state with it:
@@ -1774,16 +1872,22 @@ class GenerationEngine:
             page_row[:len(row)] = row
             prog = self._prefill_prog(t_bucket)
             k_pages, v_pages = self._kv
+            args = (self._params, toks, np.int32(plen), k_pages, v_pages,
+                    page_row)
+            if self._recurrent:
+                # the request's row of the state pools, its own until
+                # _release; the prefill overwrites whatever it held
+                row = self._state_of[req.rid] = self._state_free.pop()
+                args += self._slot_state(np.int32(row))
             if self._prefill_ladder.mark(t_bucket // P):
                 obs.count("serve.prefill_bucket_compiles")
-                nxt, logit_row, k_pages, v_pages = _timed_compile(
-                    prog, self._params, toks, np.int32(plen),
-                    k_pages, v_pages, page_row)
+                nxt, logit_row, k_pages, v_pages, *moved = _timed_compile(
+                    prog, *args)
             else:
-                nxt, logit_row, k_pages, v_pages = prog(
-                    self._params, toks, np.int32(plen), k_pages, v_pages,
-                    page_row)
+                nxt, logit_row, k_pages, v_pages, *moved = prog(*args)
             self._kv = (k_pages, v_pages)
+            if moved:
+                self._ssm = moved[0]
             tok = self._first_token(req, nxt, logit_row)
         return tok, ph.dur_ms
 
@@ -1828,7 +1932,7 @@ class GenerationEngine:
         HERE, which is why `serve.prefill` ends after it."""
         nxt, stats = _split_pick(nxt)
         if stats is not None:
-            _count_moe(jax.device_get(stats))
+            _count_sown(jax.device_get(stats))
         if req.temperature > 0.0:
             return self._sample_tok(logit_row, req, 0)
         return int(nxt)
@@ -2117,20 +2221,31 @@ class GenerationEngine:
                 seen = self._decode_seen
                 args = (self._params, k_pages, v_pages, tables, seq_lens,
                         tokens)
+            if self._recurrent:
+                # padding rows move the pools' spare row, as their page
+                # writes land on page 0
+                rows = np.full((sb,), self.max_slots, np.int32)
+                for i, slot in enumerate(active):
+                    rows[i] = self._state_of[slot.req.rid]
+                args += self._slot_state(rows)
+                # again here: a sink attached after the pool was made
+                obs.gauge("serve.ssm.state_bytes", self._ssm_bytes)
         with obs.phase("serve.decode.dispatch", slots=sb, pages=pb,
                        live=len(active)):
             if (sb, pb) not in seen:
                 seen.add((sb, pb))
                 obs.count("serve.decode_bucket_compiles")
-                nxt, k_pages, v_pages = _timed_compile(prog, *args)
+                nxt, k_pages, v_pages, *moved = _timed_compile(prog, *args)
             else:
-                nxt, k_pages, v_pages = prog(*args)
+                nxt, k_pages, v_pages, *moved = prog(*args)
             self._kv = (k_pages, v_pages)
+            if moved:
+                self._ssm = moved[0]
         with obs.phase("serve.decode.fetch"):
             # the host waits for the device here
             nxt, stats = jax.device_get(_split_pick(nxt))
             nxt = np.asarray(nxt)
-            _count_moe(stats, per_step=True)
+            _count_sown(stats, per_step=True)
         with obs.phase("serve.decode.emit"):
             emitted = 0
             trace_t = self.trace.clock() if self.trace is not None else 0.0
@@ -2196,6 +2311,9 @@ class GenerationEngine:
             for p in self._cache.pages():
                 expected[p] = expected.get(p, 0) + 1
         self.pool.check(expected)
+        assert (len(self._state_free) + len(self._state_of)
+                == (self.max_slots if self._recurrent else 0)), \
+            "a row of the per-slot state pools is neither free nor owned"
         if self._draft is not None:
             self._draft.check()
 

@@ -86,6 +86,8 @@ def compat_reason(draft_model, target_cfg) -> str | None:
     for cfg in (draft_model.cfg, target_cfg):
         if getattr(cfg, "cache_row_widths", None) is not None:
             return kv_pool.LATENT_CACHE_REASON
+        if kv_pool.has_recurrent_state(cfg):
+            return kv_pool.RECURRENT_STATE_REASON
     mod = sys.modules.get(type(draft_model).__module__)
     fn = getattr(mod, "draft_compat", None)
     if fn is None:

@@ -6,6 +6,9 @@
   configs in BASELINE.json.
 - deepseek_v3: latent attention + routed/shared experts (the kanana-2
   row), on the serving path.
+- nemotron_h: Mamba-2 layers beside attention and latent routed experts,
+  of which a chip holds its share (the Nemotron-3-Super row), on the
+  serving path.
 - lora: low-rank adapter trees whose *parameters are the delta*.
 """
 
@@ -19,8 +22,8 @@ def family_of(preset: str):
     """The family (its module: ``PRESETS``, ``make_model``) that owns a
     preset's name; GPT-2's, whose lookup then names the unknown preset,
     where none does."""
-    from . import deepseek_v3, gpt2, llama
-    for family in (llama, deepseek_v3):
+    from . import deepseek_v3, gpt2, llama, nemotron_h
+    for family in (llama, deepseek_v3, nemotron_h):
         if preset in family.PRESETS:
             return family
     return gpt2

@@ -71,6 +71,13 @@ def gmm_supports(k: int, n: int, dtype) -> bool:
             and n % 128 == 0)
 
 
+def _lane_tiles(dim: int, cap: int) -> int:
+    """The widest whole number of 128-lane tiles that divides ``dim`` and
+    does not pass ``cap`` (2,688 = 21 tiles: 896 under 1,024 or 2,048)."""
+    tiles = dim // 128
+    return 128 * max(d for d in range(1, cap // 128 + 1) if tiles % d == 0)
+
+
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                    *, impl: str | None = None) -> jax.Array:
     """``lhs`` [m, k] with rows sorted by group, ``rhs`` [G, k, n],
@@ -94,54 +101,91 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
         # rows past sum(group_sizes) belong to no group: the kernel
         # leaves them alone and the caller never reads them
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-    tiling = (GMM_TILE_M, min(_GMM_TILE_K, k),
-              1024 if n % 1024 == 0 else 512 if n % 512 == 0 else 128)
+    tk = min(_GMM_TILE_K, k)
+    tiling = (GMM_TILE_M, tk if k % tk == 0 else _lane_tiles(k, _GMM_TILE_K),
+              1024 if n % 1024 == 0 else 512 if n % 512 == 0
+              else _lane_tiles(n, 1024))
     # positional: the public gmm is a custom_vjp
     out = megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None,
                        False, impl == "gmm_interpret")
     return out[:m] if pad else out
 
 
-def _experts_sorted(x_sorted, w_gate_up, w_down, group_sizes, impl):
-    """The two grouped products over rows already sorted by expert:
-    gate and up fused as one ``[G, E, 2F]`` product, SwiGLU, down."""
+def _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl):
+    """The two grouped products over rows already sorted by expert. What
+    an expert IS follows from its two stacks: a first stack twice as wide
+    as the second is deep holds gate and up fused (``[G, E, 2F]``, gate
+    columns first) and the body is SwiGLU; one as wide as the second is
+    deep (``[G, L, F]``) is one matrix and the body is squared ReLU."""
     F = w_down.shape[1]
-    gu = grouped_matmul(x_sorted, w_gate_up, group_sizes, impl=impl)
-    act = (jax.nn.silu(gu[:, :F].astype(jnp.float32))
-           * gu[:, F:].astype(jnp.float32)).astype(x_sorted.dtype)
+    up = grouped_matmul(x_sorted, w_in, group_sizes, impl=impl)
+    if w_in.shape[-1] == 2 * F:
+        act = (jax.nn.silu(up[:, :F].astype(jnp.float32))
+               * up[:, F:].astype(jnp.float32)).astype(x_sorted.dtype)
+    elif w_in.shape[-1] == F:
+        act = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(
+            x_sorted.dtype)
+    else:
+        raise ValueError(f"expert stacks {w_in.shape} / {w_down.shape} are "
+                         "neither a fused SwiGLU nor a squared-ReLU pair")
     return grouped_matmul(act, w_down, group_sizes, impl=impl)
 
 
 def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
-                   w_gate_up: jax.Array, w_down: jax.Array, *,
+                   w_in: jax.Array, w_down: jax.Array, *,
+                   held: tuple[int, int] | None = None,
                    live: jax.Array | None = None,
                    impl: str | None = None) -> tuple[jax.Array, dict]:
-    """``sum_e w_e E_e(h)`` for the chosen experts of every row.
+    """``sum_e w_e E_e(h)`` over the chosen experts of every row THAT ARE
+    HELD HERE.
 
-    ``h`` [N, E]; ``choice``/``weights`` [N, k]; ``w_gate_up`` [G, E, 2F]
-    (gate columns first); ``w_down`` [G, F, E]. ``live`` [N] bool marks
-    the rows that are not padding: every row is computed (shapes are
-    static) and only live ones are counted. Returns ([N, E] in ``h``'s
-    dtype, {"moe_rows", "moe_experts_touched"} int32 scalars)."""
+    ``h`` [N, E]; ``choice``/``weights`` [N, k], the choice made over all
+    the router's experts; ``w_in`` / ``w_down`` the stacks of the ``G``
+    experts held (:func:`_experts_sorted` says which body they mean).
+    ``held = (first, count)``: the stacks are experts ``first .. first +
+    count - 1`` of the router's; a choice outside them is another chip's
+    to compute (expert parallelism): its row is sorted behind the last
+    held group, where the grouped product leaves rows alone, and adds
+    zero here. None: the stacks are all the router's experts. ``live``
+    [N] bool marks the rows that are not padding: every row is computed
+    (shapes are static) and only live ones are counted. Returns ([N, E]
+    in ``h``'s dtype, int32 scalars {"moe_rows": live rows computed here,
+    "moe_experts_touched"} and, with ``held``, "moe_rows_elsewhere")."""
     N, k = choice.shape
-    G = w_gate_up.shape[0]
+    G = w_in.shape[0]
     flat = choice.reshape(-1)                            # [N*k]
+    if held is not None:
+        first, count = held
+        if count != G:
+            raise ValueError(f"held {held} but the stacks hold {G} experts")
+        here = (flat >= first) & (flat < first + count)
+        flat = jnp.where(here, flat - first, G)          # G: not ours
     order = jnp.argsort(flat, stable=True)
     group_sizes = jnp.bincount(flat, length=G).astype(jnp.int32)
     with jax.named_scope("moe.experts"):
         x_sorted = jnp.take(h, order // k, axis=0)
-        y = _experts_sorted(x_sorted, w_gate_up, w_down, group_sizes, impl)
+        y = _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl)
         w_sorted = jnp.take(weights.reshape(-1), order)
         y = y.astype(jnp.float32) * w_sorted[:, None]
+        if held is not None:
+            # a row of no group is whatever the product left there
+            y = jnp.where(jnp.take(here, order)[:, None], y, 0.0)
         # un-sort: row r of the sorted order came from flat row order[r]
         y = jnp.take(y, jnp.argsort(order), axis=0)
         out = jnp.sum(y.reshape(N, k, -1), axis=1).astype(h.dtype)
     if live is None:
+        live_flat = None
         rows, touched = jnp.int32(N * k), jnp.sum(group_sizes > 0)
     else:
         live_flat = jnp.repeat(live.astype(jnp.int32), k)
         rows = jnp.sum(live_flat)
-        touched = jnp.sum(jnp.zeros((G,), jnp.int32).at[flat].add(live_flat)
-                          > 0)
-    return out, {"moe_rows": rows.astype(jnp.int32),
-                 "moe_experts_touched": touched.astype(jnp.int32)}
+        # a choice held elsewhere indexes past the held experts: dropped
+        touched = jnp.sum(jnp.zeros((G,), jnp.int32).at[flat].add(
+            live_flat, mode="drop" if held is not None else None) > 0)
+    stats = {"moe_rows": rows.astype(jnp.int32),
+             "moe_experts_touched": touched.astype(jnp.int32)}
+    if held is not None:
+        ours = jnp.sum(here if live_flat is None else here * live_flat)
+        stats.update(moe_rows=ours.astype(jnp.int32),
+                     moe_rows_elsewhere=(rows - ours).astype(jnp.int32))
+    return out, stats

@@ -88,6 +88,61 @@ def test_grouped_expert_product_compiles_at_published_widths(one_chip, rows,
     assert "%gmm" in text
 
 
+@pytest.mark.parametrize("rows", [1408, 22528])
+def test_latent_expert_product_compiles_at_published_widths(one_chip, rows,
+                                                            monkeypatch):
+    """Nemotron-3-Super's latent experts (1024 -> 2688 -> 1024, squared
+    ReLU), the 128 held of 512: 2,688 is 21 lane tiles, which neither
+    1,024 nor 512 divides, so the product's n- and k-tiles are 896."""
+    from distributedtraining_tpu.ops import moe
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    assert moe._lane_tiles(2688, 1024) == moe._lane_tiles(2688, 2048) == 896
+    assert moe._lane_tiles(1536, 1024) == 768      # kanana's takes 512
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        lambda x, up, d, s: moe._experts_sorted(x, up, d, s, None),
+        sds((rows, 1024)), sds((128, 1024, 2688)), sds((128, 2688, 1024)),
+        sds((128,), jnp.int32))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "%gmm" in text
+
+
+@pytest.mark.parametrize("impl, calls", [("kernel", 1), ("xla", 0)])
+@pytest.mark.parametrize("slots", [64, 8])
+def test_state_update_compiles_in_place_at_published_widths(one_chip, slots,
+                                                            impl, calls):
+    """`ops/ssm.ssm_decode_update` over a 64-slot pool of Nemotron-3-Super's
+    states (128 heads x 64 x 128 float32, 8 groups): Mosaic takes the
+    kernel, and neither it nor its XLA twin holds a temporary the size of
+    the pool (the donated pool is the result's buffer)."""
+    from distributedtraining_tpu.ops import ssm
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((65, 128, 64, 128))
+    assert ssm.kernel_supports(pool, 8)
+    compiled = jax.jit(
+        lambda *a: ssm.ssm_decode_update(*a, impl=impl), donate_argnums=(0,)
+    ).trace(pool, sds((slots,), jnp.int32),
+            sds((slots, 128, 64), jnp.bfloat16), sds((slots, 128)),
+            sds((128,)), sds((slots, 8, 128), jnp.bfloat16),
+            sds((slots, 8, 128), jnp.bfloat16), sds((128,))
+            ).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == calls
+    # the instruction's own name, which the device-trace reader matches
+    assert bool(re.search(r"%ssm_decode_update(\.\d+)? = ", text)) \
+        == bool(calls)
+    mem = compiled.memory_analysis()
+    pool_bytes = 65 * 128 * 64 * 128 * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 16
+
+
 @pytest.mark.parametrize("packed", [True, False])
 def test_causal_attention_gradient_compiles_at_the_train_cells_shape(
         one_chip, monkeypatch, packed):
