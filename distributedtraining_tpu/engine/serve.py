@@ -38,6 +38,12 @@ Design, in the order it matters on TPU:
   free slots every step, evicts finished sequences immediately, and
   keeps the decode program full; per-token latency is one decode step,
   not one full-batch generation.
+- **One program ahead.** ``step()`` dispatches its decode program and
+  returns; that program's tokens surface at the next ``step()``. While
+  the batch does not change, the next program takes the last one's picks
+  where they lie on the device, so the device runs step n+1 while the
+  host emits step n's tokens; a change of composition is collected
+  first (``_chainable``; docs/serving.md has the contract).
 - **Hot swap.** A :class:`BaseRevisionWatcher` subscribes to the
   averager's base revisions through the existing Transport on a
   background thread, stages the fetched tree on device, and the engine
@@ -186,9 +192,7 @@ class ServeRequest:
     submitted_t: float = dataclasses.field(default_factory=time.time)
     submitted_pc: float = dataclasses.field(
         default_factory=time.perf_counter)
-    # perf_counter at each token's emit, one per entry of ``tokens``: two
-    # tokens a caller receives from ONE step() still carry their own
-    # stamps (the first from the prefill, the second from the decode)
+    # perf_counter at each token's emit, one per entry of ``tokens``
     emit_t: list = dataclasses.field(default_factory=list)
     done_evt: threading.Event = dataclasses.field(
         default_factory=threading.Event)
@@ -207,6 +211,9 @@ class _Slot:
     spec_window: int = 0  # drafts allowed THIS step (set by _grow: the
     #                       pages for seq_len..seq_len+spec_window are
     #                       owned exclusively; 0 = plain-decode lane)
+    released: bool = False  # pages and state row handed back (_release):
+    #                         a row a dispatched program still runs for
+    #                         this slot is dropped when it is collected
     # lazy trace accumulators (utils/reqtrace.py): the per-token hot
     # path only bumps these slot-local scalars; _trace_flush folds them
     # into the request's timeline as ONE coalesced span whenever the
@@ -216,6 +223,17 @@ class _Slot:
     tr_decode_t1: float = 0.0
     tr_tpot_sum: float = 0.0
     tr_tpot_n: int = 0
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A plain decode program dispatched and not yet collected. ``out``
+    is what the program returned first, as it lies on the device: the
+    picks ``[sb]`` (the next program's ``tokens`` when it is chained on
+    this one) and, for a family that sows them, the layers' counts."""
+    slots: list          # the _Slot of each live row, in row order
+    sb: int              # the slot bucket the picks are padded to
+    out: Any
 
 
 # ---------------------------------------------------------------------------
@@ -899,6 +917,9 @@ class GenerationEngine:
         self.shed_count = 0          # frontend-counted 429 rejections
         self.cow_copies = 0
         self._active: list[_Slot] = []
+        # the plain decode program dispatched and not yet collected: the
+        # engine stays at most this ONE program ahead of the host
+        self._flight: _Flight | None = None
         self._queue: deque[ServeRequest] = deque()
         self._qlock = threading.Lock()
         self._work_evt = threading.Event()
@@ -914,6 +935,7 @@ class GenerationEngine:
         self._tok_rate_ema: float | None = None
         self.steps = 0
         self.tokens_emitted = 0
+        self._decoded = 0    # tokens the decode programs emitted, ever
         # cumulative prefill dispatches (full + suffix): the load
         # harness's prefill cost model reads the delta per step to
         # charge compute-bound prefill work against a worker's clock
@@ -1069,7 +1091,9 @@ class GenerationEngine:
 
     @property
     def idle(self) -> bool:
-        return not self._active and self.queue_depth == 0
+        """Nothing active, queued, or dispatched and not yet collected."""
+        return (not self._active and self._flight is None
+                and self.queue_depth == 0)
 
     @property
     def tokens_per_sec(self) -> float:
@@ -1107,6 +1131,10 @@ class GenerationEngine:
     @property
     def spec_rounds(self) -> int:
         return self._spec_rounds
+
+    def _spec_ready(self) -> bool:
+        return self._draft is not None and getattr(self._draft, "ready",
+                                                   False)
 
     # -- admission control --------------------------------------------------
     def admission_state(self) -> tuple[str, float]:
@@ -1470,6 +1498,7 @@ class GenerationEngine:
         for p in slot.pages:
             self.pool.decref(p)
         slot.pages = []
+        slot.released = True
         if slot.req.rid in self._state_of:
             # the row is free as it lies: the next prefill overwrites it
             self._state_free.append(self._state_of.pop(slot.req.rid))
@@ -1567,6 +1596,8 @@ class GenerationEngine:
         if self._pending_swap is None:
             return
         if self.swap_policy == "restart" and self._active:
+            # what the old revision has already run is emitted first
+            self._collect()
             # in-flight sequences restart from their prompts on the new
             # revision; their pages go back to the pool first
             for slot in list(self._active):
@@ -1623,10 +1654,17 @@ class GenerationEngine:
         return True
 
     # -- scheduling ---------------------------------------------------------
+    def _can_admit(self) -> bool:
+        return (self._pending_swap is None or self.swap_policy == "restart") \
+            and not (self._admit_hold and self._active) \
+            and len(self._active) < self.max_slots
+
     def _admit(self) -> None:
-        while (self._pending_swap is None or self.swap_policy == "restart") \
-                and not (self._admit_hold and self._active) \
-                and len(self._active) < self.max_slots:
+        if self._flight is not None and self.queue_depth \
+                and not self._can_admit():
+            # a slot whose last token is in flight may make the room
+            self._collect()
+        while self._can_admit():
             req = self._pop_queued()
             if req is None:
                 return
@@ -1991,47 +2029,53 @@ class GenerationEngine:
         max_new_tokens is wasted verify work — the run stops at the
         budget anyway), and by cache capacity (the verify window writes
         rows seq_len..seq_len+k, all of which must exist)."""
-        if self._draft is None or not getattr(self._draft, "ready", False):
+        if not self._spec_ready():
             return 0
         rem = slot.req.max_new_tokens - len(slot.req.tokens) - 1
         cap = self.max_seq_len - 1 - slot.seq_len
         return max(0, min(self.draft_k, rem, cap))
 
-    def _grow_for_window(self, slot: _Slot, window: int) -> bool:
+    def _grow_for_window(self, slot: _Slot, window: int,
+                         ahead: int = 0) -> bool:
         """Pages + write exclusivity for the rows this step scatters:
         positions seq_len..seq_len+window (window 0 = the plain decode
-        write, the pre-speculation contract verbatim). Every page in
-        the window that is still shared (refcount > 1) is
-        copy-on-write'd BEFORE any multi-token commit can bleed into a
-        sibling's or the prefix cache's rows. False on pool exhaustion
-        — no preemption here, the caller decides how hard to push."""
+        write, the pre-speculation contract verbatim), ``ahead`` rows
+        further on when the program in flight is still writing row
+        seq_len. Every page in the window that is still shared
+        (refcount > 1) is copy-on-write'd BEFORE any multi-token commit
+        can bleed into a sibling's or the prefix cache's rows. False on
+        pool exhaustion — no preemption here, the caller decides how
+        hard to push."""
         P = self.page_size
-        need = (slot.seq_len + window) // P + 1
+        first = slot.seq_len + ahead
+        need = (first + window) // P + 1
         while len(slot.pages) < need:
             got = self._alloc_pages(1)
             if got is None:
                 return False
             slot.pages.extend(got)
-        for wp in range(slot.seq_len // P, need):
+        for wp in range(first // P, need):
             while self.pool.refs(slot.pages[wp]) > 1:
                 if not self._cow_page(slot, wp):
                     return False
         return True
 
-    def _grow(self) -> None:
+    def _grow(self, ahead: int = 0) -> None:
         """Ensure every active slot owns the pages this step's writes
         land in — exclusively. Speculative slots ask for their whole
         draft window first; under pool pressure the window shrinks to 0
         (that slot rides the verify pass as a plain-decode lane) before
         anyone gets preempted — losing speculation for a step is free,
         losing a sequence's pages is not. Preemption of the youngest
-        remains the final escape hatch, exactly as before."""
+        remains the final escape hatch, exactly as before. ``ahead`` is
+        1 when this step's program is chained on the one in flight
+        (:meth:`_chainable` has seen that free pages alone will do)."""
         for slot in list(self._active):
             if slot not in self._active:
                 continue   # preempted by an earlier slot's growth
             slot.spec_window = self._spec_horizon(slot)
             while slot in self._active:
-                if self._grow_for_window(slot, slot.spec_window):
+                if self._grow_for_window(slot, slot.spec_window, ahead):
                     break
                 if slot.spec_window:
                     slot.spec_window = 0
@@ -2041,17 +2085,18 @@ class GenerationEngine:
                     self._finish(slot, "truncated")
                     break
 
-    def _decode(self) -> int:
+    def _decode(self, chain: bool = False) -> None:
         if not self._active:
-            return 0
+            return
         if self._draft is not None:
-            if getattr(self._draft, "ready", False):
-                return self._decode_spec()
+            if self._spec_ready():
+                self._decoded += self._decode_spec()
+                return
             # stale or missing draft (e.g. the fleet has not published
             # a draft base yet): degrade to plain decode — never to
             # wrong output
             obs.count("serve.spec_fallbacks")
-        return self._decode_plain()
+        self._decode_plain(chain)
 
     def _decode_spec(self) -> int:
         """One speculative round: the drafter proposes up to
@@ -2177,25 +2222,105 @@ class GenerationEngine:
                              list(slot.req.prompt) + list(slot.req.tokens))
         return emitted
 
-    def _decode_plain(self) -> int:
+    def _chainable(self) -> bool:
+        """Whether this step's plain decode program can take the picks of
+        the program in flight as they lie on the device: the same slots
+        in the same rows of the same slot bucket, each owing a token
+        after the one in flight, and every row after the one in flight
+        writable with free pages alone. Anything else (an admission, a
+        last token in flight, a pending swap, a drafter, a page to copy
+        or a slot to preempt, another bucket) is collected first."""
+        flight = self._flight
+        if flight is None or self._pending_swap is not None \
+                or self._spec_ready():
+            return False
         active = self._active
-        if not active:
-            return 0
+        if len(active) != len(flight.slots):
+            return False
+        P = self.page_size
+        short = 0
+        for slot, flown in zip(active, flight.slots):
+            if slot is not flown or \
+                    len(slot.req.tokens) + 1 >= slot.req.max_new_tokens:
+                return False
+            page = (slot.seq_len + 1) // P
+            if page >= len(slot.pages):
+                short += page + 1 - len(slot.pages)
+            elif self.pool.refs(slot.pages[page]) > 1:
+                return False
+        return short <= self.pool.free \
+            and self._plain_bucket(ahead=1)[0] == flight.sb
+
+    def _plain_bucket(self, ahead: int = 0) -> tuple[int, int]:
+        """The (slot, page) bucket of a plain decode program over the
+        active slots, written ``ahead`` rows past their lengths."""
+        active = self._active
+        progs = (self._decode_sample_progs
+                 if any(s.req.temperature > 0.0 for s in active)
+                 else self._decode_progs)
+        need_pages = max((s.seq_len + ahead) // self.page_size + 1
+                         for s in active)
+        return self._decode_bucket(len(active), need_pages, progs)
+
+    def _collect(self, flight: _Flight | None = None) -> None:
+        """Fetch and emit what a dispatched program picked (the one in
+        flight, unless the caller hands over an earlier one): the host
+        waits for the device HERE. A row whose slot was released
+        meanwhile (an end-of-sequence on the token before) is dropped."""
+        if flight is None:
+            flight, self._flight = self._flight, None
+            if flight is None:
+                return
+        with obs.phase("serve.decode.fetch"):
+            nxt, stats = jax.device_get(_split_pick(flight.out))
+            nxt = np.asarray(nxt)
+            _count_sown(stats, per_step=True)
+        with obs.phase("serve.decode.emit"):
+            trace_t = self.trace.clock() if self.trace is not None else 0.0
+            for i, slot in enumerate(flight.slots):
+                if slot.released:
+                    obs.count("serve.decode.rows_dropped")
+                    continue
+                slot.seq_len += 1
+                slot.last_tok = int(nxt[i])
+                if self.trace is not None:
+                    # lazy per-slot accumulation: the hot path is three
+                    # scalar bumps against one hoisted clock read — the
+                    # timeline gets one coalesced span at _trace_flush
+                    # (spec/cow/preempt/finish), zero device work
+                    if slot.tr_decode_n == 0:
+                        slot.tr_decode_t0 = trace_t
+                    slot.tr_decode_n += 1
+                    slot.tr_decode_t1 = trace_t
+                self._emit(slot, int(nxt[i]))
+                self._decoded += 1
+
+    def _decode_plain(self, chain: bool) -> None:
+        """Dispatch one plain decode program and return without waiting
+        for it. ``chain``: the program in flight runs the same slots, so
+        this one takes ITS picks as ``tokens`` where they lie on the
+        device, and only then is that one collected: the device runs
+        this program while the host emits the last one's tokens."""
+        active = self._active
+        earlier, ahead = (self._flight, 1) if chain else (None, 0)
         with obs.phase("serve.decode.build"):
             sampled = any(s.req.temperature > 0.0 for s in active)
-            progs = (self._decode_sample_progs if sampled
-                     else self._decode_progs)
-            need_pages = max(s.seq_len // self.page_size + 1
-                             for s in active)
-            sb, pb = self._decode_bucket(len(active), need_pages, progs)
+            sb, pb = self._plain_bucket(ahead)
             tables = np.zeros((sb, pb), np.int32)
             seq_lens = np.zeros((sb,), np.int32)
-            tokens = np.zeros((sb,), np.int32)
             for i, slot in enumerate(active):
                 row = slot.pages[:pb]
                 tables[i, :len(row)] = row
-                seq_lens[i] = slot.seq_len
-                tokens[i] = slot.last_tok
+                seq_lens[i] = slot.seq_len + ahead
+            if chain:
+                tokens = _split_pick(earlier.out)[0]
+            else:
+                # a device array on both ways in, so that a chained call
+                # finds the executable the unchained one compiled
+                tokens = np.zeros((sb,), np.int32)
+                for i, slot in enumerate(active):
+                    tokens[i] = slot.last_tok
+                tokens = jax.device_put(tokens)
             k_pages, v_pages = self._kv
             self._slot_ladder.mark(sb)
             self._page_ladder.mark(pb)
@@ -2211,7 +2336,7 @@ class GenerationEngine:
                     temps[i] = slot.req.temperature
                     top_ps[i] = slot.req.top_p
                     seeds[i] = slot.req.seed & 0x7FFFFFFF
-                    tok_idx[i] = len(slot.req.tokens)
+                    tok_idx[i] = len(slot.req.tokens) + ahead
                 prog = self._decode_sample_prog(sb, pb)
                 seen = self._decode_sample_seen
                 args = (self._params, k_pages, v_pages, tables, seq_lens,
@@ -2235,39 +2360,27 @@ class GenerationEngine:
             if (sb, pb) not in seen:
                 seen.add((sb, pb))
                 obs.count("serve.decode_bucket_compiles")
-                nxt, k_pages, v_pages, *moved = _timed_compile(prog, *args)
+                out, k_pages, v_pages, *moved = _timed_compile(prog, *args)
             else:
-                nxt, k_pages, v_pages, *moved = prog(*args)
+                out, k_pages, v_pages, *moved = prog(*args)
             self._kv = (k_pages, v_pages)
             if moved:
                 self._ssm = moved[0]
-        with obs.phase("serve.decode.fetch"):
-            # the host waits for the device here
-            nxt, stats = jax.device_get(_split_pick(nxt))
-            nxt = np.asarray(nxt)
-            _count_sown(stats, per_step=True)
-        with obs.phase("serve.decode.emit"):
-            emitted = 0
-            trace_t = self.trace.clock() if self.trace is not None else 0.0
-            for i, slot in enumerate(list(active)):
-                slot.seq_len += 1
-                slot.last_tok = int(nxt[i])
-                if self.trace is not None:
-                    # lazy per-slot accumulation: the hot path is three
-                    # scalar bumps against one hoisted clock read — the
-                    # timeline gets one coalesced span at _trace_flush
-                    # (spec/cow/preempt/finish), zero device work
-                    if slot.tr_decode_n == 0:
-                        slot.tr_decode_t0 = trace_t
-                    slot.tr_decode_n += 1
-                    slot.tr_decode_t1 = trace_t
-                self._emit(slot, int(nxt[i]))
-                emitted += 1
-        return emitted
+            # the picks start for the host now, not when it asks
+            jax.tree_util.tree_map(lambda x: x.copy_to_host_async(), out)
+            self._flight = _Flight(list(active), sb, out)
+        obs.count("serve.decode.chained" if chain
+                  else "serve.decode.collected_first")
+        if chain:
+            self._collect(earlier)
 
     def step(self) -> dict:
         """One scheduler iteration: swap check, admission, one decode
-        step over the active batch. Returns step stats."""
+        program over the active batch dispatched and NOT waited for; the
+        tokens collected are those of the program dispatched a step
+        earlier (docs/serving.md, "What `step()` does"). Returns step
+        stats; ``emitted`` counts the decode tokens that reached the
+        host in this step."""
         if self._params is None:
             raise RuntimeError("no base installed; call install_params "
                                "(or attach a watcher and publish a base)")
@@ -2275,13 +2388,18 @@ class GenerationEngine:
         # feed serve.<phase>_ms (utils/obs.phase; docs/observability.md
         # has the table); off, each is one branch (serve.step is timed
         # either way: the caller gets step_ms)
+        before = self._decoded
         with obs.phase("serve.step", timed=True) as whole:
             self._maybe_swap()
             with obs.phase("serve.admit"):
                 self._admit()
+            chain = self._chainable()
+            if not chain:
+                self._collect()
             with obs.phase("serve.grow"):
-                self._grow()
-            emitted = self._decode()
+                self._grow(ahead=int(chain))
+            self._decode(chain)
+        emitted = self._decoded - before
         dur = whole.dur_ms / 1e3
         self.steps += 1
         if emitted:
@@ -2340,6 +2458,8 @@ class GenerationEngine:
             self.watcher.close()
         if self._draft is not None:
             self._draft.close()
+        # a dispatched program's tokens are not thrown away
+        self._collect()
         for slot in list(self._active):
             self._finish(slot, "truncated")
         with self._qlock:

@@ -927,19 +927,29 @@ STEP_PHASES = ("serve.step", "serve.admit", "serve.prefill", "serve.grow",
 
 
 def test_step_phases_fill_their_histograms_and_nest(setup, sink):
-    """With obs on, one step() that admits and decodes feeds all eight
-    phase histograms; the children lie inside `serve.step`, the prefill
-    inside `serve.admit`; nothing is written to the sink per close."""
+    """With obs on, a step() that admits feeds the admission's phases and
+    the decode program's build and dispatch; the program's fetch and emit
+    are the NEXT step's (the engine returns one program ahead), beside
+    that step's own build and dispatch. The children lie inside
+    `serve.step`, the prefill inside `serve.admit`; nothing is written to
+    the sink per close."""
     model, cfg, params, _, prompts = setup
     eng = GenerationEngine(model, params, max_slots=2, page_size=8)
     try:
         eng.submit(prompts[0], GEN)
         eng.step()
         reg = obs.registry()
-        hists = {p: reg.peek(f"{p}_ms") for p in STEP_PHASES}
-        assert all(h is not None and h.count == 1 for h in hists.values()), {
-            p: getattr(h, "count", None) for p, h in hists.items()}
-        total = {p: h.total for p, h in hists.items()}
+
+        def counts():
+            return {p: getattr(reg.peek(f"{p}_ms"), "count", 0)
+                    for p in STEP_PHASES}
+
+        later = ("serve.decode.fetch", "serve.decode.emit")
+        assert counts() == {p: int(p not in later) for p in STEP_PHASES}
+        eng.step()
+        once = ("serve.prefill",) + later
+        assert counts() == {p: 1 if p in once else 2 for p in STEP_PHASES}
+        total = {p: reg.peek(f"{p}_ms").total for p in STEP_PHASES}
         inside = sum(total[p] for p in STEP_PHASES
                      if p not in ("serve.step", "serve.prefill"))
         assert total["serve.step"] >= inside
@@ -995,9 +1005,10 @@ def test_prefill_ms_is_timed_to_completion(setup, sink):
         eng.close()
 
 
-def test_emit_stamps_one_per_token_and_two_in_one_step(setup):
-    """Always on, obs or not: a request prefilled and decoded in ONE
-    step() hands its caller two tokens with two different stamps, and a
+def test_emit_stamps_one_per_token_and_one_token_a_step(setup):
+    """Always on, obs or not: the step() that prefills a request hands its
+    caller the first token and returns with the decode program in flight;
+    every later step() hands over one more, each with its own stamp, and a
     finished request has one non-decreasing stamp per token on the clock
     `submitted_pc` was taken from."""
     model, cfg, params, _, prompts = setup
@@ -1005,12 +1016,16 @@ def test_emit_stamps_one_per_token_and_two_in_one_step(setup):
     try:
         req = eng.submit(prompts[1], GEN)
         eng.step()
+        assert len(req.tokens) == 1 and len(req.emit_t) == 1
+        assert not eng.idle and eng._flight is not None
+        eng.step()
         assert len(req.tokens) == 2 and len(req.emit_t) == 2
         assert req.submitted_pc <= req.emit_t[0] < req.emit_t[1]
         while not req.done_evt.is_set():
             eng.step()
         assert len(req.emit_t) == len(req.tokens) == GEN
         assert req.emit_t == sorted(req.emit_t)
+        assert eng.idle
     finally:
         eng.close()
 
