@@ -67,6 +67,20 @@ RECURRENT_STATE_REASON = (
 StatePool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
 
 
+def unheld_cache_reason(cfg) -> str | None:
+    """Why the serve engine cannot hold what this model's layers keep for
+    a sequence, in one sentence; None for a model it can serve."""
+    unheld = sorted({c for c in (getattr(cfg, "layer_caches", None) or ())
+                     if c not in ("kv", "ssm", None)})
+    if not unheld:
+        return None
+    return (f"this model's layers keep {', '.join(map(repr, unheld))} for a "
+            "sequence (cfg.layer_caches), for which engine/kv_pool.py has no "
+            "pool: it holds a K/V pair of heads a token ('kv') and a "
+            "recurrent state with its convolution tail a slot ('ssm'), so "
+            "the model trains and is not served")
+
+
 def layer_caches(cfg, n_layers: int) -> tuple[str | None, ...]:
     """What each layer keeps for a sequence: what the model's config
     states (``layer_caches``), else the paged pair in every layer."""

@@ -898,6 +898,9 @@ class GenerationEngine:
         # those layers' pools (states, tails), and which of their rows
         # each admitted request owns; all stay empty for a family that
         # caches per token only
+        refusal = kv_pool.unheld_cache_reason(cfg)
+        if refusal:
+            raise ValueError(refusal)
         self._recurrent = kv_pool.has_recurrent_state(cfg)
         self._ssm: kv_pool.StatePool = ((), ())
         self._ssm_bytes = 0.0

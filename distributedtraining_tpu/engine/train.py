@@ -50,7 +50,9 @@ class TrainState(struct.PyTreeNode):
 def default_optimizer(learning_rate: float = 5e-4,
                       *, grad_clip: float | None = None,
                       weight_decay: float = 0.01,
-                      mu_dtype: str | None = None) -> optax.GradientTransformation:
+                      mu_dtype: str | None = None,
+                      is_buffer: Callable | None = None
+                      ) -> optax.GradientTransformation:
     """AdamW @ 5e-4, the reference's operating point (neurons/miner.py:121-128).
     Gradient clipping is off by default for parity (the reference has none in
     its live path) but first-class because real runs want it.
@@ -58,26 +60,46 @@ def default_optimizer(learning_rate: float = 5e-4,
     ``mu_dtype="bfloat16"`` stores the first moment in bf16 — it halves the
     first-moment HBM footprint, which is what lets the 7B/8B full-delta
     configs keep params+AdamW resident per chip (throughput effect not
-    measured)."""
+    measured).
+
+    ``is_buffer(path) -> bool`` is the model family's rule (its config's
+    ``is_buffer``, beside ``rounds_first``) for the leaves of the parameter
+    tree that are no parameters: AdamW would decay them though their
+    gradient is zero, so they get a zero update and no moments, and a step
+    leaves them bit-equal. A family without the rule has none, and its
+    optimizer is what it was."""
     tx = optax.adamw(learning_rate, weight_decay=weight_decay,
                      mu_dtype=mu_dtype)
     if grad_clip is not None:
         tx = optax.chain(optax.clip_by_global_norm(grad_clip), tx)
-    return tx
+    if is_buffer is None:
+        return tx
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "buffer" if is_buffer(tuple(
+                getattr(k, "key", getattr(k, "name", None)) for k in path))
+            else "parameter", params)
+
+    return optax.multi_transform(
+        {"parameter": tx, "buffer": optax.set_to_zero()}, labels)
 
 
 def accumulated_grads(loss_fn, params, batch, accum_steps: int):
-    """(loss, tokens, grads) of ``loss_fn(params, batch) -> (mean, count)``,
+    """(loss, aux, grads) of ``loss_fn(params, batch) -> (mean, aux)``,
     gradient-accumulated over ``accum_steps`` microbatches (lax.scan).
+    ``aux`` is the token count, or ``(count, counters)`` with a dict of
+    what the model's layers counted; it comes back summed over the
+    microbatches.
 
     Token-weighted across microbatches, so the result equals the full-batch
     token-mean exactly (up to float summation order): activation memory of
     batch/N at the same effective batch. With ``accum_steps == 1`` this is a
     plain value_and_grad. The batch's leading dim must divide by N."""
     if accum_steps == 1:
-        (loss, tokens), grads = jax.value_and_grad(
+        (loss, aux), grads = jax.value_and_grad(
             lambda p: loss_fn(p, batch), has_aux=True)(params)
-        return loss, tokens, grads
+        return loss, aux, grads
 
     def to_micro(x):
         b = x.shape[0]
@@ -88,23 +110,31 @@ def accumulated_grads(loss_fn, params, batch, accum_steps: int):
 
     micro = jax.tree_util.tree_map(to_micro, batch)
 
+    def tokens_of(aux):
+        return aux[0] if isinstance(aux, tuple) else aux
+
     def weighted(p, mb):
-        l, t = loss_fn(p, mb)
-        return l * t, t
+        l, aux = loss_fn(p, mb)
+        return l * tokens_of(aux), aux
 
     def body(carry, mb):
-        g_acc, ls, ts = carry
-        (wl, t), g = jax.value_and_grad(weighted, has_aux=True)(params, mb)
+        g_acc, ls, aux_acc = carry
+        (wl, aux), g = jax.value_and_grad(weighted, has_aux=True)(params, mb)
         g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
-        return (g_acc, ls + wl, ts + t), None
+        return (g_acc, ls + wl,
+                jax.tree_util.tree_map(jnp.add, aux_acc, aux)), None
 
     zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-    (g_sum, loss_sum, tok_sum), _ = jax.lax.scan(
-        body, (zeros, jnp.float32(0.0), jnp.float32(0.0)), micro)
-    denom = jnp.maximum(tok_sum, 1.0)
+    aux0 = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: loss_fn(
+            params, jax.tree_util.tree_map(lambda x: x[0], micro))[1]))
+    (g_sum, loss_sum, aux_sum), _ = jax.lax.scan(
+        body, (zeros, jnp.float32(0.0), aux0), micro)
+    denom = jnp.maximum(tokens_of(aux_sum), 1.0)
     grads = jax.tree_util.tree_map(
         lambda g: (g / denom).astype(g.dtype), g_sum)
-    return loss_sum / denom, tok_sum, grads
+    return loss_sum / denom, aux_sum, grads
 
 
 def _devprof_batch_bucket(batch) -> str:
@@ -118,13 +148,28 @@ def _devprof_batch_bucket(batch) -> str:
     return f"{shape[0]}x{shape[1]}"
 
 
-def _default_lm_loss(model, params, batch):
-    logits = model.apply(
-        {"params": params}, batch["input_ids"],
-        attention_mask=batch.get("attention_mask"),
-        segment_ids=batch.get("segment_ids"),
-        position_ids=batch.get("position_ids"))
-    return causal_lm_loss(logits, batch["input_ids"], batch.get("loss_mask"))
+def _default_lm_loss(model, params, batch, *, with_counters: bool = False):
+    """(mean loss, token count). ``with_counters`` (the train step's): a
+    family whose layers count something a step (a routed layer's rows
+    here, rows left to other chips, its fullest expert: the model sows
+    them, summed over layers, under ``intermediates/train_counters``)
+    gives ``(count, counters)`` in the count's place, so that they leave
+    the step beside its loss; a family that sows none gives the count
+    alone, and its step is the program it was."""
+    variables = {"params": params}
+    kwargs = dict(attention_mask=batch.get("attention_mask"),
+                  segment_ids=batch.get("segment_ids"),
+                  position_ids=batch.get("position_ids"))
+    if with_counters:
+        logits, sown = model.apply(variables, batch["input_ids"],
+                                   mutable=["intermediates"], **kwargs)
+    else:
+        logits, sown = model.apply(variables, batch["input_ids"],
+                                   **kwargs), {}
+    loss, count = causal_lm_loss(logits, batch["input_ids"],
+                                 batch.get("loss_mask"))
+    counters = sown.get("intermediates", {}).get("train_counters")
+    return loss, ((count, counters[0]) if counters else count)
 
 
 def _fused_lm_loss(model, params, batch, impl: str = "auto", mesh=None):
@@ -242,7 +287,8 @@ class TrainEngine:
                 loss_fn = functools.partial(_fused_lm_loss, impl=impl,
                                             mesh=loss_mesh)
         self.model = model
-        self.tx = optimizer or default_optimizer()
+        self.tx = optimizer or default_optimizer(is_buffer=getattr(
+            getattr(model, "cfg", None), "is_buffer", None))
         self.mesh = mesh
         self._param_shardings = None
         self._batch_sharding = None
@@ -266,14 +312,15 @@ class TrainEngine:
 
             from ..parallel.sharding import DEFAULT_RULES
 
-            def task_loss(model_, params, batch, _inner=base_task_loss):
+            def task_loss(model_, params, batch, _inner=base_task_loss,
+                          **kw):
                 # trace with the mesh + logical-axis rules ambient so
                 # in-model activation constraints
                 # (nn.with_logical_constraint, models/gpt2.py) and the
                 # mesh-aware embed backward (ops/embed.py) engage; inert
                 # no-ops without a mesh
                 with self.mesh, nn.logical_axis_rules(DEFAULT_RULES):
-                    return _inner(model_, params, batch)
+                    return _inner(model_, params, batch, **kw)
         else:
             task_loss = base_task_loss
         # resolved model-level loss — subclasses (LoRAEngine) reuse this so
@@ -287,15 +334,23 @@ class TrainEngine:
         def loss_fn(params, batch):
             return task_loss(model, params, batch)
 
+        # the built-in unfused loss also hands out what the model's layers
+        # counted (a family that counts nothing: the same program)
+        train_loss = loss_fn
+        if base_task_loss is _default_lm_loss:
+            def train_loss(params, batch):
+                return task_loss(model, params, batch, with_counters=True)
+
         def train_step(state: TrainState, batch):
-            loss, tokens, grads = accumulated_grads(
-                loss_fn, state.params, batch, accum_steps)
+            loss, aux, grads = accumulated_grads(
+                train_loss, state.params, batch, accum_steps)
+            tokens, counters = aux if isinstance(aux, tuple) else (aux, {})
             updates, opt_state = self.tx.update(grads, state.opt_state,
                                                 state.params)
             params = optax.apply_updates(state.params, updates)
             new_state = TrainState(step=state.step + 1, params=params,
                                    opt_state=opt_state)
-            return new_state, {"loss": loss, "tokens": tokens}
+            return new_state, {"loss": loss, "tokens": tokens, **counters}
 
         def eval_step(params, batch):
             loss, tokens = loss_fn(params, batch)
@@ -835,6 +890,10 @@ class MinerLoop:
         # float() would block the host on every step's completion and
         # serialize batch prep behind device compute)
         self._last_loss_dev = None
+        # what the steps since the last loss fetch counted (a routed
+        # layer's rows: the step returns them beside its loss), still on
+        # the device; kept only while a sink is on
+        self._counted_dev: list[dict] = []
         # cached wire-layout template (shapes fixed by the model config;
         # rebuilding a full-model zeros tree per poll is O(model bytes) of
         # pure allocation — same rationale as Validator._host_template)
@@ -1468,8 +1527,12 @@ class MinerLoop:
                 # holding the newest one across steps is safe (and only the
                 # newest is retained).
                 self._last_loss_dev = m["loss"]
+                if len(m) > 2 and obs.enabled():
+                    self._counted_dev.append(
+                        {k: v for k, v in m.items()
+                         if k not in ("loss", "tokens")})
                 if self.metrics and self.report.steps % self.log_every == 0:
-                    self.report.last_loss = float(self._last_loss_dev)
+                    self.report.last_loss = self._fetch_loss()
                     if self.anomaly is not None:
                         # loss + push-failure rules run at the log cadence:
                         # the loss is already host-fetched here, so anomaly
@@ -1510,7 +1573,7 @@ class MinerLoop:
             exiting_exceptionally = sys.exc_info()[0] is not None
             if self._last_loss_dev is not None:
                 try:
-                    self.report.last_loss = float(self._last_loss_dev)
+                    self.report.last_loss = self._fetch_loss()
                 except Exception:
                     if not exiting_exceptionally:
                         raise
@@ -1518,6 +1581,18 @@ class MinerLoop:
                         "miner %s: final loss fetch failed during "
                         "exceptional shutdown", self.miner_id, exc_info=True)
         return self.report
+
+    def _fetch_loss(self) -> float:
+        """The newest loss and, in the SAME fetch, what the steps since
+        the last one counted, which goes to the registry under the names
+        the model gave (``train.moe.rows`` ...: docs/observability.md)."""
+        loss, counted = jax.device_get((self._last_loss_dev,
+                                        self._counted_dev))
+        self._counted_dev = []
+        for step in counted:
+            for name, val in step.items():
+                obs.count(name, int(val))
+        return float(loss)
 
     def flush(self) -> None:
         """Force a delta push (and checkpoint, if configured) now, then

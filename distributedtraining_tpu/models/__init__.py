@@ -9,6 +9,10 @@
 - nemotron_h: Mamba-2 layers beside attention and latent routed experts,
   of which a chip holds its share (the Nemotron-3-Super row), on the
   serving path.
+- lfm2_moe: gated short convolutions beside grouped-query attention and
+  routed experts of which a chip holds its share (the LFM2-8B-A1B row), on
+  the TRAINING path: the first family but GPT-2 that ``TrainEngine`` and
+  ``MinerLoop`` run on the chip.
 - lora: low-rank adapter trees whose *parameters are the delta*.
 """
 
@@ -22,8 +26,8 @@ def family_of(preset: str):
     """The family (its module: ``PRESETS``, ``make_model``) that owns a
     preset's name; GPT-2's, whose lookup then names the unknown preset,
     where none does."""
-    from . import deepseek_v3, gpt2, llama, nemotron_h
-    for family in (llama, deepseek_v3, nemotron_h):
+    from . import deepseek_v3, gpt2, lfm2_moe, llama, nemotron_h
+    for family in (llama, deepseek_v3, nemotron_h, lfm2_moe):
         if preset in family.PRESETS:
             return family
     return gpt2

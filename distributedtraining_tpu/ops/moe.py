@@ -46,11 +46,13 @@ def _on_tpu() -> bool:
 
 
 def route(h: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
-          scale: float, norm: bool = True) -> tuple[jax.Array, jax.Array]:
+          scale: float, norm: bool = True, norm_eps: float = 1e-20
+          ) -> tuple[jax.Array, jax.Array]:
     """``h`` [N, E], ``w_router`` [E, G], ``bias`` [G] -> (choice
     [N, top_k] int32, weights [N, top_k] float32). Scores are float32
     whatever the activations' dtype: a near-tie between the k-th and the
-    next expert is decided here."""
+    next expert is decided here. ``norm_eps`` stands under the
+    normalisation's sum (a family's release says which)."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             h.astype(jnp.float32), w_router.astype(jnp.float32),
@@ -59,7 +61,7 @@ def route(h: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
         picked = jnp.take_along_axis(scores, choice, axis=-1)
         if norm:
             picked = picked / (jnp.sum(picked, axis=-1, keepdims=True)
-                               + 1e-20)
+                               + norm_eps)
         return choice.astype(jnp.int32), picked * scale
 
 
@@ -111,6 +113,49 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     return out[:m] if pad else out
 
 
+@jax.custom_vjp
+def _held_rows(x_sorted: jax.Array, here: jax.Array, order: jax.Array
+               ) -> jax.Array:
+    """``x_sorted`` as it is; backward, the cotangent of a row of no held
+    group is ZERO, whatever the grouped product's own backward left in an
+    output it never wrote (``gmm``'s is unwritten memory): that row's
+    expert is another chip's, and nothing of it may reach ``dh``. Forward
+    it adds no instruction (``here`` is sorted in the backward alone)."""
+    return x_sorted
+
+
+_held_rows.defvjp(
+    lambda x, here, order: (x, (here, order)),
+    lambda res, g: (jnp.where(jnp.take(res[0], res[1])[:, None], g,
+                              0.0).astype(g.dtype), None, None))
+
+
+@jax.custom_vjp
+def _weigh_held(y: jax.Array, w_sorted: jax.Array, here: jax.Array,
+                order: jax.Array) -> jax.Array:
+    """``w y`` in float32 where the row's expert is held, zero elsewhere.
+    Backward, a row held elsewhere gives zero to ``y`` AND to its weight:
+    the plain rule would multiply a zero cotangent by whatever the
+    product left in that row of ``y`` (0 x NaN)."""
+    y = y.astype(jnp.float32) * w_sorted[:, None]
+    # a row of no group is whatever the product left there
+    return jnp.where(jnp.take(here, order)[:, None], y, 0.0)
+
+
+def _weigh_held_bwd(res, g):
+    y, w_sorted, here, order = res
+    held_row = jnp.take(here, order)[:, None]
+    g = jnp.where(held_row, g, 0.0)
+    dw = jnp.sum(jnp.where(held_row, g * y.astype(jnp.float32), 0.0),
+                 axis=-1)
+    return (g * w_sorted[:, None]).astype(y.dtype), dw, None, None
+
+
+_weigh_held.defvjp(
+    lambda y, w, here, order: (_weigh_held(y, w, here, order),
+                               (y, w, here, order)), _weigh_held_bwd)
+
+
 def _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl):
     """The two grouped products over rows already sorted by expert. What
     an expert IS follows from its two stacks: a first stack twice as wide
@@ -135,7 +180,8 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
                    w_in: jax.Array, w_down: jax.Array, *,
                    held: tuple[int, int] | None = None,
                    live: jax.Array | None = None,
-                   impl: str | None = None) -> tuple[jax.Array, dict]:
+                   impl: str | None = None,
+                   count_fullest: bool = False) -> tuple[jax.Array, dict]:
     """``sum_e w_e E_e(h)`` over the chosen experts of every row THAT ARE
     HELD HERE.
 
@@ -146,11 +192,16 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     count - 1`` of the router's; a choice outside them is another chip's
     to compute (expert parallelism): its row is sorted behind the last
     held group, where the grouped product leaves rows alone, and adds
-    zero here. None: the stacks are all the router's experts. ``live``
+    zero here, forward AND backward: its gradient with respect to ``h``,
+    to its weight and to every stack is exactly zero by construction
+    (:func:`_held_rows`, :func:`_weigh_held`), on every path of the
+    grouped product. None: the stacks are all the router's experts. ``live``
     [N] bool marks the rows that are not padding: every row is computed
     (shapes are static) and only live ones are counted. Returns ([N, E]
     in ``h``'s dtype, int32 scalars {"moe_rows": live rows computed here,
-    "moe_experts_touched"} and, with ``held``, "moe_rows_elsewhere")."""
+    "moe_experts_touched"} and, with ``held``, "moe_rows_elsewhere";
+    with ``count_fullest`` also "moe_rows_fullest", the rows of the
+    fullest held expert)."""
     N, k = choice.shape
     G = w_in.shape[0]
     flat = choice.reshape(-1)                            # [N*k]
@@ -164,12 +215,14 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     group_sizes = jnp.bincount(flat, length=G).astype(jnp.int32)
     with jax.named_scope("moe.experts"):
         x_sorted = jnp.take(h, order // k, axis=0)
+        if held is not None:
+            x_sorted = _held_rows(x_sorted, here, order)
         y = _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl)
         w_sorted = jnp.take(weights.reshape(-1), order)
-        y = y.astype(jnp.float32) * w_sorted[:, None]
         if held is not None:
-            # a row of no group is whatever the product left there
-            y = jnp.where(jnp.take(here, order)[:, None], y, 0.0)
+            y = _weigh_held(y, w_sorted, here, order)
+        else:
+            y = y.astype(jnp.float32) * w_sorted[:, None]
         # un-sort: row r of the sorted order came from flat row order[r]
         y = jnp.take(y, jnp.argsort(order), axis=0)
         out = jnp.sum(y.reshape(N, k, -1), axis=1).astype(h.dtype)
@@ -188,4 +241,6 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
         ours = jnp.sum(here if live_flat is None else here * live_flat)
         stats.update(moe_rows=ours.astype(jnp.int32),
                      moe_rows_elsewhere=(rows - ours).astype(jnp.int32))
+    if count_fullest:
+        stats["moe_rows_fullest"] = jnp.max(group_sizes)
     return out, stats

@@ -54,20 +54,38 @@ def _on_tpu() -> bool:
 # the convolution in front
 # ---------------------------------------------------------------------------
 
-def causal_conv1d(u: jax.Array, w: jax.Array, b: jax.Array,
-                  live_len: jax.Array) -> tuple[jax.Array, jax.Array]:
+def causal_conv1d(u: jax.Array, w: jax.Array, b: jax.Array | None,
+                  live_len: jax.Array | None,
+                  segment_ids: jax.Array | None = None
+                  ) -> tuple[jax.Array, jax.Array | None]:
     """Depthwise causal convolution from a zero history. ``u`` [B, T, C],
-    ``w`` [K, C] (``w[K-1]`` multiplies the current row), ``b`` [C],
-    ``live_len`` [B] -> (``conv(u) + b`` [B, T, C] float32, the tail: the
-    ``K - 1`` rows of ``u`` before position ``live_len`` [B, K-1, C], zero
-    where the sequence is shorter)."""
+    ``w`` [K, C] (``w[K-1]`` multiplies the current row), ``b`` [C] or
+    None (no bias), ``live_len`` [B] -> (``conv(u) + b`` [B, T, C] float32,
+    the tail: the ``K - 1`` rows of ``u`` before position ``live_len``
+    [B, K-1, C], zero where the sequence is shorter; None without
+    ``live_len``, for a caller that keeps nothing: the train step).
+
+    ``segment_ids`` [B, T] (packed rows, data/packing.py): every document
+    starts from its OWN zero history, so a tap whose source position lies
+    in another segment adds zero, as one before the row's start does. One
+    function for a prefill (one sequence a row: no ids, and the program is
+    what it was, instruction for instruction) and for a packed train
+    row."""
     K = w.shape[0]
     T = u.shape[1]
     padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
     w32 = w.astype(jnp.float32)
-    out = b.astype(jnp.float32)
+    out = 0.0 if b is None else b.astype(jnp.float32)
     for k in range(K):
-        out = out + padded[:, k:k + T].astype(jnp.float32) * w32[k]
+        tap = padded[:, k:k + T].astype(jnp.float32) * w32[k]
+        if segment_ids is not None and k < K - 1:
+            # the source of tap k at position t is position t - (K - 1 - k)
+            source = jnp.pad(segment_ids, ((0, 0), (K - 1 - k, 0)),
+                             constant_values=-1)[:, :T]
+            tap = jnp.where((source == segment_ids)[..., None], tap, 0.0)
+        out = out + tap
+    if live_len is None:
+        return out, None
     tail = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(
         rows, n, K - 1, axis=0))(padded, live_len)
     return out, tail

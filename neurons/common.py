@@ -298,7 +298,9 @@ def build(cfg: RunConfig) -> Components:
         optimizer=default_optimizer(cfg.learning_rate,
                                     grad_clip=cfg.grad_clip,
                                     weight_decay=cfg.weight_decay,
-                                    mu_dtype=cfg.mu_dtype),
+                                    mu_dtype=cfg.mu_dtype,
+                                    is_buffer=getattr(model_cfg, "is_buffer",
+                                                      None)),
         mesh=mesh, seq_len=seq, fused_loss=cfg.fused_loss,
         accum_steps=cfg.accum_steps)
 

@@ -22,6 +22,12 @@ candidates that lost live only here.
     JAX_PLATFORMS=cpu python scripts/ab_flash.py --aot
                                   # compile every candidate for a described
                                   # v5e; nothing runs, no chip needed
+    chiprun -- python scripts/ab_flash.py --gqa
+                                  # grouped-query heads (32 over 8 K/V heads
+                                  # of 64) at B=2, T=8,192, packed: K/V
+                                  # repeated to the query heads through
+                                  # `landed`, against the library's grouped
+                                  # (MQA) kernel mapped over the K/V heads
 
 A time is the device's: `reps` calls chained inside ONE jitted loop (each
 call's output is the next one's query), the wall clock around it with
@@ -159,6 +165,73 @@ def candidates():
     return out
 
 
+# -- grouped-query heads ----------------------------------------------------
+
+GQA = (2, 32, 8, 8192, 64)         # B, query heads, K/V heads, T, D
+
+
+def gqa_repeat(q, k, v, seg):
+    rep = q.shape[2] // k.shape[2]
+    return landed(q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+                  seg)
+
+
+def gqa_grouped(q, k, v, seg):
+    """The library's MQA kernel (one K/V head under a group of query
+    heads), mapped over the batch and the K/V heads, with `landed`'s
+    blocks."""
+    B, T, Hq, D = q.shape
+    Hkv = k.shape[2]
+    kernel = sk.make_splash_mqa_single_device(
+        sm.MultiHeadMask([sm.CausalMask((T, T))] * (Hq // Hkv)),
+        block_sizes=landed_mod._block_sizes(T))
+    qs = (q * D ** -0.5).astype(q.dtype).reshape(B, T, Hkv, Hq // Hkv, D)
+    out = jax.vmap(jax.vmap(
+        lambda a, b, c, s: kernel(a, b, c, segment_ids=sk.SegmentIds(
+            q=s, kv=s)), in_axes=(0, 0, 0, None)))(
+        qs.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), seg)          # [B, Hkv, G, T, D]
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, T, Hq, D).astype(q.dtype)
+
+
+def run_gqa(args, sharding):
+    """Both spellings at the LFM2 train cell's shape, forward and forward
+    + gradient; the grouped kernel is checked against the repeated one
+    (a dense oracle would hold 17 GB of scores)."""
+    B, Hq, Hkv, T, D = GQA
+    q, _, _, do, seg = make_inputs((B, Hq, T, D), args.seed, True)
+    _, k, v, _, _ = make_inputs((B, Hkv, T, D), args.seed + 1, False)
+    inputs = on_device((q, k, v, do, seg), sharding)
+    rows, ref = [], None
+    for name, fn in (("gqa repeat (landed)", gqa_repeat),
+                     ("gqa grouped (splash mqa)", gqa_grouped)):
+        row = {"shape": list(GQA), "candidate": name}
+        try:
+            if sharding is not None:
+                for grad in (False, True):
+                    programs(fn, grad).trace(*inputs, 1).lower(
+                        lowering_platforms=("tpu",)).compile()
+                row["compiled"] = True
+            else:
+                row["fwd_ms"] = time_ms(programs(fn, False), inputs,
+                                        args.reps, args.trials)
+                prog = programs(fn, True)
+                got = prog(*inputs, 1)
+                row["fwd_bwd_ms"] = time_ms(prog, inputs, args.reps,
+                                            args.trials)
+                if ref is None:
+                    ref = got
+                else:
+                    row["err_packed"] = worst(got, ref)
+                    row["ok"] = (row["err_packed"][0] <= ATOL[0]
+                                 and max(row["err_packed"][1:]) <= ATOL[1])
+        except Exception as e:
+            row["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
 # -- inputs -----------------------------------------------------------------
 
 def make_inputs(shape, seed, packed):
@@ -191,7 +264,8 @@ def programs(fn, grad):
                                c[1], k, v)
             return (out,) + vjp(do)
         z = jnp.zeros_like(q)
-        return jax.lax.fori_loop(0, reps, body, (z, q, z, z))
+        zk = jnp.zeros_like(k)          # fewer K/V heads under --gqa
+        return jax.lax.fori_loop(0, reps, body, (z, q, zk, zk))
     return jax.jit(fwd_bwd if grad else fwd)
 
 
@@ -298,6 +372,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=2147483659)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--trials", type=int, default=5)
+    ap.add_argument("--gqa", action="store_true",
+                    help="the grouped-query A/B alone (see above)")
     ap.add_argument("--only", default="",
                     help="substring a candidate's name must hold")
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
@@ -323,10 +399,14 @@ def main(argv=None) -> int:
 
     cands = [c for c in candidates() if args.only in c[0]]
     rows, oracle = [], {}
-    rows += run_shape(TRAIN, cands, args, oracle, sharding)
-    fwd_only = [c for c in cands if c[2] == "fwd"]
-    for shape in FORWARD_ONLY:
-        rows += run_shape(shape, fwd_only, args, oracle, sharding)
+    if args.gqa:
+        rows += run_gqa(args, sharding)
+        args.out = os.path.splitext(args.out)[0] + "_gqa.json"
+    else:
+        rows += run_shape(TRAIN, cands, args, oracle, sharding)
+        fwd_only = [c for c in cands if c[2] == "fwd"]
+        for shape in FORWARD_ONLY:
+            rows += run_shape(shape, fwd_only, args, oracle, sharding)
     text = table(rows)
     print(text)
     os.makedirs(os.path.dirname(args.out), exist_ok=True)

@@ -178,6 +178,36 @@ def test_causal_attention_gradient_compiles_at_the_train_cells_shape(
     assert fwd.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("tokens", [16384, 1024])
+def test_held_expert_layer_gradient_compiles_at_published_widths(
+        one_chip, monkeypatch, tokens):
+    """`train-lfm2-t8192`: LFM2-8B-A1B's routed layer (2,048 -> 2 x 1,792 ->
+    2,048, 4 of 32 a token, 8 held) under `jax.grad`, 16,384 tokens a step
+    (and a serve prefill's 1,024): two grouped products forward, two on
+    the transposed stacks and two `tgmm` backward, all within Mosaic's 16
+    MiB of VMEM at the one tiling `ops/moe.py` has."""
+    from distributedtraining_tpu.ops import moe
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(h, w_r, bias, w_in, w_down, target):
+        choice, weights = moe.route(h, w_r, bias, 4, 1.0, True, 1e-6)
+        out, _ = moe.routed_experts(h, choice, weights, w_in, w_down,
+                                    held=(0, 8))
+        return jnp.sum(out.astype(jnp.float32) * target)
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 3, 4)), sds((tokens, 2048)),
+        sds((2048, 32), jnp.float32), sds((32,), jnp.float32),
+        sds((8, 2048, 3584)), sds((8, 1792, 2048)),
+        sds((tokens, 2048), jnp.float32))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 6
+    assert "%gmm" in text and "%tgmm" in text
+
+
 @pytest.mark.parametrize("preset, mosaic_calls", [("gpt2-774m", 36),
                                                   ("gpt2-1.5b", 0)])
 def test_decode_program_reads_each_matrix_in_the_dtype_it_multiplies_in(
