@@ -17,6 +17,17 @@ the kernel is tested against). On the chip at the decode shape (384 rows
 over 128 experts) the kernel takes 1.07 + 0.57 ms a layer where
 ``ragged_dot`` takes 1.42 + 0.94 (PERF.md, PR 27).
 
+Under ``jax.grad`` the rows move by gathers alone: the layer has ONE
+permutation (``order``: token order -> expert order) and its inverse, and
+the two functions that move rows own their transposes, :func:`_dispatch`
+and :func:`_combine`. Autodiff would turn each ``take`` into a row-wide
+scatter-add, on the chip six times the equivalent gather's time (PERF.md,
+PR 34). With ``held``, both rules zero the rows of no held group before
+and behind any product (:func:`_held_rows`). Forward they add and move no
+instruction: a serve program lowers to the text it had, which is why each
+rule sorts for the inverse in its own forward rule (the compiler folds the
+two sorts into one) and none is made ahead of the dispatch.
+
 The router is DeepSeek-V3's ``noaux_tc`` with one group: sigmoid scores
 in float32, the choice made on ``scores + bias`` (the bias moves the
 choice and never the weights), the chosen scores normalised to sum to one
@@ -113,47 +124,92 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     return out[:m] if pad else out
 
 
-@jax.custom_vjp
-def _held_rows(x_sorted: jax.Array, here: jax.Array, order: jax.Array
+def _held_rows(x: jax.Array, here: jax.Array | None, order: jax.Array
                ) -> jax.Array:
-    """``x_sorted`` as it is; backward, the cotangent of a row of no held
-    group is ZERO, whatever the grouped product's own backward left in an
-    output it never wrote (``gmm``'s is unwritten memory): that row's
-    expert is another chip's, and nothing of it may reach ``dh``. Forward
-    it adds no instruction (``here`` is sorted in the backward alone)."""
-    return x_sorted
+    """``x`` with every row of no held group set to ZERO (row ``r`` of
+    ``x`` is flat ``(token, choice)`` row ``order[r]``), whatever lay
+    there: a select, never a product. Every cotangent of both rules goes
+    through it (``benchmarks/tools/lfm2_moe.py`` breaks the masks from
+    outside by this name and :func:`_weigh_held`'s)."""
+    if here is None:
+        return x
+    return jnp.where(jnp.take(here, order)[..., None], x, 0.0)
 
 
-_held_rows.defvjp(
-    lambda x, here, order: (x, (here, order)),
-    lambda res, g: (jnp.where(jnp.take(res[0], res[1])[:, None], g,
-                              0.0).astype(g.dtype), None, None))
+def _weigh_held(y: jax.Array, w_sorted: jax.Array, here: jax.Array | None,
+                order: jax.Array) -> jax.Array:
+    """``w y`` in float32 where the row's expert is held, zero elsewhere:
+    a row of no group is whatever the grouped product left there."""
+    return _held_rows(y.astype(jnp.float32) * w_sorted[:, None], here, order)
 
 
 @jax.custom_vjp
-def _weigh_held(y: jax.Array, w_sorted: jax.Array, here: jax.Array,
-                order: jax.Array) -> jax.Array:
-    """``w y`` in float32 where the row's expert is held, zero elsewhere.
-    Backward, a row held elsewhere gives zero to ``y`` AND to its weight:
-    the plain rule would multiply a zero cotangent by whatever the
-    product left in that row of ``y`` (0 x NaN)."""
-    y = y.astype(jnp.float32) * w_sorted[:, None]
-    # a row of no group is whatever the product left there
-    return jnp.where(jnp.take(here, order)[:, None], y, 0.0)
+def _dispatch(h: jax.Array, here: jax.Array | None, order: jax.Array
+              ) -> jax.Array:
+    """Token order -> expert order: ``h[order // k]``. Backward, a token's
+    ``k`` sorted rows are GATHERED along the inverse permutation and
+    summed in float32: no scatter-add into ``[N, E]``. A row of no held
+    group gives ZERO, whatever the grouped product's own backward left in
+    an output it never wrote (``gmm``'s is unwritten memory): that row's
+    expert is another chip's, and nothing of it may reach ``dh``. ``here``
+    is read in the backward alone."""
+    return jnp.take(h, order // (order.shape[0] // h.shape[0]), axis=0)
 
 
-def _weigh_held_bwd(res, g):
-    y, w_sorted, here, order = res
-    held_row = jnp.take(here, order)[:, None]
-    g = jnp.where(held_row, g, 0.0)
-    dw = jnp.sum(jnp.where(held_row, g * y.astype(jnp.float32), 0.0),
-                 axis=-1)
-    return (g * w_sorted[:, None]).astype(y.dtype), dw, None, None
+def _dispatch_bwd(res, g):
+    # inverse [k, N]: the i-th sorted row of every token, so that the
+    # gather writes k whole [N, E] planes and the sum re-tiles nothing;
+    # plane i's row j is flat row j * k + i
+    here, inverse = res
+    k, N = inverse.shape
+    g = jnp.take(g, inverse, axis=0)                      # [k, N, E]
+    g = _held_rows(g, here, jnp.arange(N * k).reshape(N, k).T)
+    return (jnp.sum(g.astype(jnp.float32), axis=0).astype(g.dtype),
+            None, None)
 
 
-_weigh_held.defvjp(
-    lambda y, w, here, order: (_weigh_held(y, w, here, order),
-                               (y, w, here, order)), _weigh_held_bwd)
+_dispatch.defvjp(
+    lambda h, here, order: (
+        _dispatch(h, here, order),
+        (here, jnp.argsort(order).reshape(h.shape[0], -1).T)), _dispatch_bwd)
+
+
+def _combine_fwd(y, weights, here, order):
+    N, k = weights.shape
+    out = _weigh_held(y, jnp.take(weights.reshape(-1), order), here, order)
+    # un-sort: row r of the sorted order came from flat row order[r]
+    inverse = jnp.argsort(order)
+    out = jnp.take(out, inverse, axis=0)
+    out = jnp.sum(out.reshape(N, k, -1), axis=1).astype(y.dtype)
+    return out, (y, weights, here, order, inverse)
+
+
+@jax.custom_vjp
+def _combine(y: jax.Array, weights: jax.Array, here: jax.Array | None,
+             order: jax.Array) -> jax.Array:
+    """Expert order -> token order: the held rows weighed, un-sorted along
+    the inverse permutation and summed over ``k``. Backward, the
+    ``[N, E]`` cotangent comes to sorted order by ONE gather
+    (``order // k``: no ``[N, k, E]`` broadcast, no scatter-add) and a
+    weight's goes back as a gather of ``[N * k]`` scalars. A row held
+    elsewhere gives zero to ``y`` AND to its weight: the mask stands
+    before and behind the product with whatever the grouped product left
+    in that row of ``y`` (0 x NaN)."""
+    return _combine_fwd(y, weights, here, order)[0]
+
+
+def _combine_bwd(res, g):
+    y, weights, here, order, inverse = res
+    k = weights.shape[1]
+    g = _held_rows(jnp.take(g, order // k, axis=0).astype(jnp.float32),
+                   here, order)
+    dw = jnp.sum(_held_rows(g * y.astype(jnp.float32), here, order), axis=-1)
+    dy = g * jnp.take(weights.reshape(-1), order)[:, None]
+    return (dy.astype(y.dtype), jnp.take(dw, inverse).reshape(weights.shape),
+            None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl):
@@ -193,9 +249,12 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     to compute (expert parallelism): its row is sorted behind the last
     held group, where the grouped product leaves rows alone, and adds
     zero here, forward AND backward: its gradient with respect to ``h``,
-    to its weight and to every stack is exactly zero by construction
-    (:func:`_held_rows`, :func:`_weigh_held`), on every path of the
-    grouped product. None: the stacks are all the router's experts. ``live``
+    to its weight and to every stack is exactly zero by construction, on
+    every path of the grouped product. None: the stacks are all the
+    router's experts, and the same two functions move the rows with no
+    mask (:func:`_dispatch` owns the transpose of token -> expert order,
+    :func:`_combine` that of the weigh, the un-sort and the sum over
+    ``k``: gathers along ``order`` and its inverse, no scatter). ``live``
     [N] bool marks the rows that are not padding: every row is computed
     (shapes are static) and only live ones are counted. Returns ([N, E]
     in ``h``'s dtype, int32 scalars {"moe_rows": live rows computed here,
@@ -204,7 +263,7 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     fullest held expert)."""
     N, k = choice.shape
     G = w_in.shape[0]
-    flat = choice.reshape(-1)                            # [N*k]
+    flat, here = choice.reshape(-1), None                # [N*k]
     if held is not None:
         first, count = held
         if count != G:
@@ -214,18 +273,9 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     order = jnp.argsort(flat, stable=True)
     group_sizes = jnp.bincount(flat, length=G).astype(jnp.int32)
     with jax.named_scope("moe.experts"):
-        x_sorted = jnp.take(h, order // k, axis=0)
-        if held is not None:
-            x_sorted = _held_rows(x_sorted, here, order)
+        x_sorted = _dispatch(h, here, order)
         y = _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl)
-        w_sorted = jnp.take(weights.reshape(-1), order)
-        if held is not None:
-            y = _weigh_held(y, w_sorted, here, order)
-        else:
-            y = y.astype(jnp.float32) * w_sorted[:, None]
-        # un-sort: row r of the sorted order came from flat row order[r]
-        y = jnp.take(y, jnp.argsort(order), axis=0)
-        out = jnp.sum(y.reshape(N, k, -1), axis=1).astype(h.dtype)
+        out = _combine(y, weights, here, order)
     if live is None:
         live_flat = None
         rows, touched = jnp.int32(N * k), jnp.sum(group_sizes > 0)

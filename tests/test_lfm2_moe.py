@@ -257,6 +257,31 @@ def test_remat_on_equals_remat_off(tiny):
                                    atol=1e-7)
 
 
+def test_the_train_step_moves_routed_rows_by_gathers_through_remat(tiny):
+    """The engine's own step over the rematted blocks: `ops/moe.py`'s two
+    rules survive `nn.remat` and the engine's `jax.grad`, so nothing inside
+    a routed layer scatters into anything a row wide. What scatters there:
+    the `bincount` over the held groups (forward, and remat's re-run) and
+    the router's `[N, experts]` scores; outside, the lookup and the labels."""
+    from test_moe_grad import scatters
+
+    _, pc, _, _, _ = tiny
+    model, _ = lf.make_model(dataclasses.replace(pc, remat=True))
+    engine = TrainEngine(model)
+    step = engine.train_step.__wrapped__.trace(
+        engine.abstract_state(), _packed_batch(pc, [[20, 28], [48]])
+    ).jaxpr.jaxpr
+    found = list(scatters(step))
+    routed = [(shape, stack) for _, shape, stack in found
+              if "._experts" in stack]
+    G, R, rows = pc.experts_held[1], pc.num_experts, 2 * 48
+    assert {shape for shape, _ in routed} == {(G,), (rows, R)}
+    assert any("rematted_computation" in stack for _, stack in routed)
+    assert not [shape for shape, stack in routed if "moe.experts" in stack]
+    # the lookup's transpose is still one: the walker does see a wide one
+    assert (pc.padded_vocab, pc.hidden_size) in {s for _, s, _ in found}
+
+
 # -- the share and the model --------------------------------------------------
 
 def test_the_four_shares_add_up_to_the_uncut_layer_forward_and_backward(

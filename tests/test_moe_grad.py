@@ -145,6 +145,113 @@ def test_rows_held_elsewhere_give_exactly_zero_whatever_the_product_left(
     assert not np.asarray(got[0], np.float32)[none_here].any()
 
 
+def scatters(jaxpr, scope=""):
+    """(primitive, operand shape, name stack) of every scatter of a jaxpr,
+    those of its sub-jaxprs (remat, pjit, a custom rule's) among them."""
+    for eqn in jaxpr.eqns:
+        stack = f"{scope}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name.startswith("scatter"):
+            yield eqn.primitive.name, eqn.invars[0].aval.shape, stack
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from scatters(sub, stack)
+
+
+@pytest.mark.parametrize("impl,dtype", [("ragged_dot", jnp.float32),
+                                        ("gmm_interpret", jnp.bfloat16)])
+@pytest.mark.parametrize("held", [None, (2, 4)])
+def test_the_gradient_moves_rows_by_gathers_alone(impl, dtype, held):
+    """Both row moves own their transpose (`_dispatch`, `_combine`), so
+    the gradient with respect to `h`, the weights and both stacks holds no
+    scatter into anything a row wide: what stays is the `bincount` over
+    the groups, the kernel's small int32 tables and the router's
+    `[N, ROUTER]` scores."""
+    h, w_r, bias, w_in, w_down = _setup(17, dtype, held)
+    choice, weights = moe.route(h, w_r, bias, K, 1.0)
+
+    def loss(h, weights, w_in, w_down):
+        out, _ = moe.routed_experts(h, choice, weights, w_in, w_down,
+                                    held=held, impl=impl)
+        return jnp.sum(out.astype(jnp.float32))
+
+    grad = jax.grad(loss, (0, 1, 2, 3))
+    found = list(scatters(jax.make_jaxpr(grad)(h, weights, w_in,
+                                               w_down).jaxpr))
+    assert found                            # the bincount's, at least
+    wide = [(name, shape) for name, shape, _ in found
+            if len(shape) >= 2 or shape[0] > N * K]
+    assert not wide, wide
+    # and the whole of it, router included: [N, ROUTER] is the widest
+    found = list(scatters(jax.make_jaxpr(jax.grad(
+        lambda *a: _loss(lambda h, w, c, a, b: moe.routed_experts(
+            h, c, w, a, b, held=held, impl=impl)[0], *a, 1.0),
+        (0, 1, 3, 4)))(h, w_r, bias, w_in, w_down).jaxpr))
+    assert max(shape[-1] for _, shape, _ in found if len(shape) >= 2) < E
+
+
+@pytest.mark.parametrize("impl,dtype", [("ragged_dot", jnp.float32),
+                                        ("ragged_dot", jnp.bfloat16),
+                                        ("gmm_interpret", jnp.bfloat16)])
+@pytest.mark.parametrize("held", [None, (2, 4)])
+def test_the_forward_is_the_plain_formula_to_the_bit(impl, dtype, held):
+    """take, the products, a float32 weigh, the mask, take by
+    argsort(order), the sum over k: the rules change what `jax.grad`
+    builds and nothing of what the layer returns."""
+    h, w_r, bias, w_in, w_down = _setup(19, dtype, held)
+    choice, weights = moe.route(h, w_r, bias, K, 1.0)
+    flat = choice.reshape(-1)
+    G = w_in.shape[0]
+    if held is not None:
+        here = (flat >= held[0]) & (flat < held[0] + held[1])
+        flat = jnp.where(here, flat - held[0], G)
+    order = jnp.argsort(flat, stable=True)
+    y = moe._experts_sorted(
+        jnp.take(h, order // K, axis=0), w_in, w_down,
+        jnp.bincount(flat, length=G).astype(jnp.int32), impl)
+    y = y.astype(jnp.float32) * jnp.take(weights.reshape(-1), order)[:, None]
+    if held is not None:
+        y = jnp.where(jnp.take(here, order)[:, None], y, 0.0)
+    y = jnp.take(y, jnp.argsort(order), axis=0)
+    want = jnp.sum(y.reshape(N, K, -1), axis=1).astype(dtype)
+    got, _ = jax.jit(lambda *a: moe.routed_experts(
+        *a, held=held, impl=impl))(h, choice, weights, w_in, w_down)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
+                                       (jnp.bfloat16, 0.02)])
+def test_a_tokens_k_held_rows_sum_into_its_gradient(dtype, tol):
+    """The dispatch's backward gathers a token's k sorted rows and sums
+    them. Token 0 has all three of its experts held, token 1 the same
+    held expert THREE times (k colliding rows of one group), token 2 none,
+    the rest as routed: `dh` is the dense masked sum's, row by row."""
+    held = (2, 4)
+    h, w_r, bias, w_in, w_down = _setup(23, dtype, held)
+    choice, weights = moe.route(h, w_r, bias, K, 1.0)
+    choice = choice.at[0].set(jnp.asarray([2, 3, 5])).at[1].set(3).at[
+        2].set(jnp.asarray([0, 1, 7]))
+    target = jnp.asarray(np.random.default_rng(7).standard_normal((N, E)),
+                         jnp.float32)
+
+    def routed(h, weights):
+        return jnp.sum(moe.routed_experts(
+            h, choice, weights, w_in, w_down, held=held,
+            impl="ragged_dot")[0].astype(jnp.float32) * target)
+
+    def dense(h, weights):
+        return jnp.sum(_dense(h, weights, choice, w_in, w_down, held)
+                       * target)
+
+    got = jax.grad(routed, (0, 1))(h, weights)
+    want = jax.grad(dense, (0, 1))(h, weights)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= tol * np.abs(w).max()
+        assert np.abs(g[:2] - w[:2]).max() <= tol * np.abs(w[:2]).max()
+        assert w[:2].any() and not g[2].any()
+
+
 @pytest.mark.parametrize("m", [4096, 1024])
 def test_the_interpreted_kernel_gives_ragged_dots_product(m):
     """At a train step's thousands of rows an expert and at a serve
