@@ -71,9 +71,10 @@ class LoRAEngine(TrainEngine):
             l, count, grads = accumulated_grads(
                 lambda p, mb: loss(p, base, mb), state.params, batch,
                 accum_steps)
-            updates, opt_state = self.tx.update(grads, state.opt_state,
-                                                state.params)
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("train.optimizer"):
+                updates, opt_state = self.tx.update(grads, state.opt_state,
+                                                    state.params)
+                params = optax.apply_updates(state.params, updates)
             return (TrainState(step=state.step + 1, params=params,
                                opt_state=opt_state),
                     {"loss": l, "tokens": count})

@@ -166,8 +166,9 @@ def _default_lm_loss(model, params, batch, *, with_counters: bool = False):
     else:
         logits, sown = model.apply(variables, batch["input_ids"],
                                    **kwargs), {}
-    loss, count = causal_lm_loss(logits, batch["input_ids"],
-                                 batch.get("loss_mask"))
+    with jax.named_scope("train.loss"):
+        loss, count = causal_lm_loss(logits, batch["input_ids"],
+                                     batch.get("loss_mask"))
     counters = sown.get("intermediates", {}).get("train_counters")
     return loss, ((count, counters[0]) if counters else count)
 
@@ -191,9 +192,10 @@ def _fused_lm_loss(model, params, batch, impl: str = "auto", mesh=None):
     head = params["lm_head"] if "lm_head" in params else params["wte"]
     mask = batch.get("loss_mask")
     if mesh is None:
-        return fused_linear_cross_entropy(
-            hidden[:, :-1, :], head, batch["input_ids"][:, 1:],
-            None if mask is None else mask[:, 1:], impl=impl)
+        with jax.named_scope("train.loss"):
+            return fused_linear_cross_entropy(
+                hidden[:, :-1, :], head, batch["input_ids"][:, 1:],
+                None if mask is None else mask[:, 1:], impl=impl)
     # mesh spelling: same math WITHOUT slicing the sequence axis — the
     # shift moves into the (tiny, global) labels/mask arrays, so hidden
     # keeps its full [B, T, E] shape and the shard_map kernel composes
@@ -204,8 +206,9 @@ def _fused_lm_loss(model, params, batch, impl: str = "auto", mesh=None):
     m = (jnp.ones(ids.shape[:2], jnp.float32) if mask is None
          else mask.astype(jnp.float32))
     m = jnp.pad(m[:, 1:], ((0, 0), (0, 1)))
-    return fused_linear_cross_entropy(hidden, head, labels, m,
-                                      impl=impl, mesh=mesh)
+    with jax.named_scope("train.loss"):
+        return fused_linear_cross_entropy(hidden, head, labels, m,
+                                          impl=impl, mesh=mesh)
 
 
 class TrainEngine:
@@ -345,9 +348,10 @@ class TrainEngine:
             loss, aux, grads = accumulated_grads(
                 train_loss, state.params, batch, accum_steps)
             tokens, counters = aux if isinstance(aux, tuple) else (aux, {})
-            updates, opt_state = self.tx.update(grads, state.opt_state,
-                                                state.params)
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("train.optimizer"):
+                updates, opt_state = self.tx.update(grads, state.opt_state,
+                                                    state.params)
+                params = optax.apply_updates(state.params, updates)
             new_state = TrainState(step=state.step + 1, params=params,
                                    opt_state=opt_state)
             return new_state, {"loss": loss, "tokens": tokens, **counters}
@@ -1490,35 +1494,34 @@ class MinerLoop:
         if self.heartbeat is not None:
             self.heartbeat.start()   # idempotent across run() calls
         start_steps = self.report.steps  # max_steps bounds *this* call
-        import time as _time
         batch_iter = iter(batches)
         try:
             while True:
                 # data-wait attribution: host time blocked on the input
                 # pipeline pulling the NEXT batch — the third leg of the
                 # step-time anatomy (host-blocked vs device vs data-wait)
-                # heartbeats and fleet_report render via devprof.anatomy()
-                tw = _time.perf_counter()
-                try:
-                    batch = next(batch_iter)
-                except StopIteration:
+                # heartbeats and fleet_report render via devprof.anatomy().
+                # One observation a ``next``: the one that finds the feed
+                # exhausted is a wait too.
+                with obs.phase("miner.data_wait"):
+                    batch = next(batch_iter, None)
+                if batch is None:
                     break
-                obs.observe("miner.data_wait_ms",
-                            (_time.perf_counter() - tw) * 1e3)
                 if max_steps is not None and self.report.steps - start_steps >= max_steps:
                     break
                 self._pull_action.poll()
-                # step-time attribution: dispatch-side wall time per step
-                # (the host's view — what pipeline stalls actually cost).
-                # Two perf_counter reads + one gated histogram observe.
-                t0 = _time.perf_counter()
-                m = self._train_one(batch)
-                step_ms = (_time.perf_counter() - t0) * 1e3
-                obs.observe("miner.step_ms", step_ms)
+                # step-time attribution: place + DISPATCH wall time per
+                # step (the host's view: once the runtime's queue is full
+                # a dispatch blocks for about one device step, so the mean
+                # over a long run is the step and the p50 is not). The
+                # clock is read only for a sink or an anomaly monitor.
+                with obs.phase("miner.step",
+                               timed=self.anomaly is not None) as stepped:
+                    m = self._train_one(batch)
                 if self.trace is not None:
                     self.trace.tick()
                 if self.anomaly is not None:
-                    self.anomaly.observe_step_ms(step_ms)
+                    self.anomaly.observe_step_ms(stepped.dur_ms)
                     self.anomaly.tick()
                 self.report.steps += 1
                 # keep the loss on-device: train_step dispatches
@@ -1554,13 +1557,15 @@ class MinerLoop:
                     # periodic registry flush: counters + span/step
                     # histograms ride the same sink at the same cadence
                     obs.flush(self.metrics, step=self.report.steps)
-                if self._val_guard_action is not None:
-                    # before push: a revert must land before publishing, so
-                    # the pushed delta is never the known-degraded state
-                    self._val_guard_action.poll()
-                self._push_action.poll()
-                if self._ckpt_action is not None:
-                    self._ckpt_action.poll()
+                with obs.phase("miner.actions"):
+                    if self._val_guard_action is not None:
+                        # before push: a revert must land before publishing,
+                        # so the pushed delta is never the known-degraded
+                        # state
+                        self._val_guard_action.poll()
+                    self._push_action.poll()
+                    if self._ckpt_action is not None:
+                        self._ckpt_action.poll()
         finally:
             # finally: the KeyboardInterrupt shutdown path (neurons/miner.py)
             # reads report.last_loss after an exceptional exit too. On THAT
@@ -1586,8 +1591,9 @@ class MinerLoop:
         """The newest loss and, in the SAME fetch, what the steps since
         the last one counted, which goes to the registry under the names
         the model gave (``train.moe.rows`` ...: docs/observability.md)."""
-        loss, counted = jax.device_get((self._last_loss_dev,
-                                        self._counted_dev))
+        with obs.phase("miner.fetch_loss"):   # the loop's one wait for the chip
+            loss, counted = jax.device_get((self._last_loss_dev,
+                                            self._counted_dev))
         self._counted_dev = []
         for step in counted:
             for name, val in step.items():

@@ -153,53 +153,55 @@ class Block(nn.Module):
         leaving the training forward byte-identical to before."""
         cfg = self.cfg
         B, T, E = x.shape
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype(),
-                         param_dtype=cfg.storage_dtype(),
-                         scale_init=nn.with_logical_partitioning(
-                             nn.initializers.ones_init(), ("embed",)),
-                         bias_init=nn.with_logical_partitioning(
-                             nn.initializers.zeros_init(), ("embed",)),
-                         name="ln_1")(x)
-        qkv = _dense(3 * E, "c_attn", ("embed", "qkv"), cfg)(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, cfg.n_head, cfg.head_dim)
-        k = k.reshape(B, T, cfg.n_head, cfg.head_dim)
-        v = v.reshape(B, T, cfg.n_head, cfg.head_dim)
-        if sow_kv:
-            self.sow("intermediates", "kv_cache", (k, v))
-        if kv_pages is not None:
-            from ..ops.paged_attention import paged_attention
-            attn = paged_attention(q, kv_pages[0], kv_pages[1],
-                                   page_tables, kv_lens, k, v)
-        elif kv_ctx is not None:
-            k_ctx, v_ctx = kv_ctx
-            attn = cached_attention(q,
-                                    jnp.concatenate([k_ctx, k], axis=1),
-                                    jnp.concatenate([v_ctx, v], axis=1),
-                                    kv_lens)
-        else:
-            attn = causal_attention(q, k, v, attention_mask=attention_mask,
-                                    segment_ids=segment_ids,
-                                    impl=cfg.attention_impl)
-        attn = attn.reshape(B, T, E)
-        attn = _dense(E, "c_proj", ("qkv", "embed"), cfg)(attn)
-        if cfg.dropout > 0:
-            attn = nn.Dropout(cfg.dropout)(attn, deterministic=deterministic)
-        x = x + attn
+        with jax.named_scope("gpt2.attn"):
+            h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype(),
+                             param_dtype=cfg.storage_dtype(),
+                             scale_init=nn.with_logical_partitioning(
+                                 nn.initializers.ones_init(), ("embed",)),
+                             bias_init=nn.with_logical_partitioning(
+                                 nn.initializers.zeros_init(), ("embed",)),
+                             name="ln_1")(x)
+            qkv = _dense(3 * E, "c_attn", ("embed", "qkv"), cfg)(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(B, T, cfg.n_head, cfg.head_dim)
+            k = k.reshape(B, T, cfg.n_head, cfg.head_dim)
+            v = v.reshape(B, T, cfg.n_head, cfg.head_dim)
+            if sow_kv:
+                self.sow("intermediates", "kv_cache", (k, v))
+            if kv_pages is not None:
+                from ..ops.paged_attention import paged_attention
+                attn = paged_attention(q, kv_pages[0], kv_pages[1],
+                                       page_tables, kv_lens, k, v)
+            elif kv_ctx is not None:
+                k_ctx, v_ctx = kv_ctx
+                attn = cached_attention(q,
+                                        jnp.concatenate([k_ctx, k], axis=1),
+                                        jnp.concatenate([v_ctx, v], axis=1),
+                                        kv_lens)
+            else:
+                attn = causal_attention(q, k, v, attention_mask=attention_mask,
+                                        segment_ids=segment_ids,
+                                        impl=cfg.attention_impl)
+            attn = attn.reshape(B, T, E)
+            attn = _dense(E, "c_proj", ("qkv", "embed"), cfg)(attn)
+            if cfg.dropout > 0:
+                attn = nn.Dropout(cfg.dropout)(attn, deterministic=deterministic)
+            x = x + attn
 
-        h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype(),
-                         param_dtype=cfg.storage_dtype(),
-                         scale_init=nn.with_logical_partitioning(
-                             nn.initializers.ones_init(), ("embed",)),
-                         bias_init=nn.with_logical_partitioning(
-                             nn.initializers.zeros_init(), ("embed",)),
-                         name="ln_2")(x)
-        h = _dense(4 * E, "c_fc", ("embed", "mlp"), cfg)(h)
-        h = nn.gelu(h, approximate=True)  # gelu_new, as in GPT-2
-        h = _dense(E, "mlp_proj", ("mlp", "embed"), cfg)(h)
-        if cfg.dropout > 0:
-            h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
-        return x + h
+        with jax.named_scope("gpt2.mlp"):
+            h = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype(),
+                             param_dtype=cfg.storage_dtype(),
+                             scale_init=nn.with_logical_partitioning(
+                                 nn.initializers.ones_init(), ("embed",)),
+                             bias_init=nn.with_logical_partitioning(
+                                 nn.initializers.zeros_init(), ("embed",)),
+                             name="ln_2")(x)
+            h = _dense(4 * E, "c_fc", ("embed", "mlp"), cfg)(h)
+            h = nn.gelu(h, approximate=True)  # gelu_new, as in GPT-2
+            h = _dense(E, "mlp_proj", ("mlp", "embed"), cfg)(h)
+            if cfg.dropout > 0:
+                h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
+            return x + h
 
 
 class _BlockScan(nn.Module):
@@ -263,23 +265,24 @@ class GPT2(nn.Module):
         # Positions index with the 1-D arange (NOT [None, :]): a
         # [1, T, E] intermediate would carry a degenerately batch-sharded
         # size-1 axis. [T, E] broadcasts identically and stays replicated.
-        if position_ids is None:
-            x = embed_lookup(wte, input_ids) + embed_lookup(
-                wpe, jnp.arange(T))
-        else:
-            x = embed_lookup(wte, input_ids) + embed_lookup(
-                wpe, position_ids)
-        # pin the embedding output (and, critically, its COTANGENT — the
-        # constraint applies to both) to batch sharding: on hybrid
-        # (dcn_dp) meshes the partitioner otherwise reshards dx onto the
-        # embed/fsdp axis for the wte/wpe scatter backward, a transfer
-        # that is inexpressible on the hybrid device order and falls back
-        # to involuntary full rematerialization. No-op without ambient
-        # logical_axis_rules (single-device paths).
-        x = nn.with_logical_constraint(x, ("batch", None, None))
-        x = x.astype(cfg.compute_dtype())
-        if cfg.dropout > 0:
-            x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
+        with jax.named_scope("gpt2.embed"):
+            if position_ids is None:
+                x = embed_lookup(wte, input_ids) + embed_lookup(
+                    wpe, jnp.arange(T))
+            else:
+                x = embed_lookup(wte, input_ids) + embed_lookup(
+                    wpe, position_ids)
+            # pin the embedding output (and, critically, its COTANGENT —
+            # the constraint applies to both) to batch sharding: on hybrid
+            # (dcn_dp) meshes the partitioner otherwise reshards dx onto
+            # the embed/fsdp axis for the wte/wpe scatter backward, a
+            # transfer that is inexpressible on the hybrid device order and
+            # falls back to involuntary full rematerialization. No-op
+            # without ambient logical_axis_rules (single-device paths).
+            x = nn.with_logical_constraint(x, ("batch", None, None))
+            x = x.astype(cfg.compute_dtype())
+            if cfg.dropout > 0:
+                x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
 
         if cfg.scan_blocks:
             # one Block program, lax.scan'd n_layer times: ~L-fold smaller
@@ -315,35 +318,36 @@ class GPT2(nn.Module):
                 x = block(cfg, name=f"h_{i}")(x, attention_mask, segment_ids,
                                               deterministic)
 
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype(),
-                         param_dtype=cfg.storage_dtype(),
-                         scale_init=nn.with_logical_partitioning(
-                             nn.initializers.ones_init(), ("embed",)),
-                         bias_init=nn.with_logical_partitioning(
-                             nn.initializers.zeros_init(), ("embed",)),
-                         name="ln_f")(x)
-        if return_hidden:
-            return x
-        # tied lm head: logits accumulate fp32 on the MXU. The logical
-        # constraint pins logits to batch x vocab(tp) sharding so the
-        # partitioner all-gathers the (small) head over fsdp rather than
-        # resharding the [B, T, E] hidden states onto the embed axis — on
-        # hybrid (dcn_dp) meshes that reshard is inexpressible and falls
-        # back to involuntary full rematerialization. No-op without an
-        # ambient logical_axis_rules context (single-device paths).
-        if self.has_variable("params", "lm_head"):
-            # a serving tree (engine/serve_weights.py): the head's operand
-            # was rounded when the revision was installed. No base and no
-            # training tree has this leaf: they trace the line below.
-            head = self.get_variable("params", "lm_head")
-        else:
-            head = wte.astype(cfg.compute_dtype())
-        logits = jnp.einsum("bte,ve->btv", x, head,
-                            preferred_element_type=jnp.float32)
-        logits = nn.with_logical_constraint(logits, ("batch", None, "vocab"))
-        # the astype fuses into the matmul epilogue, so "bfloat16" means the
-        # stored buffer (not the accumulation) shrinks
-        return logits.astype(jnp.dtype(cfg.logits_dtype))
+        with jax.named_scope("gpt2.head"):
+            x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.compute_dtype(),
+                             param_dtype=cfg.storage_dtype(),
+                             scale_init=nn.with_logical_partitioning(
+                                 nn.initializers.ones_init(), ("embed",)),
+                             bias_init=nn.with_logical_partitioning(
+                                 nn.initializers.zeros_init(), ("embed",)),
+                             name="ln_f")(x)
+            if return_hidden:
+                return x
+            # tied lm head: logits accumulate fp32 on the MXU. The logical
+            # constraint pins logits to batch x vocab(tp) sharding so the
+            # partitioner all-gathers the (small) head over fsdp rather than
+            # resharding the [B, T, E] hidden states onto the embed axis — on
+            # hybrid (dcn_dp) meshes that reshard is inexpressible and falls
+            # back to involuntary full rematerialization. No-op without an
+            # ambient logical_axis_rules context (single-device paths).
+            if self.has_variable("params", "lm_head"):
+                # a serving tree (engine/serve_weights.py): the head's operand
+                # was rounded when the revision was installed. No base and no
+                # training tree has this leaf: they trace the line below.
+                head = self.get_variable("params", "lm_head")
+            else:
+                head = wte.astype(cfg.compute_dtype())
+            logits = jnp.einsum("bte,ve->btv", x, head,
+                                preferred_element_type=jnp.float32)
+            logits = nn.with_logical_constraint(logits, ("batch", None, "vocab"))
+            # the astype fuses into the matmul epilogue, so "bfloat16" means the
+            # stored buffer (not the accumulation) shrinks
+            return logits.astype(jnp.dtype(cfg.logits_dtype))
 
     def init_params(self, rng, *, seq_len: int = 8):
         """Raw (unboxed) param pytree; logical axis metadata is recovered

@@ -225,59 +225,63 @@ class Lfm2MoeBlock(nn.Module):
     @nn.compact
     def __call__(self, x, attention_mask, segment_ids, position_ids):
         """-> (x, what the routed layer counted: {} for a dense FFN)."""
-        h = _norm(self.cfg, "operator_norm")(x)
-        mix = self._conv if self.mixer == "conv" else self._attention
-        x = x + mix(h, attention_mask, segment_ids, position_ids)
-        h = _norm(self.cfg, "ffn_norm")(x)
-        if not self.routed:
-            return x + self._dense_ffn(h), {}
-        out, stats = self._experts(h)
-        return x + out, stats
+        # each half, norm to residual add, under its scope for the device
+        # trace (docs/observability.md); `moe.route` / `moe.experts` open
+        # inside `lfm2.moe_ffn`, which keeps what they leave: the norm, the
+        # stacks' casts, the sort and the counters
+        conv = self.mixer == "conv"
+        with jax.named_scope("lfm2.conv" if conv else "lfm2.attn"):
+            h = _norm(self.cfg, "operator_norm")(x)
+            mix = self._conv if conv else self._attention
+            x = x + mix(h, attention_mask, segment_ids, position_ids)
+        with jax.named_scope("lfm2.moe_ffn" if self.routed
+                             else "lfm2.dense_ffn"):
+            h = _norm(self.cfg, "ffn_norm")(x)
+            if not self.routed:
+                return x + self._dense_ffn(h), {}
+            out, stats = self._experts(h)
+            return x + out, stats
 
     def _conv(self, h, _mask, segment_ids, _pos):
         cfg = self.cfg
         E = cfg.hidden_size
-        with jax.named_scope("lfm2.conv"):
-            bcu = _dense(3 * E, "in_proj", ("embed", "mlp"), cfg)(h)
-            gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
-            taps = self.param("conv_weight", _conv_init,
-                              (cfg.conv_L_cache, E), jnp.float32)
-            conv, _ = ssm.causal_conv1d(gate_b * u, taps, None, None,
-                                        segment_ids)
-            y = (gate_c.astype(jnp.float32) * conv).astype(
-                cfg.compute_dtype())
-            return _dense(E, "out_proj", ("mlp", "embed"), cfg)(y)
+        bcu = _dense(3 * E, "in_proj", ("embed", "mlp"), cfg)(h)
+        gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
+        taps = self.param("conv_weight", _conv_init,
+                          (cfg.conv_L_cache, E), jnp.float32)
+        conv, _ = ssm.causal_conv1d(gate_b * u, taps, None, None,
+                                    segment_ids)
+        y = (gate_c.astype(jnp.float32) * conv).astype(cfg.compute_dtype())
+        return _dense(E, "out_proj", ("mlp", "embed"), cfg)(y)
 
     def _attention(self, h, attention_mask, segment_ids, position_ids):
         cfg = self.cfg
         B, T, E = h.shape
         Hq, Hkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
-        with jax.named_scope("lfm2.attn"):
-            q = _dense(Hq * D, "q_proj", ("embed", "qkv"), cfg)(h)
-            k = _dense(Hkv * D, "k_proj", ("embed", "qkv"), cfg)(h)
-            v = _dense(Hkv * D, "v_proj", ("embed", "qkv"), cfg)(h)
-            q = _norm(cfg, "q_layernorm")(q.reshape(B, T, Hq, D))
-            k = _norm(cfg, "k_layernorm")(k.reshape(B, T, Hkv, D))
-            v = v.reshape(B, T, Hkv, D)
-            q = rotary_embedding(q, position_ids, cfg.rope_theta)
-            k = rotary_embedding(k, position_ids, cfg.rope_theta)
-            rep = Hq // Hkv
-            attn = causal_attention(
-                q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
-                attention_mask=attention_mask, segment_ids=segment_ids,
-                impl="flash")
-            return _dense(E, "out_proj", ("qkv", "embed"), cfg)(
-                attn.reshape(B, T, Hq * D))
+        q = _dense(Hq * D, "q_proj", ("embed", "qkv"), cfg)(h)
+        k = _dense(Hkv * D, "k_proj", ("embed", "qkv"), cfg)(h)
+        v = _dense(Hkv * D, "v_proj", ("embed", "qkv"), cfg)(h)
+        q = _norm(cfg, "q_layernorm")(q.reshape(B, T, Hq, D))
+        k = _norm(cfg, "k_layernorm")(k.reshape(B, T, Hkv, D))
+        v = v.reshape(B, T, Hkv, D)
+        q = rotary_embedding(q, position_ids, cfg.rope_theta)
+        k = rotary_embedding(k, position_ids, cfg.rope_theta)
+        rep = Hq // Hkv
+        attn = causal_attention(
+            q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+            attention_mask=attention_mask, segment_ids=segment_ids,
+            impl="flash")
+        return _dense(E, "out_proj", ("qkv", "embed"), cfg)(
+            attn.reshape(B, T, Hq * D))
 
     def _dense_ffn(self, h):
         cfg = self.cfg
         F = cfg.intermediate_size
-        with jax.named_scope("lfm2.dense_ffn"):
-            gate = _dense(F, "w1", ("embed", "mlp"), cfg)(h)
-            up = _dense(F, "w3", ("embed", "mlp"), cfg)(h)
-            return _dense(cfg.hidden_size, "w2", ("mlp", "embed"), cfg)(
-                nn.silu(gate) * up)
+        gate = _dense(F, "w1", ("embed", "mlp"), cfg)(h)
+        up = _dense(F, "w3", ("embed", "mlp"), cfg)(h)
+        return _dense(cfg.hidden_size, "w2", ("mlp", "embed"), cfg)(
+            nn.silu(gate) * up)
 
     def _experts(self, h):
         cfg = self.cfg
@@ -328,7 +332,8 @@ class Lfm2Moe(nn.Module):
             (cfg.padded_vocab, cfg.hidden_size), cfg.storage_dtype())
         if position_ids is None:
             position_ids = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-        x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
+        with jax.named_scope("lfm2.embed"):
+            x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
         block = nn.remat(Lfm2MoeBlock) if cfg.remat else Lfm2MoeBlock
         counted: dict = {}
         for i, mixer in enumerate(cfg.layer_types):
@@ -339,12 +344,14 @@ class Lfm2Moe(nn.Module):
                 counted[key] = counted[key] + val if key in counted else val
         if counted:
             self.sow("intermediates", "train_counters", counted)
-        x = _norm(cfg, "norm_f")(x)
-        if return_hidden:
-            return x
-        logits = jnp.einsum("bte,ve->btv", x, wte.astype(cfg.compute_dtype()),
-                            preferred_element_type=jnp.float32)
-        return logits.astype(jnp.dtype(cfg.logits_dtype))
+        with jax.named_scope("lfm2.head"):
+            x = _norm(cfg, "norm_f")(x)
+            if return_hidden:
+                return x
+            logits = jnp.einsum("bte,ve->btv", x,
+                                wte.astype(cfg.compute_dtype()),
+                                preferred_element_type=jnp.float32)
+            return logits.astype(jnp.dtype(cfg.logits_dtype))
 
     def init_params(self, rng, *, seq_len: int = 8):
         dummy = jnp.zeros((1, seq_len), jnp.int32)

@@ -13,8 +13,9 @@ This module is the one home of the structured layer every role emits:
   name. Spans nest; each record carries its parent and depth.
 - ``phase("serve.admit")`` is the hot-loop primitive beside ``span``: a
   host span on the profiler's clock (``jax.profiler.TraceAnnotation``)
-  plus one histogram observation, no record. The serve engine's step is
-  timed with it.
+  plus one histogram observation, no record. The serve engine's step
+  and ``MinerLoop.run`` (``miner.*``) are timed with it; a ``span`` opens
+  the same annotation, so both lie in one device trace.
 - a process-wide :class:`Registry` of counters and latency histograms
   (p50/p95/p99 from bounded ring reservoirs) with name linting —
   ``[a-z0-9_.]`` only, and one name cannot be both a counter and a
@@ -540,21 +541,6 @@ def rider_delta_id(meta: dict | None) -> str | None:
     return None
 
 
-def fetch_cid(transport, miner_id: str) -> str | None:
-    """Correlation id of ``miner_id``'s current artifact, from its meta
-    rider — observability only, so every failure reads as None (riderless
-    miners and transports without riders stay fully supported)."""
-    if _STATE.sink is None:
-        return None
-    fm = getattr(transport, "fetch_delta_meta", None)
-    if fm is None:
-        return None
-    try:
-        return rider_delta_id(fm(miner_id))
-    except Exception:
-        return None
-
-
 # ---------------------------------------------------------------------------
 # Phases
 # ---------------------------------------------------------------------------
@@ -576,6 +562,13 @@ def _trace_annotation():
     except ImportError:
         return None
     return TraceAnnotation
+
+
+def _annotation(name: str, args: dict):
+    """The host span ``phase`` and ``span`` both open while a sink is on:
+    a ``TraceAnnotation(name, **args)``, or None without jax."""
+    annot = _trace_annotation()
+    return None if annot is None else annot(name, **args)
 
 
 class _NoPhase:
@@ -605,8 +598,7 @@ class _Phase:
         # the histogram of the registry that was live at entry: a phase
         # that straddles reset() must not dirty the fresh state
         self._hist = registry.histogram(_phase_hist(name, hist))
-        annot = _trace_annotation()
-        self._annot = None if annot is None else annot(name, **args)
+        self._annot = _annotation(name, args)
 
     def __enter__(self) -> "_Phase":
         if self._annot is not None:
@@ -657,7 +649,10 @@ def span(name: str, *, cid: str | None = None, **attrs):
     and feed the ``span.<name>_ms`` histogram. Nesting is tracked per
     thread (records carry ``parent`` and ``depth``). Zero-cost no-op when
     no sink is configured. ``attrs`` ride verbatim in the record (keep
-    them JSON-able and small)."""
+    them JSON-able and small). While it is open the span is also the
+    host span a ``phase`` is (``TraceAnnotation(name, cid=...)`` on the
+    opening thread's line), so a device trace shows ``push.snapshot`` on
+    the loop's thread and ``push.upload`` on the publisher's."""
     st = _STATE
     if st.sink is None:
         yield
@@ -669,6 +664,9 @@ def span(name: str, *, cid: str | None = None, **attrs):
     if cid is not None:
         tl.cid = cid
     tl.stack.append(name)
+    annot = _annotation(name, {} if tl.cid is None else {"cid": tl.cid})
+    if annot is not None:
+        annot.__enter__()
     t0_wall = time.time()
     t0 = time.perf_counter()
     ok = True
@@ -679,6 +677,8 @@ def span(name: str, *, cid: str | None = None, **attrs):
         raise
     finally:
         dur_ms = (time.perf_counter() - t0) * 1e3
+        if annot is not None:
+            annot.__exit__(None, None, None)
         tl.stack.pop()
         ccid = tl.cid
         tl.cid = prev_cid

@@ -638,8 +638,8 @@ def test_chaos_forensics_round_end_to_end(tmp_path):
         # (still m0-000001: the torn publish never committed a manifest
         # or rider — manifest-last kept readers consistent)
         assert fm.poll(["m0"]) == 1
-        with obs.span("avg.fetch", cid=obs.fetch_cid(chaos_a, "m0"),
-                      miner="m0"):
+        cid = obs.rider_delta_id(chaos_a.fetch_delta_meta("m0"))
+        with obs.span("avg.fetch", cid=cid, miner="m0"):
             assert chaos_a.fetch_delta_bytes("m0") is not None
         for _ in range(3):
             fm.poll(["m0"])

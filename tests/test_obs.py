@@ -355,6 +355,95 @@ def test_phase_observes_when_its_body_raises(fake_annotation):
     assert [e[1] for e in fake_annotation] == ["enter", "exit"]
 
 
+def _frozen_span_records(monkeypatch):
+    """The records two nested spans and a failing one write, clocks
+    frozen: (JSON lines, histogram counts)."""
+    ticks = iter(range(100, 200))
+    monkeypatch.setattr(obs.time, "time", lambda: 1700000000.25)
+    monkeypatch.setattr(obs.time, "perf_counter",
+                        lambda: next(ticks) / 8.0)
+    sink = InMemorySink()
+    reg = obs.configure(sink, role="miner")
+    with obs.span("push.snapshot", cid="m0-000001"):
+        with obs.span("push.upload", bytes=12, ok=True):
+            pass
+    with pytest.raises(KeyError):
+        with obs.span("avg.fetch", miner="m1"):
+            raise KeyError("gone")
+    monkeypatch.undo()
+    return ([json.dumps(r) for r in sink.records],
+            {n: reg.peek(n).count for n in reg.names()})
+
+
+def test_span_record_is_byte_for_byte_what_it_was(monkeypatch,
+                                                  fake_annotation):
+    """The annotation a span holds since PR 36 adds nothing to its record,
+    its histogram or its nesting (the lines below: the parent commit's
+    obs.span under the same frozen clocks)."""
+    lines, counts = _frozen_span_records(monkeypatch)
+    assert lines == [
+        '{"step": null, "span": "push.upload", "dur_ms": 125.0, '
+        '"t0": 1700000000.25, "depth": 1, "role": "miner", '
+        '"parent": "push.snapshot", "cid": "m0-000001", "bytes": 12, '
+        '"ok": true}',
+        '{"step": null, "span": "push.snapshot", "dur_ms": 375.0, '
+        '"t0": 1700000000.25, "depth": 0, "role": "miner", '
+        '"cid": "m0-000001"}',
+        '{"step": null, "span": "avg.fetch", "dur_ms": 125.0, '
+        '"t0": 1700000000.25, "depth": 0, "role": "miner", "error": true, '
+        '"miner": "m1"}']
+    assert counts == {"span.avg.fetch_ms": 1, "span.push.snapshot_ms": 1,
+                      "span.push.upload_ms": 1}
+
+
+def test_span_opens_one_annotation_while_a_sink_is_on_and_none_off(
+        fake_annotation):
+    """`obs.span` is on the profiler's clock: the annotation a phase opens
+    (one helper), on the opening thread's line, with the span's cid; a
+    raising body closes it; off, nothing is built."""
+    with obs.span("push.snapshot", cid="m0-000001"):
+        pass
+    assert fake_annotation == [] and _FakeAnnotation.made == []
+    obs.configure(InMemorySink(), role="miner")
+    with obs.span("push.snapshot", cid="m0-000001"):
+        with obs.span("push.upload"):       # inherits the cid
+            pass
+    with pytest.raises(KeyError):
+        with obs.span("avg.fetch"):
+            raise KeyError("gone")
+    assert _FakeAnnotation.made == [
+        ("push.snapshot", {"cid": "m0-000001"}),
+        ("push.upload", {"cid": "m0-000001"}),
+        ("avg.fetch", {})]
+    me = threading.get_ident()
+    assert fake_annotation == [
+        (me, "enter", "push.snapshot"), (me, "enter", "push.upload"),
+        (me, "exit", "push.upload"), (me, "exit", "push.snapshot"),
+        (me, "enter", "avg.fetch"), (me, "exit", "avg.fetch")]
+
+
+def test_span_and_phase_share_a_thread_line(fake_annotation):
+    """A worker's span lies on the worker's line, beside the phases the
+    train thread writes meanwhile: `push.upload` next to `miner.*`."""
+    obs.configure(InMemorySink(), role="miner")
+
+    def worker():
+        with obs.correlate("m0-000002"):
+            with obs.span("push.upload"):
+                pass
+
+    with obs.phase("miner.actions"):
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=10)
+    me = threading.get_ident()
+    assert [e[1:] for e in fake_annotation if e[0] == me] == [
+        ("enter", "miner.actions"), ("exit", "miner.actions")]
+    assert [e[1:] for e in fake_annotation if e[0] != me] == [
+        ("enter", "push.upload"), ("exit", "push.upload")]
+    assert ("push.upload", {"cid": "m0-000002"}) in _FakeAnnotation.made
+
+
 def test_correlate_is_thread_local():
     sink = InMemorySink()
     obs.configure(sink)
