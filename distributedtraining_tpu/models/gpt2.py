@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import cached_attention, causal_attention
+from ..ops.attention import (
+    cached_attention, causal_attention, remat_policy)
 from ..ops.embed import embed_lookup
 
 
@@ -210,7 +211,9 @@ class _BlockScan(nn.Module):
 
     @nn.compact
     def __call__(self, x, attention_mask, segment_ids, deterministic):
-        blk = nn.remat(Block, static_argnums=(4,)) if self.cfg.remat else Block
+        blk = Block
+        if self.cfg.remat:
+            blk = nn.remat(Block, static_argnums=(4,), policy=remat_policy())
         x = blk(self.cfg, name="block")(x, attention_mask, segment_ids,
                                         deterministic)
         return x, None
@@ -313,7 +316,8 @@ class GPT2(nn.Module):
         else:
             block = Block
             if cfg.remat:
-                block = nn.remat(Block, static_argnums=(4,))
+                block = nn.remat(Block, static_argnums=(4,),
+                                 policy=remat_policy())
             for i in range(cfg.n_layer):
                 x = block(cfg, name=f"h_{i}")(x, attention_mask, segment_ids,
                                               deterministic)

@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import moe, ssm
-from ..ops.attention import causal_attention
+from ..ops.attention import causal_attention, remat_policy
 from ..ops.embed import embed_lookup
 from .gpt2 import pad_vocab
 from .llama import RMSNorm, _dense, rotary_embedding
@@ -334,7 +334,9 @@ class Lfm2Moe(nn.Module):
             position_ids = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
         with jax.named_scope("lfm2.embed"):
             x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
-        block = nn.remat(Lfm2MoeBlock) if cfg.remat else Lfm2MoeBlock
+        block = Lfm2MoeBlock
+        if cfg.remat:
+            block = nn.remat(Lfm2MoeBlock, policy=remat_policy())
         counted: dict = {}
         for i, mixer in enumerate(cfg.layer_types):
             x, stats = block(cfg, mixer, i >= cfg.num_dense_layers,

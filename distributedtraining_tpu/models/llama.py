@@ -19,7 +19,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import cached_attention, causal_attention
+from ..ops.attention import (
+    cached_attention, causal_attention, remat_policy)
 from ..ops.embed import embed_lookup
 from .gpt2 import pad_vocab
 
@@ -201,7 +202,9 @@ class _BlockScan(nn.Module):
 
     @nn.compact
     def __call__(self, x, attention_mask, segment_ids, position_ids):
-        blk = nn.remat(LlamaBlock) if self.cfg.remat else LlamaBlock
+        blk = LlamaBlock
+        if self.cfg.remat:
+            blk = nn.remat(LlamaBlock, policy=remat_policy())
         x = blk(self.cfg, name="block")(x, attention_mask, segment_ids,
                                         position_ids)
         return x, None
@@ -269,7 +272,7 @@ class Llama(nn.Module):
         else:
             block = LlamaBlock
             if cfg.remat:
-                block = nn.remat(LlamaBlock)
+                block = nn.remat(LlamaBlock, policy=remat_policy())
             for i in range(cfg.n_layer):
                 x = block(cfg, name=f"layer_{i}")(x, attention_mask,
                                                   segment_ids, position_ids)

@@ -284,3 +284,15 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # short T: dense is faster and the temps are tiny
     mask = combine_masks(make_causal_mask(T), attention_mask, segment_ids)
     return dot_product_attention(q, k, v, mask)
+
+
+def remat_policy():
+    """The ``policy`` of every ``nn.remat`` around a block that calls
+    :func:`causal_attention`: beside the block's input, keep the flash
+    forward kernel's output and ``[H, T]`` log-sum-exp
+    (``B x T x E x 2 + B x H x T x 4`` bytes an attention layer), so that
+    the re-run hands them to the backward kernel and does not call the
+    forward kernel a second time. Where the kernel does not run nothing
+    carries the name, and remat keeps what a bare one keeps."""
+    from . import flash_attention
+    return flash_attention.KEEP_RESIDUALS

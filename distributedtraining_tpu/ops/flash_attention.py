@@ -13,7 +13,12 @@ at trace time: a (q block, kv block) pair the mask covers whole does no
 kernel work, a pair it leaves whole runs unmasked, and only the pairs the
 diagonal crosses compute the mask. The backward is ONE kernel of five
 matrix products (dK, dV and dQ from one pass over the scores), and the
-softmax statistics reach it as a ``[H, T]`` log-sum-exp. Measured on the
+softmax statistics reach it as a ``[H, T]`` log-sum-exp. A remat-wrapped
+block keeps those two arrays of the forward kernel, its output and the
+log-sum-exp (``B x T x E x 2 + B x H x T x 4`` bytes an attention layer by
+shape; heads of 64 are stored in 128-lane tiles on the chip, which doubles
+the first term), so remat's re-run does not call the forward kernel again
+(``RESIDUAL_NAME`` below, ``ops.attention.remat_policy``). Measured on the
 v5e at the train cell's shape (B=4, H=20, T=1024, D=64, bfloat16, packed
 documents; ``scripts/ab_flash.py``, PERF.md section 6, PR 28) against the
 library's older ``flash_attention`` kernel that stood here before: forward
@@ -70,6 +75,14 @@ def _kernel_name(*args, **kwargs) -> str:
 
 
 splash.get_kernel_name = _kernel_name
+
+
+# The ``checkpoint_name`` the library gives the forward kernel's ``out`` and
+# log-sum-exp inside its forward rule, and the ``jax.checkpoint`` policy that
+# saves what carries it (``ops.attention.remat_policy``). Where the rule
+# below says no, nothing carries the name and the policy keeps nothing.
+RESIDUAL_NAME = "flash_attn_residuals"
+KEEP_RESIDUALS = jax.checkpoint_policies.save_only_these_names(RESIDUAL_NAME)
 
 
 def _on_tpu() -> bool:
@@ -164,4 +177,5 @@ def _causal_kernel(T: int, H: int) -> splash.SplashAttentionKernel:
     program."""
     return splash.make_splash_mha(
         splash_mask.MultiHeadMask([splash_mask.CausalMask((T, T))] * H),
-        block_sizes=_block_sizes(T), head_shards=1, q_seq_shards=1)
+        block_sizes=_block_sizes(T), head_shards=1, q_seq_shards=1,
+        residual_checkpoint_name=RESIDUAL_NAME)

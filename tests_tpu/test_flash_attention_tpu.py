@@ -7,6 +7,8 @@ the selection rule and the block schedule on CPU). The schedule
 (ops/flash_attention.py) rests on these numerics.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -161,3 +163,66 @@ def test_fused_loss_matches_standard_on_chip():
         losses[fused] = float(m["loss"])
     assert np.isfinite(losses[True])
     np.testing.assert_allclose(losses[True], losses[False], rtol=2e-3)
+
+
+@pytest.mark.parametrize("param_dtype", ["float32", "bfloat16"])
+def test_remat_policy_grads_are_bare_remats_to_bfloat16_rounding(
+        param_dtype, monkeypatch):
+    """Two GPT-2 blocks over packed documents, three ways: no remat, a bare
+    `nn.remat` (which calls the forward kernel again in its re-run) and
+    the blocks' policy (`attention.remat_policy`: the forward kernel's
+    output and log-sum-exp stay, one forward call a layer). The loss is
+    one number; every leaf's gradient agrees to bfloat16's rounding, and
+    NOT to the bit: XLA fuses the re-run differently from the forward
+    (and each of the three programs differently), so bfloat16
+    intermediates round at other points and a bare remat is already off
+    the unwrapped blocks' gradient by as much (PERF.md section 6, PR 37:
+    widest element 4.7e-3 of a leaf's largest here, 7.7e-3 at 36 layers).
+    What is held: the policy is no farther from either than that."""
+    from distributedtraining_tpu.models import gpt2
+
+    cfg = gpt2.GPT2Config(vocab_size=512, n_positions=1024, n_embd=256,
+                          n_layer=2, n_head=4, vocab_multiple=128,
+                          remat=True, param_dtype=param_dtype)
+    rng = np.random.default_rng(0)
+    ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 1024)), jnp.int32)
+    seg = _documents(2, 1024)
+    params = gpt2.make_model(cfg)[0].init(jax.random.PRNGKey(0), ids)
+
+    def forward_calls(jaxpr):
+        return sum(
+            ("flash_mha_fwd" in eqn.params["name"]
+             if eqn.primitive.name == "pallas_call" else
+             sum(map(forward_calls, jax.core.jaxprs_in_params(eqn.params))))
+            for eqn in jaxpr.eqns)
+
+    def forward_calls_and_grads(cfg):
+        model, _ = gpt2.make_model(cfg)
+
+        def loss(p):
+            logits = model.apply(p, ids, segment_ids=seg)
+            return jnp.mean(jax.nn.logsumexp(logits.astype(jnp.float32), -1))
+
+        fn = jax.value_and_grad(loss)
+        return (forward_calls(jax.make_jaxpr(fn)(params).jaxpr),
+                jax.jit(fn)(params))
+
+    none_calls, none = forward_calls_and_grads(
+        dataclasses.replace(cfg, remat=False))
+    kept_calls, kept = forward_calls_and_grads(cfg)
+    monkeypatch.setattr(gpt2, "remat_policy", lambda: None)
+    bare_calls, bare = forward_calls_and_grads(cfg)
+    assert (none_calls, kept_calls, bare_calls) == (
+        cfg.n_layer, cfg.n_layer, 2 * cfg.n_layer)
+    np.testing.assert_allclose(
+        [float(kept[0]), float(bare[0])], float(none[0]), rtol=1e-5)
+    trees = [jax.tree_util.tree_leaves_with_path(t[1])
+             for t in (kept, bare, none)]
+    assert {str(g.dtype) for _, g in trees[0]} == {param_dtype}
+    for (path, a), (_, b), (_, c) in zip(*trees):
+        a, b, c = (np.asarray(x, np.float32) for x in (a, b, c))
+        assert np.any(a), path
+        for got, want in ((a, b), (a, c), (b, c)):
+            np.testing.assert_allclose(
+                got, want, rtol=0, atol=4e-2 * np.abs(want).max(),
+                err_msg=jax.tree_util.keystr(path))
