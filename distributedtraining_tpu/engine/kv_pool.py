@@ -21,19 +21,25 @@ format stays ``[L, P, Hkv, D]`` per page — :func:`read_pages` and
 
 What one layer caches a token is the MODEL's to state
 (:func:`row_widths`): a K/V pair of heads gives ``(Hkv*D, Hkv*D)``; a
-latent-attention model (models/deepseek_v3.py) gives ``(kv_lora_rank,
-qk_rope_head_dim)`` — the normed latent ``c`` in the first half of the
-pair, the one shared rotary key ``k_r`` in the second. The pair, the
-per-layer arrays, donation and every write below are the same for both:
+latent-attention layer (models/deepseek_v3.py, models/gigachat3_5.py)
+gives ``(kv_lora_rank, qk_rope_head_dim)`` — the normed latent ``c`` in
+the first half of the pair, the one shared rotary key ``k_r`` in the
+second. The pair, the per-layer arrays, donation and every write below
+are the same for both:
 "k" and "v" name the halves, not what is in them.
 
 A second KIND of cache lives beside the pages, for a family whose layers
-do not all cache per token (models/nemotron_h.py): the family states per
-layer what it keeps (``cfg.layer_caches``: ``"kv"`` the paged pair above,
-``"ssm"`` a recurrent state, None nothing), the paged pool holds arrays
-for the ``"kv"`` layers only, and each ``"ssm"`` layer has one float32
-state ``[slots + 1, H, P, N]`` and one convolution tail ``[slots + 1,
-K - 1, C]`` addressed by SLOT, not by position (:func:`make_state_pool`).
+do not all cache per token (models/nemotron_h.py: Mamba-2 states beside
+K/V heads; models/gigachat3_5.py: delta-rule states beside a LATENT pair):
+the family states per layer what it keeps (``cfg.layer_caches``: ``"kv"``
+the paged pair above, with the row widths it states; ``"ssm"`` a recurrent
+state, None nothing), the paged pool holds arrays for the ``"kv"`` layers
+only, and each ``"ssm"`` layer has one float32 state ``[slots + 1,
+*cfg.ssm_state_shape]`` and one convolution tail ``[slots + 1,
+*cfg.ssm_tail_shape]`` addressed by SLOT, not by position
+(:func:`make_state_pool`: the shapes are the config's to state, Mamba-2's
+``[H, P, N]`` or a delta rule's ``[Hv, dk, dv]``; what the state is CALLED
+in the registry too, :func:`state_name`).
 They are donated and returned like the pages. A slot's row is written
 whole by its prefill (:func:`write_slot_state`) and moved on in place by
 every decode step (the model's layer does that itself and sows the pools
@@ -55,15 +61,15 @@ Pool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
 LANES = 128
 LATENT_CACHE_REASON = (
     "this model caches a latent row and one shared rotary key per token "
-    "(models/deepseek_v3.py), not a K/V pair of heads: the speculative "
-    "lane and the KV transfer plane mirror the K/V geometry and are not "
-    "ported to it")
+    "(its config states cache_row_widths), not a K/V pair of heads: the "
+    "speculative lane and the KV transfer plane mirror the K/V geometry "
+    "and are not ported to it")
 RECURRENT_STATE_REASON = (
-    "this model keeps a recurrent state per slot (models/nemotron_h.py), "
-    "which is not addressed by position: a shared prefix has no state to "
-    "map, a refused draft cannot be rolled back and there are no pages to "
-    "ship, so the prefix cache, the speculative lane and the KV transfer "
-    "plane are not ported to it")
+    "this model keeps a recurrent state per slot (its config states 'ssm' "
+    "in layer_caches), which is not addressed by position: a shared prefix "
+    "has no state to map, a refused draft cannot be rolled back and there "
+    "are no pages to ship, so the prefix cache, the speculative lane and "
+    "the KV transfer plane are not ported to it")
 StatePool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
 
 
@@ -90,6 +96,13 @@ def layer_caches(cfg, n_layers: int) -> tuple[str | None, ...]:
 
 def has_recurrent_state(cfg) -> bool:
     return "ssm" in (getattr(cfg, "layer_caches", None) or ())
+
+
+def state_name(cfg) -> str:
+    """What the per-slot state is called in the registry
+    (``serve.<name>.state_bytes``): what the config states, else
+    ``"ssm"``."""
+    return getattr(cfg, "state_name", "ssm")
 
 
 def row_widths(cfg) -> tuple[int, int]:

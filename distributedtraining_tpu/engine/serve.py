@@ -97,10 +97,12 @@ Rollback is length bookkeeping, the drafter has its own hot-swap lane,
 and a missing/stale/broken drafter degrades to plain decode.
 
 A family whose layers do not all cache per token (models/nemotron_h.py:
-state-space layers beside attention) states per layer what it keeps
+state-space layers beside attention; models/gigachat3_5.py: delta-rule
+layers beside LATENT attention) states per layer what it keeps
 (``cfg.layer_caches``): the page pool then holds the attention layers
-only, and beside it lives one fixed-size recurrent state per SLOT for
-each state-space layer (engine/kv_pool.py), written whole by a slot's
+only, with the row widths the config states, and beside it lives one
+fixed-size recurrent state per SLOT for each other layer, in the shapes
+the config states (engine/kv_pool.py), written whole by a slot's
 prefill, moved on in place by every decode step, handed back at
 ``_release``. The pools ride behind the programs' other arguments; a
 family without them passes nothing there. The prefix cache, the
@@ -601,13 +603,14 @@ _SOWN_COUNTERS = {
     "moe_experts_touched": "serve.moe.experts_touched",
     "moe_rows_elsewhere": "serve.moe.rows_elsewhere",
     "ssm_slot_steps": "serve.ssm.slot_steps",
+    "gdn_slot_steps": "serve.gdn.slot_steps",
 }
 
 
 def _count_sown(stats: dict, per_step: bool = False) -> None:
     """Feed what one program run's layers counted (summed over layers) to
     the registry: a routed-expert layer's rows computed here, rows left
-    to other chips and touched experts, a state-space layer's live slots;
+    to other chips and touched experts, a recurrent layer's live slots;
     off, or for a model that sows none, one branch."""
     if not stats or not obs.enabled():
         return
@@ -904,6 +907,7 @@ class GenerationEngine:
         self._recurrent = kv_pool.has_recurrent_state(cfg)
         self._ssm: kv_pool.StatePool = ((), ())
         self._ssm_bytes = 0.0
+        self._state_gauge = f"serve.{kv_pool.state_name(cfg)}.state_bytes"
         self._state_free: list[int] = []
         self._state_of: dict[int, int] = {}
         # the transfer plane's wire is a K/V pair of heads: a model that
@@ -1013,7 +1017,7 @@ class GenerationEngine:
                     cfg, len(self._ssm_layers), self.max_slots)
                 self._ssm_bytes = float(sum(
                     x.nbytes for half in self._ssm for x in half))
-                obs.gauge("serve.ssm.state_bytes", self._ssm_bytes)
+                obs.gauge(self._state_gauge, self._ssm_bytes)
         return self._kv_arrays
 
     @_kv.setter
@@ -2357,7 +2361,7 @@ class GenerationEngine:
                     rows[i] = self._state_of[slot.req.rid]
                 args += self._slot_state(rows)
                 # again here: a sink attached after the pool was made
-                obs.gauge("serve.ssm.state_bytes", self._ssm_bytes)
+                obs.gauge(self._state_gauge, self._ssm_bytes)
         with obs.phase("serve.decode.dispatch", slots=sb, pages=pb,
                        live=len(active)):
             if (sb, pb) not in seen:

@@ -13,6 +13,10 @@
   routed experts of which a chip holds its share (the LFM2-8B-A1B row), on
   the TRAINING path: the first family but GPT-2 that ``TrainEngine`` and
   ``MinerLoop`` run on the chip.
+- gigachat3_5: gated delta-rule layers beside latent attention (a
+  per-slot state and a latent page pool in one engine), routed + shared
+  SwiGLU experts of which a chip holds its share (the
+  GigaChat3.5-432B-A28B row), on the serving path.
 - lora: low-rank adapter trees whose *parameters are the delta*.
 """
 
@@ -26,8 +30,9 @@ def family_of(preset: str):
     """The family (its module: ``PRESETS``, ``make_model``) that owns a
     preset's name; GPT-2's, whose lookup then names the unknown preset,
     where none does."""
-    from . import deepseek_v3, gpt2, lfm2_moe, llama, nemotron_h
-    for family in (llama, deepseek_v3, nemotron_h, lfm2_moe):
+    from . import (deepseek_v3, gigachat3_5, gpt2, lfm2_moe, llama,
+                   nemotron_h)
+    for family in (llama, deepseek_v3, nemotron_h, lfm2_moe, gigachat3_5):
         if preset in family.PRESETS:
             return family
     return gpt2

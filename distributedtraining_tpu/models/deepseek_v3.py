@@ -18,9 +18,10 @@ h = RMSNorm(x) (no biases anywhere):
   ``ops.attention.causal_attention`` (q/k of 192, v of 128: the dense
   product takes the two widths as they are, nothing is padded). Over the
   paged cache it attends in the ABSORBED form: ``q' = q_nope W_uk^T``,
-  scores ``(q' . c + q_rope . k_r)``, ``o = (P c) W_uv``
-  (ops/mla_attention.py), with ``W_uk``/``W_uv`` the two halves of
-  ``W_kv_b``. The two forms are one function (tests/test_deepseek_v3.py).
+  scores ``(q' . c + q_rope . k_r)``, ``o = (P c) W_uv``, with
+  ``W_uk``/``W_uv`` the two halves of ``W_kv_b``. The two forms are one
+  function (``ops.mla_attention.latent_attention``, which
+  models/gigachat3_5.py calls too; tests/test_deepseek_v3.py).
 * Dense FFN (the first ``first_k_dense_replace`` layers): SwiGLU of
   ``intermediate_size``.
 * Routed FFN (the rest): sigmoid router with a selection bias
@@ -43,9 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import moe
-from ..ops.attention import causal_attention
 from ..ops.embed import embed_lookup
-from ..ops.mla_attention import mla_paged_attention
+from ..ops.mla_attention import latent_attention
 from .gpt2 import pad_vocab
 from .llama import RMSNorm, _dense, rotary_embedding
 
@@ -113,8 +113,11 @@ class DeepseekV3Config:
         bad = [k for k, v in unsupported.items() if v]
         if bad:
             raise ValueError(f"DeepseekV3Config: {', '.join(bad)} not "
-                             "supported (models/deepseek_v3.py writes the "
-                             "equations of the kanana-2 row only)")
+                             "supported (this block takes the query from "
+                             "one full-rank matrix, plain rotary "
+                             "frequencies and one sigmoid-scored group; a "
+                             "config that states q_lora_rank and "
+                             "rope_scaling is models/gigachat3_5.py's)")
 
     @property
     def padded_vocab(self) -> int:
@@ -209,25 +212,11 @@ class DeepseekV3Block(nn.Module):
                                          (None, "qkv")),
             (C, H * (Dn + Dv)), cfg.storage_dtype())
         w_kv_b = w_kv_b.astype(cdt).reshape(C, H, Dn + Dv)
-        scale = (Dn + Dr) ** -0.5
-        if kv_pages is not None:
-            # absorbed: attend in the latent space over the paged cache
-            q_abs = jnp.einsum("bthn,chn->bthc", q_nope, w_kv_b[..., :Dn])
-            o_lat = mla_paged_attention(
-                q_abs, q_rope, kv_pages[0], kv_pages[1], page_tables,
-                kv_lens, c, k_r, scale)
-            attn = jnp.einsum("bthc,chv->bthv", o_lat, w_kv_b[..., Dn:])
-        else:
-            with jax.named_scope("mla.prefill"):
-                kv = jnp.einsum("btc,chd->bthd", c, w_kv_b)
-                k = jnp.concatenate(
-                    [kv[..., :Dn],
-                     jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, Dr))],
-                    axis=-1)
-                attn = causal_attention(
-                    jnp.concatenate([q_nope, q_rope], axis=-1), k,
-                    kv[..., Dn:], attention_mask=attention_mask,
-                    segment_ids=segment_ids, impl=cfg.attention_impl)
+        attn = latent_attention(
+            q_nope, q_rope, c, k_r, w_kv_b, (Dn + Dr) ** -0.5,
+            kv_pages=kv_pages, page_tables=page_tables, kv_lens=kv_lens,
+            attention_mask=attention_mask, segment_ids=segment_ids,
+            impl=cfg.attention_impl)
         x = x + _dense(E, "o_proj", ("qkv", "embed"), cfg)(
             attn.reshape(B, T, H * Dv))
 

@@ -88,12 +88,17 @@ PRESETS: dict[str, LlamaConfig] = {
 
 
 def rotary_embedding(x: jax.Array, position_ids: jax.Array,
-                     theta: float, *, interleaved: bool = False) -> jax.Array:
+                     theta: float, *, interleaved: bool = False,
+                     inv_freq: jax.Array | None = None) -> jax.Array:
     """Apply RoPE to [B, T, H, D] given positions [B, T]. Pair i is the
     lanes ``(i, i + D/2)`` (Llama's halves) or, ``interleaved``, the
-    lanes ``(2i, 2i + 1)`` (DeepSeek-V3's ``rope_interleave``)."""
+    lanes ``(2i, 2i + 1)`` (DeepSeek-V3's ``rope_interleave``).
+    ``inv_freq`` [D/2] stands in for ``theta``'s plain frequencies (a
+    family whose ``rope_scaling`` blends them)."""
     D = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32)
+                                    / D))
     angles = position_ids[..., None].astype(jnp.float32) * inv_freq  # [B,T,D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import NEG_INF
+from .attention import NEG_INF, causal_attention
 
 # pages DMA'd and attended per grid step: 512 rows of 16-token pages
 # (0.6 MB of latent rows in VMEM), so that a step's DMA latency is paid
@@ -277,3 +277,45 @@ def mla_paged_attention(q_abs, q_rope, c_pages, kr_pages, page_tables,
         return mla_decode_reference(q_abs, q_rope, c_pages, kr_pages,
                                     page_tables, seq_lens, c_new, kr_new,
                                     scale)
+
+
+def latent_attention(q_nope, q_rope, c, k_r, w_kv_b, scale: float, *,
+                     kv_pages=None, page_tables=None, kv_lens=None,
+                     attention_mask=None, segment_ids=None,
+                     impl: str = "dense"):
+    """A latent-attention layer's two forms, for every family that caches
+    ``(c, k_r)`` a token (models/deepseek_v3.py, models/gigachat3_5.py).
+    ``q_nope`` [B, T, H, Dn], ``q_rope`` [B, T, H, Dr] (after its rotary);
+    ``c`` [B, T, C] the normed latent and ``k_r`` [B, T, Dr] the one
+    shared rotary key of the FRESH rows; ``w_kv_b`` [C, H, Dn + Dv] the
+    up-projection, key half first; ``scale`` the softmax scale (``(Dn +
+    Dr) ** -0.5``, times what the family's position scaling states) ->
+    the heads' values [B, T, H, Dv].
+
+    Over the paged cache (``kv_pages``: one layer's pair) it attends in
+    the ABSORBED form: ``q' = q_nope W_uk^T``, scores ``q' . c + q_rope .
+    k_r``, ``o = (P c) W_uv``. Without a cache it attends in the EXPANDED
+    form, ``[k_nope | v] = c W_kv_b`` a head, through
+    ``ops.attention.causal_attention`` (q/k of ``Dn + Dr``, v of ``Dv``:
+    the dense product takes the two widths as they are); that function
+    scales by the query's width alone, so any more rides on the query."""
+    B, T, H, Dn = q_nope.shape
+    Dr = q_rope.shape[-1]
+    if kv_pages is not None:
+        q_abs = jnp.einsum("bthn,chn->bthc", q_nope, w_kv_b[..., :Dn])
+        o_lat = mla_paged_attention(
+            q_abs, q_rope, kv_pages[0], kv_pages[1], page_tables, kv_lens,
+            c, k_r, scale)
+        return jnp.einsum("bthc,chv->bthv", o_lat, w_kv_b[..., Dn:])
+    with jax.named_scope("mla.prefill"):
+        kv = jnp.einsum("btc,chd->bthd", c, w_kv_b)
+        k = jnp.concatenate(
+            [kv[..., :Dn],
+             jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, Dr))], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        plain = (Dn + Dr) ** -0.5
+        if scale != plain:
+            q = (q.astype(jnp.float32) * (scale / plain)).astype(q.dtype)
+        return causal_attention(q, k, kv[..., Dn:],
+                                attention_mask=attention_mask,
+                                segment_ids=segment_ids, impl=impl)

@@ -212,17 +212,40 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl):
+def _swiglu_gate(gate: jax.Array, limit: float | None) -> jax.Array:
+    gate = gate.astype(jnp.float32)
+    return jax.nn.silu(gate if limit is None else jnp.minimum(gate, limit))
+
+
+def _swiglu_up(up: jax.Array, limit: float | None) -> jax.Array:
+    up = up.astype(jnp.float32)
+    return up if limit is None else jnp.clip(up, -limit, limit)
+
+
+def clamped_swiglu(gate: jax.Array, up: jax.Array,
+                   limit: float | None) -> jax.Array:
+    """``silu(gate) * up`` in float32; with ``limit`` (a family's
+    ``swiglu_limit``) the gate is held under it and the up half inside
+    ``[-limit, limit]`` first. One spelling for the routed rows, the
+    shared expert and a dense FFN."""
+    return _swiglu_gate(gate, limit) * _swiglu_up(up, limit)
+
+
+def _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl,
+                    swiglu_limit=None):
     """The two grouped products over rows already sorted by expert. What
     an expert IS follows from its two stacks: a first stack twice as wide
     as the second is deep holds gate and up fused (``[G, E, 2F]``, gate
-    columns first) and the body is SwiGLU; one as wide as the second is
-    deep (``[G, L, F]``) is one matrix and the body is squared ReLU."""
+    columns first) and the body is SwiGLU (clamped where the family
+    states a ``swiglu_limit``); one as wide as the second is deep
+    (``[G, L, F]``) is one matrix and the body is squared ReLU."""
     F = w_down.shape[1]
     up = grouped_matmul(x_sorted, w_in, group_sizes, impl=impl)
     if w_in.shape[-1] == 2 * F:
-        act = (jax.nn.silu(up[:, :F].astype(jnp.float32))
-               * up[:, F:].astype(jnp.float32)).astype(x_sorted.dtype)
+        # the gate's half first, whole, then the up half's slice: the
+        # order the serve programs' pinned text has
+        act = (_swiglu_gate(up[:, :F], swiglu_limit)
+               * _swiglu_up(up[:, F:], swiglu_limit)).astype(x_sorted.dtype)
     elif w_in.shape[-1] == F:
         act = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(
             x_sorted.dtype)
@@ -237,7 +260,9 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
                    held: tuple[int, int] | None = None,
                    live: jax.Array | None = None,
                    impl: str | None = None,
-                   count_fullest: bool = False) -> tuple[jax.Array, dict]:
+                   count_fullest: bool = False,
+                   swiglu_limit: float | None = None
+                   ) -> tuple[jax.Array, dict]:
     """``sum_e w_e E_e(h)`` over the chosen experts of every row THAT ARE
     HELD HERE.
 
@@ -254,8 +279,10 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     router's experts, and the same two functions move the rows with no
     mask (:func:`_dispatch` owns the transpose of token -> expert order,
     :func:`_combine` that of the weigh, the un-sort and the sum over
-    ``k``: gathers along ``order`` and its inverse, no scatter). ``live``
-    [N] bool marks the rows that are not padding: every row is computed
+    ``k``: gathers along ``order`` and its inverse, no scatter).
+    ``swiglu_limit`` clamps a SwiGLU body (:func:`clamped_swiglu`).
+    ``live`` [N] bool marks the rows that are not padding: every row is
+    computed
     (shapes are static) and only live ones are counted. Returns ([N, E]
     in ``h``'s dtype, int32 scalars {"moe_rows": live rows computed here,
     "moe_experts_touched"} and, with ``held``, "moe_rows_elsewhere";
@@ -274,7 +301,8 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     group_sizes = jnp.bincount(flat, length=G).astype(jnp.int32)
     with jax.named_scope("moe.experts"):
         x_sorted = _dispatch(h, here, order)
-        y = _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl)
+        y = _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl,
+                            swiglu_limit)
         out = _combine(y, weights, here, order)
     if live is None:
         live_flat = None

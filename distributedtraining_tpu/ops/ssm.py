@@ -92,15 +92,17 @@ def causal_conv1d(u: jax.Array, w: jax.Array, b: jax.Array | None,
 
 
 def conv_decode_update(tails: jax.Array, slots: jax.Array, u: jax.Array,
-                       w: jax.Array, b: jax.Array
+                       w: jax.Array, b: jax.Array | None
                        ) -> tuple[jax.Array, jax.Array]:
     """One token a slot. ``tails`` [S, K-1, C] (the pool), ``slots`` [B],
-    ``u`` [B, C] -> (``conv + b`` [B, C] float32, the pool with the live
-    slots' tails moved on by one row)."""
+    ``u`` [B, C], ``b`` [C] or None (no bias) -> (``conv + b`` [B, C]
+    float32, the pool with the live slots' tails moved on by one row)."""
     window = jnp.concatenate([tails[slots], u[:, None].astype(tails.dtype)],
                              axis=1)                         # [B, K, C]
     out = jnp.sum(window.astype(jnp.float32) * w.astype(jnp.float32),
-                  axis=1) + b.astype(jnp.float32)
+                  axis=1)
+    if b is not None:
+        out = out + b.astype(jnp.float32)
     return out, tails.at[slots].set(window[:, 1:])
 
 

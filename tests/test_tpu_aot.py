@@ -143,6 +143,65 @@ def test_state_update_compiles_in_place_at_published_widths(one_chip, slots,
     assert mem.temp_size_in_bytes < pool_bytes // 16
 
 
+@pytest.mark.parametrize("impl, calls", [("kernel", 1), ("xla", 0)])
+@pytest.mark.parametrize("slots", [64, 8])
+def test_delta_rule_update_compiles_in_place_at_published_widths(
+        one_chip, slots, impl, calls):
+    """`ops/delta_rule.gdn_decode_update` over a 64-slot pool of
+    GigaChat3.5's states (64 value heads x 128 x 128 float32 over 32 key
+    heads, 16 value heads a block): Mosaic takes the kernel, and neither
+    it nor its XLA twin holds a temporary the size of the pool."""
+    from distributedtraining_tpu.ops import delta_rule
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((65, 64, 128, 128))
+    assert delta_rule.kernel_supports(pool, 32)
+    compiled = jax.jit(
+        lambda *a: delta_rule.gdn_decode_update(*a, impl=impl),
+        donate_argnums=(0,)
+    ).trace(pool, sds((slots,), jnp.int32), sds((slots, 32, 128)),
+            sds((slots, 32, 128)), sds((slots, 64, 128)), sds((slots, 64)),
+            sds((slots, 64)), sds((slots,), jnp.bool_)
+            ).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == calls
+    # the instruction's own name, which the device-trace reader matches
+    assert bool(re.search(r"%gdn_decode_update(\.\d+)? = ", text)) \
+        == bool(calls)
+    mem = compiled.memory_analysis()
+    pool_bytes = 65 * 64 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 16
+
+
+def test_latent_decode_kernel_compiles_at_64_heads_and_a_yarn_scale(
+        one_chip):
+    """The latent decode kernel at GigaChat3.5's 64 heads (kanana-2: 32)
+    and a softmax scale that carries YaRN's `mscale^2`."""
+    from distributedtraining_tpu.models import gigachat3_5 as gc
+    from distributedtraining_tpu.ops import mla_attention as mla
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slots, pages = 64, 256
+    pool = 1 + slots * pages
+    args = (sds((slots, 1, 64, 512)), sds((slots, 1, 64, 64)),
+            sds((pool, 16, 512)), sds((pool, 16, 128)),
+            sds((slots, pages), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots, 1, 512)), sds((slots, 1, 64)))
+    assert mla.kernel_supports(*args[:4])
+    scale = gc.PRESETS["gigachat3.5-432b-a28b-l5-e16-v16k"].softmax_scale
+    compiled = _compile(
+        lambda *a: mla.mla_decode_attention(*a, scale), *args)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "mla_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 24
+
+
 @pytest.mark.parametrize("packed", [True, False])
 def test_causal_attention_gradient_compiles_at_the_train_cells_shape(
         one_chip, monkeypatch, packed):
