@@ -54,7 +54,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import moe, ssm
+from ..ops import flash_attention, moe, ssm
 from ..ops.attention import causal_attention, remat_policy
 from ..ops.embed import embed_lookup
 from .gpt2 import pad_vocab
@@ -72,6 +72,10 @@ TRAIN_COUNTERS = {
     "moe_rows_fullest": "train.moe.rows_fullest_expert",
     "moe_experts_touched": "train.moe.experts_touched",
 }
+# and its attention layers, where the flash kernels run the block pairs the
+# rows' segment ids need (ops/flash_attention.block_pairs)
+ATTN_COUNTERS = ("train.attn.block_pairs_run",
+                 "train.attn.block_pairs_causal")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,7 +228,8 @@ class Lfm2MoeBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, attention_mask, segment_ids, position_ids):
-        """-> (x, what the routed layer counted: {} for a dense FFN)."""
+        """-> (x, what the layer counted: the routed layer's rows, an
+        attention layer's block pairs; {} where neither counts)."""
         # each half, norm to residual add, under its scope for the device
         # trace (docs/observability.md); `moe.route` / `moe.experts` open
         # inside `lfm2.moe_ffn`, which keeps what they leave: the norm, the
@@ -233,14 +238,15 @@ class Lfm2MoeBlock(nn.Module):
         with jax.named_scope("lfm2.conv" if conv else "lfm2.attn"):
             h = _norm(self.cfg, "operator_norm")(x)
             mix = self._conv if conv else self._attention
-            x = x + mix(h, attention_mask, segment_ids, position_ids)
+            out, stats = mix(h, attention_mask, segment_ids, position_ids)
+            x = x + out
         with jax.named_scope("lfm2.moe_ffn" if self.routed
                              else "lfm2.dense_ffn"):
             h = _norm(self.cfg, "ffn_norm")(x)
             if not self.routed:
-                return x + self._dense_ffn(h), {}
-            out, stats = self._experts(h)
-            return x + out, stats
+                return x + self._dense_ffn(h), stats
+            out, rows = self._experts(h)
+            return x + out, {**stats, **rows}
 
     def _conv(self, h, _mask, segment_ids, _pos):
         cfg = self.cfg
@@ -252,7 +258,7 @@ class Lfm2MoeBlock(nn.Module):
         conv, _ = ssm.causal_conv1d(gate_b * u, taps, None, None,
                                     segment_ids)
         y = (gate_c.astype(jnp.float32) * conv).astype(cfg.compute_dtype())
-        return _dense(E, "out_proj", ("mlp", "embed"), cfg)(y)
+        return _dense(E, "out_proj", ("mlp", "embed"), cfg)(y), {}
 
     def _attention(self, h, attention_mask, segment_ids, position_ids):
         cfg = self.cfg
@@ -272,8 +278,9 @@ class Lfm2MoeBlock(nn.Module):
             q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
             attention_mask=attention_mask, segment_ids=segment_ids,
             impl="flash")
+        pairs = flash_attention.block_pairs(q, attention_mask, segment_ids)
         return _dense(E, "out_proj", ("qkv", "embed"), cfg)(
-            attn.reshape(B, T, Hq * D))
+            attn.reshape(B, T, Hq * D)), dict(zip(ATTN_COUNTERS, pairs or ()))
 
     def _dense_ffn(self, h):
         cfg = self.cfg
@@ -319,7 +326,7 @@ class Lfm2Moe(nn.Module):
                  position_ids=None, deterministic: bool = True,
                  return_hidden: bool = False):
         """Logits [B, T, padded_vocab] (or the normed hidden states). What
-        the routed layers counted, summed over layers, is sown once under
+        the layers counted, summed over layers, is sown once under
         ``intermediates/train_counters`` for a caller that asks for the
         collection (engine/train.py)."""
         del deterministic

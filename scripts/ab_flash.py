@@ -29,6 +29,18 @@ candidates that lost live only here.
                                   # `landed`, against the library's grouped
                                   # (MQA) kernel mapped over the K/V heads
 
+    chiprun -- python scripts/ab_flash.py --packed
+                                  # `landed`'s two paths: the library's
+                                  # kernels over the constants of the causal
+                                  # mask against its own over the pair list
+                                  # computed from the rows' segment ids, at
+                                  # the LFM2 train cell's shape, GPT-2's,
+                                  # and T of 2,048 and 4,096 between them,
+                                  # on the cells' own documents; the cost of
+                                  # a grid step that runs and of one that
+                                  # does not, and the threshold the rule
+                                  # takes
+
 A time is the device's: `reps` calls chained inside ONE jitted loop (each
 call's output is the next one's query), the wall clock around it with
 `block_until_ready`, the fastest of `--trials`, over `reps`. One compile
@@ -232,6 +244,149 @@ def run_gqa(args, sharding):
     return rows
 
 
+# -- the pair list from the segment ids ---------------------------------------
+
+# B, H, T, D and the traffic mix whose documents fill the rows (its batch and
+# row length overridden where the shape is neither cell's)
+PACKED = [((4, 20, 1024, 64), "packed-b4-t1024"),
+          ((2, 32, 2048, 64), "packed-b2-t8192"),
+          ((2, 32, 4096, 64), "packed-b2-t8192"),
+          ((2, 32, 8192, 64), "packed-b2-t8192")]
+# q and kv block alike, forward and backward, computed in one piece; the
+# first is what `landed`'s rule gives these T
+PACKED_BLOCKS = [512, 256, 128]
+
+
+def packed(from_ids: bool, block: int):
+    """`landed` with the pair list forced on (every packed row) or off (the
+    library's kernels over the causal constants), at blocks of `block`."""
+    def fn(q, k, v, seg):
+        kept = landed_mod.TABLE_MIN_BLOCKS, landed_mod._block_sizes
+        landed_mod.TABLE_MIN_BLOCKS = 0 if from_ids else q.shape[1] + 1
+        landed_mod._block_sizes = lambda T: sk.BlockSizes(
+            block_q=block, block_kv=block, block_kv_compute=block,
+            block_q_dkv=block, block_kv_dkv=block,
+            block_kv_dkv_compute=block, use_fused_bwd_kernel=True)
+        try:
+            return landed(q, k, v, seg)
+        finally:
+            landed_mod.TABLE_MIN_BLOCKS, landed_mod._block_sizes = kept
+    return fn
+
+
+def cell_documents(mix_name, B, T, seed):
+    """[B, T] segment ids of the benchmark's own packed rows. The script
+    reads the cells' generator by path: nothing a cell measures imports
+    this file."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "gen", os.path.join(ROOT, "benchmarks", "traffic", "gen.py"))
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    mix = dict(gen.load_mix(mix_name), batch=B, seq_len=T)
+    return next(gen.packed_batches(mix, seed, 1024))["segment_ids"]
+
+
+def steps(seg, H, block):
+    """(grid steps that run under the causal constants, pairs the ids need,
+    the constants' grid steps in all) of one forward or backward call."""
+    needed = np.asarray(landed_mod.needed_pairs(seg, seg, block))
+    B, n, _ = needed.shape
+    return H * B * n * (n + 1) // 2, H * int(needed.sum()), H * B * n * n
+
+
+def run_packed(args, sharding):
+    rows = []
+    for shape, mix_name in PACKED:
+        B, H, T, D = shape
+        seg = cell_documents(mix_name, B, T, args.seed)
+        q, k, v, do, _ = make_inputs(shape, args.seed, False)
+        inputs = on_device((q, k, v, do, seg), sharding)
+        ref = {}
+        for block in PACKED_BLOCKS:
+            if block > T // 2:
+                continue
+            for from_ids in (False, True):
+                causal, pairs, grid = steps(seg, H, block)
+                row = {"shape": list(shape), "candidate":
+                       f"{'pair list' if from_ids else 'causal constants'} "
+                       f"{block}",
+                       "steps_run": pairs if from_ids else causal,
+                       "steps": pairs if from_ids else grid}
+                fn = packed(from_ids, block)
+                try:
+                    if sharding is not None:
+                        for grad in (False, True):
+                            programs(fn, grad).trace(*inputs, 1).lower(
+                                lowering_platforms=("tpu",)).compile()
+                        row["compiled"] = True
+                    else:
+                        row["fwd_ms"] = time_ms(programs(fn, False), inputs,
+                                                args.reps, args.trials)
+                        prog = programs(fn, True)
+                        got = prog(*inputs, 1)
+                        row["fwd_bwd_ms"] = time_ms(prog, inputs, args.reps,
+                                                    args.trials)
+                        # the pair list against the constants at the same
+                        # blocks: out, dK and dV to the bit; dQ adds up in
+                        # float32 where the library sums bfloat16 shares,
+                        # so it may differ by a rounding of its largest
+                        if not from_ids:
+                            ref[block] = got
+                        else:
+                            err = worst(got, ref[block])
+                            row["err_packed"] = err
+                            dq_scale = float(jnp.max(jnp.abs(
+                                ref[block][1].astype(jnp.float32))))
+                            row["ok"] = (err[0] == err[2] == err[3] == 0.0
+                                         and err[1] <= dq_scale * 2 ** -7)
+                except Exception as e:
+                    row["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+    if sharding is None:
+        print(packed_summary(rows), flush=True)
+    return rows
+
+
+def packed_summary(rows):
+    """Per shape and block: what a grid step that runs costs (the pair
+    list's time over its pairs: it visits nothing else), what an empty step
+    of the constants' grid then costs (their time less their running steps
+    at that price, over their empty steps), and the fewest blocks a row at
+    which the pair list won."""
+    lines, won = [], {}
+    by = {(tuple(r["shape"]), r["candidate"]): r for r in rows
+          if "fwd_ms" in r}
+    for (shape, name), listed in by.items():
+        if not name.startswith("pair list"):
+            continue
+        block = int(name.split()[-1])
+        cau = by.get((shape, f"causal constants {block}"))
+        if cau is None:
+            continue
+        for key, label in (("fwd_ms", "forward"), ("bwd", "backward")):
+            t = [(r["fwd_bwd_ms"] - r["fwd_ms"]) if key == "bwd" else r[key]
+                 for r in (cau, listed)]
+            runs = 1e3 * t[1] / listed["steps_run"]
+            empty = (1e3 * t[0] - cau["steps_run"] * runs) / max(
+                1, cau["steps"] - cau["steps_run"])
+            lines.append(
+                f"{','.join(map(str, shape))} block {block} {label}: "
+                f"{t[0]:.3f} -> {t[1]:.3f} ms, {cau['steps_run']} of "
+                f"{cau['steps']} steps run -> {listed['steps_run']} pairs; "
+                f"a step that runs {runs:.3f} us, an empty step of the "
+                f"constants' grid {empty:.3f} us")
+        if block == 512:
+            won[shape[2] // 512] = (
+                listed["fwd_ms"] < cau["fwd_ms"]
+                and listed["fwd_bwd_ms"] < cau["fwd_bwd_ms"])
+    lines.append("pair list faster, forward and forward + backward, by "
+                 f"blocks a row at 512: {won}; the rule engages at "
+                 f"TABLE_MIN_BLOCKS = {landed_mod.TABLE_MIN_BLOCKS}")
+    return "\n".join(lines)
+
+
 # -- inputs -----------------------------------------------------------------
 
 def make_inputs(shape, seed, packed):
@@ -374,6 +529,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trials", type=int, default=5)
     ap.add_argument("--gqa", action="store_true",
                     help="the grouped-query A/B alone (see above)")
+    ap.add_argument("--packed", action="store_true",
+                    help="causal constants against the pair list (see above)")
     ap.add_argument("--only", default="",
                     help="substring a candidate's name must hold")
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
@@ -402,6 +559,9 @@ def main(argv=None) -> int:
     if args.gqa:
         rows += run_gqa(args, sharding)
         args.out = os.path.splitext(args.out)[0] + "_gqa.json"
+    elif args.packed:
+        rows += run_packed(args, sharding)
+        args.out = os.path.splitext(args.out)[0] + "_packed.json"
     else:
         rows += run_shape(TRAIN, cands, args, oracle, sharding)
         fwd_only = [c for c in cands if c[2] == "fwd"]

@@ -82,3 +82,293 @@ def test_schedule_runs_only_the_causal_pairs(T, most):
         assert not np.triu(table, 1).any()
         assert (np.diag(table) == 1).all()
         assert (table[np.tril_indices_from(table, -1)] == 2).all()
+
+
+# -- block tables from the rows' segment ids ---------------------------------
+
+def _packing(T, seed, rows=2, shortest=64):
+    """[rows, T] ids of documents of Pareto lengths (shape 1.2) packed with
+    no padding, `gen.packed_batches`' law: boundaries off every block grid."""
+    rng = np.random.default_rng(seed)
+    lens = (shortest * (1.0 + rng.pareto(1.2, (rows, T // shortest)))
+            ).astype(np.int64)
+    return np.stack([np.repeat(np.arange(r.size), r)[:T]
+                     for r in lens]).astype(np.int32)
+
+
+def _shuffled(seg, seed):
+    """The same documents under ids that rise AND fall along the row."""
+    perm = np.random.default_rng(seed).permutation(seg.max() + 1)
+    return perm[seg].astype(np.int32)
+
+
+def _brute_pairs(seg_q, seg_kv, block):
+    """[T / block, T / block]: does the block pair hold a (same segment,
+    causal) pair? From the [T, T] mask itself, one row of a batch."""
+    T = seg_q.size
+    allowed = np.tril(seg_q[:, None] == seg_kv[None, :])
+    n = T // block
+    return allowed.reshape(n, block, n, block).any((1, 3))
+
+
+def _listed(needed, forward):
+    """The pair list of `needed` ([B, n, n] booleans, [row, q block, kv
+    block]) as numpy: (row, q block, kv block, edges) of its `count` pairs,
+    and the filler after them. The forward lists q-major, the backward the
+    transpose (kv-major)."""
+    arg = needed if forward else needed.swapaxes(1, 2)
+    row, major, minor, edges, count = (
+        np.asarray(x) for x in fl._pair_list(jnp.asarray(arg)))
+    count = int(count)
+    q, kv = (major, minor) if forward else (minor, major)
+    filler = np.stack([row, q, kv])[:, count:]
+    return (row[:count], q[:count], kv[:count], edges[:count]), filler
+
+
+def _ran(needed, forward):
+    """[B, n, n] booleans: the pairs the list visits, each once."""
+    (row, q, kv, _), _ = _listed(needed, forward)
+    ran = np.zeros(needed.shape, np.int64)
+    np.add.at(ran, (row, q, kv), 1)
+    assert ran.max() <= 1
+    return ran.astype(bool)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("T, block", [(2048, 128), (2048, 512),
+                                      (8192, 128), (8192, 512)])
+def test_table_from_segment_ids_is_the_brute_force_one(T, block, seed):
+    """On the packer's ids the pairs that run, forward and kv-major, are
+    exactly the pairs that hold an allowed (same segment, causal) pair."""
+    seg = _packing(T, seed)
+    needed = np.asarray(fl.needed_pairs(seg, seg, block))
+    want = np.stack([_brute_pairs(row, row, block) for row in seg])
+    np.testing.assert_array_equal(needed, want)
+    np.testing.assert_array_equal(_ran(needed, True), want)
+    np.testing.assert_array_equal(_ran(needed, False), want)
+    if T == 8192:
+        # most of the causal half is left out (the replay's 27.7% at 512)
+        n = T // block
+        assert needed.sum() < 0.6 * seg.shape[0] * n * (n + 1) / 2
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("T, block", [(2048, 128), (2048, 512),
+                                      (8192, 512)])
+@pytest.mark.parametrize("kv_differs", [False, True])
+def test_table_skips_no_pair_it_needs_on_any_ids(T, block, seed, kv_differs):
+    """Ids that DO decrease (shuffled segments), and kv ids that are not
+    the q ids: the list may run too much, never too little."""
+    seg_q = _shuffled(_packing(T, seed), seed)
+    seg_kv = _shuffled(_packing(T, seed + 7), seed) if kv_differs else seg_q
+    needed = np.asarray(fl.needed_pairs(seg_q, seg_kv, block))
+    want = np.stack([_brute_pairs(q, kv, block)
+                     for q, kv in zip(seg_q, seg_kv)])
+    assert not (want & ~needed).any()
+    assert not (want & ~_ran(needed, True)).any()
+    assert not (want & ~_ran(needed, False)).any()
+    for row in needed:
+        assert not np.triu(row, 1).any() and row.diagonal().all()
+
+
+@pytest.mark.parametrize("T", [2048, 4096, 8192])
+def test_a_row_of_one_document_gives_the_static_causal_table(T):
+    """One document a row: the list is the pairs the library's constants
+    run, forward and backward."""
+    kernel = fl._causal_kernel(T, 2)
+    block = fl._block_sizes(T).block_q
+    seg = np.zeros((1, T), np.int32)
+    needed = np.asarray(fl.needed_pairs(seg, seg, block))
+    for info, forward in ((kernel.fwd_mask_info, True),
+                          (kernel.dkv_mask_info, False)):
+        static = np.asarray(info.block_mask)[0] > 0
+        np.testing.assert_array_equal(_ran(needed, forward)[0], static)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("shuffle", [False, True])
+def test_the_list_is_in_order_and_its_edges_mark_the_groups(seed, shuffle):
+    """Forward: row, then q block, then kv block, ascending, so a q block's
+    pairs follow each other and its diagonal comes last (the softmax sees a
+    key of its own before it writes); bit 0 / 1 of `edges` mark a q
+    block's first / last pair. Backward: row, kv block, q block, a kv
+    block's diagonal first; bits 2 / 3 a row's first / last pair (where dQ
+    is zeroed and written). Nothing sits above the diagonal, and the filler
+    past `count` names the last pair again."""
+    seg = _packing(4096, seed)
+    if shuffle:
+        seg = _shuffled(seg, seed)
+    needed = np.asarray(fl.needed_pairs(seg, seg, 128))
+    n = needed.shape[-1]
+    for forward in (True, False):
+        (row, q, kv, edges), filler = _listed(needed, forward)
+        assert (kv <= q).all()
+        major, minor = (q, kv) if forward else (kv, q)
+        key = (row * n + major) * n + minor
+        assert (np.diff(key) > 0).all()
+        group = row * n + major
+        starts = np.flatnonzero(np.diff(group, prepend=-1))
+        ends = np.flatnonzero(np.diff(group, append=-1))
+        assert len(starts) == len(ends) == needed.shape[0] * n
+        np.testing.assert_array_equal(np.flatnonzero(edges & 1), starts)
+        np.testing.assert_array_equal(np.flatnonzero(edges & 2), ends)
+        diagonal = ends if forward else starts
+        assert (q[diagonal] == kv[diagonal]).all()
+        np.testing.assert_array_equal(
+            np.flatnonzero(edges & 4),
+            np.flatnonzero(np.diff(row, prepend=-1)))
+        np.testing.assert_array_equal(
+            np.flatnonzero(edges & 8),
+            np.flatnonzero(np.diff(row, append=-1)))
+        assert filler.shape[1] == needed.shape[0] * n * (n + 1) // 2 - len(q)
+        assert (filler == np.array([row[-1], q[-1], kv[-1]])[:, None]).all()
+
+
+def _pallas_calls(fn, *args):
+    import jax
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("T, packed, engages", [
+    (1024, True, False),            # train-large-t1024: two blocks a row
+    (8192, False, False),           # no ids: nothing to read a table from
+    (512 * (fl.TABLE_MIN_BLOCKS - 1), True, False),
+    (512 * fl.TABLE_MIN_BLOCKS, True, True),
+    (8192, True, True)])
+def test_the_table_engages_with_ids_at_or_over_the_threshold(
+        T, packed, engages, monkeypatch):
+    """Below the threshold and without ids the kernel is the library's,
+    mapped over the rows, its tables `_causal_kernel`'s constants; over it
+    ONE call of this module's kernel over all rows' pairs, its grid's
+    length the count of them (an operand, not a constant)."""
+    import jax
+    monkeypatch.setattr(fl, "_on_tpu", lambda: True)
+    B = 2
+    seg = jnp.asarray(_packing(T, 0, rows=B)) if packed else None
+    assert fl._table_engages(T, seg) == engages
+    q = jax.ShapeDtypeStruct((B, T, 1, 64), jnp.bfloat16)
+    calls = _pallas_calls(
+        lambda q, k, v: fl.flash_attention(q, k, v, segment_ids=seg),
+        q, q, q)
+    assert [c.params["name"].startswith("flash_mha_fwd") for c in calls] \
+        == [True]
+    assert bool(calls[0].params["grid_mapping"].num_dynamic_grid_bounds) \
+        == engages
+    pairs = fl.block_pairs(q, None, seg)
+    assert (pairs is not None) == engages
+
+
+def test_the_rule_stops_where_a_row_outgrows_the_chips_small_memories():
+    """The backward holds a head's whole dQ row in VMEM and the kernels the
+    pair list in scalar memory: past `TABLE_MAX_T` tokens a row the
+    library's kernels stand."""
+    seg = np.zeros((1, 8), np.int32)        # the rule reads T, not the ids
+    assert fl._table_engages(fl.TABLE_MAX_T, seg)
+    assert not fl._table_engages(2 * fl.TABLE_MAX_T, seg)
+    assert fl._vmem_limit(fl.TABLE_MAX_T) <= 64 * 2 ** 20
+
+
+# a row of blocks of 128 just over the threshold: the smallest shape the
+# rule hands a pair list, at a size the interpreter runs in seconds
+_T_SMALL = 128 * (fl.TABLE_MIN_BLOCKS + 1)
+
+
+@pytest.fixture
+def interpreted():
+    fl.use_interpret(True)
+    try:
+        yield
+    finally:
+        fl.use_interpret(False)
+
+
+def _with_gradients(fn, q, k, v, do):
+    import jax
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out,) + vjp(do)
+
+
+@pytest.mark.parametrize("ids, dtype", [
+    ("packed", "float32"), ("shuffled", "float32"),
+    ("kv_differs", "float32"), ("packed", "bfloat16")])
+def test_interpreted_kernel_with_the_table_is_dense_and_the_static_one(
+        ids, dtype, interpreted, monkeypatch):
+    """Output and the three gradients: `dot_product_attention` with the
+    same ids to the dtype's rounding, and the library's kernel over the
+    causal constants BIT FOR BIT (a pair the list leaves out is one whose
+    scores the segment mask sets to the mask value whole: where it ran it
+    added exact zeros). In bfloat16 that holds for the output, dK and dV;
+    dQ adds up in float32 here, where the library rounds every kv block's
+    share to bfloat16 and sums those, so the two differ by a rounding."""
+    from distributedtraining_tpu.ops.attention import (
+        combine_masks, dot_product_attention, make_causal_mask)
+    B, T, H, D = 2, _T_SMALL, 2, 64
+    rng = np.random.default_rng(3)
+    q, k, v, do = (jnp.asarray(rng.standard_normal((B, T, H, D)),
+                               dtype) for _ in range(4))
+    seg_q = _packing(T, 5, shortest=32)
+    if ids != "packed":
+        seg_q = _shuffled(seg_q, 5)
+    seg_kv = seg_q
+    if ids == "kv_differs":
+        # a row must see one key at least, or dense and kernel each give
+        # their own garbage: a document's first key keeps the q id
+        first = np.diff(seg_q, axis=1, prepend=-1) != 0
+        seg_kv = np.where(first | (rng.random(seg_q.shape) < 0.5), seg_q,
+                          _shuffled(_packing(T, 6), 5))
+    seg_q, seg_kv = jnp.asarray(seg_q), jnp.asarray(seg_kv)
+    assert fl._table_engages(T, seg_q)
+    if ids == "packed":
+        run, causal = fl.block_pairs(q, None, seg_q)
+        assert int(run) < int(causal)       # some pair is left out
+
+    def flash(q, k, v):
+        return fl.flash_attention(q, k, v, segment_ids=seg_q,
+                                  kv_segment_ids=seg_kv)
+
+    def dense(q, k, v):
+        mask = combine_masks(make_causal_mask(T), None, seg_q, seg_kv)
+        return dot_product_attention(q, k, v, mask)
+
+    f32 = lambda x: np.asarray(x, np.float32)
+    exact = dtype == "float32"
+    got = _with_gradients(flash, q, k, v, do)
+    # the forward alone (an eval) is the kernel that keeps no log-sum-exp
+    np.testing.assert_array_equal(f32(flash(q, k, v)), f32(got[0]))
+    for g, want in zip(got, _with_gradients(dense, q, k, v, do)):
+        np.testing.assert_allclose(f32(g), f32(want), rtol=0,
+                                   atol=2e-5 if exact else 2 ** -5)
+    monkeypatch.setattr(fl, "TABLE_MIN_BLOCKS", T)       # never engages
+    assert not fl._table_engages(T, seg_q)
+    static = _with_gradients(lambda *a: flash(*a), q, k, v, do)
+    for name, g, want in zip(("out", "dq", "dk", "dv"), got, static):
+        if exact or name != "dq":
+            np.testing.assert_array_equal(f32(g), f32(want), err_msg=name)
+        else:
+            # a rounding of the largest share, not of the sum
+            np.testing.assert_allclose(f32(g), f32(want), rtol=2 ** -7,
+                                       atol=2 ** -6)
+
+
+def test_block_pairs_counts_what_the_tables_run(interpreted):
+    T = _T_SMALL
+    seg = jnp.asarray(_packing(T, 9, shortest=16))
+    q = jnp.zeros((2, T, 1, 64), jnp.float32)
+    run, causal = fl.block_pairs(q, None, seg)
+    n = T // 128
+    assert int(causal) == 2 * n * (n + 1) // 2
+    assert int(run) == sum(_brute_pairs(row, row, 128).sum()
+                           for row in np.asarray(seg))
+    assert int(run) < int(causal)
+    one = jnp.zeros((2, T), jnp.int32)
+    run, causal = fl.block_pairs(q, None, one)
+    assert int(run) == int(causal)
+    assert fl.block_pairs(q, None, None) is None
+    assert fl.block_pairs(q, jnp.ones((2, T), jnp.int32), seg) is None

@@ -465,6 +465,67 @@ def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
     assert set(gm) == {"loss", "tokens"}
 
 
+def test_the_step_counts_the_block_pairs_its_attention_runs(tiny):
+    """Where the flash kernels take their block tables from the rows'
+    segment ids (a head of 64 and nine blocks of 128 a row here, the
+    kernels interpreted), the step returns how many block pairs ran and how
+    many the causal mask alone would have run, and `MinerLoop` counts them
+    with the routed layers' rows. Their ratio is the brute-force share of
+    the batch's own [T, T] mask."""
+    from distributedtraining_tpu.ops import flash_attention as fl
+    _, pc, _, _, _ = tiny
+    T, block = 128 * (fl.TABLE_MIN_BLOCKS + 1), 128
+    one_head = dataclasses.replace(pc, num_attention_heads=1,
+                                   num_key_value_heads=1)
+    model, _ = lf.make_model(one_head)
+    rng = np.random.default_rng(11)
+    rows = []
+    for _ in range(2):
+        lens = (40 * (1.0 + rng.pareto(1.2, T // 40))).astype(np.int64)
+        lens = lens[:np.searchsorted(np.cumsum(lens), T) + 1]
+        lens[-1] -= lens.sum() - T
+        rows.append([int(n) for n in lens])
+    batch = _packed_batch(pc, rows)
+    n = T // block
+    want = 0
+    for seg in batch["segment_ids"]:
+        allowed = np.tril(seg[:, None] == seg[None, :])
+        want += int(allowed.reshape(n, block, n, block).any((1, 3)).sum())
+    engine = TrainEngine(model)
+    params = model.init_params(jax.random.PRNGKey(0))
+    run, causal = lf.ATTN_COUNTERS
+
+    class Sink:
+        def log(self, *_a, **_k):
+            pass
+
+        def close(self):
+            pass
+
+    fl.use_interpret(True)
+    obs.configure(Sink(), role="miner")
+    try:
+        _, m = engine.train_step(engine.init_state(params=params), batch)
+        loop = MinerLoop(engine, InMemoryTransport(), "m0",
+                         send_interval=1e9, check_update_interval=1e9)
+        loop.bootstrap(params=params)
+        loop.run(iter([batch, batch]))
+        counted = {k: obs.registry().peek(k).value for k in (run, causal)}
+    finally:
+        obs.reset()
+        fl.use_interpret(False)
+    assert counted == {run: 2 * want, causal: 2 * int(m[causal])}
+    assert set(m) == {"loss", "tokens", *lf.TRAIN_COUNTERS.values(),
+                      *lf.ATTN_COUNTERS}
+    assert np.isfinite(float(m["loss"]))
+    assert int(m[causal]) == 2 * n * (n + 1) // 2
+    assert int(m[run]) == want and 0 < want < int(m[causal])
+    # off the kernel's path (this lane's default) the step counts rows only
+    plain = TrainEngine(model)
+    _, m = plain.train_step(plain.init_state(params=params), batch)
+    assert set(m) == {"loss", "tokens", *lf.TRAIN_COUNTERS.values()}
+
+
 def test_a_delta_goes_through_the_memory_transport_and_applies(tiny):
     """What the fleet plane does with the family's tree today (ROADMAP M2):
     a push from `MinerLoop`, the fetch a validator makes, the delta applied

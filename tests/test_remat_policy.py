@@ -45,7 +45,7 @@ def _equations(jaxpr):
             yield from _equations(sub)
 
 
-def _step_equations(module, cfg, mesh=None):
+def _step_equations(module, cfg, mesh=None, T=T):
     """Every equation of the engine's own train step over two packed rows."""
     model, _ = module.make_model(cfg)
     engine = TrainEngine(model, mesh=mesh, seq_len=T)
@@ -107,6 +107,34 @@ def test_forward_kernel_calls_a_layer(family, case, kernel_selected,
     assert all(e.params["policy"] is want for e in remats)
 
 
+@pytest.mark.parametrize("case", ["remat_policy", "remat_bare",
+                                  "no_remat"])
+def test_forward_kernel_calls_a_layer_where_the_pair_list_engages(
+        case, kernel_selected, monkeypatch):
+    """Packed rows long enough for the pair list of their own ids
+    (`flash_attention.TABLE_MIN_BLOCKS`) run this module's kernels, not the
+    library's: its forward rule names `out` and the log-sum-exp as the
+    library names its own, and the policy keeps them, so the re-run calls
+    no kernel."""
+    module, cfg, layers = FAMILIES["lfm2"]
+    remat, policy, _, forward_a_layer = CASES[case]
+    if not policy:
+        monkeypatch.setattr(module, "remat_policy", lambda: None)
+    long = 128 * (flash_attention.TABLE_MIN_BLOCKS + 1)
+    eqns = _step_equations(module, dataclasses.replace(cfg, remat=remat),
+                           T=long)
+    assert _kernel_calls(eqns) == (forward_a_layer * layers, layers)
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert all(e.params["grid_mapping"].num_dynamic_grid_bounds == 1
+               for e in kernels)
+    named = [e for e in eqns if e.primitive.name == "name"]
+    assert {e.params["name"] for e in named} == {
+        flash_attention.RESIDUAL_NAME}
+    if policy:
+        # (out, log-sum-exp), in the forward alone
+        assert len(named) == 2 * layers
+
+
 @pytest.mark.parametrize("axes,forward_a_layer", [
     (dict(dp=2), 1), (dict(fsdp=2, tp=2), 1), (dict(sp=2), 0)])
 def test_the_name_is_inside_the_shard_map_and_the_policy_outside(
@@ -120,6 +148,26 @@ def test_the_name_is_inside_the_shard_map_and_the_policy_outside(
     eqns = _step_equations(module, cfg, make_mesh(MeshConfig(**axes)))
     assert _kernel_calls(eqns) == (forward_a_layer * layers,
                                    forward_a_layer * layers)
+
+
+def test_the_pair_list_is_made_inside_the_shard_map(kernel_selected):
+    """Under a mesh the list is each device's own: made from the rows it
+    holds, inside the `shard_map`, and the kernels' grid bound with it."""
+    module, cfg, layers = FAMILIES["lfm2"]
+    long = 128 * (flash_attention.TABLE_MIN_BLOCKS + 1)
+    eqns = _step_equations(module, dataclasses.replace(cfg, remat=True),
+                           make_mesh(MeshConfig(dp=2)), T=long)
+    assert _kernel_calls(eqns) == (layers, layers)
+    maps = [e for e in eqns if e.primitive.name == "shard_map"]
+    inside = [e for m in maps for e in _equations(m.params["jaxpr"])]
+    kernels = [e for e in inside if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 2 * layers
+    assert all(e.params["grid_mapping"].num_dynamic_grid_bounds == 1
+               for e in kernels)
+    # one row a device: the list holds a row's triangle
+    n = flash_attention.TABLE_MIN_BLOCKS + 1
+    assert all(e.invars[1].aval.shape == (n * (n + 1) // 2,)
+               for e in kernels)
 
 
 @pytest.mark.parametrize("family", FAMILIES)

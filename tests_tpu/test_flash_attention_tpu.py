@@ -1,6 +1,8 @@
 """Flash-attention numerics on the real chip: forward AND grad parity vs the
-dense oracle at T in {256, 1024}, packed segments included, and at the
-train cell's own shape with document boundaries off every block grid.
+dense oracle at T in {256, 1024}, packed segments included, at the train
+cell's own shape with document boundaries off every block grid, and at the
+LFM2 cell's (B=2, H=32, T=8,192), where the kernels are this module's own
+over the pair list of the rows' segment ids.
 
 This is the on-device half of tests/test_flash_attention.py (which pins
 the selection rule and the block schedule on CPU). The schedule
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributedtraining_tpu.ops import flash_attention as fl
 from distributedtraining_tpu.ops.attention import causal_attention
 from distributedtraining_tpu.ops.flash_attention import flash_attention
 
@@ -114,6 +117,113 @@ def test_train_cell_shape_unaligned_documents(seed):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             atol=1e-1, err_msg=f"d{name} mismatch at the cell's shape")
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+def test_lfm2_cell_shape_pair_list_from_the_segment_ids(seed, monkeypatch):
+    """B=2, H=32, T=8,192, D=64 packed (`train-lfm2-t8192`): the kernels
+    visit the block pairs the rows' segment ids need. The output equals the
+    library's kernel under the causal constants BIT FOR BIT. dK and dV are
+    the same float32 sums over the same pairs in the same order, but Mosaic
+    folds an accumulator into a product's passes its own way in each
+    kernel, so a few elements in 100,000 round to the neighbouring bfloat16
+    (PR 40's first chip run: 1,133 and 338 of 33,554,432 in dK, at blocks of
+    128, one pass a product, none). dQ adds up in float32 here where the
+    library sums bfloat16 shares, so it is the library's to a rounding of
+    its largest share. All four agree with the dense oracle on the heads it
+    can hold (heads are independent; the [B, H, T, T] scores of all 32
+    would be 17 GB)."""
+    B, T, H, D = 2, 8192, 32, 64
+    q, k, v = _qkv(B=B, T=T, H=H, D=D, seed=seed)
+    w = _qkv(B=B, T=T, H=H, D=D, seed=seed + 10)[0]
+    seg = _documents(B, T, seed=seed)
+    assert fl._table_engages(T, seg)
+    run, causal = fl.block_pairs(q, None, seg)
+    assert 0 < int(run) < int(causal) == B * 16 * 17 // 2
+
+    def loss(attend, w):
+        def f(q, k, v):
+            out = attend(q, k, v)
+            return jnp.sum(out.astype(jnp.float32)
+                           * w.astype(jnp.float32)), out
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, segment_ids=seg)
+
+    (_, out), grads = loss(flash, w)(q, k, v)
+    monkeypatch.setattr(fl, "TABLE_MIN_BLOCKS", T)       # the constants
+    assert not fl._table_engages(T, seg)
+    (_, out_s), grads_s = loss(lambda *a: flash(*a), w)(q, k, v)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), (out, *grads),
+                          (out_s, *grads_s)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if name == "out":
+            np.testing.assert_array_equal(
+                a, b, err_msg="out: pair list against causal constants")
+            continue
+        np.testing.assert_allclose(a, b, rtol=2 ** -6, err_msg=name,
+                                   atol=2 ** -7 * np.abs(b).max())
+        if name != "dq":
+            assert (a != b).mean() < 1e-3, (name, (a != b).mean())
+    heads = np.array([0, H - 1])
+    cut = lambda x: x[:, :, heads]
+    (_, out_d), grads_d = loss(
+        lambda q, k, v: causal_attention(q, k, v, segment_ids=seg,
+                                         impl="dense"),
+        cut(w))(cut(q), cut(k), cut(v))
+    # bfloat16: a rounding of a value near 4 is 2 ** -5
+    np.testing.assert_allclose(np.asarray(cut(out), np.float32),
+                               np.asarray(out_d, np.float32), atol=3e-2,
+                               rtol=2 ** -7)
+    for name, a, b in zip("qkv", grads, grads_d):
+        np.testing.assert_allclose(
+            np.asarray(cut(a), np.float32), np.asarray(b, np.float32),
+            atol=1e-1, err_msg=f"d{name} mismatch at the LFM2 cell's shape")
+
+
+def test_remat_policy_keeps_the_residuals_under_the_pair_list():
+    """One attention layer of the LFM2 cell's widths over packed rows of
+    8,192, under `nn.remat` with the blocks' policy: the gradient's
+    program calls the forward kernel once, not twice (its `out` and
+    log-sum-exp carry `RESIDUAL_NAME` and stay), and the result is the
+    unwrapped layer's to bfloat16 rounding."""
+    import flax.linen as nn
+
+    from distributedtraining_tpu.ops.attention import remat_policy
+    B, T, H, D = 2, 8192, 32, 64
+    q, k, v = _qkv(B=B, T=T, H=H, D=D, seed=3)
+    seg = _documents(B, T, seed=3)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, q, k, v):
+            return causal_attention(q, k, v, segment_ids=seg, impl="flash")
+
+    def forward_calls(jaxpr):
+        return sum(
+            ("flash_mha_fwd" in eqn.params["name"]
+             if eqn.primitive.name == "pallas_call" else
+             sum(map(forward_calls, jax.core.jaxprs_in_params(eqn.params))))
+            for eqn in jaxpr.eqns)
+
+    def run(layer):
+        def loss(q, k, v):
+            return jnp.sum(layer.apply({}, q, k, v).astype(jnp.float32) ** 2)
+        fn = jax.value_and_grad(loss, argnums=(0, 1, 2))
+        return (forward_calls(jax.make_jaxpr(fn)(q, k, v).jaxpr),
+                jax.jit(fn)(q, k, v))
+
+    plain_calls, plain = run(Layer())
+    kept_calls, kept = run(nn.remat(Layer, policy=remat_policy())())
+    bare_calls, _ = run(nn.remat(Layer)())
+    assert (plain_calls, kept_calls, bare_calls) == (1, 1, 2)
+    np.testing.assert_allclose(float(kept[0]), float(plain[0]), rtol=1e-5)
+    for a, b in zip(kept[1], plain[1]):
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b, rtol=0,
+                                   atol=4e-2 * np.abs(b).max())
 
 
 def test_train_step_flash_vs_dense_loss():
