@@ -44,9 +44,14 @@ They are donated and returned like the pages. A slot's row is written
 whole by its prefill (:func:`write_slot_state`) and moved on in place by
 every decode step (the model's layer does that itself and sows the pools
 back: :func:`sown_state`); the last row belongs to no request and takes a
-decode bucket's padding rows, as page 0 takes their page writes. Nothing
-of it can be shared by prefix, rolled back after a refused draft or
-shipped page by page: :data:`RECURRENT_STATE_REASON`.
+decode bucket's padding rows, as page 0 takes their page writes. A state
+exists only where a prefill ended, so the prefix cache keeps SNAPSHOTS of
+it: rows of a second pool of the same layout (:func:`make_snapshot_pool`),
+copied from a slot's row when its prompt is registered and back into a
+slot's row when a later prompt extends it (:func:`copy_state_row`); the
+suffix's prefill then continues from the row (:func:`slot_state_rows`).
+Nothing of it can be rolled back after a refused draft or shipped page by
+page: :data:`RECURRENT_STATE_REASON`.
 """
 
 from __future__ import annotations
@@ -66,10 +71,10 @@ LATENT_CACHE_REASON = (
     "and are not ported to it")
 RECURRENT_STATE_REASON = (
     "this model keeps a recurrent state per slot (its config states 'ssm' "
-    "in layer_caches), which is not addressed by position: a shared prefix "
-    "has no state to map, a refused draft cannot be rolled back and there "
-    "are no pages to ship, so the prefix cache, the speculative lane and "
-    "the KV transfer plane are not ported to it")
+    "in layer_caches), which is not addressed by position: a refused draft "
+    "cannot be rolled back (the state has moved past it) and a state is no "
+    "page to ship, so the speculative lane and the KV transfer plane are "
+    "not ported to it")
 StatePool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
 
 
@@ -147,6 +152,30 @@ def make_state_pool(cfg, n_layers: int, slots: int) -> StatePool:
                   for _ in range(n_layers)),
             tuple(jnp.zeros((slots + 1, *cfg.ssm_tail_shape),
                             cfg.compute_dtype()) for _ in range(n_layers)))
+
+
+def make_snapshot_pool(cfg, n_layers: int, rows: int) -> StatePool:
+    """The prefix cache's copies of per-slot state: the layout of
+    :func:`make_state_pool`, ``rows`` rows and no spare."""
+    return make_state_pool(cfg, n_layers, rows - 1)
+
+
+def copy_state_row(dst: StatePool, src: StatePool, src_row, dst_row
+                   ) -> StatePool:
+    """Row ``src_row`` of every layer of ``src`` over row ``dst_row`` of
+    ``dst``: slot -> snapshot when a prompt is registered, snapshot ->
+    slot when a later one extends it."""
+    return tuple(tuple(d.at[dst_row].set(x[src_row].astype(d.dtype))
+                       for d, x in zip(d_half, s_half))
+                 for d_half, s_half in zip(dst, src))
+
+
+def slot_state_rows(states, tails, slot) -> tuple:
+    """What a prefill that CONTINUES starts from: row ``slot`` of every
+    layer's pools as one ``(state [1, ..], tail [1, ..])`` pair a layer
+    (the models' ``ssm_init``)."""
+    return tuple((s[slot][None], t[slot][None])
+                 for s, t in zip(states, tails))
 
 
 def sown_state(inter, layers: Sequence[str]) -> StatePool:

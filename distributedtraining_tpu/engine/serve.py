@@ -105,9 +105,13 @@ fixed-size recurrent state per SLOT for each other layer, in the shapes
 the config states (engine/kv_pool.py), written whole by a slot's
 prefill, moved on in place by every decode step, handed back at
 ``_release``. The pools ride behind the programs' other arguments; a
-family without them passes nothing there. The prefix cache, the
-speculative lane and KV transfer address state by position and refuse
-such a family (``kv_pool.RECURRENT_STATE_REASON``; docs/serving.md).
+family without them passes nothing there. The prefix cache serves such a
+family from SNAPSHOTS: the state after a registered prompt's last token,
+kept in a pool beside the slot pool, restored into the admitted slot's row
+when a later prompt extends that one, the suffix's prefill continuing from
+it (:class:`PrefixCache`). The speculative lane and KV transfer cannot
+roll a state back or ship it and refuse such a family
+(``kv_pool.RECURRENT_STATE_REASON``; docs/serving.md).
 
 Everything is exposed through the PR-3 obs registry as ``serve.*`` and
 scraped by the PR-5 exporter as ``dt_serve_*`` gauges.
@@ -126,7 +130,7 @@ import re
 import threading
 import time
 import weakref
-from collections import deque
+from collections import OrderedDict, deque
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Sequence
 
@@ -370,21 +374,48 @@ class PrefixCache:
 
     Matching is capped one token short of the prompt on purpose: at
     least one suffix token must run through prefill to produce the
-    request's first next-token logits."""
+    request's first next-token logits.
+
+    For a family that keeps a recurrent state a slot the pages are half
+    of what a prefix is: the state after its last token is the other, and
+    it exists only where a prefill ENDED. With ``snapshot_rows`` the cache
+    also indexes SNAPSHOTS: rows of a pool beside the slot pool
+    (engine/kv_pool.make_snapshot_pool), one state and tail set for every
+    ``"ssm"`` layer, keyed like a partial page by ``(digest of the full
+    pages, the tail's tokens)`` of the registered prompt, so a key says
+    exactly which tokens the state has seen. :meth:`match_state` answers
+    all or nothing: the longest registered prompt that the new one
+    extends, with its pages AND its row, or a miss; a shorter overlap has
+    pages and no state. Rows are LRU like pages, with one refinement: a
+    snapshot that ONE registered prompt has extended is a session's
+    previous turn, which no one asks for again, and goes to the cold end
+    (a second extension marks a prefix that many share, and it stays).
+    Taking such a row back is a RETIREMENT (``snapshots_retired``); only
+    the loss of a snapshot nothing has superseded counts as an EVICTION
+    (``snapshots_evicted``): the number a deployment sizes its pool by."""
 
     ROOT = b"pfx-root"
 
-    def __init__(self, pool: PagePool, page_size: int):
+    def __init__(self, pool: PagePool, page_size: int,
+                 snapshot_rows: int = 0):
         self.pool = pool
         self.P = page_size
         # key = (parent_digest, token_tuple) -> page id; dict order IS
         # the LRU order (hits re-insert at the back)
         self._entries: dict[tuple, int] = {}
         self._kids: dict[bytes, list[tuple]] = {}
+        # snapshots: the same keys -> a row of the snapshot pool, LRU in
+        # dict order; how many registered prompts extended each
+        self.snapshot_rows = snapshot_rows
+        self._snaps: OrderedDict[tuple, int] = OrderedDict()
+        self._snap_kids: dict[bytes, list[tuple]] = {}
+        self._snap_free: list[int] = list(range(snapshot_rows))
+        self._snap_extended: dict[tuple, int] = {}
         self.hits = 0
         self.misses = 0
         self.tokens_saved = 0
         self.pages_shared = 0
+        self.snapshots_evicted = 0
 
     @staticmethod
     def _digest(parent: bytes, tokens: tuple) -> bytes:
@@ -438,14 +469,91 @@ class PrefixCache:
             break   # partial page use is terminal
         return pages, matched
 
-    def register(self, prompt: list, slot_pages: list) -> None:
+    def match_state(self, prompt: list
+                    ) -> tuple[list[int], int, tuple | None]:
+        """For a family with per-slot state: ``(pages, matched tokens,
+        snapshot key)`` of the LONGEST registered prompt that ``prompt``
+        extends by at least one token, whose every page and whose
+        snapshot are still cached; ``([], 0, None)`` otherwise. The last
+        page is partial where the registered prompt ended inside it (the
+        caller copies it before it writes). Takes no references."""
+        P = self.P
+        limit = len(prompt) - 1
+        walked: list[int] = []
+        best: tuple[list[int], int, tuple | None] = ([], 0, None)
+        h = self.ROOT
+        at = 0
+        while True:
+            for key in self._snap_kids.get(h, ()):
+                tail = key[1]
+                end = at + len(tail)
+                if (end <= best[1] or end > limit or key not in self._snaps
+                        or tuple(prompt[at:end]) != tail):
+                    continue
+                if tail and key not in self._entries:
+                    continue        # the tail's page was evicted: no hit
+                best = (walked + ([self._entries[key]] if tail else []),
+                        end, key)
+            key = (h, tuple(prompt[at:at + P]))
+            if at + P > limit or key not in self._entries:
+                break
+            walked.append(self._entries[key])
+            self._touch(key)
+            h = self._digest(h, key[1])
+            at += P
+        if best[2] is not None:
+            if best[2][1]:
+                self._touch(best[2])
+            self._snaps.move_to_end(best[2])
+        return best
+
+    def snapshot_row(self, key: tuple) -> int:
+        return self._snaps[key]
+
+    def take_snapshot_row(self) -> int | None:
+        """A row of the snapshot pool for a prompt about to be
+        registered: a free one, else the least recently used snapshot's
+        (None for a pool of no rows)."""
+        if self._snap_free:
+            return self._snap_free.pop()
+        if not self._snaps:
+            return None
+        key = next(iter(self._snaps))
+        # a snapshot that one registered prompt extended is that prompt's
+        # past (the session moved on and took a new one): RETIRED, no
+        # loss; any other is a prefix someone may still ask for: EVICTED
+        if self._snap_extended.get(key) == 1:
+            obs.count("serve.prefix.snapshots_retired")
+        else:
+            self.snapshots_evicted += 1
+            obs.count("serve.prefix.snapshots_evicted")
+        return self._drop_snapshot(key)
+
+    def _drop_snapshot(self, key: tuple) -> int:
+        row = self._snaps.pop(key)
+        self._snap_extended.pop(key, None)
+        kids = self._snap_kids[key[0]]
+        kids.remove(key)
+        if not kids:
+            del self._snap_kids[key[0]]
+        return row
+
+    def register(self, prompt: list, slot_pages: list,
+                 snapshot_row: int | None = None,
+                 extends: tuple | None = None) -> None:
         """Index a freshly prefilled prompt's pages (full pages by
         chain digest, the partial tail by its token tuple). Each NEW
         entry takes one pool reference; a page already cached under the
         same key is skipped — the identical-prompt case keeps finding
-        the original entry, not the admitting slot's CoW copy."""
+        the original entry, not the admitting slot's CoW copy.
+
+        ``snapshot_row`` (a row from :meth:`take_snapshot_row`, already
+        holding the state after ``prompt``'s last token) is indexed under
+        the prompt's end; ``extends`` is the key of the snapshot this
+        prompt was restored from, if any."""
         P = self.P
         h = self.ROOT
+        toks: tuple = ()
         for i in range(0, len(prompt), P):
             toks = tuple(prompt[i:i + P])
             key = (h, toks)
@@ -459,6 +567,28 @@ class PrefixCache:
             if len(toks) < P:
                 break
             h = self._digest(h, toks)
+            toks = ()
+        if snapshot_row is None:
+            return
+        key = (h, toks)
+        if key in self._snaps:
+            # the same prompt again: the row it holds is this state too
+            self._snap_free.append(self._snaps.pop(key))
+        else:
+            self._snap_kids.setdefault(h, []).append(key)
+        self._snaps[key] = snapshot_row
+        if extends in self._snaps and extends != key:
+            n = self._snap_extended[extends] = (
+                self._snap_extended.get(extends, 0) + 1)
+            if n == 1:
+                # a session's previous turn: the first to go
+                self._snaps.move_to_end(extends, last=False)
+
+    def check(self) -> None:
+        """Every row of the snapshot pool is free or indexed, once."""
+        rows = self._snap_free + list(self._snaps.values())
+        assert sorted(rows) == list(range(self.snapshot_rows)), (
+            f"snapshot rows {sorted(rows)} of {self.snapshot_rows}")
 
     def evict_one(self) -> bool:
         """Drop the least-recently-used entry whose cache reference is
@@ -487,6 +617,10 @@ class PrefixCache:
             self.pool.decref(page)
         self._entries.clear()
         self._kids.clear()
+        self._snap_free += list(self._snaps.values())
+        self._snaps.clear()
+        self._snap_kids.clear()
+        self._snap_extended.clear()
         obs.count("serve.prefix_flushes")
 
 
@@ -604,6 +738,7 @@ _SOWN_COUNTERS = {
     "moe_rows_elsewhere": "serve.moe.rows_elsewhere",
     "ssm_slot_steps": "serve.ssm.slot_steps",
     "gdn_slot_steps": "serve.gdn.slot_steps",
+    "kda_slot_steps": "serve.kda.slot_steps",
 }
 
 
@@ -620,6 +755,15 @@ def _count_sown(stats: dict, per_step: bool = False) -> None:
     if per_step and touched:
         obs.observe("serve.moe.rows_per_expert",
                     int(stats["moe_rows"]) / touched)
+
+
+def _count_chunk(out) -> None:
+    """The counts of a prefill chunk that is not a prompt's last: its pick
+    is no token of the request's, what its layers sowed is counted all
+    the same."""
+    stats = _split_pick(out)[1]
+    if stats is not None and obs.enabled():     # no fetch for nothing
+        _count_sown(jax.device_get(stats))
 
 
 def _layer_keys(params) -> list[str]:
@@ -766,6 +910,8 @@ class GenerationEngine:
                  watcher: BaseRevisionWatcher | None = None,
                  max_queue: int = 0,
                  prefix_cache: bool = False,
+                 snapshot_rows: int = 0,
+                 prefill_chunk: int = 0,
                  debug_invariants: bool = False,
                  draft=None,
                  draft_k: int = 4,
@@ -791,10 +937,10 @@ class GenerationEngine:
             raise ValueError("max_slots and page_size must be >= 1")
         cfg = model.cfg
         cfg = dataclasses.replace(cfg, remat=False, scan_blocks=False)
-        # a recurrent state is not addressed by position: what rests on
-        # positions refuses the family, with the reason
+        # a recurrent state cannot be rolled back or shipped: what needs
+        # either refuses the family, with the reason
         if kv_pool.has_recurrent_state(cfg) and (
-                prefix_cache or draft is not None or kv_exporter is not None
+                draft is not None or kv_exporter is not None
                 or kv_adopter is not None):
             raise ValueError(kv_pool.RECURRENT_STATE_REASON)
         self.model = type(model)(cfg)
@@ -818,6 +964,17 @@ class GenerationEngine:
             raise ValueError(f"max_seq_len {self.max_seq_len} < page_size "
                              f"{page_size}")
         self.pages_per_slot = self.max_seq_len // page_size
+        # the most tokens one prefill program takes: a longer prompt (or
+        # suffix) is prefilled as that many and then as continuations over
+        # its own pages and state, inside its admission. 0: no bound, one
+        # program whatever the length (what every engine did before a
+        # 40k-token prompt met a dense [T, T] attention)
+        if prefill_chunk % page_size:
+            raise ValueError(f"prefill_chunk {prefill_chunk} is no whole "
+                             f"number of pages of {page_size}")
+        self._chunk_pages = min(self.pages_per_slot,
+                                prefill_chunk // page_size
+                                or self.pages_per_slot)
         # page 0 is the TRASH page: padded batch slots and padded
         # page-table entries all point at it, so scatter writes from
         # dead lanes land somewhere harmless
@@ -833,7 +990,7 @@ class GenerationEngine:
                                          prefer_compiled=prefer_compiled)
         self._page_ladder = BucketLadder(self.pages_per_slot,
                                          prefer_compiled=prefer_compiled)
-        self._prefill_ladder = BucketLadder(self.pages_per_slot,
+        self._prefill_ladder = BucketLadder(self._chunk_pages,
                                             prefer_compiled=prefer_compiled)
         self.prefer_compiled = prefer_compiled
 
@@ -866,7 +1023,7 @@ class GenerationEngine:
         # pins never see them)
         self._decode_sample_progs: dict[tuple[int, int], Callable] = {}
         self._prefill_ctx_progs: dict[tuple[int, int], Callable] = {}
-        self._pctx_t_ladder = BucketLadder(self.pages_per_slot,
+        self._pctx_t_ladder = BucketLadder(self._chunk_pages,
                                            prefer_compiled=prefer_compiled)
         self._pctx_p_ladder = BucketLadder(self.pages_per_slot,
                                            prefer_compiled=prefer_compiled)
@@ -910,6 +1067,12 @@ class GenerationEngine:
         self._state_gauge = f"serve.{kv_pool.state_name(cfg)}.state_bytes"
         self._state_free: list[int] = []
         self._state_of: dict[int, int] = {}
+        # the prefix cache's copies of it (kv_pool.make_snapshot_pool): as
+        # many rows as slots unless told; none without the cache
+        self._snapshot_rows = ((snapshot_rows or max_slots)
+                               if self._recurrent and prefix_cache else 0)
+        self._snap: kv_pool.StatePool = ((), ())
+        self._state_copy_progs: dict[str, Callable] = {}
         # the transfer plane's wire is a K/V pair of heads: a model that
         # caches anything else is refused here, with the reason
         self._kv_geom = (kv_pool.kv_head_geometry(cfg)
@@ -997,7 +1160,8 @@ class GenerationEngine:
     def _init_kv(self) -> None:
         self.pool = PagePool(self.pool_pages)
         if self._prefix_cache:
-            self._cache = PrefixCache(self.pool, self.page_size)
+            self._cache = PrefixCache(self.pool, self.page_size,
+                                      self._snapshot_rows)
         if self._recurrent:
             self._state_free = list(range(self.max_slots))
 
@@ -1018,6 +1182,11 @@ class GenerationEngine:
                 self._ssm_bytes = float(sum(
                     x.nbytes for half in self._ssm for x in half))
                 obs.gauge(self._state_gauge, self._ssm_bytes)
+            if self._snapshot_rows:
+                self._snap = kv_pool.make_snapshot_pool(
+                    cfg, len(self._ssm_layers), self._snapshot_rows)
+                obs.gauge("serve.prefix.snapshot_bytes", float(sum(
+                    x.nbytes for half in self._snap for x in half)))
         return self._kv_arrays
 
     @_kv.setter
@@ -1122,6 +1291,34 @@ class GenerationEngine:
     @property
     def prefix_tokens_saved(self) -> int:
         return self._cache.tokens_saved if self._cache is not None else 0
+
+    @property
+    def prefix_snapshots_evicted(self) -> int:
+        return (self._cache.snapshots_evicted if self._cache is not None
+                else 0)
+
+    def flush_prefix_cache(self) -> None:
+        """Forget every cached prefix (pages and snapshots): what a swap
+        of the base does, for an operator who wants it without one."""
+        if self._cache is not None:
+            self._cache.flush()
+
+    def declare_buckets(self, *, prefill_pages: Sequence[int] = (),
+                        suffix_pages: Sequence[int] = (),
+                        table_pages: Sequence[int] = (),
+                        decode_pages: Sequence[int] = ()) -> None:
+        """Name the rungs of the bucket ladders this deployment will
+        compile: the prefill's and the suffix prefill's token buckets (in
+        pages), the suffix prefill's and the decode programs' page-table
+        widths. A need under a declared rung pads up to it as it does to
+        a compiled one (``prefer_compiled``), so the programs that exist
+        are the declared ones, whatever order the requests come in; each
+        is compiled when first met."""
+        for ladder, rungs in ((self._prefill_ladder, prefill_pages),
+                              (self._pctx_t_ladder, suffix_pages),
+                              (self._pctx_p_ladder, table_pages),
+                              (self._page_ladder, decode_pages)):
+            ladder.seen.update(int(r) for r in rungs)
 
     @property
     def speculative(self) -> bool:
@@ -1314,49 +1511,92 @@ class GenerationEngine:
         return prog
 
     def _prefill_ctx_prog(self, t_bucket: int, pb: int) -> Callable:
-        """Suffix prefill over shared context: the prefix cache mapped
-        ``ctx_len`` prompt tokens to cached KV pages, so only the
-        divergent tail runs the model — ``t_bucket`` fresh tokens
-        attend the paged context (the model's ``kv_pages`` hook; Tq>1
-        rides the XLA reference path of ops/paged_attention.py) and
-        their kv scatters into this slot's pages at arbitrary offsets
-        (padded tail rows land on trash page 0)."""
+        """Suffix prefill over cached context: ``ctx_len`` prompt tokens
+        already lie in this slot's pages (the prefix cache mapped them,
+        or this prompt's earlier chunk wrote them), so only the tail runs
+        the model — ``t_bucket`` fresh tokens attend the paged context
+        (the model's ``kv_pages`` hook; Tq>1 rides the XLA paths of
+        ops/paged_attention.py) and their kv scatters into this slot's
+        pages at arbitrary offsets (padded tail rows land on trash page
+        0). A family with per-slot state passes its pools and the slot's
+        row behind: the recurrent layers CONTINUE from the row (restored
+        from a snapshot, or left by the chunk before) and the row is
+        written with the state after the suffix."""
         prog = self._prefill_ctx_progs.get((t_bucket, pb))
         if prog is not None:
             return prog
         model, P, vocab = self.model, self.page_size, self.cfg.vocab_size
-        layers = self._layers
+        layers, kv_layers, ssm_layers = (self._layers, self._kv_layers,
+                                         self._ssm_layers)
         cap = self.max_seq_len
 
         def serve_prefill_ctx(params, tokens, ctx_len, suffix_len,
-                              k_pages, v_pages, page_table):
+                              k_pages, v_pages, page_table, *slot_state):
             kv_pages = tuple(zip(k_pages, v_pages))
             pos = ctx_len + jnp.arange(t_bucket)
+            valid = jnp.arange(t_bucket) < suffix_len
+            # the recurrent layers must know which rows are padding (a
+            # pad row would move the state); a family without them is
+            # told nothing, as before
+            state = dict(
+                attention_mask=valid.astype(jnp.int32)[None, :],
+                ssm_init=kv_pool.slot_state_rows(*slot_state)
+            ) if slot_state else {}
             logits, muts = model.apply(
                 {"params": params}, tokens,
                 position_ids=jnp.minimum(pos, cap - 1)[None, :],
                 kv_pages=kv_pages, page_tables=page_table,
                 kv_lens=jnp.reshape(ctx_len, (1,)),
-                sow_kv=True, mutable=["intermediates"])
-            valid = jnp.arange(t_bucket) < suffix_len
+                sow_kv=True, mutable=["intermediates"], **state)
             page_idx = jnp.where(
                 valid, page_table[0, jnp.minimum(pos // P, pb - 1)], 0)
             k_pages, v_pages = kv_pool.write_rows(
-                k_pages, v_pages, muts["intermediates"], layers,
+                k_pages, v_pages, muts["intermediates"], kv_layers,
                 page_idx[None, :], (pos % P)[None, :])
+            moved = (kv_pool.write_slot_state(
+                *slot_state[:2], muts["intermediates"], ssm_layers,
+                slot_state[2]),) if slot_state else ()
             row = logits[0, suffix_len - 1, :vocab]
             nxt = jnp.argmax(row)
             return (_with_stats(nxt.astype(jnp.int32),
                                 muts["intermediates"], layers),
-                    row, k_pages, v_pages)
+                    row, k_pages, v_pages, *moved)
 
         prog = devprof.wrap(
             "serve.prefill_ctx",
-            jax.jit(serve_prefill_ctx,
-                    donate_argnums=(4, 5) if self._donate else ()),
+            jax.jit(serve_prefill_ctx, donate_argnums=self._donated(4, 7)),
             bucket=f"{t_bucket}x{pb}")
         self._prefill_ctx_progs[(t_bucket, pb)] = prog
         return prog
+
+    def _copy_state_row(self, which: str, src_row: int, dst_row: int
+                        ) -> None:
+        """``"restore"``: row ``src_row`` of the snapshot pool over row
+        ``dst_row`` of the slot pool (a hit's admission);
+        ``"snapshot"``: the slot's row over the snapshot's (a prompt's
+        registration). One program each way, the written pool donated."""
+        prog = self._state_copy_progs.get(which)
+        fresh = prog is None
+        if fresh:
+            def serve_state_copy(d_states, d_tails, s_states, s_tails,
+                                 src, dst):
+                return kv_pool.copy_state_row(
+                    (d_states, d_tails), (s_states, s_tails), src, dst)
+
+            prog = self._state_copy_progs[which] = devprof.wrap(
+                f"serve.state_{which}",
+                jax.jit(serve_state_copy,
+                        donate_argnums=(0, 1) if self._donate else ()),
+                bucket=1)
+        dst, src = ((self._ssm, self._snap) if which == "restore"
+                    else (self._snap, self._ssm))
+        args = (*dst, *src, np.int32(src_row), np.int32(dst_row))
+        with obs.phase(f"serve.prefix.{which}"):
+            out = _timed_compile(prog, *args) if fresh else prog(*args)
+        if which == "restore":
+            self._ssm = out
+        else:
+            self._snap = out
 
     def _verify_prog(self, n_slots: int, n_pages: int) -> Callable:
         """The speculative verify pass: score W = draft_k + 1 positions
@@ -1701,8 +1941,13 @@ class GenerationEngine:
             # served revision)
         shared: list[int] = []
         matched = 0
+        snap_key = None
         if self._cache is not None:
-            shared, matched = self._cache.match(list(req.prompt))
+            if self._recurrent:
+                shared, matched, snap_key = self._cache.match_state(
+                    list(req.prompt))
+            else:
+                shared, matched = self._cache.match(list(req.prompt))
             if matched:
                 for p in shared:
                     self.pool.incref(p)
@@ -1748,6 +1993,14 @@ class GenerationEngine:
             else:
                 self.trace.stage(req.rid, "admit",
                                  queue_age_ms=queue_age_ms)
+        if self._recurrent:
+            # the request's row of the state pools, its own until
+            # _release; a prefill overwrites whatever it held
+            row = self._state_of[req.rid] = self._state_free.pop()
+            if snap_key is not None:
+                self._copy_state_row(
+                    "restore", self._cache.snapshot_row(snap_key), row)
+                obs.count("serve.prefix.snapshots_restored")
         # to completion: both return the first token ON THE HOST, and the
         # `serve.prefill` span's time around it
         if matched:
@@ -1755,13 +2008,24 @@ class GenerationEngine:
         else:
             tok, dur_ms = self._prefill(req, pages)
         obs.count("serve.prefills")
+        obs.count("serve.prefill_tokens", plen - matched)
         self.prefills_done += 1
         if self.trace is not None:
             self.trace.stage(req.rid, "prefill", pfx_hit=int(matched > 0),
                              pfx_tokens=matched, prompt_tokens=plen,
                              dur_ms=round(dur_ms, 3))
-        if self._cache is not None and not matched:
-            self._cache.register(list(req.prompt), pages)
+        if self._cache is not None:
+            # a prompt that HIT is registered too: its new pages extend
+            # the chain, so a session gains on every turn, not on one
+            snap_row = None
+            if self._recurrent:
+                snap_row = self._cache.take_snapshot_row()
+                if snap_row is not None:
+                    self._copy_state_row(
+                        "snapshot", self._state_of[req.rid], snap_row)
+                    obs.count("serve.prefix.snapshots_taken")
+            self._cache.register(list(req.prompt), pages, snap_row,
+                                 snap_key)
         self._activate(req, pages, tok)
         return True
 
@@ -1902,29 +2166,32 @@ class GenerationEngine:
     def _prefill(self, req: ServeRequest, pages: list) -> tuple[int, float]:
         """Full prefill. Returns the first token and the milliseconds of
         its `serve.prefill` span: input build, dispatch and the token's
-        arrival on the host."""
+        arrival on the host. A prompt longer than the top prefill bucket
+        (``prefill_chunk``) runs that many tokens here and the rest as
+        continuations over its own pages and state."""
         P = self.page_size
         plen = len(req.prompt)
+        head = min(plen, self._chunk_pages * P)
         t_bucket = self._prefill_ladder.bucket_for(
-            (plen + P - 1) // P) * P
+            (head + P - 1) // P) * P
         with obs.phase("serve.prefill", timed=True, rid=req.rid,
                        bucket=t_bucket) as ph:
             mp = t_bucket // P
             toks = np.zeros((1, t_bucket), np.int32)
-            toks[0, :plen] = req.prompt
+            toks[0, :head] = req.prompt[:head]
             page_row = np.zeros((mp,), np.int32)
             row = pages[:mp]
             page_row[:len(row)] = row
+            # a declared rung (declare_buckets) is seen before it is built
+            fresh = t_bucket not in self._prefill_progs
             prog = self._prefill_prog(t_bucket)
             k_pages, v_pages = self._kv
-            args = (self._params, toks, np.int32(plen), k_pages, v_pages,
+            args = (self._params, toks, np.int32(head), k_pages, v_pages,
                     page_row)
             if self._recurrent:
-                # the request's row of the state pools, its own until
-                # _release; the prefill overwrites whatever it held
-                row = self._state_of[req.rid] = self._state_free.pop()
-                args += self._slot_state(np.int32(row))
-            if self._prefill_ladder.mark(t_bucket // P):
+                args += self._slot_state(np.int32(self._state_of[req.rid]))
+            self._prefill_ladder.mark(t_bucket // P)
+            if fresh:
                 obs.count("serve.prefill_bucket_compiles")
                 nxt, logit_row, k_pages, v_pages, *moved = _timed_compile(
                     prog, *args)
@@ -1933,42 +2200,68 @@ class GenerationEngine:
             self._kv = (k_pages, v_pages)
             if moved:
                 self._ssm = moved[0]
+            if head < plen:
+                _count_chunk(nxt)
+                nxt, logit_row = self._prefill_rest(req, pages, head)
             tok = self._first_token(req, nxt, logit_row)
         return tok, ph.dur_ms
+
+    def _prefill_rest(self, req: ServeRequest, pages: list, ctx_len: int
+                      ) -> tuple:
+        """The prompt's tokens from ``ctx_len`` on, a chunk at a time
+        through ``serve.prefill_ctx``, each over the pages (and from the
+        state row) the one before it wrote. Returns the LAST chunk's pick
+        and logits row as they lie on the device."""
+        P = self.page_size
+        plen = len(req.prompt)
+        pb = self._pctx_p_ladder.bucket_for(plen // P + 1)
+        self._pctx_p_ladder.mark(pb)
+        table = np.zeros((1, pb), np.int32)
+        table[0, :len(pages)] = pages
+        while True:
+            suffix = min(plen - ctx_len, self._chunk_pages * P)
+            t_bucket = self._pctx_t_ladder.bucket_for(
+                (suffix + P - 1) // P) * P
+            self._pctx_t_ladder.mark(t_bucket // P)
+            toks = np.zeros((1, t_bucket), np.int32)
+            toks[0, :suffix] = req.prompt[ctx_len:ctx_len + suffix]
+            prog = self._prefill_ctx_prog(t_bucket, pb)
+            k_pages, v_pages = self._kv
+            args = (self._params, toks, np.int32(ctx_len), np.int32(suffix),
+                    k_pages, v_pages, table)
+            if self._recurrent:
+                args += self._slot_state(np.int32(self._state_of[req.rid]))
+            if (t_bucket, pb) not in self._pctx_seen:
+                self._pctx_seen.add((t_bucket, pb))
+                obs.count("serve.prefill_bucket_compiles")
+                nxt, logit_row, k_pages, v_pages, *moved = _timed_compile(
+                    prog, *args)
+            else:
+                nxt, logit_row, k_pages, v_pages, *moved = prog(*args)
+            self._kv = (k_pages, v_pages)
+            if moved:
+                self._ssm = moved[0]
+            ctx_len += suffix
+            if ctx_len >= plen:
+                return nxt, logit_row
+            _count_chunk(nxt)
 
     def _prefill_shared(self, req: ServeRequest, pages: list,
                         ctx_len: int) -> tuple[int, float]:
         """Suffix prefill: ``ctx_len`` prompt tokens already live in
-        shared cache pages; only the tail runs the model. Returns what
-        ``_prefill`` does."""
+        shared cache pages (and, for a family with per-slot state, the
+        state after them in the slot's row); only the tail runs the
+        model. Returns what ``_prefill`` does."""
         P = self.page_size
         plen = len(req.prompt)
-        suffix = plen - ctx_len
-        t_bucket = self._pctx_t_ladder.bucket_for(
-            (suffix + P - 1) // P) * P
-        pb = self._pctx_p_ladder.bucket_for(plen // P + 1)
-        with obs.phase("serve.prefill", timed=True, rid=req.rid,
-                       bucket=t_bucket, ctx_pages=pb) as ph:
-            toks = np.zeros((1, t_bucket), np.int32)
-            toks[0, :suffix] = req.prompt[ctx_len:]
-            table = np.zeros((1, pb), np.int32)
-            table[0, :len(pages)] = pages
-            prog = self._prefill_ctx_prog(t_bucket, pb)
-            k_pages, v_pages = self._kv
-            key = (t_bucket, pb)
-            self._pctx_t_ladder.mark(t_bucket // P)
-            self._pctx_p_ladder.mark(pb)
-            if key not in self._pctx_seen:
-                self._pctx_seen.add(key)
-                obs.count("serve.prefill_bucket_compiles")
-                nxt, logit_row, k_pages, v_pages = _timed_compile(
-                    prog, self._params, toks, np.int32(ctx_len),
-                    np.int32(suffix), k_pages, v_pages, table)
-            else:
-                nxt, logit_row, k_pages, v_pages = prog(
-                    self._params, toks, np.int32(ctx_len), np.int32(suffix),
-                    k_pages, v_pages, table)
-            self._kv = (k_pages, v_pages)
+        first = min(plen - ctx_len, self._chunk_pages * P)
+        with obs.phase(
+                "serve.prefill", timed=True, rid=req.rid,
+                bucket=self._pctx_t_ladder.bucket_for((first + P - 1) // P)
+                * P,
+                ctx_pages=self._pctx_p_ladder.bucket_for(plen // P + 1)
+                ) as ph:
+            nxt, logit_row = self._prefill_rest(req, pages, ctx_len)
             tok = self._first_token(req, nxt, logit_row)
         return tok, ph.dur_ms
 
@@ -2436,6 +2729,8 @@ class GenerationEngine:
             for p in self._cache.pages():
                 expected[p] = expected.get(p, 0) + 1
         self.pool.check(expected)
+        if self._cache is not None:
+            self._cache.check()
         assert (len(self._state_free) + len(self._state_of)
                 == (self.max_slots if self._recurrent else 0)), \
             "a row of the per-slot state pools is neither free nor owned"

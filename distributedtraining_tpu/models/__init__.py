@@ -17,6 +17,10 @@
   per-slot state and a latent page pool in one engine), routed + shared
   SwiGLU experts of which a chip holds its share (the
   GigaChat3.5-432B-A28B row), on the serving path.
+- solar_open2: per-channel gated delta-rule layers (Kimi Delta Attention)
+  beside gated grouped-query attention with no position term, every FFN
+  routed + shared SwiGLU experts of which a chip holds its share (the
+  Solar-Open2-250B row), on the serving path.
 - lora: low-rank adapter trees whose *parameters are the delta*.
 """
 
@@ -31,8 +35,9 @@ def family_of(preset: str):
     preset's name; GPT-2's, whose lookup then names the unknown preset,
     where none does."""
     from . import (deepseek_v3, gigachat3_5, gpt2, lfm2_moe, llama,
-                   nemotron_h)
-    for family in (llama, deepseek_v3, nemotron_h, lfm2_moe, gigachat3_5):
+                   nemotron_h, solar_open2)
+    for family in (llama, deepseek_v3, nemotron_h, lfm2_moe, gigachat3_5,
+                   solar_open2):
         if preset in family.PRESETS:
             return family
     return gpt2

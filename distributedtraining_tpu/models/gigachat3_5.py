@@ -346,14 +346,16 @@ class GigaChat35Block(nn.Module):
     @nn.compact
     def __call__(self, x, attention_mask, segment_ids, position_ids, live,
                  live_len, kv_lens=None, sow_kv=False, kv_pages=None,
-                 page_tables=None, ssm_pools=None, slots=None):
+                 page_tables=None, ssm_pools=None, slots=None,
+                 ssm_init=None):
         cfg = self.cfg
         h = _norm(cfg, "pre_mixer_norm")(x)
         if self.full_attention:
             y = self._latent(h, attention_mask, segment_ids, position_ids,
                              kv_lens, sow_kv, kv_pages, page_tables)
         else:
-            y = self._delta(h, live_len, kv_lens, sow_kv, ssm_pools, slots)
+            y = self._delta(h, live_len, kv_lens, sow_kv, ssm_pools, slots,
+                            ssm_init)
         x = x + _norm(cfg, "post_mixer_norm")(y)
         h = _norm(cfg, "pre_ffn_norm")(x)
         if self.routed:
@@ -363,7 +365,8 @@ class GigaChat35Block(nn.Module):
                         ("gate_proj", "up_proj", "down_proj"), cfg)
         return x + _norm(cfg, "post_ffn_norm")(y)
 
-    def _delta(self, u, live_len, kv_lens, sow_kv, ssm_pools, slots):
+    def _delta(self, u, live_len, kv_lens, sow_kv, ssm_pools, slots,
+               ssm_init=None):
         cfg = self.cfg
         B, T, E = u.shape
         Hv, dk, dv = cfg.ssm_state_shape
@@ -398,10 +401,17 @@ class GigaChat35Block(nn.Module):
 
         if ssm_pools is None:
             with jax.named_scope("gdn.prefill"):
-                conv, tail = ssm.causal_conv1d(qkv, conv_w, None, live_len)
+                # from zero, or from what the sequence's earlier part left
+                s0, tail0 = (None, None) if ssm_init is None else ssm_init
+                # `tail0` is named only when there is one: the fault
+                # injectors of benchmarks/tools swap in a
+                # `causal_conv1d` of the older signature
+                conv, tail = ssm.causal_conv1d(
+                    qkv, conv_w, None, live_len,
+                    **({} if tail0 is None else {"tail0": tail0}))
                 q, k, v = split(conv)
                 o, state = delta_rule.delta_rule_prefill(
-                    q, k, v, g, beta, live_len, chunk=cfg.chunk_size)
+                    q, k, v, g, beta, live_len, s0, chunk=cfg.chunk_size)
             if sow_kv:
                 # the whole of what this layer keeps for the sequence
                 self.sow("intermediates", "ssm_cache", (state, tail))
@@ -516,15 +526,17 @@ class GigaChat35(nn.Module):
                  position_ids=None, deterministic: bool = True,
                  return_hidden: bool = False, kv_lens=None,
                  sow_kv: bool = False, kv_pages=None, page_tables=None,
-                 ssm_pools=None, slots=None):
+                 ssm_pools=None, slots=None, ssm_init=None):
         """The serving hooks are nemotron_h.NemotronH.__call__'s:
         ``kv_pages`` one pair for each full-attention layer, in layer
         order (here the LATENT pair), ``ssm_pools`` one ``(states,
         tails)`` pair for each linear layer, ``slots`` [B] the pools' rows
         this step moves on by one token; the moved pools are sown back
         under ``ssm_cache``. Without them a linear layer starts from a
-        zero state and sows the state after the last live position
-        (``attention_mask`` says which are live)."""
+        zero state, or from ``ssm_init`` (one ``(state, tail)`` pair a
+        linear layer: what the sequence's earlier part left), and sows the
+        state after the last live position (``attention_mask`` says which
+        are live)."""
         del deterministic
         cfg = self.cfg
         B, T = input_ids.shape
@@ -549,15 +561,20 @@ class GigaChat35(nn.Module):
         n_kv = n_ssm = 0
         for i in range(cfg.num_hidden_layers):
             full = i in cfg.full_attention_layers
-            pages = pools = None
+            pages = pools = init = None
             if full and kv_pages is not None:
                 pages, n_kv = kv_pages[n_kv], n_kv + 1
-            if not full and ssm_pools is not None:
-                pools, n_ssm = ssm_pools[n_ssm], n_ssm + 1
+            if not full:
+                if ssm_pools is not None:
+                    pools = ssm_pools[n_ssm]
+                if ssm_init is not None:
+                    init = ssm_init[n_ssm]
+                n_ssm += 1
             x = GigaChat35Block(cfg, full, i >= cfg.first_k_dense_replace,
                                 name=f"layer_{i}")(
                 x, attention_mask, segment_ids, position_ids, live,
-                live_len, kv_lens, sow_kv, pages, page_tables, pools, slots)
+                live_len, kv_lens, sow_kv, pages, page_tables, pools, slots,
+                init)
         x = _norm(cfg, "norm")(x)
         if return_hidden:
             return x

@@ -265,16 +265,17 @@ class NemotronHBlock(nn.Module):
     @nn.compact
     def __call__(self, x, attention_mask, segment_ids, live, live_len,
                  kv_lens=None, sow_kv=False, kv_pages=None,
-                 page_tables=None, ssm_pools=None, slots=None):
+                 page_tables=None, ssm_pools=None, slots=None,
+                 ssm_init=None):
         h = _norm(self.cfg, "norm")(x)
         mixer = {"M": self._mamba, "*": self._attention,
                  "E": self._experts}[self.kind]
         return x + mixer(h, attention_mask, segment_ids, live, live_len,
                          kv_lens, sow_kv, kv_pages, page_tables, ssm_pools,
-                         slots)
+                         slots, ssm_init)
 
     def _mamba(self, u, _mask, _seg, _live, live_len, kv_lens, sow_kv,
-               _pages, _tables, ssm_pools, slots):
+               _pages, _tables, ssm_pools, slots, ssm_init):
         cfg = self.cfg
         B, T, E = u.shape
         H, P, N = cfg.ssm_state_shape
@@ -304,9 +305,16 @@ class NemotronHBlock(nn.Module):
 
         if ssm_pools is None:
             with jax.named_scope("ssm.prefill"):
-                conv, tail = ssm.causal_conv1d(xbc, conv_w, conv_b, live_len)
+                # from zero, or from what the sequence's earlier part left
+                h0, tail0 = (None, None) if ssm_init is None else ssm_init
+                # `tail0` is named only when there is one: the fault
+                # injectors of benchmarks/tools swap in a
+                # `causal_conv1d` of the older signature
+                conv, tail = ssm.causal_conv1d(
+                    xbc, conv_w, conv_b, live_len,
+                    **({} if tail0 is None else {"tail0": tail0}))
                 xs, b, c = split(conv)
-                y, state = ssm.ssd_prefill(xs, dt, A, b, c, D, live_len,
+                y, state = ssm.ssd_prefill(xs, dt, A, b, c, D, live_len, h0,
                                            chunk=cfg.chunk_size)
             if sow_kv:
                 # the whole of what this layer keeps for the sequence
@@ -334,7 +342,8 @@ class NemotronHBlock(nn.Module):
         return _dense(E, "out_proj", ("mlp", "embed"), cfg)(y)
 
     def _attention(self, h, attention_mask, segment_ids, _live, _live_len,
-                   kv_lens, sow_kv, kv_pages, page_tables, _pools, _slots):
+                   kv_lens, sow_kv, kv_pages, page_tables, _pools, _slots,
+                   _init):
         cfg = self.cfg
         B, T, E = h.shape
         Hq, Hkv, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
@@ -358,7 +367,7 @@ class NemotronHBlock(nn.Module):
             attn.reshape(B, T, Hq * Dh))
 
     def _experts(self, h, _mask, _seg, live, _live_len, _lens, sow_kv,
-                 _pages, _tables, _pools, _slots):
+                 _pages, _tables, _pools, _slots, _init):
         cfg = self.cfg
         B, T, E = h.shape
         cdt = cfg.compute_dtype()
@@ -402,7 +411,7 @@ class NemotronH(nn.Module):
                  position_ids=None, deterministic: bool = True,
                  return_hidden: bool = False, kv_lens=None,
                  sow_kv: bool = False, kv_pages=None, page_tables=None,
-                 ssm_pools=None, slots=None):
+                 ssm_pools=None, slots=None, ssm_init=None):
         """The serving hooks are gpt2.GPT2.__call__'s (``sow_kv`` sows
         each layer's fresh cache, ``kv_pages``/``page_tables``/``kv_lens``
         attend over the paged cache: one pair for each ``*`` layer, in
@@ -410,8 +419,10 @@ class NemotronH(nn.Module):
         ``(states, tails)`` pair for each ``M`` layer, in layer order) and
         ``slots`` [B], the pools' rows this step moves on by one token;
         the moved pools are sown back under ``ssm_cache``. Without them
-        an ``M`` layer starts from a zero state and sows the state after
-        the last live position (``attention_mask`` says which are live).
+        an ``M`` layer starts from a zero state, or from ``ssm_init`` (one
+        ``(state, tail)`` pair an ``M`` layer: what the sequence's earlier
+        part left), and sows the state after the last live position
+        (``attention_mask`` says which are live).
         ``position_ids`` is taken and not read: nothing here is
         positional."""
         del position_ids, deterministic
@@ -435,14 +446,18 @@ class NemotronH(nn.Module):
         x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
         n_kv = n_ssm = 0
         for i, kind in enumerate(cfg.hybrid_override_pattern):
-            pages = pools = None
+            pages = pools = init = None
             if kind == "*" and kv_pages is not None:
                 pages, n_kv = kv_pages[n_kv], n_kv + 1
-            if kind == "M" and ssm_pools is not None:
-                pools, n_ssm = ssm_pools[n_ssm], n_ssm + 1
+            if kind == "M":
+                if ssm_pools is not None:
+                    pools = ssm_pools[n_ssm]
+                if ssm_init is not None:
+                    init = ssm_init[n_ssm]
+                n_ssm += 1
             x = NemotronHBlock(cfg, kind, name=f"layer_{i}")(
                 x, attention_mask, segment_ids, live, live_len, kv_lens,
-                sow_kv, pages, page_tables, pools, slots)
+                sow_kv, pages, page_tables, pools, slots, init)
         x = _norm(cfg, "norm_f")(x)
         if return_hidden:
             return x

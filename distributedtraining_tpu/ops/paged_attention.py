@@ -326,6 +326,84 @@ def paged_decode_reference(q: jax.Array, k_pages: jax.Array,
     return cached_attention(q, k_full, v_full, seq_lens)
 
 
+# a suffix (Tq > 1) over a paged context of at least this many positions
+# attends it a block at a time (:func:`paged_suffix_attention`): the
+# gathered spelling builds ``[B, Hq, Tq, S]`` float32 scores, 1 GiB at 64
+# heads x 512 rows x 8,192 positions. Every cell served before the blocked
+# spelling existed stays under it (their contexts are <= 4,096)
+BLOCKED_MIN_CONTEXT = 8192
+CONTEXT_BLOCK = 512      # positions gathered and attended a loop step
+
+
+def paged_suffix_attention(q: jax.Array, k_pages: jax.Array,
+                           v_pages: jax.Array, page_tables: jax.Array,
+                           seq_lens: jax.Array, k_new: jax.Array,
+                           v_new: jax.Array, *,
+                           block: int = CONTEXT_BLOCK) -> jax.Array:
+    """``Tq`` fresh tokens a row over a LONG paged context, in XLA: the
+    mask semantics of :func:`paged_decode_reference` (context positions
+    below ``seq_lens[b]``, the fresh rows causal among themselves), with
+    no ``[Tq, context]`` tensor: a loop gathers ``block`` positions' pages
+    at a time, scores them a K/V head's query group at once (``[B, Hkv,
+    G, Tq, block]`` float32, GQA never broadcast) and folds them into a
+    running float32 online softmax; the fresh rows are the last block.
+    The loop runs only as far as the longest row's context, so a page
+    table padded to the top of the ladder costs nothing for a short one."""
+    B, Tq, Hq, D = q.shape
+    _, P, HD = k_pages.shape
+    Hkv = HD // D
+    G, MP = Hq // Hkv, page_tables.shape[1]
+    bp = max(1, min(block // P, MP))                  # pages a loop step
+    f32 = jnp.float32
+    qg = (q.reshape(B, Tq, Hkv, G, D).astype(f32) * D ** -0.5)
+
+    def fold(carry, k_blk, v_blk, valid):
+        """k_blk, v_blk [B, S, Hkv, D]; valid [B, Tq, S]."""
+        m, l, acc = carry                             # [B,Hkv,G,Tq](,D)
+        s = jnp.einsum("bqhgd,bshd->bhgqs", qg, k_blk.astype(f32),
+                       preferred_element_type=f32)
+        ok = valid[:, None, None]
+        s = jnp.where(ok, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        pv = jnp.einsum("bhgqs,bshd->bhgqd", p.astype(v_blk.dtype), v_blk,
+                        preferred_element_type=f32)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1),
+                acc * alpha[..., None] + pv)
+
+    def in_context(pos):
+        """pos [S] the positions of a context block -> valid [B, Tq, S]."""
+        return jnp.broadcast_to(
+            (pos[None, :] < seq_lens[:, None])[:, None, :],
+            (B, Tq, pos.shape[0]))
+
+    def context_block(i, carry):
+        pages = jax.lax.dynamic_slice_in_dim(page_tables, i * bp, bp, axis=1)
+        k_blk = k_pages[pages].reshape(B, bp * P, Hkv, D)
+        v_blk = v_pages[pages].reshape(B, bp * P, Hkv, D)
+        return fold(carry, k_blk, v_blk,
+                    in_context(i * bp * P + jnp.arange(bp * P)))
+
+    carry = (jnp.full((B, Hkv, G, Tq), NEG_INF, f32),
+             jnp.zeros((B, Hkv, G, Tq), f32),
+             jnp.zeros((B, Hkv, G, Tq, D), f32))
+    n_blocks = jnp.minimum(-(-jnp.max(seq_lens) // (bp * P)), MP // bp)
+    carry = jax.lax.fori_loop(0, n_blocks, context_block, carry)
+    if MP % bp:
+        # the table's last pages, where it is no whole number of blocks
+        tail = page_tables[:, MP - MP % bp:]
+        carry = fold(
+            carry, k_pages[tail].reshape(B, -1, Hkv, D),
+            v_pages[tail].reshape(B, -1, Hkv, D),
+            in_context((MP - MP % bp) * P + jnp.arange((MP % bp) * P)))
+    causal = jnp.broadcast_to(jnp.tril(jnp.ones((Tq, Tq), bool))[None],
+                              (B, Tq, Tq))
+    m, l, acc = fold(carry, k_new, v_new, causal)
+    out = (acc / l[..., None]).astype(v_new.dtype)    # [B,Hkv,G,Tq,D]
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, Tq, Hq, D)
+
+
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     page_tables: jax.Array, seq_lens: jax.Array,
                     k_new: jax.Array, v_new: jax.Array) -> jax.Array:
@@ -333,9 +411,16 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     at a shape its layout supports, the XLA reference otherwise —
     identical numerics either way (parity pinned in
     tests/test_paged_attention.py and
-    tests_tpu/test_paged_attention_tpu.py)."""
+    tests_tpu/test_paged_attention_tpu.py). A suffix (``Tq > 1``) over a
+    table of :data:`BLOCKED_MIN_CONTEXT` positions or more attends it in
+    blocks (:func:`paged_suffix_attention`): a rule over the shape, so a
+    short-context engine runs what it ran."""
     if _on_tpu() and kernel_supports(q, k_pages):
         return paged_decode_attention(q, k_pages, v_pages, page_tables,
+                                      seq_lens, k_new, v_new)
+    if (q.shape[1] > 1 and page_tables.shape[1] * k_pages.shape[1]
+            >= BLOCKED_MIN_CONTEXT):
+        return paged_suffix_attention(q, k_pages, v_pages, page_tables,
                                       seq_lens, k_new, v_new)
     return paged_decode_reference(q, k_pages, v_pages, page_tables,
                                   seq_lens, k_new, v_new)

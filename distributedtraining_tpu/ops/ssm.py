@@ -56,9 +56,13 @@ def _on_tpu() -> bool:
 
 def causal_conv1d(u: jax.Array, w: jax.Array, b: jax.Array | None,
                   live_len: jax.Array | None,
-                  segment_ids: jax.Array | None = None
+                  segment_ids: jax.Array | None = None,
+                  tail0: jax.Array | None = None
                   ) -> tuple[jax.Array, jax.Array | None]:
-    """Depthwise causal convolution from a zero history. ``u`` [B, T, C],
+    """Depthwise causal convolution from a zero history, or from the
+    ``K - 1`` rows ``tail0`` [B, K-1, C] that an earlier part of the same
+    sequence left (a prefill that CONTINUES: the tail it returns is then
+    the whole sequence's). ``u`` [B, T, C],
     ``w`` [K, C] (``w[K-1]`` multiplies the current row), ``b`` [C] or
     None (no bias), ``live_len`` [B] -> (``conv(u) + b`` [B, T, C] float32,
     the tail: the ``K - 1`` rows of ``u`` before position ``live_len``
@@ -73,7 +77,8 @@ def causal_conv1d(u: jax.Array, w: jax.Array, b: jax.Array | None,
     row."""
     K = w.shape[0]
     T = u.shape[1]
-    padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
+    padded = (jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0))) if tail0 is None
+              else jnp.concatenate([tail0.astype(u.dtype), u], axis=1))
     w32 = w.astype(jnp.float32)
     out = 0.0 if b is None else b.astype(jnp.float32)
     for k in range(K):

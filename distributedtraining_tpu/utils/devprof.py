@@ -108,6 +108,12 @@ PROGRAMS: dict[str, str] = {
     "serve.page_copy": "whole-page KV copy — the copy-on-write "
                        "primitive behind prefix sharing "
                        "(engine/serve.py)",
+    "serve.state_restore": "prefix-cache hit of a family with per-slot "
+                           "state: one snapshot row copied over the "
+                           "admitted slot's row (engine/serve.py)",
+    "serve.state_snapshot": "registration of such a family's prompt: "
+                            "the slot's row copied over a snapshot row "
+                            "(engine/serve.py)",
     "serve.kv_adopt": "adopted-KV page write on a decode worker — "
                       "scatter one fetched [L,P,Hkv,D] page pair into "
                       "the pool (disaggregated serving; "

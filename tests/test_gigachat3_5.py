@@ -345,10 +345,14 @@ def test_the_selection_bias_is_balanced_and_the_programs_is_the_references(
 
 # -- who refuses, who takes it ----------------------------------------------
 
-def test_prefix_cache_drafter_and_kv_export_refuse_with_the_sentence(tiny):
+def test_drafter_and_kv_export_refuse_with_the_sentence(tiny):
     model, pc, params, _, _ = tiny
     gmodel, gcfg = gpt2.make_model("tiny")
-    for kw in ({"prefix_cache": True}, {"draft": object()},
+    # the prefix cache is no longer among them: it keeps snapshots of the
+    # state (tests/test_prefix_state.py)
+    serve.GenerationEngine(model, params, max_slots=2, page_size=8,
+                           max_seq_len=32, prefix_cache=True)
+    for kw in ({"draft": object()},
                {"phase": "prefill", "kv_exporter": object()},
                {"phase": "decode", "kv_adopter": object()}):
         with pytest.raises(ValueError) as err:

@@ -219,3 +219,64 @@ def test_model_kv_pages_matches_kv_ctx_gpt2():
                                    atol=1e-6)
         np.testing.assert_allclose(np.asarray(vp_s), np.asarray(vg_s),
                                    atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# A suffix over a LONG paged context: blocks, no [Tq, context] tensor
+# ---------------------------------------------------------------------------
+
+def _suffix_case(B, Tq, Hq, Hkv, D, P, MP, lens, seed=0):
+    q, kp, vp, pt, sl, _, _ = _case(B, Hq, Hkv, D, P, MP, lens, seed=seed)
+    rng = np.random.default_rng(seed + 1)
+    q = jnp.asarray(rng.standard_normal((B, Tq, Hq, D)), jnp.float32)
+    kn = jnp.asarray(rng.standard_normal((B, Tq, Hkv, D)), jnp.float32)
+    vn = jnp.asarray(rng.standard_normal((B, Tq, Hkv, D)), jnp.float32)
+    return q, kp, vp, pt, sl, kn, vn
+
+
+@pytest.mark.parametrize("MP, block, lens", [
+    (8, 16, [37, 3]),       # whole blocks, one row's context ends early
+    (7, 16, [56, 20]),      # the table is no whole number of blocks
+    (4, 64, [32, 1]),       # one block holds the whole table
+    (6, 8, [0, 48]),        # no context at all on a row
+])
+def test_blocked_suffix_attention_is_the_gathered_reference(MP, block, lens):
+    args = _suffix_case(2, 5, 8, 2, 16, 8, MP, lens, seed=MP)
+    ref = pa.paged_decode_reference(*args)
+    out = pa.paged_suffix_attention(*args, block=block)
+    assert float(jnp.max(jnp.abs(out - ref))) < 2e-6
+
+
+def test_blocked_suffix_is_chosen_by_the_tables_reach_alone(monkeypatch):
+    """A rule over the shape: below BLOCKED_MIN_CONTEXT positions the
+    entry is the gathered reference, bit for bit (what every short-context
+    engine ran before the blocked spelling existed); at or past it, the
+    blocked one; one token a row is the decode path's either way."""
+    args = _suffix_case(1, 3, 4, 2, 16, 8, 8, [50])
+    assert 8 * 8 < pa.BLOCKED_MIN_CONTEXT
+    np.testing.assert_array_equal(
+        np.asarray(pa.paged_attention(*args)),
+        np.asarray(pa.paged_decode_reference(*args)))
+    called = []
+    monkeypatch.setattr(pa, "BLOCKED_MIN_CONTEXT", 64)
+    monkeypatch.setattr(
+        pa, "paged_suffix_attention",
+        lambda *a, **k: called.append(a[0].shape) or a[0])
+    pa.paged_attention(*args)
+    assert called == [(1, 3, 4, 16)]
+    one = _case(1, 4, 2, 16, 8, 8, [50])
+    pa.paged_attention(*one)
+    assert len(called) == 1
+
+
+def test_blocked_suffix_lowers_no_context_wide_score_tensor():
+    """What it is for: at 64 heads x 256 rows over 32,768 positions the
+    gathered spelling holds a [1, 64, 256, 33024] float32 score tensor
+    (2 GiB); the blocked one's largest buffer is a block's."""
+    q = jax.ShapeDtypeStruct((1, 256, 64, 128), jnp.bfloat16)
+    pages = jax.ShapeDtypeStruct((4096, 16, 1024), jnp.bfloat16)
+    new = jax.ShapeDtypeStruct((1, 256, 8, 128), jnp.bfloat16)
+    text = jax.jit(pa.paged_attention).lower(
+        q, pages, pages, jax.ShapeDtypeStruct((1, 2048), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32), new, new).as_text()
+    assert "33024" not in text and "32768" not in text

@@ -326,3 +326,138 @@ def test_decode_program_reads_each_matrix_in_the_dtype_it_multiplies_in(
     assert text.count("tpu_custom_call") == mosaic_calls
     table_copies = re.findall(rf"= f32\[{V},{E}\]\S* copy\(", text)
     assert len(table_copies) == (int(E) % 128 != 0)
+
+
+@pytest.mark.parametrize("impl, calls", [("kernel", 1), ("xla", 0)])
+@pytest.mark.parametrize("slots", [64, 8])
+def test_channel_delta_rule_update_compiles_in_place_at_published_widths(
+        one_chip, slots, impl, calls):
+    """`ops/delta_rule.gdn_decode_update` with a decay a key CHANNEL over a
+    64-slot pool of Solar-Open2's states (64 heads x 128 x 128 float32,
+    as many key heads, `g` of [slots, 64, 128]): the one kernel takes the
+    decay as a `[dk, heads]` block where the scalar gate's is `[1,
+    heads]`, Mosaic takes it, and neither it nor its XLA twin holds a
+    temporary the size of the pool."""
+    from distributedtraining_tpu.ops import delta_rule
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((65, 64, 128, 128))
+    assert delta_rule.kernel_supports(pool, 64)
+    compiled = jax.jit(
+        lambda *a: delta_rule.gdn_decode_update(*a, impl=impl),
+        donate_argnums=(0,)
+    ).trace(pool, sds((slots,), jnp.int32), sds((slots, 64, 128)),
+            sds((slots, 64, 128)), sds((slots, 64, 128)),
+            sds((slots, 64, 128)), sds((slots, 64)),
+            sds((slots,), jnp.bool_)
+            ).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == calls
+    assert bool(re.search(r"%gdn_decode_update(\.\d+)? = ", text)) \
+        == bool(calls)
+    mem = compiled.memory_analysis()
+    pool_bytes = 65 * 64 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 16
+
+
+def _solar_programs(one_chip, monkeypatch, cache: bool):
+    """The engine of `serve-solar-sessions` over avals: (engine, the
+    arguments' avals by name)."""
+    from distributedtraining_tpu.engine import kv_pool, serve, serve_weights
+    from distributedtraining_tpu.models import solar_open2 as so
+    from distributedtraining_tpu.ops import delta_rule, moe, paged_attention
+    for module in (delta_rule, paged_attention, moe):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    slots, P, pool_pages = 64, 16, 73728
+    model, cfg = so.make_model("solar-open2-250b-l4-e40-v24k")
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    base = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0))))
+    eng = serve.GenerationEngine(
+        model, None, max_slots=slots, page_size=P, pool_pages=pool_pages,
+        max_seq_len=40960, prefix_cache=cache, snapshot_rows=96,
+        prefill_chunk=1024)
+    eng._layers, eng._donate = serve._layer_keys(base), True
+    halves = tuple((sds((pool_pages, P, w), jnp.bfloat16),)
+                   for w in kv_pool.row_widths(cfg))
+    state = (tuple(sds((slots + 1, 64, 128, 128), jnp.float32)
+                   for _ in range(3)),
+             tuple(sds((slots + 1, 3, 24576), jnp.bfloat16)
+                   for _ in range(3)))
+    return eng, serve_weights.abstract(cfg, base), halves, state, sds
+
+
+@pytest.mark.parametrize("cache", [True, False])
+def test_solar_decode_program_compiles_at_the_sessions_cells_size(
+        one_chip, monkeypatch, cache):
+    """The largest decode program of `serve-solar-sessions`: 64 slots x
+    2,560 pages (40,960 positions a slot: the paged kernel's
+    scalar-prefetched table takes them at page size 16), weights, a 4.5
+    GiB page pool and the state pool in place. Its Mosaic calls are the
+    cell's `expect_paths`; the prefix cache changes no decode program."""
+    eng, tree, halves, state, sds = _solar_programs(one_chip, monkeypatch,
+                                                    cache)
+    try:
+        compiled = eng._decode_prog(64, 2560).__wrapped__.trace(
+            tree, *halves, sds((64, 2560)), sds((64,)), sds((64,)), *state,
+            sds((64,))).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        eng.close()
+    own = [ln.split(" = ")[0].strip() for ln in compiled.as_text(
+        ).splitlines() if "tpu_custom_call" in ln]
+    calls = {k: sum(bool(re.fullmatch(rf"%?{k}(\.\d+)?", n)) for n in own)
+             for k in ("gdn_decode_update", "paged_decode_attention", "gmm")}
+    assert calls == {"gdn_decode_update": 3, "paged_decode_attention": 1,
+                     "gmm": 8}
+    m = compiled.memory_analysis()
+    # pools updated in place: no temporary the size of either
+    assert m.temp_size_in_bytes < 2 ** 28
+    assert m.argument_size_in_bytes < 11.6 * 2 ** 30
+
+
+def test_solar_suffix_prefill_compiles_with_no_context_wide_scores(
+        one_chip, monkeypatch):
+    """The largest suffix-prefill program: 1,024 fresh tokens over a table
+    of 2,560 pages, continuing from the slot's state row. The attention
+    layer attends the paged context in blocks: no [heads, 1024, 40960]
+    tensor (10 GiB), under half a GiB of temporaries in all; the experts'
+    grouped products are Mosaic's."""
+    eng, tree, halves, state, sds = _solar_programs(one_chip, monkeypatch,
+                                                    True)
+    try:
+        compiled = eng._prefill_ctx_prog(1024, 2560).__wrapped__.trace(
+            tree, sds((1, 1024)), sds(()), sds(()), *halves,
+            sds((1, 2560)), *state, sds(())
+        ).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        eng.close()
+    text = compiled.as_text()
+    # no array as long as the table's reach in any dimension
+    assert not re.findall(r"\[(?:\d+,)*40960(?:,\d+)*\]", text)
+    assert text.count("tpu_custom_call") == 8
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
+
+
+def test_paged_decode_kernel_takes_a_table_of_2560_pages_a_slot(one_chip):
+    """`paged_decode_attention` at this cell's shape: 64 query / 8 K/V
+    heads of 128 (1,024 lanes), 64 slots, 2,560 table entries a slot in
+    scalar memory (640 KiB), pages of 16."""
+    from distributedtraining_tpu.ops import paged_attention as pa
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((64, 1, 64, 128)), sds((73728, 16, 1024)),
+            sds((73728, 16, 1024)), sds((64, 2560), jnp.int32),
+            sds((64,), jnp.int32), sds((64, 1, 8, 128)),
+            sds((64, 1, 8, 128)))
+    assert pa.kernel_supports(args[0], args[1])
+    compiled = _compile(pa.paged_decode_attention, *args)
+    assert compiled.as_text().count("tpu_custom_call") == 1
