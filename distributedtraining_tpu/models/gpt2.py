@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import (
-    cached_attention, causal_attention, remat_policy)
+    cached_attention, causal_attention, causal_attention_qkv, remat_policy)
 from ..ops.embed import embed_lookup
 
 
@@ -163,27 +163,37 @@ class Block(nn.Module):
                                  nn.initializers.zeros_init(), ("embed",)),
                              name="ln_1")(x)
             qkv = _dense(3 * E, "c_attn", ("embed", "qkv"), cfg)(h)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, T, cfg.n_head, cfg.head_dim)
-            k = k.reshape(B, T, cfg.n_head, cfg.head_dim)
-            v = v.reshape(B, T, cfg.n_head, cfg.head_dim)
-            if sow_kv:
-                self.sow("intermediates", "kv_cache", (k, v))
-            if kv_pages is not None:
-                from ..ops.paged_attention import paged_attention
-                attn = paged_attention(q, kv_pages[0], kv_pages[1],
-                                       page_tables, kv_lens, k, v)
-            elif kv_ctx is not None:
-                k_ctx, v_ctx = kv_ctx
-                attn = cached_attention(q,
-                                        jnp.concatenate([k_ctx, k], axis=1),
-                                        jnp.concatenate([v_ctx, v], axis=1),
-                                        kv_lens)
+            if sow_kv or kv_pages is not None or kv_ctx is not None:
+                # the serve paths sow and page k and v a head at a time
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(B, T, cfg.n_head, cfg.head_dim)
+                k = k.reshape(B, T, cfg.n_head, cfg.head_dim)
+                v = v.reshape(B, T, cfg.n_head, cfg.head_dim)
+                if sow_kv:
+                    self.sow("intermediates", "kv_cache", (k, v))
+                if kv_pages is not None:
+                    from ..ops.paged_attention import paged_attention
+                    attn = paged_attention(q, kv_pages[0], kv_pages[1],
+                                           page_tables, kv_lens, k, v)
+                elif kv_ctx is not None:
+                    k_ctx, v_ctx = kv_ctx
+                    attn = cached_attention(q,
+                                            jnp.concatenate([k_ctx, k], axis=1),
+                                            jnp.concatenate([v_ctx, v], axis=1),
+                                            kv_lens)
+                else:
+                    attn = causal_attention(q, k, v, attention_mask=attention_mask,
+                                            segment_ids=segment_ids,
+                                            impl=cfg.attention_impl)
+                attn = attn.reshape(B, T, E)
             else:
-                attn = causal_attention(q, k, v, attention_mask=attention_mask,
-                                        segment_ids=segment_ids,
-                                        impl=cfg.attention_impl)
-            attn = attn.reshape(B, T, E)
+                # training and eval: the attention core takes c_attn's
+                # array as it lies and hands c_proj its own (where the
+                # flash kernels run they read q, k and v out of it: no
+                # split, no transpose, no copy)
+                attn = causal_attention_qkv(
+                    qkv, cfg.n_head, attention_mask=attention_mask,
+                    segment_ids=segment_ids, impl=cfg.attention_impl)
             attn = _dense(E, "c_proj", ("qkv", "embed"), cfg)(attn)
             if cfg.dropout > 0:
                 attn = nn.Dropout(cfg.dropout)(attn, deterministic=deterministic)
@@ -286,6 +296,21 @@ class GPT2(nn.Module):
             x = x.astype(cfg.compute_dtype())
             if cfg.dropout > 0:
                 x = nn.Dropout(cfg.dropout)(x, deterministic=deterministic)
+
+        if cfg.attention_impl == "flash" and not (
+                kv_ctx is not None or kv_pages is not None or sow_kv):
+            # where the flash kernels walk the block pairs the rows' segment
+            # ids need, the step counts them, every layer alike (engine/
+            # train.py hands `train_counters` out beside the loss)
+            from ..ops import flash_attention
+            pairs = flash_attention.block_pairs(
+                jax.ShapeDtypeStruct((B, T, cfg.n_head, cfg.head_dim),
+                                     cfg.compute_dtype()),
+                attention_mask, segment_ids)
+            if pairs is not None:
+                self.sow("intermediates", "train_counters", {
+                    name: cfg.n_layer * n for name, n in zip(
+                        flash_attention.BLOCK_PAIR_COUNTERS, pairs)})
 
         if cfg.scan_blocks:
             # one Block program, lax.scan'd n_layer times: ~L-fold smaller

@@ -74,8 +74,7 @@ TRAIN_COUNTERS = {
 }
 # and its attention layers, where the flash kernels run the block pairs the
 # rows' segment ids need (ops/flash_attention.block_pairs)
-ATTN_COUNTERS = ("train.attn.block_pairs_run",
-                 "train.attn.block_pairs_causal")
+ATTN_COUNTERS = flash_attention.BLOCK_PAIR_COUNTERS
 
 
 @dataclasses.dataclass(frozen=True)

@@ -286,11 +286,38 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return dot_product_attention(q, k, v, mask)
 
 
+def causal_attention_qkv(qkv: jax.Array, n_head: int,
+                         *,
+                         attention_mask: Optional[jax.Array] = None,
+                         segment_ids: Optional[jax.Array] = None,
+                         impl: str = "dense") -> jax.Array:
+    """:func:`causal_attention` on a fused projection's ``[B, T, 3E]``
+    (q, k, v side by side) -> ``[B, T, E]``. Where the flash kernels take
+    the fused array as it lies (``flash_attention.flash_attention_qkv``)
+    nothing is split or copied; everywhere else this is the split, the
+    heads' reshape and :func:`causal_attention`."""
+    if impl == "flash":
+        from . import flash_attention
+        out = flash_attention.flash_attention_qkv(
+            qkv, n_head, attention_mask=attention_mask,
+            segment_ids=segment_ids)
+        if out is not None:
+            return out
+    B, T, E3 = qkv.shape
+    q, k, v = (x.reshape(B, T, n_head, -1)
+               for x in jnp.split(qkv, 3, axis=-1))
+    return causal_attention(q, k, v, attention_mask=attention_mask,
+                            segment_ids=segment_ids,
+                            impl=impl).reshape(B, T, E3 // 3)
+
+
 def remat_policy():
     """The ``policy`` of every ``nn.remat`` around a block that calls
     :func:`causal_attention`: beside the block's input, keep the flash
     forward kernel's output and ``[H, T]`` log-sum-exp
-    (``B x T x E x 2 + B x H x T x 4`` bytes an attention layer), so that
+    (``B x T x E x 2 + B x H x T x 4`` bytes an attention layer; where this
+    module's own kernels run the output is the ``[B, T, E]`` array the
+    output projection reads, stored as its shape says), so that
     the re-run hands them to the backward kernel and does not call the
     forward kernel a second time. Where the kernel does not run nothing
     carries the name, and remat keeps what a bare one keeps."""

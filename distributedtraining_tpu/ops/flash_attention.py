@@ -13,40 +13,36 @@ mask at trace time: a (q block, kv block) pair the mask covers whole does
 no kernel work, a pair it leaves whole runs unmasked, and only the pairs
 the diagonal crosses compute the mask. Those block tables are constants of
 the program, and its grid still visits every pair: one that does no work
-costs its step (0.3 us forward, 0.75 backward on the v5e). Packed rows of
-at least ``TABLE_MIN_BLOCKS`` blocks go another way (:func:`_table_engages`
-is the whole rule): the pairs their documents need are computed from their
-OWN segment ids, on the device inside the step (:func:`needed_pairs`: a
-pair runs unless the two blocks' id ranges are disjoint, so on the packer's
-non-decreasing ids q block ``i`` runs the kv blocks from its first token's
-document to ``i`` and nothing else; on any other ids it runs too much,
-never too little), listed (:func:`_pair_list`), and two kernels of this
-module walk the list and nothing else: their grid is ``(heads, pairs)``,
-its second bound the COUNT of pairs, an operand of the call. They are the
-library's kernels step for step (``_fwd_kernel``, ``_dkv_kernel``: the same
-products in the same precisions, the causal and the segment mask in every
-pair that runs), under the library's names, so a pair that runs computes
-what it computed and a pair left out is one whose scores the segment mask
-set to nothing: the output, dK and dV are the library's bit for bit; dQ
-adds up in float32 in VMEM where the library writes every kv block's share
-to HBM in q's dtype and sums those, so in bfloat16 it is the library's to a
-rounding (:func:`block_pairs` counts the pairs that run and the causal
-half's). Either way the backward is ONE kernel of five
-matrix products (dK, dV and dQ from one pass over the scores), and the
-softmax statistics reach it as a ``[H, T]`` log-sum-exp. A remat-wrapped
-block keeps those two arrays of the forward kernel, its output and the
-log-sum-exp (``B x T x E x 2 + B x H x T x 4`` bytes an attention layer by
-shape; heads of 64 are stored in 128-lane tiles on the chip, which doubles
-the first term), so remat's re-run does not call the forward kernel again
+costs its step (0.3 us forward, 0.75 backward on the v5e). Packed rows go
+another way wherever their heads fill whole 128-lane blocks
+(:func:`_table_engages` is the whole rule): the pairs their documents need
+are computed from their OWN segment ids, on the device inside the step
+(:func:`needed_pairs`: a pair runs unless the two blocks' id ranges are
+disjoint, so on the packer's non-decreasing ids q block ``i`` runs the kv
+blocks from its first token's document to ``i`` and nothing else; on any
+other ids it runs too much, never too little), listed (:func:`_pair_list`),
+and two kernels of this module walk the list and nothing else: their grid
+is ``(lane blocks, pairs)``, its second bound the COUNT of pairs, an operand
+of the call. They are the library's kernels step for step a head
+(``_fwd_kernel``, ``_dkv_kernel``: the same products in the same
+precisions, the causal and the segment mask in every pair that runs),
+under the library's names, so a pair that runs computes what it computed
+and a pair left out is one whose scores the segment mask set to nothing:
+the output and dV are the library's bit for bit; dQ adds up in float32 in
+VMEM where the library writes every kv block's share to HBM in q's dtype
+and sums those, so in bfloat16 it is the library's to a rounding, as is dK
+in a few elements (:func:`block_pairs` counts the pairs that run and the
+causal half's). Either way the backward is ONE kernel of five matrix
+products (dK, dV and dQ from one pass over the scores), and the softmax
+statistics reach it as a ``[H, T]`` log-sum-exp. A remat-wrapped block
+keeps those two arrays of the forward kernel, its output and the
+log-sum-exp (``B x T x E x 2 + B x H x T x 4`` bytes an attention layer;
+the library's heads-first output of heads of 64 is stored in 128-lane
+tiles on the chip, twice that first term; this module's is stored as its
+shape says), so remat's re-run does not call the forward kernel again
 (``RESIDUAL_NAME`` below, ``ops.attention.remat_policy``). Measured on the
 v5e at the train cell's shape (B=4, H=20, T=1024, D=64, bfloat16, packed
-documents; ``scripts/ab_flash.py``, PERF.md section 6, PR 28) against the
-library's older ``flash_attention`` kernel that stood here before: forward
-0.376 ms for 0.568, forward + backward 1.16 ms for 2.31. That kernel with
-one 1,024-row q block never skipped a block (its test of the block's
-bottom-left corner was true for all of them), ran seven products in two
-backward kernels and first wrote its statistics to HBM 128 lanes wide;
-re-blocked at its best (512 everywhere) it reached 0.346 / 1.96 ms.
+documents; ``scripts/ab_flash.py``, PERF.md section 6, PRs 28, 40, 42).
 
 Supports causal masking and packed-sequence ``segment_ids`` (block-diagonal
 attention), which is the data pipeline's hot path. Selection is a rule over
@@ -57,11 +53,19 @@ the dense or blockwise XLA path (ops/attention.py) — identical numerics,
 different memory profile. Once the rule says yes, the kernel's build and
 compile errors propagate.
 
-Layouts: this framework uses [B, T, H, D]; the kernel wants [H, T, D] and
-is mapped over B. The transposes are free at trace level (XLA fuses them
-into the kernel's block loads). The kernel takes no scale: q is scaled by
-``D ** -0.5`` before it, in q's dtype (exact in bfloat16 at D = 64, a power
-of two; one more rounding of q elsewhere).
+Layouts: this framework holds [B, T, H, D], which is ``[B, T, H D]`` as it
+lies, and that is what this module's kernels take and give: a
+``[block, 128]`` block of it holds ``128 / D`` heads (two heads of 64; one
+head of 128), each head's products run on its own lanes, and q's scale
+(``D ** -0.5``, in q's dtype: exact in bfloat16 at D = 64, a power of two;
+one more rounding of q elsewhere) is applied inside. A fused projection's
+``[B, T, 3 H D]`` goes in as ONE array (:func:`flash_attention_qkv`: the
+index maps of k and v start ``H D / 128`` and ``2 H D / 128`` lane blocks
+in), and the output is the ``[B, T, H D]`` the output projection reads: no
+split, scaling, transpose or copy lies between the projections and the
+kernels. The library's kernels want [H, T, D], mapped over B: where they
+run (no ids, a row of one block, an odd number of heads of 64 a device) q
+is scaled and all three are transposed heads first and back, as before.
 """
 
 from __future__ import annotations
@@ -106,17 +110,30 @@ splash.get_kernel_name = _kernel_name
 # saves what carries it (``ops.attention.remat_policy``). Where the rule
 # below says no, nothing carries the name and the policy keeps nothing.
 RESIDUAL_NAME = "flash_attn_residuals"
-KEEP_RESIDUALS = jax.checkpoint_policies.save_only_these_names(RESIDUAL_NAME)
+# ... and the backward kernel's pair list where this module's kernels run: a
+# few dozen integers a layer, the same in every layer of a step. Kept, the
+# lists of all layers are ONE computation of the forward pass; left to the
+# re-run, every layer sorts its own behind remat's barrier.
+PAIRS_NAME = "flash_attn_pairs"
+KEEP_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    RESIDUAL_NAME, PAIRS_NAME)
 
 
 # Rows of at least this many blocks run the pairs their segment ids need
-# (at two blocks nothing can be left out), and rows of at most TABLE_MAX_T
-# tokens: the backward holds a head's whole dQ row in VMEM (1 KiB a token)
-# and the kernels the pair list in scalar memory (``scripts/ab_flash.py
-# --packed``, PERF.md section 6, PR 40: faster than the library's kernels
-# at 4, 8 and 16 blocks a row).
-TABLE_MIN_BLOCKS = 4
+# (a row of two blocks can leave out one of three: the pair below the
+# diagonal, when no document crosses the middle), and rows of at most
+# TABLE_MAX_T tokens: the backward holds a lane block's whole dQ row in VMEM
+# (1 KiB a token) and the kernels the pair list in scalar memory
+# (``scripts/ab_flash.py --packed`` and ``--layout``, PERF.md section 6,
+# PRs 40 and 42: faster than the library's kernels at 2, 4, 8 and 16 blocks
+# a row).
+TABLE_MIN_BLOCKS = 2
 TABLE_MAX_T = 16384
+
+# the registry's names for what :func:`block_pairs` counts, under which a
+# model's train step hands them out (docs/observability.md)
+BLOCK_PAIR_COUNTERS = ("train.attn.block_pairs_run",
+                       "train.attn.block_pairs_causal")
 
 # test hook: off the chip the rule selects the kernel all the same and it
 # runs in the Pallas interpreter, so that the CPU lane reads the KERNEL's
@@ -163,7 +180,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     attention is independent per sequence and per head. A
     sequence-sharded mesh (sp > 1) is ring attention's, and the rule says
     no."""
-    if not _selected(q, attention_mask):
+    if not _selected(q.shape, attention_mask):
         return None
     mesh = ambient_mesh()
     B, T, H, D = q.shape
@@ -173,49 +190,109 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     def kernel(q, k, v, seg_q, seg_kv):
         if seg_q is not None:
             seg_q, seg_kv = seg_q.astype(jnp.int32), seg_kv.astype(jnp.int32)
-        heads_first = ((q * D ** -0.5).transpose(0, 2, 1, 3),
-                       k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-        if _table_engages(T, seg_q):
-            out = _packed_attention(*heads_first, seg_q, seg_kv,
-                                    _block_sizes(T).block_q)
-        else:
-            seg = (None if seg_q is None
-                   else splash.SegmentIds(q=seg_q, kv=seg_kv))
-            # q.shape[2]: the heads this device holds under the shard_map
-            out = jax.vmap(_causal_kernel(T, q.shape[2]))(*heads_first, seg)
+        b, _, h, _ = q.shape    # the rows and heads this device holds
+        if _table_engages(T, h, D, seg_q):
+            # [B, T, H, D] is [B, T, H D] as it lies: no copy either way
+            rows_major = tuple(x.reshape(b, T, h * D) for x in (q, k, v))
+            return _packed_attention(rows_major, seg_q, seg_kv, h,
+                                     _block_sizes(T).block_q).reshape(q.shape)
+        seg = (None if seg_q is None
+               else splash.SegmentIds(q=seg_q, kv=seg_kv))
+        out = jax.vmap(_causal_kernel(T, h))(
+            (q * D ** -0.5).transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), seg)
         return out.transpose(0, 2, 1, 3)
 
     if mesh is None:
         return kernel(q, k, v, segment_ids, kv_segment_ids)
-    # an axis that does not divide its dim stays replicated (duplicate
-    # work on that axis, same answer)
-    rows = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)
-    if B % math.prod(mesh.shape[a] for a in rows):
-        rows = ()
-    heads = "tp" if H % mesh.shape.get("tp", 1) == 0 else None
-    qkv = P(rows or None, None, heads, None)
-    seg = None if segment_ids is None else P(rows or None, None)
+    rows = _row_axes(mesh, B)
+    heads = None if _heads_here(H) == H else "tp"
+    qkv = P(rows, None, heads, None)
+    seg = None if segment_ids is None else P(rows, None)
     return shard_map(kernel, mesh=mesh, in_specs=(qkv, qkv, qkv, seg, seg),
                      out_specs=qkv, check_vma=False)(
         q, k, v, segment_ids, kv_segment_ids)
 
 
-def _selected(q: jax.Array, attention_mask: Optional[jax.Array]) -> bool:
-    """The whole selection rule: the chip (or the test hook), the shapes
-    and mask :func:`supports` takes, and no sequence-sharded mesh."""
-    if not ((_on_tpu() or _FORCE_INTERPRET) and supports(q, attention_mask)):
+def flash_attention_qkv(qkv: jax.Array, n_head: int,
+                        *,
+                        attention_mask: Optional[jax.Array] = None,
+                        segment_ids: Optional[jax.Array] = None
+                        ) -> Optional[jax.Array]:
+    """:func:`flash_attention` on a fused projection's ``[B, T, 3 E]``
+    (q, k and v side by side, heads inside each) -> ``[B, T, E]``, or None
+    where the rule says no. Where this module's kernels run
+    (:func:`_table_engages`) they read q, k and v out of the ONE array and
+    write the heads side by side again: nothing is split, scaled,
+    transposed or copied between the two projections and the kernels.
+    Elsewhere (the library's kernels; heads split over ``tp``) it is
+    :func:`flash_attention` on the three parts."""
+    B, T, E = qkv.shape[0], qkv.shape[1], qkv.shape[2] // 3
+    shape = (B, T, n_head, E // n_head)
+    if not _selected(shape, attention_mask):
+        return None
+    if (_heads_here(n_head) != n_head
+            or not _table_engages(T, *shape[2:], segment_ids)):
+        q, k, v = (x.reshape(shape) for x in jnp.split(qkv, 3, axis=-1))
+        return flash_attention(q, k, v, attention_mask=attention_mask,
+                               segment_ids=segment_ids).reshape(B, T, E)
+
+    def kernel(qkv, seg):
+        seg = seg.astype(jnp.int32)
+        return _packed_attention((qkv,), seg, seg, n_head,
+                                 _block_sizes(T).block_q)
+
+    mesh = ambient_mesh()
+    if mesh is None:
+        return kernel(qkv, segment_ids)
+    rows = _row_axes(mesh, B)
+    return shard_map(kernel, mesh=mesh,
+                     in_specs=(P(rows, None, None), P(rows, None)),
+                     out_specs=P(rows, None, None), check_vma=False)(
+        qkv, segment_ids)
+
+
+def _row_axes(mesh, B: int):
+    """The mesh axes the batch rows are split over inside the
+    ``shard_map``: an axis that does not divide its dim stays replicated
+    (duplicate work on that axis, same answer)."""
+    rows = tuple(a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1)
+    if B % math.prod(mesh.shape[a] for a in rows):
+        rows = ()
+    return rows or None
+
+
+def _heads_here(H: int) -> int:
+    """The heads of H a device holds inside the ``shard_map``."""
+    mesh = ambient_mesh()
+    tp = 1 if mesh is None else mesh.shape.get("tp", 1)
+    return H // tp if H % tp == 0 else H
+
+
+def _selected(shape, attention_mask: Optional[jax.Array]) -> bool:
+    """The whole selection rule: the chip (or the test hook), the
+    ``[B, T, H, D]`` shape and mask :func:`supports` takes, and no
+    sequence-sharded mesh."""
+    if not ((_on_tpu() or _FORCE_INTERPRET)
+            and supports(jax.ShapeDtypeStruct(shape, jnp.float32),
+                         attention_mask)):
         return False
     mesh = ambient_mesh()
     return mesh is None or mesh.shape.get("sp", 1) == 1
 
 
-def _table_engages(T: int, segment_ids: Optional[jax.Array]) -> bool:
-    """Whether the kernels walk the pairs the rows' segment ids need: only
-    where there are ids, and only for a row long enough for it to pay.
-    Elsewhere the library's kernels over :func:`_causal_kernel`'s constants
-    stand, and the program is the one it was."""
+def _table_engages(T: int, H: int, D: int,
+                   segment_ids: Optional[jax.Array]) -> bool:
+    """Whether this module's kernels run, over the pairs the rows' segment
+    ids need: where there are ids, the row is at least ``TABLE_MIN_BLOCKS``
+    blocks and at most ``TABLE_MAX_T`` tokens long, and the H heads a
+    device holds fill whole 128-lane blocks of ``[B, T, H D]`` (heads of 64
+    in pairs; a head of 128 or more by itself). Elsewhere the library's
+    kernels over :func:`_causal_kernel`'s constants stand, heads first, and
+    the program is the one it was."""
     return (segment_ids is not None and T <= TABLE_MAX_T
-            and T // _block_sizes(T).block_q >= TABLE_MIN_BLOCKS)
+            and T // _block_sizes(T).block_q >= TABLE_MIN_BLOCKS
+            and (H * D) % max(D, _LANES) == 0)
 
 
 def needed_pairs(seg_q: jax.Array, seg_kv: jax.Array,
@@ -284,15 +361,53 @@ def _allowed(q_block, kv_block, q_ids, kv_ids, block: int, kv_axis: int):
     return (q_pos >= kv_pos) & (q_ids == kv_ids)
 
 
+def _mine(g: int, D: int, width: int):
+    """[1, width] booleans: head ``g``'s ``D`` lanes of a lane block."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return (lane >= g * D) & (lane < (g + 1) * D)
+
+
+def _head(ref, g: int, D: int):
+    """Head ``g`` of a ``[block, lanes]`` block, for a product over ALL
+    lanes: the block whole with the other heads' lanes exact zeros, so the
+    product adds zeros to a float32 sum and nothing else (on a 128 x 128
+    MXU a contraction of 64 costs the full pass anyway). Static 64-lane
+    slices, the other spelling, compile too and run slower: Mosaic re-lays
+    the upper half (``scripts/ab_flash.py --layout``, PERF.md section 6,
+    PR 42)."""
+    x = ref[...]
+    if D >= x.shape[-1]:
+        return x
+    return jnp.where(_mine(g, D, x.shape[-1]), x, jnp.zeros_like(x))
+
+
+def _accumulate(ref, g: int, D: int, share=None, *, scale=None,
+                rows=slice(None)):
+    """Head ``g``'s lanes of an accumulator: times ``scale``, a number a
+    row (128 lanes wide, as the statistics are kept), plus the head's
+    ``share``, which :func:`_head`'s operands left zero on the other
+    heads' lanes (their scale is 1)."""
+    x = ref[rows, :]
+    width = x.shape[-1]
+    if scale is not None:
+        scale = jnp.tile(scale, (1, pl.cdiv(width, _LANES)))[:, :width]
+        if D < width:
+            scale = jnp.where(_mine(g, D, width), scale, 1.0)
+        x = scale * x
+    ref[rows, :] = x if share is None else x + share
+
+
 def _fwd_kernel(_row, q_block, kv_block, edges, q_ref, k_ref, v_ref,
-                q_ids_ref, kv_ids_ref, out_ref, *rest, block: int):
-    """One (q block, kv block) pair of the forward: the library's
-    ``flash_attention_kernel`` step for step (the same products in the same
-    precisions, the running maximum and sum 128 lanes wide), on a grid
-    whose second axis is the pair list of :func:`_pair_list`, q-major."""
+                q_ids_ref, kv_ids_ref, out_ref, *rest, block: int, D: int):
+    """One (q block, kv block) pair of the forward for the heads of one
+    lane block: the library's ``flash_attention_kernel`` step for step a
+    head (the same products in the same precisions, the running maximum
+    and sum 128 lanes wide), on a grid whose second axis is the pair list
+    of :func:`_pair_list`, q-major. q is scaled here, in its own dtype."""
     *lse_ref, m_ref, l_ref, acc_ref = rest
     p = pl.program_id(1)
     f32 = jnp.float32
+    heads = range(acc_ref.shape[-1] // D)
 
     @pl.when(edges[p] & 1 != 0)
     def _():
@@ -300,45 +415,50 @@ def _fwd_kernel(_row, q_block, kv_block, edges, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, _MASK_VALUE)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qk = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
-                             preferred_element_type=f32)
     wide = block // _LANES
     allowed = _allowed(q_block[p], kv_block[p],
                        jnp.tile(q_ids_ref[...], (1, wide)),
                        kv_ids_ref[:1, :], block, kv_axis=1)
-    qk = jnp.where(allowed, qk, _MASK_VALUE)
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_next = jnp.maximum(m_prev, qk.max(axis=-1)[:, None])
-    s = jnp.exp(qk - jnp.tile(m_next, (1, wide)))
-    alpha = jnp.exp(m_prev - m_next)
-    l_ref[...] = jax.lax.broadcast_in_dim(
-        s.sum(axis=-1), l_prev.shape, (0,)) + alpha * l_prev
-    m_ref[...] = m_next
-    D = acc_ref.shape[-1]
-    lanes = lambda x: jnp.tile(x, (1, pl.cdiv(D, _LANES)))[..., :D]
-    acc_ref[...] = lanes(alpha) * acc_ref[...] + jax.lax.dot_general(
-        s, v_ref[...].astype(f32), _NN)
+    for g in heads:
+        qk = jax.lax.dot_general(_head(q_ref, g, D) * D ** -0.5,
+                                 _head(k_ref, g, D), _NT,
+                                 preferred_element_type=f32)
+        qk = jnp.where(allowed, qk, _MASK_VALUE)
+        m_prev, l_prev = m_ref[g], l_ref[g]
+        m_next = jnp.maximum(m_prev, qk.max(axis=-1)[:, None])
+        s = jnp.exp(qk - jnp.tile(m_next, (1, wide)))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[g] = jax.lax.broadcast_in_dim(
+            s.sum(axis=-1), l_prev.shape, (0,)) + alpha * l_prev
+        m_ref[g] = m_next
+        _accumulate(acc_ref, g, D, jax.lax.dot_general(
+            s, _head(v_ref, g, D).astype(f32), _NN), scale=alpha)
 
     @pl.when(edges[p] & 2 != 0)
     def _():
-        l = l_ref[...]
-        out_ref[...] = (acc_ref[...] * lanes(1.0 / l)).astype(out_ref.dtype)
-        for ref in lse_ref:
-            ref[...] = jnp.log(l) + m_ref[...]
+        for g in heads:
+            l = l_ref[g]
+            _accumulate(acc_ref, g, D, scale=1.0 / l)
+            for ref in lse_ref:
+                # a number a q position, along lanes
+                ref[g] = (jnp.log(l) + m_ref[g]).T[:1]
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
 def _dkv_kernel(_row, kv_block, q_block, edges, q_ref, k_ref, v_ref,
                 q_ids_ref, kv_ids_ref, lse_ref, do_ref, di_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                block: int):
-    """One pair of the fused backward, kv-major: the library's
-    ``_flash_attention_dkv_kernel`` product for product (five of them, from
-    one pass over the scores). dK and dV add up over a kv block's pairs,
-    which follow each other; dQ adds up in float32 over the whole row of
-    one head, ``[T, D]`` of VMEM, and is written once a row (the library
-    writes a ``[T / block, H, T, D]`` array of shares and sums it after)."""
+                block: int, D: int):
+    """One pair of the fused backward for the heads of one lane block,
+    kv-major: the library's ``_flash_attention_dkv_kernel`` product for
+    product a head (five of them, from one pass over the scores). dK and dV
+    add up over a kv block's pairs, which follow each other; dQ adds up in
+    float32 over the whole row, ``[T, lanes]`` of VMEM, and is written once
+    a row with q's scale on it (the library writes a
+    ``[T / block, H, T, D]`` array of shares and sums it after)."""
     p = pl.program_id(1)
     f32 = jnp.float32
+    scale = D ** -0.5
 
     @pl.when(edges[p] & 4 != 0)
     def _():
@@ -349,22 +469,25 @@ def _dkv_kernel(_row, kv_block, q_block, edges, q_ref, k_ref, v_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
-    qk = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32)
     allowed = _allowed(q_block[p], kv_block[p], q_ids_ref[:1, :],
                        jnp.tile(kv_ids_ref[...], (1, block // _LANES)),
                        block, kv_axis=0)
-    qk = jnp.where(allowed, qk, _MASK_VALUE)
-    prob = jnp.exp(qk - lse_ref[:1, :])
-    dv_acc[...] += jax.lax.dot(prob.astype(do.dtype), do,
-                               preferred_element_type=f32)
-    dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
-    ds = (dp - di_ref[:1, :]) * prob
-    dk_acc[...] += jax.lax.dot_general(ds.astype(do.dtype), q, _NN,
-                                       preferred_element_type=f32)
     rows = pl.ds(pl.multiple_of(q_block[p] * block, block), block)
-    dq_acc[rows, :] += jax.lax.dot_general(ds.T.astype(k.dtype), k, _NN,
-                                           preferred_element_type=f32)
+    for g in range(dq_acc.shape[-1] // D):
+        q, k, v, do = (_head(q_ref, g, D) * scale, _head(k_ref, g, D),
+                       _head(v_ref, g, D), _head(do_ref, g, D))
+        qk = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32)
+        qk = jnp.where(allowed, qk, _MASK_VALUE)
+        prob = jnp.exp(qk - lse_ref[g])
+        _accumulate(dv_acc, g, D, jax.lax.dot(
+            prob.astype(do.dtype), do, preferred_element_type=f32))
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
+        ds = (dp - di_ref[g]) * prob
+        _accumulate(dk_acc, g, D, jax.lax.dot_general(
+            ds.astype(do.dtype), q, _NN, preferred_element_type=f32))
+        _accumulate(dq_acc, g, D, jax.lax.dot_general(
+            ds.T.astype(k.dtype), k, _NN, preferred_element_type=f32),
+            rows=rows)
 
     @pl.when(edges[p] & 2 != 0)
     def _():
@@ -373,7 +496,7 @@ def _dkv_kernel(_row, kv_block, q_block, edges, q_ref, k_ref, v_ref,
 
     @pl.when(edges[p] & 8 != 0)
     def _():
-        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _ids_operands(seg_q, seg_kv, block: int, kv_axis: int):
@@ -384,12 +507,12 @@ def _ids_operands(seg_q, seg_kv, block: int, kv_axis: int):
         return (jnp.broadcast_to(seg[:, None, :],
                                  (seg.shape[0], _SUBLANES, seg.shape[1])),
                 pl.BlockSpec((None, _SUBLANES, block),
-                             lambda h, p, row, *at: (row[p], 0, at[which][p])))
+                             lambda c, p, row, *at: (row[p], 0, at[which][p])))
 
     def along_sublanes(seg, which):
         return (jnp.broadcast_to(seg[:, :, None], (*seg.shape, _LANES)),
                 pl.BlockSpec((None, block, _LANES),
-                             lambda h, p, row, *at: (row[p], at[which][p], 0)))
+                             lambda c, p, row, *at: (row[p], at[which][p], 0)))
     # `at` is (major, minor, edges): the q block is the forward's major and
     # the backward's minor
     if kv_axis == 1:
@@ -397,127 +520,193 @@ def _ids_operands(seg_q, seg_kv, block: int, kv_axis: int):
     return along_lanes(seg_q, 1), along_sublanes(seg_kv, 0)
 
 
-def _head_blocks(block: int, D: int, which: int) -> pl.BlockSpec:
-    """One ``[block, D]`` block of a ``[B, H, T, D]`` array: the pair's row,
-    the grid's head, the pair's major (0) or minor (1) block."""
-    return pl.BlockSpec((None, None, block, D),
-                        lambda h, p, row, *at: (row[p], h, at[which][p], 0))
+def _row_blocks(block: int, width: int, which: int,
+                first: int = 0) -> pl.BlockSpec:
+    """One ``[block, width]`` block of a ``[B, T, lanes]`` array: the
+    pair's row, its major (0) or minor (1) block, the grid's lane block
+    counted from ``first`` (where q, k or v starts in a fused array)."""
+    return pl.BlockSpec(
+        (None, block, width),
+        lambda c, p, row, *at: (row[p], at[which][p], first + c))
 
 
-def _vmem_limit(T: int) -> int:
-    # the backward's whole-row dQ (float32 beside the doubly buffered
-    # output, a head of 64 stored 128 lanes wide) on top of what the pairs'
-    # own blocks take
-    return 32 * 2 ** 20 + T * _LANES * (4 + 2 * 2)
+def _stat_blocks(block: int, heads: int, n: int, which: int) -> pl.BlockSpec:
+    """The statistics of a lane block's ``heads`` heads over one q block,
+    out of a ``[B H, 1, T]`` array: a number a q position, along lanes.
+    ``n``: the lane blocks of a row."""
+    return pl.BlockSpec(
+        (heads, 1, block),
+        lambda c, p, row, *at: (row[p] * n + c, 0, at[which][p]))
 
 
-def _fwd_call(q, k, v, seg_q, seg_kv, *, block: int, save_residuals: bool):
-    B, H, T, D = q.shape
+def _vmem_limit(T: int, width: int, backward: bool) -> int:
+    """What a kernel may take of VMEM, and no more: XLA keeps the step's
+    activations in what the limit leaves, and under the 33 MiB this module
+    asked before it evicted a 42 MB operand of the MLP's weight gradient to
+    HBM in every layer of gpt2-large (PERF.md section 6, PR 42). At blocks
+    of 512 the pairs' blocks, accumulators and ``[block, block]`` float32
+    temporaries take 4.4 MiB forward and 5.4 backward, twice that 256 lanes
+    wide (AOT for v5e, ``tests/test_tpu_aot.py``); the backward adds its
+    whole-row dQ, float32 beside the doubly buffered output."""
+    blocks = 8 * 2 ** 20 * width // _LANES
+    return blocks + (T * width * (4 + 2 * 2) if backward else 0)
+
+
+def _layout(arrays, H: int):
+    """What the kernels are handed: ``(q, k, v)`` of ``[B, T, H D]`` each,
+    or ONE fused ``[B, T, 3 H D]`` array three times over (no copy of
+    either) -> ``((q, k, v), the lane block each starts at, D, the lane
+    blocks of H heads, their width)``."""
+    fused = len(arrays) == 1
+    D = arrays[0].shape[-1] // (3 * H if fused else H)
+    width = max(D, _LANES)
+    n = H * D // width
+    return (arrays * 3, (0, n, 2 * n), D, n, width) if fused else (
+        arrays, (0, 0, 0), D, n, width)
+
+
+# Both calls are jitted, as the library's kernel is: a model of 36 layers
+# traces and lowers each kernel ONCE and calls it 36 times (unjitted, a
+# warm set-up of gpt2-large spent 18 s more lowering 72 kernel bodies:
+# PERF.md section 6, PR 42). ``interpret`` is an argument because a cached
+# trace keeps the mode it was made in.
+@functools.partial(jax.jit, static_argnames=("H", "block", "save_residuals",
+                                             "interpret"))
+def _fwd_call(arrays, seg_q, seg_kv, *, H: int, block: int,
+              save_residuals: bool, interpret: bool):
+    (q, k, v), first, D, n, width = _layout(arrays, H)
+    B, T = seg_q.shape
     *table, count = _pair_list(needed_pairs(seg_q, seg_kv, block))
     (q_ids, q_ids_spec), (kv_ids, kv_ids_spec) = _ids_operands(
         seg_q, seg_kv, block, kv_axis=1)
-    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
-    out_specs = [_head_blocks(block, D, 0)]
+    out_shape = [jax.ShapeDtypeStruct((B, T, H * D), q.dtype)]
+    out_specs = [_row_blocks(block, width, 0)]
     if save_residuals:
-        out_shape.append(jax.ShapeDtypeStruct((B, H, T, _LANES), jnp.float32))
-        out_specs.append(_head_blocks(block, _LANES, 0))
+        out_shape.append(jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32))
+        out_specs.append(_stat_blocks(block, width // D, n, 0))
     name = _kernel_name(is_mqa=False, save_residuals=save_residuals,
                         is_segmented=True, phase="fwd")
+    stat = pltpu.VMEM((width // D, block, _LANES), jnp.float32)
     with jax.named_scope(name):
         out, *lse = pl.pallas_call(
-            functools.partial(_fwd_kernel, block=block),
+            functools.partial(_fwd_kernel, block=block, D=D),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=4, grid=(H, count),
-                in_specs=[_head_blocks(block, D, 0), _head_blocks(block, D, 1),
-                          _head_blocks(block, D, 1), q_ids_spec, kv_ids_spec],
+                num_scalar_prefetch=4, grid=(n, count),
+                in_specs=[_row_blocks(block, width, 0, first[0]),
+                          _row_blocks(block, width, 1, first[1]),
+                          _row_blocks(block, width, 1, first[2]),
+                          q_ids_spec, kv_ids_spec],
                 out_specs=out_specs,
-                scratch_shapes=[pltpu.VMEM((block, _LANES), jnp.float32),
-                                pltpu.VMEM((block, _LANES), jnp.float32),
-                                pltpu.VMEM((block, D), jnp.float32)]),
+                scratch_shapes=[stat, stat,
+                                pltpu.VMEM((block, width), jnp.float32)]),
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            name=name, interpret=_FORCE_INTERPRET,
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(T, width, False)),
+            name=name, interpret=interpret,
         )(*table, q, k, v, q_ids, kv_ids)
-    return (out, lse[0][..., 0]) if save_residuals else out
+    return (out, lse[0].reshape(B, H, T)) if save_residuals else out
 
 
-def _dkv_call(q, k, v, seg_q, seg_kv, lse, do, di, *, block: int):
-    B, H, T, D = q.shape
-    *table, count = _pair_list(
-        needed_pairs(seg_q, seg_kv, block).swapaxes(1, 2))
+@functools.partial(jax.jit, static_argnames=("H", "block", "interpret"))
+def _dkv_call(arrays, seg_q, seg_kv, pairs, lse, do, di, *, H: int,
+              block: int, interpret: bool):
+    (q, k, v), first, D, n, width = _layout(arrays, H)
+    B, T = seg_q.shape
+    *table, count = pairs
     (q_ids, q_ids_spec), (kv_ids, kv_ids_spec) = _ids_operands(
         seg_q, seg_kv, block, kv_axis=0)
-    # a statistic a q position, along lanes, once a sublane tile
-    stat_spec = pl.BlockSpec(
-        (None, None, _SUBLANES, block),
-        lambda h, p, row, kv, q_block, _: (row[p], h, 0, q_block[p]))
-    wide = lambda x: jnp.broadcast_to(x[:, :, None, :], (B, H, _SUBLANES, T))
+    stat_spec = _stat_blocks(block, width // D, n, 1)
+    stat = lambda x: x.reshape(B * H, 1, T)
     name = _kernel_name(is_mqa=False, save_residuals=False,
                         is_segmented=True, phase="dkv")
     with jax.named_scope(name):
         return pl.pallas_call(
-            functools.partial(_dkv_kernel, block=block),
+            functools.partial(_dkv_kernel, block=block, D=D),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=4, grid=(H, count),
-                in_specs=[_head_blocks(block, D, 1), _head_blocks(block, D, 0),
-                          _head_blocks(block, D, 0), q_ids_spec, kv_ids_spec,
-                          stat_spec, _head_blocks(block, D, 1), stat_spec],
+                num_scalar_prefetch=4, grid=(n, count),
+                in_specs=[_row_blocks(block, width, 1, first[0]),
+                          _row_blocks(block, width, 0, first[1]),
+                          _row_blocks(block, width, 0, first[2]),
+                          q_ids_spec, kv_ids_spec, stat_spec,
+                          _row_blocks(block, width, 1), stat_spec],
                 out_specs=[
-                    pl.BlockSpec((None, None, T, D),
-                                 lambda h, p, row, *_: (row[p], h, 0, 0)),
-                    _head_blocks(block, D, 0), _head_blocks(block, D, 0)],
-                scratch_shapes=[pltpu.VMEM((T, D), jnp.float32),
-                                pltpu.VMEM((block, D), jnp.float32),
-                                pltpu.VMEM((block, D), jnp.float32)]),
-            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
-                       for x in (q, k, v)],
+                    pl.BlockSpec((None, T, width),
+                                 lambda c, p, row, *_: (row[p], 0, c)),
+                    _row_blocks(block, width, 0),
+                    _row_blocks(block, width, 0)],
+                scratch_shapes=[pltpu.VMEM((T, width), jnp.float32),
+                                pltpu.VMEM((block, width), jnp.float32),
+                                pltpu.VMEM((block, width), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct(do.shape, do.dtype)] * 3,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
-                vmem_limit_bytes=_vmem_limit(T)),
-            name=name, interpret=_FORCE_INTERPRET,
-        )(*table, q, k, v, q_ids, kv_ids, wide(lse), do, wide(di))
+                vmem_limit_bytes=_vmem_limit(T, width, True)),
+            name=name, interpret=interpret,
+        )(*table, q, k, v, q_ids, kv_ids, stat(lse), do, stat(di))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _packed_attention(q, k, v, seg_q, seg_kv, block: int):
-    """Causal attention inside the segments of ``[B, H, T, D]`` rows, over
-    the block pairs the rows' ids need and no others."""
-    return _fwd_call(q, k, v, seg_q, seg_kv, block=block,
-                     save_residuals=False)
+@functools.partial(jax.jit, static_argnames="block")
+def _kv_major_pairs(seg_q, seg_kv, *, block: int):
+    """The fused backward's pair list (jitted for the reason above)."""
+    return _pair_list(needed_pairs(seg_q, seg_kv, block).swapaxes(1, 2))
 
 
-def _packed_attention_fwd(q, k, v, seg_q, seg_kv, block):
-    out, lse = _fwd_call(q, k, v, seg_q, seg_kv, block=block,
-                         save_residuals=True)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _packed_attention(arrays, seg_q, seg_kv, H: int, block: int):
+    """Causal attention inside the segments of ``[B, T]`` rows of H heads,
+    rows-major (``arrays``: :func:`_layout`) -> ``[B, T, H D]``, over the
+    block pairs the rows' ids need and no others."""
+    return _fwd_call(arrays, seg_q, seg_kv, H=H, block=block,
+                     save_residuals=False, interpret=_FORCE_INTERPRET)
+
+
+def _packed_attention_fwd(arrays, seg_q, seg_kv, H, block):
+    out, lse = _fwd_call(arrays, seg_q, seg_kv, H=H, block=block,
+                         save_residuals=True, interpret=_FORCE_INTERPRET)
     # what a remat-wrapped block keeps (KEEP_RESIDUALS), as the library
-    # names its own
+    # names its own: ``out`` is the array the output projection reads
     out = checkpoint_name(out, RESIDUAL_NAME)
     lse = checkpoint_name(lse, RESIDUAL_NAME)
-    return out, (q, k, v, seg_q, seg_kv, out, lse)
+    # the backward's list, kv-major
+    pairs = checkpoint_name(_kv_major_pairs(seg_q, seg_kv, block=block),
+                            PAIRS_NAME)
+    return out, (arrays, seg_q, seg_kv, pairs, out, lse)
 
 
-def _packed_attention_bwd(block, residuals, do):
-    q, k, v, seg_q, seg_kv, out, lse = residuals
-    di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32),
-                    do.astype(jnp.float32))
-    dq, dk, dv = _dkv_call(q, k, v, seg_q, seg_kv, lse, do, di, block=block)
-    return dq, dk, dv, None, None
+def _packed_attention_bwd(H, block, residuals, do):
+    arrays, seg_q, seg_kv, pairs, out, lse = residuals
+    # a head's sum of out * do over its own lanes, as a product with the
+    # 0 / 1 matrix that says which lanes are whose: a sum over PART of the
+    # lanes would first re-lay the whole [B, T, E] float32 array. `HIGH`
+    # (the operand in two bfloat16 pieces) is exact on the product of two
+    # bfloat16 numbers, so this is the float32 sum the library makes.
+    f32 = jnp.float32
+    mine = jnp.repeat(jnp.eye(H, dtype=f32), out.shape[-1] // H, axis=0)
+    di = jnp.einsum("bte,eh->bht", out.astype(f32) * do.astype(f32), mine,
+                    precision=jax.lax.Precision.HIGH)
+    grads = tuple(_dkv_call(arrays, seg_q, seg_kv, pairs, lse, do, di, H=H,
+                            block=block, interpret=_FORCE_INTERPRET))
+    if len(arrays) == 1:
+        grads = (jnp.concatenate(grads, axis=-1),)
+    return grads, None, None
 
 
 _packed_attention.defvjp(_packed_attention_fwd, _packed_attention_bwd)
 
 
-def block_pairs(q: jax.Array, attention_mask: Optional[jax.Array],
+def block_pairs(q, attention_mask: Optional[jax.Array],
                 segment_ids: Optional[jax.Array]
                 ) -> Optional[tuple[jax.Array, jax.Array]]:
     """``(pairs the kernels run, pairs the causal mask alone would run)``
-    of one :func:`flash_attention` call on these arguments, summed over the
-    rows (the forward's list; the backward's is its transpose), or None
-    where the library's kernels run over the constants. Their ratio is the share of the causal
-    half the packing leaves: 1.0 for rows of one document each."""
-    T = q.shape[1]
-    if not (_selected(q, attention_mask) and _table_engages(T, segment_ids)):
+    of one :func:`flash_attention` call on these arguments (``q``: the
+    ``[B, T, H, D]`` array or its shape alone), summed over the rows (the
+    forward's list; the backward's is its transpose), or None where the
+    library's kernels run over the constants. Their ratio is the share of
+    the causal half the packing leaves: 1.0 for rows of one document each."""
+    _, T, H, D = q.shape
+    if not (_selected(q.shape, attention_mask)
+            and _table_engages(T, _heads_here(H), D, segment_ids)):
         return None
     seg = segment_ids.astype(jnp.int32)
     needed = needed_pairs(seg, seg, _block_sizes(T).block_q)

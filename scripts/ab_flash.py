@@ -41,6 +41,20 @@ candidates that lost live only here.
                                   # does not, and the threshold the rule
                                   # takes
 
+    chiprun -- python scripts/ab_flash.py --layout
+                                  # where the operands lie: the library's
+                                  # kernels heads first, PR 40's pair list
+                                  # heads first (a head a grid row, blocks
+                                  # of 64 lanes), and `landed`'s pair list
+                                  # rows-major ([B, T, H D], two heads of
+                                  # 64 a 128-lane block) with a head's
+                                  # lanes taken by masks (what landed) or
+                                  # by static slices; each the kernels alone and from
+                                  # c_attn's fused [B, T, 3E] to c_proj's
+                                  # [B, T, E] with the splits and
+                                  # transposes it needs; GPT-2's shape, T =
+                                  # 512 (one block a row) and LFM2's
+
 A time is the device's: `reps` calls chained inside ONE jitted loop (each
 call's output is the next one's query), the wall clock around it with
 `block_until_ready`, the fastest of `--trials`, over `reps`. One compile
@@ -50,6 +64,8 @@ serves the timing (reps = n) and the check (reps = 1).
 from __future__ import annotations
 
 import argparse
+import contextlib
+import functools
 import json
 import os
 import sys
@@ -387,6 +403,285 @@ def packed_summary(rows):
     return "\n".join(lines)
 
 
+# -- where the operands lie ---------------------------------------------------
+
+LAYOUT = [((4, 20, 1024, 64), "packed-b4-t1024"),
+          ((4, 20, 512, 64), "packed-b4-t1024"),
+          ((2, 32, 8192, 64), "packed-b2-t8192")]
+
+
+@contextlib.contextmanager
+def patched(**values):
+    """`landed`'s module with these attributes for the length of a trace."""
+    kept = {name: getattr(landed_mod, name) for name in values}
+    for name, value in values.items():
+        setattr(landed_mod, name, value)
+    try:
+        yield
+    finally:
+        for name, value in kept.items():
+            setattr(landed_mod, name, value)
+
+
+def _head_by_slice(ref, g, D):
+    """`landed._head` by a static slice: head g's D lanes alone."""
+    return ref[:, g * D:(g + 1) * D]
+
+
+def _accumulate_by_slice(ref, g, D, share=None, *, scale=None,
+                         rows=slice(None)):
+    """`landed._accumulate` on head g's lanes alone."""
+    at = rows, slice(g * D, (g + 1) * D)
+    x = ref[at]
+    if scale is not None:
+        x = jnp.tile(scale, (1, -(-D // 128)))[:, :D] * x
+    ref[at] = x if share is None else x + share
+
+
+def _rejitted(call):
+    """A kernel call of `landed` under a jit of its own FUNCTION: jit's
+    traces are cached by the function and the operands' shapes, and one
+    made with the landed spelling must not answer for another."""
+    def own(*args, **kwargs):
+        return call.__wrapped__(*args, **kwargs)
+    return jax.jit(own, static_argnames=("H", "block", "save_residuals",
+                                         "interpret"))
+
+
+SLICES = dict(_head=_head_by_slice, _accumulate=_accumulate_by_slice,
+              _fwd_call=_rejitted(landed_mod._fwd_call),
+              _dkv_call=_rejitted(landed_mod._dkv_call))
+
+
+def _heads_first_call(q, k, v, seg, lse=None, do=None, di=None, *,
+                      block, save_residuals=False):
+    """PR 40's grid and layout around `landed`'s kernel bodies: a head a
+    grid row, `[block, D]` blocks of `[B, H, T, D]` arrays (a head of 64 in
+    half a 128-lane tile). Forward, or with `do` the fused backward."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    m = landed_mod
+    B, H, T, D = q.shape
+    backward = do is not None
+    needed = m.needed_pairs(seg, seg, block)
+    *table, count = m._pair_list(needed.swapaxes(1, 2) if backward
+                                 else needed)
+    (q_ids, q_spec), (kv_ids, kv_spec) = m._ids_operands(
+        seg, seg, block, kv_axis=0 if backward else 1)
+    head = lambda which: pl.BlockSpec(
+        (None, None, block, D),
+        lambda h, p, row, *at: (row[p], h, at[which][p], 0))
+    stat = lambda which: pl.BlockSpec(
+        (1, 1, block), lambda h, p, row, *at: (row[p] * H + h, 0,
+                                                at[which][p]))
+    f32 = jnp.float32
+    if not backward:
+        out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+        out_specs = [head(0)]
+        if save_residuals:
+            out_shape.append(jax.ShapeDtypeStruct((B * H, 1, T), f32))
+            out_specs.append(stat(0))
+        name = m._kernel_name(is_mqa=False, save_residuals=save_residuals,
+                              is_segmented=True, phase="fwd")
+        out, *rest = pl.pallas_call(
+            functools.partial(m._fwd_kernel, block=block, D=D),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(H, count),
+                in_specs=[head(0), head(1), head(1), q_spec, kv_spec],
+                out_specs=out_specs,
+                scratch_shapes=[pltpu.VMEM((1, block, 128), f32),
+                                pltpu.VMEM((1, block, 128), f32),
+                                pltpu.VMEM((block, D), f32)]),
+            out_shape=out_shape, name=name,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+        )(*table, q, k, v, q_ids, kv_ids)
+        return (out, rest[0]) if save_residuals else out
+    name = m._kernel_name(is_mqa=False, save_residuals=False,
+                          is_segmented=True, phase="dkv")
+    return pl.pallas_call(
+        functools.partial(m._dkv_kernel, block=block, D=D),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(H, count),
+            in_specs=[head(1), head(0), head(0), q_spec, kv_spec, stat(1),
+                      head(1), stat(1)],
+            out_specs=[pl.BlockSpec((None, None, T, D),
+                                    lambda h, p, row, *_: (row[p], h, 0, 0)),
+                       head(0), head(0)],
+            scratch_shapes=[pltpu.VMEM((T, D), f32),
+                            pltpu.VMEM((block, D), f32),
+                            pltpu.VMEM((block, D), f32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3, name=name,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=m._vmem_limit(T, 128, True)),
+    )(*table, q, k, v, q_ids, kv_ids, lse, do, di)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def heads_first_pairs(q, k, v, seg, block):
+    return _heads_first_call(q, k, v, seg, block=block)
+
+
+def _heads_first_fwd(q, k, v, seg, block):
+    out, lse = _heads_first_call(q, k, v, seg, block=block,
+                                 save_residuals=True)
+    return out, (q, k, v, seg, out, lse)
+
+
+def _heads_first_bwd(block, residuals, do):
+    q, k, v, seg, out, lse = residuals
+    di = jnp.einsum("bhsd,bhsd->bhs", out.astype(jnp.float32),
+                    do.astype(jnp.float32)).reshape(lse.shape)
+    return (*_heads_first_call(q, k, v, seg, lse, do, di, block=block),
+            None)
+
+
+heads_first_pairs.defvjp(_heads_first_fwd, _heads_first_bwd)
+
+
+def layout_candidates(H, D, T):
+    """(name, where, fn(x, seg) -> out, the layout of x): `where` is
+    "kernels" (operands and result already as the kernels take them) or
+    "block" (from c_attn's fused [B, T, 3E] to c_proj's [B, T, E], with
+    every split, scaling and transpose between)."""
+    m = landed_mod
+    block = m._block_sizes(T).block_q
+    always = dict(TABLE_MIN_BLOCKS=1)        # T = 512 is one block a row
+    never = dict(TABLE_MIN_BLOCKS=T)
+    E = H * D
+
+    def library(x, seg):
+        q, k, v = x
+        ids = sk.SegmentIds(q=seg, kv=seg)
+        return jax.vmap(m._causal_kernel(T, H))(q * D ** -0.5, k, v, ids)
+
+    def pairs(x, seg):
+        return heads_first_pairs(*x, seg, block)
+
+    def rows_major(spelling):
+        def fn(x, seg):
+            with patched(**spelling):
+                return m._packed_attention(tuple(x), seg, seg, H, block)
+        return fn
+
+    def split(x):
+        return [a.reshape(*a.shape[:2], H, D)
+                for a in jnp.split(x[0], 3, axis=-1)]
+
+    def block_library(x, seg):
+        with patched(**never):
+            return m.flash_attention(*split(x), segment_ids=seg).reshape(
+                *x[0].shape[:2], E)
+
+    def block_pairs(x, seg):
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in split(x))
+        out = heads_first_pairs(q, k, v, seg, block)
+        return out.transpose(0, 2, 1, 3).reshape(*x[0].shape[:2], E)
+
+    def block_rows_major(spelling):
+        def fn(x, seg):
+            with patched(**always, **spelling):
+                return m.flash_attention_qkv(x[0], H, segment_ids=seg)
+        return fn
+
+    return [
+        ("library, heads first", "kernels", library, "heads_first"),
+        ("pair list, heads first (PR 40)", "kernels", pairs, "heads_first"),
+        ("pair list, rows-major, lane slices", "kernels", rows_major(SLICES),
+         "rows_major"),
+        ("pair list, rows-major, lane masks", "kernels", rows_major({}),
+         "rows_major"),
+        ("library, heads first", "block", block_library, "fused"),
+        ("pair list, heads first (PR 40)", "block", block_pairs, "fused"),
+        ("pair list, rows-major, lane slices", "block",
+         block_rows_major(SLICES), "fused"),
+        ("pair list, rows-major, lane masks", "block",
+         block_rows_major({}), "fused"),
+    ]
+
+
+def layout_programs(fn, grad):
+    """(x, do, seg, reps) -> the LAST call's (out, dx): `reps` calls
+    chained through the first operand (forward: the output goes back into
+    q's place; with the gradient: dx is the next x)."""
+    def feed(x, out):
+        if len(x) == 3:
+            return (out,) + tuple(x[1:])
+        return (jax.lax.dynamic_update_slice(x[0], out, (0, 0, 0)),)
+
+    def fwd(x, do, seg, reps):
+        def body(_, x):
+            return feed(x, fn(x, seg))
+        return fn(jax.lax.fori_loop(0, reps - 1, body, x), seg), None
+
+    def fwd_bwd(x, do, seg, reps):
+        def body(_, c):
+            out, vjp = jax.vjp(lambda x_: fn(x_, seg), c[1])
+            return out, vjp(do)[0]
+        return jax.lax.fori_loop(0, reps, body, (jnp.zeros_like(do), x))
+    return jax.jit(fwd_bwd if grad else fwd)
+
+
+def run_layout(args, sharding):
+    rows = []
+    for shape, mix_name in LAYOUT:
+        B, H, T, D = shape
+        seg = cell_documents(mix_name, B, T, args.seed)
+        rng = np.random.default_rng(args.seed)
+        qkv = rng.standard_normal((B, T, 3, H, D)).astype(np.float32)
+        do = rng.standard_normal((B, T, H, D)).astype(np.float32)
+
+        def put(x, dt=jnp.bfloat16):
+            if sharding is not None:
+                return jax.ShapeDtypeStruct(x.shape, dt, sharding=sharding)
+            return jnp.asarray(x, dt)
+        laid = {
+            "heads_first": (tuple(put(qkv[:, :, i].transpose(0, 2, 1, 3))
+                                  for i in range(3)),
+                            put(do.transpose(0, 2, 1, 3))),
+            "rows_major": (tuple(put(qkv[:, :, i].reshape(B, T, H * D))
+                                 for i in range(3)),
+                           put(do.reshape(B, T, H * D))),
+            "fused": ((put(qkv.reshape(B, T, 3 * H * D)),),
+                      put(do.reshape(B, T, H * D))),
+        }
+        seg = put(seg, jnp.int32)
+        ref = None
+        for name, where, fn, layout in layout_candidates(H, D, T):
+            x, cot = laid[layout]
+            row = {"shape": list(shape), "candidate": name, "where": where}
+            try:
+                if sharding is not None:
+                    for grad in (False, True):
+                        layout_programs(fn, grad).trace(
+                            x, cot, seg, 1).lower(
+                            lowering_platforms=("tpu",)).compile()
+                    row["compiled"] = True
+                else:
+                    row["fwd_ms"] = time_ms(layout_programs(fn, False),
+                                            (x, cot, seg), args.reps,
+                                            args.trials)
+                    prog = layout_programs(fn, True)
+                    row["fwd_bwd_ms"] = time_ms(prog, (x, cot, seg),
+                                                args.reps, args.trials)
+                    if where == "block":
+                        # out [B, T, E] and d(qkv) [B, T, 3E] against the
+                        # library's, in one layout whatever the kernels'
+                        out, dx = prog(x, cot, seg, 1)
+                        got = (out, *jnp.split(dx[0], 3, axis=-1))
+                        if ref is None:
+                            ref = got
+                        row["err_packed"] = worst(got, ref)
+                        row["ok"] = (row["err_packed"][0] <= ATOL[0] and
+                                     max(row["err_packed"][1:]) <= ATOL[1])
+            except Exception as e:
+                row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+    return rows
+
+
 # -- inputs -----------------------------------------------------------------
 
 def make_inputs(shape, seed, packed):
@@ -514,7 +809,8 @@ def table(rows):
             note = "compiled"
         else:
             note = f"{er(r.get('err_packed'))}; {er(r.get('err_unpacked'))}"
-        lines.append(f"| {','.join(map(str, r['shape']))} | {r['candidate']} "
+        lines.append(f"| {','.join(map(str, r['shape']))} | {r['candidate']}"
+                     f"{' (' + r['where'] + ')' if 'where' in r else ''} "
                      f"| {ms(r.get('fwd_ms'))} | {ms(r.get('fwd_bwd_ms'))} "
                      f"| {note} | {r.get('ok', '')} |")
     return "\n".join(lines)
@@ -531,6 +827,8 @@ def main(argv=None) -> int:
                     help="the grouped-query A/B alone (see above)")
     ap.add_argument("--packed", action="store_true",
                     help="causal constants against the pair list (see above)")
+    ap.add_argument("--layout", action="store_true",
+                    help="where the operands lie (see above)")
     ap.add_argument("--only", default="",
                     help="substring a candidate's name must hold")
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
@@ -562,6 +860,9 @@ def main(argv=None) -> int:
     elif args.packed:
         rows += run_packed(args, sharding)
         args.out = os.path.splitext(args.out)[0] + "_packed.json"
+    elif args.layout:
+        rows += run_layout(args, sharding)
+        args.out = os.path.splitext(args.out)[0] + "_layout.json"
     else:
         rows += run_shape(TRAIN, cands, args, oracle, sharding)
         fwd_only = [c for c in cands if c[2] == "fwd"]

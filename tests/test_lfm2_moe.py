@@ -467,17 +467,18 @@ def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
 
 def test_the_step_counts_the_block_pairs_its_attention_runs(tiny):
     """Where the flash kernels take their block tables from the rows'
-    segment ids (a head of 64 and nine blocks of 128 a row here, the
-    kernels interpreted), the step returns how many block pairs ran and how
+    segment ids (two heads of 64 over one K/V head and three blocks of 128
+    a row here, the kernels interpreted), the step returns how many block pairs ran and how
     many the causal mask alone would have run, and `MinerLoop` counts them
     with the routed layers' rows. Their ratio is the brute-force share of
     the batch's own [T, T] mask."""
     from distributedtraining_tpu.ops import flash_attention as fl
     _, pc, _, _, _ = tiny
     T, block = 128 * (fl.TABLE_MIN_BLOCKS + 1), 128
-    one_head = dataclasses.replace(pc, num_attention_heads=1,
-                                   num_key_value_heads=1)
-    model, _ = lf.make_model(one_head)
+    two_heads = dataclasses.replace(pc, hidden_size=128,
+                                    num_attention_heads=2,
+                                    num_key_value_heads=1)
+    model, _ = lf.make_model(two_heads)
     rng = np.random.default_rng(11)
     rows = []
     for _ in range(2):

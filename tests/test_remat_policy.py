@@ -107,32 +107,45 @@ def test_forward_kernel_calls_a_layer(family, case, kernel_selected,
     assert all(e.params["policy"] is want for e in remats)
 
 
+# two heads of 64 (one 128-lane block of `[B, T, H D]`) and rows of three
+# blocks of 128: the smallest shape at which `flash_attention`'s rule hands
+# the packed rows to this module's own rows-major kernels
+LONG = 128 * (flash_attention.TABLE_MIN_BLOCKS + 1)
+TWO_HEADS = {
+    "gpt2": dict(n_embd=128, n_head=2, n_positions=LONG),
+    "lfm2": dict(hidden_size=128, num_attention_heads=2,
+                 num_key_value_heads=2),
+}
+
+
+@pytest.mark.parametrize("family", TWO_HEADS)
 @pytest.mark.parametrize("case", ["remat_policy", "remat_bare",
                                   "no_remat"])
 def test_forward_kernel_calls_a_layer_where_the_pair_list_engages(
-        case, kernel_selected, monkeypatch):
+        family, case, kernel_selected, monkeypatch):
     """Packed rows long enough for the pair list of their own ids
-    (`flash_attention.TABLE_MIN_BLOCKS`) run this module's kernels, not the
-    library's: its forward rule names `out` and the log-sum-exp as the
-    library names its own, and the policy keeps them, so the re-run calls
-    no kernel."""
-    module, cfg, layers = FAMILIES["lfm2"]
+    (`flash_attention.TABLE_MIN_BLOCKS`) whose heads fill lane blocks run
+    this module's kernels, not the library's: its forward rule names `out`
+    and the log-sum-exp as the library names its own, and the policy keeps
+    them, so the re-run calls no kernel."""
+    module, cfg, layers = FAMILIES[family]
     remat, policy, _, forward_a_layer = CASES[case]
     if not policy:
         monkeypatch.setattr(module, "remat_policy", lambda: None)
-    long = 128 * (flash_attention.TABLE_MIN_BLOCKS + 1)
-    eqns = _step_equations(module, dataclasses.replace(cfg, remat=remat),
-                           T=long)
+    cfg = dataclasses.replace(cfg, remat=remat, **TWO_HEADS[family])
+    eqns = _step_equations(module, cfg, T=LONG)
     assert _kernel_calls(eqns) == (forward_a_layer * layers, layers)
     kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
     assert all(e.params["grid_mapping"].num_dynamic_grid_bounds == 1
                for e in kernels)
-    named = [e for e in eqns if e.primitive.name == "name"]
-    assert {e.params["name"] for e in named} == {
-        flash_attention.RESIDUAL_NAME}
+    named = [e.params["name"] for e in eqns if e.primitive.name == "name"]
+    assert set(named) == {flash_attention.RESIDUAL_NAME,
+                          flash_attention.PAIRS_NAME}
     if policy:
-        # (out, log-sum-exp), in the forward alone
-        assert len(named) == 2 * layers
+        # (out, log-sum-exp) and the backward's pair list (four arrays and
+        # their count), in the forward alone
+        assert named.count(flash_attention.RESIDUAL_NAME) == 2 * layers
+        assert named.count(flash_attention.PAIRS_NAME) == 5 * layers
 
 
 @pytest.mark.parametrize("axes,forward_a_layer", [
@@ -154,9 +167,8 @@ def test_the_pair_list_is_made_inside_the_shard_map(kernel_selected):
     """Under a mesh the list is each device's own: made from the rows it
     holds, inside the `shard_map`, and the kernels' grid bound with it."""
     module, cfg, layers = FAMILIES["lfm2"]
-    long = 128 * (flash_attention.TABLE_MIN_BLOCKS + 1)
-    eqns = _step_equations(module, dataclasses.replace(cfg, remat=True),
-                           make_mesh(MeshConfig(dp=2)), T=long)
+    cfg = dataclasses.replace(cfg, remat=True, **TWO_HEADS["lfm2"])
+    eqns = _step_equations(module, cfg, make_mesh(MeshConfig(dp=2)), T=LONG)
     assert _kernel_calls(eqns) == (layers, layers)
     maps = [e for e in eqns if e.primitive.name == "shard_map"]
     inside = [e for m in maps for e in _equations(m.params["jaxpr"])]
@@ -168,6 +180,26 @@ def test_the_pair_list_is_made_inside_the_shard_map(kernel_selected):
     n = flash_attention.TABLE_MIN_BLOCKS + 1
     assert all(e.invars[1].aval.shape == (n * (n + 1) // 2,)
                for e in kernels)
+
+
+@pytest.mark.parametrize("axes, rows_major", [
+    (dict(dp=2), True),             # the fused array, rows over dp
+    (dict(fsdp=2, tp=2), False),    # one head a device: the library's
+    (dict(tp=2), False)])
+def test_the_fused_projection_reaches_the_kernel_unsplit_on_a_mesh(
+        axes, rows_major, kernel_selected):
+    """GPT-2's block hands `c_attn`'s `[B, T, 3E]` to the kernels as it
+    lies where every device holds all the heads; heads split over `tp`
+    (an odd count a device here) take the split entry, heads first."""
+    module, cfg, layers = FAMILIES["gpt2"]
+    cfg = dataclasses.replace(cfg, remat=True, **TWO_HEADS["gpt2"])
+    eqns = _step_equations(module, cfg, make_mesh(MeshConfig(**axes)),
+                           T=LONG)
+    assert _kernel_calls(eqns) == (layers, layers)
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    fused = [any(v.aval.shape[1:] == (LONG, 3 * cfg.n_embd)
+                 for v in e.invars) for e in kernels]
+    assert all(fused) if rows_major else not any(fused)
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -203,3 +235,28 @@ def test_the_three_models_take_one_policy_object():
     assert policy is attention.remat_policy() is flash_attention.KEEP_RESIDUALS
     assert (gpt2.remat_policy is llama.remat_policy
             is lfm2_moe.remat_policy is attention.remat_policy)
+
+
+def test_a_gpt2_block_keeps_the_rows_major_output_and_the_log_sum_exp(
+        kernel_selected):
+    """Where this module's kernels run, what a remat-wrapped GPT-2 block
+    keeps under the name is exactly `out` as `c_proj` reads it,
+    `[B, T, E]` in the compute dtype, and the `[B, H, T]` float32
+    log-sum-exp, a layer: no heads-first copy, no padded head."""
+    module, cfg, layers = FAMILIES["gpt2"]
+    cfg = dataclasses.replace(cfg, remat=True, **TWO_HEADS["gpt2"])
+    eqns = _step_equations(module, cfg, T=LONG)
+    named = [e for e in eqns if e.primitive.name == "name"]
+    kept = sorted((tuple(e.outvars[0].aval.shape),
+                   str(e.outvars[0].aval.dtype)) for e in named
+                  if e.params["name"] == flash_attention.RESIDUAL_NAME)
+    assert kept == (layers * [((2, cfg.n_head, LONG), "float32")]
+                    + layers * [((2, LONG, cfg.n_embd), cfg.dtype)])
+    # beside them, under its own name: the backward's pair list, at most a
+    # row's triangle of block pairs in four int32 arrays and their count
+    n = LONG // 128
+    pairs = [e.outvars[0].aval for e in named
+             if e.params["name"] == flash_attention.PAIRS_NAME]
+    assert len(pairs) == 5 * layers
+    assert {a.shape for a in pairs} == {(), (2 * n * (n + 1) // 2,)}
+    assert {str(a.dtype) for a in pairs} == {"int32"}

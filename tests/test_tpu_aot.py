@@ -237,6 +237,46 @@ def test_causal_attention_gradient_compiles_at_the_train_cells_shape(
     assert fwd.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("cell", ["train-large-t1024", "train-lfm2-t8192"])
+def test_rows_major_attention_gradient_compiles_with_nothing_heads_first(
+        one_chip, monkeypatch, cell):
+    """The two train cells' attention as their blocks call it: GPT-2's
+    fused `[4, 1024, 3 x 1280]` through `flash_attention_qkv`, LFM2's
+    `[2, 8192, 32, 64]` through `flash_attention`. Mosaic takes the static
+    64-lane slices of a 128-lane block (two heads a block), the gradient
+    holds the two kernels under the names the trace readers know, and no
+    array of the compiled program lies heads first or carries a statistic
+    128 lanes wide."""
+    from distributedtraining_tpu.ops import flash_attention as fl
+    monkeypatch.setattr(fl, "_on_tpu", lambda: True)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if cell == "train-large-t1024":
+        B, T, H, D = 4, 1024, 20, 64
+
+        def loss(qkv, w, seg):
+            out = fl.flash_attention_qkv(qkv, H, segment_ids=seg)
+            return jnp.sum(out.astype(jnp.float32) * w)
+        args = (sds((B, T, 3 * H * D)), sds((B, T, H * D), jnp.float32))
+        wrt = 0
+    else:
+        B, T, H, D = 2, 8192, 32, 64
+
+        def loss(q, k, v, w, seg):
+            out = fl.flash_attention(q, k, v, segment_ids=seg)
+            return jnp.sum(out.astype(jnp.float32) * w)
+        args = (sds((B, T, H, D)),) * 3 + (sds((B, T, H, D), jnp.float32),)
+        wrt = (0, 1, 2)
+    text = _compile(jax.grad(loss, argnums=wrt), *args,
+                    sds((B, T), jnp.int32)).as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "%flash_mha_fwd_segmented_residuals" in text
+    assert "%flash_mha_dkv_segmented_no_residuals" in text
+    assert not re.findall(rf"\[{B},{H},{T},(?:{D}|128)\]", text)
+
+
 @pytest.mark.parametrize("tokens", [16384, 1024])
 def test_held_expert_layer_gradient_compiles_at_published_widths(
         one_chip, monkeypatch, tokens):

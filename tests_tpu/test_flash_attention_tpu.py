@@ -1,8 +1,9 @@
 """Flash-attention numerics on the real chip: forward AND grad parity vs the
 dense oracle at T in {256, 1024}, packed segments included, at the train
 cell's own shape with document boundaries off every block grid, and at the
-LFM2 cell's (B=2, H=32, T=8,192), where the kernels are this module's own
-over the pair list of the rows' segment ids.
+LFM2 cell's (B=2, H=32, T=8,192); at both the kernels are this module's own
+over the pair list of the rows' segment ids, rows-major (two heads of 64 a
+128-lane block), and GPT-2's block hands them c_attn's fused array.
 
 This is the on-device half of tests/test_flash_attention.py (which pins
 the selection rule and the block schedule on CPU). The schedule
@@ -120,6 +121,52 @@ def test_train_cell_shape_unaligned_documents(seed):
 
 
 @pytest.mark.parametrize("seed", [1, 2])
+def test_train_cell_shape_the_fused_entry(seed, monkeypatch):
+    """`train-large-t1024` as GPT-2's block calls it: c_attn's fused
+    `[4, 1024, 3 x 1280]` through `flash_attention_qkv` (this module's
+    rows-major kernels, two heads of 64 a lane block, at two blocks a row)
+    against `flash_attention` on the three parts, bit for bit in output and
+    gradients, and against the library's kernels heads first: `out` bit
+    for bit, the gradients to a rounding (dQ adds up in float32 here, `di`
+    is summed as a product with the heads' 0 / 1 matrix)."""
+    B, T, H, D = 4, 1024, 20, 64
+    E = H * D
+    qkv = jnp.concatenate([x.reshape(B, T, E) for x in
+                           _qkv(B=B, T=T, H=H, D=D, seed=seed)], axis=-1)
+    w = _qkv(B=B, T=T, H=H, D=D, seed=seed + 10)[0].reshape(B, T, E)
+    seg = _documents(B, T, seed=seed)
+    assert fl._table_engages(T, H, D, seg)
+    run, causal = fl.block_pairs(jax.ShapeDtypeStruct((B, T, H, D), w.dtype),
+                                 None, seg)
+    assert 2 * B <= int(run) <= int(causal) == 3 * B
+
+    def fused(qkv):
+        return fl.flash_attention_qkv(qkv, H, segment_ids=seg)
+
+    def split(qkv):
+        q, k, v = (x.reshape(B, T, H, D) for x in jnp.split(qkv, 3, -1))
+        return flash_attention(q, k, v, segment_ids=seg).reshape(B, T, E)
+
+    def with_gradient(fn):
+        def f(qkv):
+            out = fn(qkv)
+            return jnp.sum(out.astype(jnp.float32)
+                           * w.astype(jnp.float32)), out
+        (_, out), grad = jax.jit(jax.value_and_grad(f, has_aux=True))(qkv)
+        return np.asarray(out, np.float32), np.asarray(grad, np.float32)
+
+    got = with_gradient(fused)
+    for a, b in zip(got, with_gradient(split)):
+        np.testing.assert_array_equal(a, b)
+    monkeypatch.setattr(fl, "TABLE_MIN_BLOCKS", T)       # the library's
+    assert not fl._table_engages(T, H, D, seg)
+    out_l, grad_l = with_gradient(lambda x: split(x))
+    np.testing.assert_array_equal(got[0], out_l)
+    np.testing.assert_allclose(got[1], grad_l, rtol=2 ** -6,
+                               atol=2 ** -7 * np.abs(grad_l).max())
+
+
+@pytest.mark.parametrize("seed", [1, 2])
 def test_lfm2_cell_shape_pair_list_from_the_segment_ids(seed, monkeypatch):
     """B=2, H=32, T=8,192, D=64 packed (`train-lfm2-t8192`): the kernels
     visit the block pairs the rows' segment ids need. The output equals the
@@ -137,7 +184,7 @@ def test_lfm2_cell_shape_pair_list_from_the_segment_ids(seed, monkeypatch):
     q, k, v = _qkv(B=B, T=T, H=H, D=D, seed=seed)
     w = _qkv(B=B, T=T, H=H, D=D, seed=seed + 10)[0]
     seg = _documents(B, T, seed=seed)
-    assert fl._table_engages(T, seg)
+    assert fl._table_engages(T, H, D, seg)
     run, causal = fl.block_pairs(q, None, seg)
     assert 0 < int(run) < int(causal) == B * 16 * 17 // 2
 
@@ -154,7 +201,7 @@ def test_lfm2_cell_shape_pair_list_from_the_segment_ids(seed, monkeypatch):
 
     (_, out), grads = loss(flash, w)(q, k, v)
     monkeypatch.setattr(fl, "TABLE_MIN_BLOCKS", T)       # the constants
-    assert not fl._table_engages(T, seg)
+    assert not fl._table_engages(T, H, D, seg)
     (_, out_s), grads_s = loss(lambda *a: flash(*a), w)(q, k, v)
     for name, a, b in zip(("out", "dq", "dk", "dv"), (out, *grads),
                           (out_s, *grads_s)):
