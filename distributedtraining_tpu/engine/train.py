@@ -151,8 +151,9 @@ def _devprof_batch_bucket(batch) -> str:
 def _default_lm_loss(model, params, batch, *, with_counters: bool = False):
     """(mean loss, token count). ``with_counters`` (the train step's): a
     family whose layers count something a step (a routed layer's rows
-    here, rows left to other chips, its fullest expert: the model sows
-    them, summed over layers, under ``intermediates/train_counters``)
+    here, rows left to other chips, its fullest expert, held rows past
+    its static prefix: the model sows them, summed over layers, under
+    ``intermediates/train_counters``)
     gives ``(count, counters)`` in the count's place, so that they leave
     the step beside its loss; a family that sows none gives the count
     alone, and its step is the program it was."""

@@ -71,6 +71,7 @@ TRAIN_COUNTERS = {
     "moe_rows_elsewhere": "train.moe.rows_elsewhere",
     "moe_rows_fullest": "train.moe.rows_fullest_expert",
     "moe_experts_touched": "train.moe.experts_touched",
+    "moe_rows_past_prefix": "train.moe.rows_past_prefix",
 }
 # and its attention layers, where the flash kernels run the block pairs the
 # rows' segment ids need (ops/flash_attention.block_pairs)
@@ -312,7 +313,7 @@ class Lfm2MoeBlock(nn.Module):
             cfg.route_norm_eps)
         out, stats = moe.routed_experts(
             flat, choice, weights, w_in.astype(cdt), w_down.astype(cdt),
-            held=held, count_fullest=True)
+            held=held, router_experts=cfg.num_experts, count_fullest=True)
         return out.reshape(B, T, E), {name: stats[k]
                                       for k, name in TRAIN_COUNTERS.items()}
 

@@ -28,6 +28,35 @@ instruction: a serve program lowers to the text it had, which is why each
 rule sorts for the inverse in its own forward rule (the compiler folds the
 two sorts into one) and none is made ahead of the dispatch.
 
+With ``held`` the rows of a held expert sort FIRST, so a chip that holds
+``count`` of the router's experts has its work in rows ``0 ..
+sum(group_sizes) - 1`` of the sorted order and nothing but zeros behind
+them. A caller that also states the router's expert count gets a static
+PREFIX of the sorted rows (:func:`prefix_rows`: the expected share and a
+margin, whole row tiles): the dispatch gathers ``order[:P]``, both grouped
+products, the activation, the weigh, the mask and the casts run on ``[P,
+.]`` arrays with the group sizes clipped to the prefix, and the un-sort
+reads a ``[P, E]`` source (a position at or past ``P`` gives zero),
+forward, in remat's re-run and backward. The prefix path ALWAYS runs, at
+the top level of the program. Held rows past ``P`` (the OVERFLOW: a router
+further out of balance than the margin) are the one thing behind a
+``lax.cond``: that branch runs rows ``P .. R - 1`` with the remaining
+group sizes through the same functions, so no row is ever dropped; a step
+that enters it pays the full-width cost on top of the prefix's, and its
+grouped products are ``jax.lax.ragged_dot`` (on a TPU the compiler's own
+grouped product): the dropless guarantee, not the fast path, and every run
+of a step that never takes it is spared the tracing and lowering of six
+more Pallas kernels (3.5 s of a 34 s warm set-up: PERF.md, PR 43). No kernel
+call of the prefix path may sit under a ``cond``, nor be differentiated by
+a ``jax.vjp`` of this module's own: every reader of a device trace tells a
+kernel by the event's own name (``gmm``, ``tgmm``), and the compiler takes
+that name from the innermost wrapper of the call's ``op_name``: ``jit(gmm)``
+gives ``gmm``, the ``jvp(jit(gmm))`` that a transformation applied INSIDE
+the layer leaves (a differentiated ``cond``'s branches, a rule that calls
+``jax.vjp``) gives ``jvp_jit_gmm__`` (PERF.md, PRs 39 and 43). So the
+prefix is differentiated by the caller's ``jax.grad``, like the full-width
+layer, and only the overflow's gradient is made inside a rule.
+
 The router is DeepSeek-V3's ``noaux_tc`` with one group: sigmoid scores
 in float32, the choice made on ``scores + bias`` (the bias moves the
 choice and never the weights), the chosen scores normalised to sum to one
@@ -35,6 +64,9 @@ and scaled.
 """
 
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -143,69 +175,127 @@ def _weigh_held(y: jax.Array, w_sorted: jax.Array, here: jax.Array | None,
     return _held_rows(y.astype(jnp.float32) * w_sorted[:, None], here, order)
 
 
-@jax.custom_vjp
-def _dispatch(h: jax.Array, here: jax.Array | None, order: jax.Array
-              ) -> jax.Array:
-    """Token order -> expert order: ``h[order // k]``. Backward, a token's
-    ``k`` sorted rows are GATHERED along the inverse permutation and
-    summed in float32: no scatter-add into ``[N, E]``. A row of no held
-    group gives ZERO, whatever the grouped product's own backward left in
-    an output it never wrote (``gmm``'s is unwritten memory): that row's
-    expert is another chip's, and nothing of it may reach ``dh``. ``here``
-    is read in the backward alone."""
-    return jnp.take(h, order // (order.shape[0] // h.shape[0]), axis=0)
+# how far past its expected share of the sorted rows the prefix reaches. A
+# router balanced to a few percent (an auxiliary loss, a selection bias)
+# must stay inside, so a share that sits exactly AT its expectation (the
+# train cell: 16,384 of 65,536 rows a layer) may not sit at the bound; a
+# layer past it still computes every row and pays the full width on top,
+# about 12% of that cell's step. On the chip each sixteenth of margin
+# costs the cell 1.0-1.7% of its rate (a quarter 54,106 tokens/s, three
+# sixteenths 55,051, an eighth 55,590; the parent 47,470: PERF.md, PR 43):
+# an eighth pays only where fewer than one layer-step in 18 lands between
+# the two bounds, which `moe_rows_past_prefix` is there to tell
+PREFIX_MARGIN = 0.25
 
 
-def _dispatch_bwd(res, g):
+def prefix_rows(rows: int, count: int, router_experts: int) -> int:
+    """The static bound ``P``: of ``rows`` sorted rows, those a chip that
+    holds ``count`` of the router's ``router_experts`` expects (an even
+    router's share) and :data:`PREFIX_MARGIN` more, in whole row tiles of
+    the grouped product, never more than ``rows``."""
+    bound = math.ceil(rows * count / router_experts * (1 + PREFIX_MARGIN))
+    return min(rows, -(-bound // GMM_TILE_M) * GMM_TILE_M)
+
+
+def _clip_sizes(group_sizes: jax.Array, bound: int
+                ) -> tuple[jax.Array, jax.Array]:
+    """``group_sizes`` split at sorted row ``bound``: the rows of each
+    group that lie before it and those at or past it."""
+    ends = jnp.minimum(jnp.cumsum(group_sizes), bound)
+    inside = jnp.diff(ends, prepend=0)
+    return inside, group_sizes - inside
+
+
+def _window(order: jax.Array, window: tuple[int, int] | None) -> jax.Array:
+    return order if window is None else order[window[0]:window[1]]
+
+
+def _take_sorted(src: jax.Array, at: jax.Array,
+                 window: tuple[int, int] | None) -> jax.Array:
+    """Sorted rows ``at`` of ``src``, which holds the sorted rows
+    ``window = (lo, hi)`` alone: a row outside them reads ZERO (a select
+    on the index, whatever ``src`` holds)."""
+    axis = 0 if src.ndim > 1 else None      # as the full-width text has it
+    if window is None:
+        return jnp.take(src, at, axis=axis)
+    lo, hi = window
+    if lo:
+        # jnp.take wraps a negative index: send it past the end
+        at = jnp.where(at < lo, hi - lo, at - lo)
+    return jnp.take(src, at, axis=axis, mode="fill", fill_value=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(h: jax.Array, here: jax.Array | None, order: jax.Array,
+              window: tuple[int, int] | None) -> jax.Array:
+    """Token order -> expert order: ``h[order // k]``, of all sorted rows
+    or of those of ``window`` alone. Backward, a token's ``k`` sorted rows
+    are GATHERED along the inverse permutation and summed in float32: no
+    scatter-add into ``[N, E]``. A row of no held group gives ZERO,
+    whatever the grouped product's own backward left in an output it never
+    wrote (``gmm``'s is unwritten memory): that row's expert is another
+    chip's, and nothing of it may reach ``dh``; so does a row outside the
+    window. ``here`` is read in the backward alone."""
+    k = order.shape[0] // h.shape[0]
+    return jnp.take(h, _window(order, window) // k, axis=0)
+
+
+def _dispatch_fwd(h, here, order, window):
+    return (_dispatch(h, here, order, window),
+            (here, jnp.argsort(order).reshape(h.shape[0], -1).T))
+
+
+def _dispatch_bwd(window, res, g):
     # inverse [k, N]: the i-th sorted row of every token, so that the
     # gather writes k whole [N, E] planes and the sum re-tiles nothing;
     # plane i's row j is flat row j * k + i
     here, inverse = res
     k, N = inverse.shape
-    g = jnp.take(g, inverse, axis=0)                      # [k, N, E]
+    g = _take_sorted(g, inverse, window)                  # [k, N, E]
     g = _held_rows(g, here, jnp.arange(N * k).reshape(N, k).T)
     return (jnp.sum(g.astype(jnp.float32), axis=0).astype(g.dtype),
             None, None)
 
 
-_dispatch.defvjp(
-    lambda h, here, order: (
-        _dispatch(h, here, order),
-        (here, jnp.argsort(order).reshape(h.shape[0], -1).T)), _dispatch_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _combine_fwd(y, weights, here, order):
+def _combine_fwd(y, weights, here, order, window):
     N, k = weights.shape
-    out = _weigh_held(y, jnp.take(weights.reshape(-1), order), here, order)
+    rows = _window(order, window)
+    out = _weigh_held(y, jnp.take(weights.reshape(-1), rows), here, rows)
     # un-sort: row r of the sorted order came from flat row order[r]
     inverse = jnp.argsort(order)
-    out = jnp.take(out, inverse, axis=0)
+    out = _take_sorted(out, inverse, window)
     out = jnp.sum(out.reshape(N, k, -1), axis=1).astype(y.dtype)
     return out, (y, weights, here, order, inverse)
 
 
-@jax.custom_vjp
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _combine(y: jax.Array, weights: jax.Array, here: jax.Array | None,
-             order: jax.Array) -> jax.Array:
+             order: jax.Array, window: tuple[int, int] | None) -> jax.Array:
     """Expert order -> token order: the held rows weighed, un-sorted along
-    the inverse permutation and summed over ``k``. Backward, the
-    ``[N, E]`` cotangent comes to sorted order by ONE gather
+    the inverse permutation and summed over ``k``; ``y`` holds all sorted
+    rows, or those of ``window`` alone (the others add zero). Backward,
+    the ``[N, E]`` cotangent comes to sorted order by ONE gather
     (``order // k``: no ``[N, k, E]`` broadcast, no scatter-add) and a
     weight's goes back as a gather of ``[N * k]`` scalars. A row held
     elsewhere gives zero to ``y`` AND to its weight: the mask stands
     before and behind the product with whatever the grouped product left
     in that row of ``y`` (0 x NaN)."""
-    return _combine_fwd(y, weights, here, order)[0]
+    return _combine_fwd(y, weights, here, order, window)[0]
 
 
-def _combine_bwd(res, g):
+def _combine_bwd(window, res, g):
     y, weights, here, order, inverse = res
     k = weights.shape[1]
-    g = _held_rows(jnp.take(g, order // k, axis=0).astype(jnp.float32),
-                   here, order)
-    dw = jnp.sum(_held_rows(g * y.astype(jnp.float32), here, order), axis=-1)
-    dy = g * jnp.take(weights.reshape(-1), order)[:, None]
-    return (dy.astype(y.dtype), jnp.take(dw, inverse).reshape(weights.shape),
+    rows = _window(order, window)
+    g = _held_rows(jnp.take(g, rows // k, axis=0).astype(jnp.float32),
+                   here, rows)
+    dw = jnp.sum(_held_rows(g * y.astype(jnp.float32), here, rows), axis=-1)
+    dy = g * jnp.take(weights.reshape(-1), rows)[:, None]
+    return (dy.astype(y.dtype),
+            _take_sorted(dw, inverse, window).reshape(weights.shape),
             None, None)
 
 
@@ -255,9 +345,112 @@ def _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl,
     return grouped_matmul(act, w_down, group_sizes, impl=impl)
 
 
+def _rows(h, weights, w_in, w_down, here, order, sizes, window, impl,
+          swiglu_limit):
+    """What the sorted rows of ``window`` (None: all of them), ``sizes`` of
+    them to each group, add to the layer's ``[N, E]``; and those rows as
+    they left the experts."""
+    y = _experts_sorted(_dispatch(h, here, order, window), w_in, w_down,
+                        sizes, impl, swiglu_limit)
+    return _combine(y, weights, here, order, window), y
+
+
+# The overflow: held rows past the prefix, behind a ``lax.cond`` on their
+# count. The prefix itself is differentiated by the caller's own
+# ``jax.grad``, at the top level; two functions frame it so that the step
+# WITHOUT an overflow pays nothing for the branch it does not take.
+# :func:`_overflow_out`, behind the prefix, makes the forward;
+# :func:`_overflow_in`, ahead of it, is the identity forward, so its
+# transpose runs AFTER the prefix's and is handed the prefix's gradients:
+# the branch adds the overflow's to them, the other branch hands them
+# through untouched. Left to autodiff, the branch not taken would fill a
+# zero for every residual and cotangent of the one that is, and add them.
+# ``static = (bound, impl, swiglu_limit)``.
+
+@functools.partial(jax.jit, static_argnames=("static",))
+def _overflow_rows(y, operands, here, order, beyond, *, static):
+    """The layer at its full width: the rows past the prefix through the
+    experts, behind the prefix's own ``y``, and ONE un-sort and sum over
+    ``k`` of them all, so that the result is the plain formula's to the bit
+    whatever the routing (a sum of two ``[N, E]`` would round a token's
+    ``k`` rows in another order). Jitted, like :func:`_overflow_grads`: a
+    step's routed layers then trace and lower ONE such function, not one a
+    layer (a warm set-up pays for every branch the step holds)."""
+    bound, impl, swiglu_limit = static
+    h, weights, w_in, w_down = operands
+    rest = _experts_sorted(
+        _dispatch(h, here, order, (bound, order.shape[0])), w_in, w_down,
+        beyond, impl, swiglu_limit)
+    return _combine(jnp.concatenate([y, rest]), weights, here, order, None)
+
+
+@functools.partial(jax.jit, static_argnames=("static",))
+def _overflow_grads(g, operands, here, order, beyond, *, static):
+    """The gradients, with respect to ``operands``, of what the sorted
+    rows past the prefix add to the layer's ``[N, E]``."""
+    bound, impl, swiglu_limit = static
+    _, rest = jax.vjp(
+        lambda *operands: _rows(
+            *operands, here, order, beyond, (bound, order.shape[0]), impl,
+            swiglu_limit)[0], *operands)
+    return rest(g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _overflow_in(operands, here, order, beyond, static):
+    """``operands = (h, weights, w_in, w_down)`` as the prefix reads them,
+    and a ``[N, E]`` of zeros that :func:`_overflow_out` takes and never
+    reads: its cotangent is the layer's output's, which the overflow's
+    gradients need here."""
+    return operands, jnp.zeros_like(operands[0])
+
+
+def _overflow_in_fwd(operands, here, order, beyond, static):
+    return ((operands, jnp.zeros_like(operands[0])),
+            (operands, here, order, beyond))
+
+
+def _overflow_in_bwd(static, res, cotangents):
+    operands, here, order, beyond = res
+    grads, g = cotangents
+    return (jax.lax.cond(
+        jnp.sum(beyond) > 0,
+        lambda grads: jax.tree.map(jnp.add, grads, _overflow_grads(
+            g, operands, here, order, beyond, static=static)),
+        lambda grads: grads, grads), None, None, None)
+
+
+_overflow_in.defvjp(_overflow_in_fwd, _overflow_in_bwd)
+
+
+def _overflow_out_fwd(out, carrier, y, operands, here, order, beyond,
+                      static):
+    return jax.lax.cond(
+        jnp.sum(beyond) > 0,
+        lambda out, y: _overflow_rows(y, operands, here, order, beyond,
+                                      static=static),
+        lambda out, y: out, out, y), None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _overflow_out(out, carrier, y, operands, here, order, beyond, static):
+    """``out`` where the prefix held every held row, else
+    :func:`_overflow_rows`'s."""
+    return _overflow_out_fwd(out, carrier, y, operands, here, order, beyond,
+                             static)[0]
+
+
+_overflow_out.defvjp(
+    _overflow_out_fwd,
+    # the prefix's share of the output is the output's cotangent whole,
+    # and so is what the overflow's gradients are made from
+    lambda static, _, g: (g, g, None, None, None, None, None))
+
+
 def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
                    w_in: jax.Array, w_down: jax.Array, *,
                    held: tuple[int, int] | None = None,
+                   router_experts: int | None = None,
                    live: jax.Array | None = None,
                    impl: str | None = None,
                    count_fullest: bool = False,
@@ -280,30 +473,60 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     mask (:func:`_dispatch` owns the transpose of token -> expert order,
     :func:`_combine` that of the weigh, the un-sort and the sum over
     ``k``: gathers along ``order`` and its inverse, no scatter).
+    ``router_experts``, beside ``held``: how many experts the router
+    chooses among. With it only the first :func:`prefix_rows` sorted rows
+    are gathered, multiplied, activated, weighed and un-sorted, under no
+    ``cond``; held rows past them (counted: "moe_rows_past_prefix") run
+    behind one, through the same functions, so the layer stays dropless
+    whatever the routing, and a step that has any pays the full width's
+    cost PLUS the prefix's (through ``ragged_dot`` unless ``impl`` says
+    otherwise). Without it (and where the bound is all the
+    rows) the layer is the full-width one, instruction for instruction.
     ``swiglu_limit`` clamps a SwiGLU body (:func:`clamped_swiglu`).
     ``live`` [N] bool marks the rows that are not padding: every row is
     computed
     (shapes are static) and only live ones are counted. Returns ([N, E]
     in ``h``'s dtype, int32 scalars {"moe_rows": live rows computed here,
     "moe_experts_touched"} and, with ``held``, "moe_rows_elsewhere";
-    with ``count_fullest`` also "moe_rows_fullest", the rows of the
-    fullest held expert)."""
+    with ``router_experts`` "moe_rows_past_prefix"; with
+    ``count_fullest`` also "moe_rows_fullest", the rows of the fullest
+    held expert)."""
     N, k = choice.shape
     G = w_in.shape[0]
     flat, here = choice.reshape(-1), None                # [N*k]
+    bound = N * k
     if held is not None:
         first, count = held
         if count != G:
             raise ValueError(f"held {held} but the stacks hold {G} experts")
         here = (flat >= first) & (flat < first + count)
         flat = jnp.where(here, flat - first, G)          # G: not ours
+        if router_experts is not None:
+            if first + count > router_experts:
+                raise ValueError(f"held {held} of a router's "
+                                 f"{router_experts} experts")
+            bound = prefix_rows(N * k, count, router_experts)
     order = jnp.argsort(flat, stable=True)
     group_sizes = jnp.bincount(flat, length=G).astype(jnp.int32)
+    past = jnp.int32(0)
     with jax.named_scope("moe.experts"):
-        x_sorted = _dispatch(h, here, order)
-        y = _experts_sorted(x_sorted, w_in, w_down, group_sizes, impl,
-                            swiglu_limit)
-        out = _combine(y, weights, here, order)
+        if bound == N * k:
+            out, _ = _rows(h, weights, w_in, w_down, here, order,
+                           group_sizes, None, impl, swiglu_limit)
+        else:
+            inside, beyond = _clip_sizes(group_sizes, bound)
+            past = jnp.sum(beyond)
+            # the overflow's products are `ragged_dot` unless the caller
+            # chose: the compiler's own grouped product on a TPU, so a
+            # step that never overflows traces and lowers no second set of
+            # Pallas kernels for the rows it never runs
+            static = (bound, impl or "ragged_dot", swiglu_limit)
+            operands, carrier = _overflow_in((h, weights, w_in, w_down),
+                                             here, order, beyond, static)
+            out, y = _rows(*operands, here, order, inside, (0, bound), impl,
+                           swiglu_limit)
+            out = _overflow_out(out, carrier, y, (h, weights, w_in, w_down),
+                                here, order, beyond, static)
     if live is None:
         live_flat = None
         rows, touched = jnp.int32(N * k), jnp.sum(group_sizes > 0)
@@ -319,6 +542,8 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
         ours = jnp.sum(here if live_flat is None else here * live_flat)
         stats.update(moe_rows=ours.astype(jnp.int32),
                      moe_rows_elsewhere=(rows - ours).astype(jnp.int32))
+    if router_experts is not None:
+        stats["moe_rows_past_prefix"] = past
     if count_fullest:
         stats["moe_rows_fullest"] = jnp.max(group_sizes)
     return out, stats

@@ -97,6 +97,30 @@ def _flat_program(driver, tree, mixers):
 
 # -- program against reference ----------------------------------------------
 
+class _Sink:
+    """A sink that keeps nothing: with one configured the registry counts."""
+
+    def log(self, *_a, **_k):
+        pass
+
+    def close(self):
+        pass
+
+
+def _share(pc, params, count):
+    """The model and the tree of a chip that holds the first `count`
+    experts of every routed layer."""
+    model, _ = lf.make_model(dataclasses.replace(pc,
+                                                 experts_held=(0, count)))
+    params = dict(params)
+    for i in range(pc.num_dense_layers, pc.num_hidden_layers):
+        layer = dict(params[f"layer_{i}"])
+        layer["experts_in"] = layer["experts_in"][:count]
+        layer["experts_down"] = layer["experts_down"][:count]
+        params[f"layer_{i}"] = layer
+    return model, params
+
+
 def test_logits_match_the_reference(bench, tiny):
     reference, _ = bench
     model, pc, params, mcfg, weights = tiny
@@ -257,15 +281,23 @@ def test_remat_on_equals_remat_off(tiny):
                                    atol=1e-7)
 
 
-def test_the_train_step_moves_routed_rows_by_gathers_through_remat(tiny):
+@pytest.mark.parametrize("held", [
+    pytest.param(8, id="all-held"), pytest.param(2, id="a-quarter-prefix")])
+def test_the_train_step_moves_routed_rows_by_gathers_through_remat(tiny,
+                                                                   held):
     """The engine's own step over the rematted blocks: `ops/moe.py`'s two
     rules survive `nn.remat` and the engine's `jax.grad`, so nothing inside
     a routed layer scatters into anything a row wide. What scatters there:
     the `bincount` over the held groups (forward, and remat's re-run) and
-    the router's `[N, experts]` scores; outside, the lookup and the labels."""
+    the router's `[N, experts]` scores; outside, the lookup and the labels.
+    A quarter of the experts held: the prefix is on (128 of the 192 sorted
+    rows) and brings no scatter, in its path or in the overflow's branch."""
     from test_moe_grad import scatters
 
     _, pc, _, _, _ = tiny
+    pc = dataclasses.replace(pc, experts_held=(0, held))
+    assert moe.prefix_rows(2 * 48 * 2, held, pc.num_experts) == (
+        192 if held == 8 else 128)
     model, _ = lf.make_model(dataclasses.replace(pc, remat=True))
     engine = TrainEngine(model)
     step = engine.train_step.__wrapped__.trace(
@@ -405,33 +437,22 @@ def test_common_build_takes_the_fifth_family_and_trains_it(tmp_path):
 
 def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
         tiny):
-    model, pc, params, _, _ = tiny
-    half = dataclasses.replace(pc, experts_held=(0, 4))
-    model, _ = lf.make_model(half)
-    params = jax.tree_util.tree_map(lambda x: x, params)
-    for i in range(1, 5):
-        layer = dict(params[f"layer_{i}"])
-        layer["experts_in"] = layer["experts_in"][:4]
-        layer["experts_down"] = layer["experts_down"][:4]
-        params = dict(params, **{f"layer_{i}": layer})
+    _, pc, params, _, _ = tiny
+    model, params = _share(pc, params, 4)
     engine = TrainEngine(model)
     batch = _packed_batch(pc, [[48], [48]])
     _, m = engine.train_step(engine.init_state(params=params), batch)
     assert set(m) == {"loss", "tokens", *lf.TRAIN_COUNTERS.values()}
-    rows, away, fullest, touched = (
+    rows, away, fullest, touched, past = (
         int(m[k]) for k in lf.TRAIN_COUNTERS.values())
     # 4 routed layers x 96 tokens x 2 experts a token
     assert rows + away == 4 * 96 * 2 and 0 < rows < 4 * 96 * 2
     assert rows / 4 / 4 <= fullest / 4 <= rows / 4
     # each layer's rows fell to at least one and at most its 4 held experts
     assert 4 <= touched <= 4 * 4
-
-    class Sink:
-        def log(self, *_a, **_k):
-            pass
-
-        def close(self):
-            pass
+    # half the experts held: the prefix is 128 of a layer's 192 sorted rows,
+    # and a router this even stays inside it
+    assert past == 0 and rows / 4 < 128
 
     def run_loop():
         loop = MinerLoop(engine, InMemoryTransport(), "m0",
@@ -442,7 +463,7 @@ def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
 
     run_loop()                              # no sink: nothing is kept
     assert obs.registry().peek("train.moe.rows") is None
-    obs.configure(Sink(), role="miner")
+    obs.configure(_Sink(), role="miner")
     try:
         loop = run_loop()
         reg = obs.registry()
@@ -452,6 +473,7 @@ def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
         assert reg.peek("train.moe.rows_fullest_expert").value > 0
         # every routed layer of every step touched 1..8 of its experts
         assert 3 * 4 <= reg.peek("train.moe.experts_touched").value <= 96
+        assert reg.peek("train.moe.rows_past_prefix").value == 0
         assert loop._counted_dev == []
     finally:
         obs.reset()
@@ -463,6 +485,73 @@ def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
     _, gm = ge.train_step(ge.init_state(jax.random.PRNGKey(0)),
                           {"input_ids": ids})
     assert set(gm) == {"loss", "tokens"}
+
+
+def test_the_model_states_the_routers_expert_count(tiny, monkeypatch):
+    """`routed_experts` takes the prefix's bound from what its caller
+    states: the family passes `cfg.num_experts` beside `held`."""
+    model, pc, params, _, _ = tiny
+    seen = []
+    real = moe.routed_experts
+
+    def recorded(*args, **kwargs):
+        seen.append((kwargs["held"], kwargs["router_experts"]))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(moe, "routed_experts", recorded)
+    model.apply({"params": params},
+                _packed_batch(pc, [[48], [48]])["input_ids"])
+    assert seen == [(pc.experts_held, pc.num_experts)] * 4
+
+
+def test_a_step_past_the_prefix_counts_its_rows_and_loses_none(tiny,
+                                                               monkeypatch):
+    """A selection bias that sends EVERY row to the held half: 192 held
+    rows a layer against a prefix of 128, so each routed layer of the
+    engine's step runs its overflow, through remat. The step says so (4
+    layers x 64 rows), the registry adds it up, and loss and gradients are
+    those of the full-width layer (the bound at all the rows)."""
+    _, pc, params, _, _ = tiny
+    model, params = _share(dataclasses.replace(pc, remat=True), params, 4)
+    for i in range(pc.num_dense_layers, pc.num_hidden_layers):
+        params[f"layer_{i}"] = dict(
+            params[f"layer_{i}"], expert_bias=jnp.where(
+                jnp.arange(pc.num_experts) < 4, 10.0, 0.0))
+    batch = _packed_batch(pc, [[48], [48]])
+
+    def step():
+        engine = TrainEngine(model)
+        state, m = engine.train_step(engine.init_state(params=params), batch)
+        grads = jax.grad(lambda p: _default_lm_loss(model, p, batch)[0])(
+            params)
+        return engine, m, grads
+
+    engine, m, grads = step()
+    assert int(m["train.moe.rows"]) == 4 * 192
+    assert int(m["train.moe.rows_past_prefix"]) == 4 * (192 - 128)
+    monkeypatch.setattr(moe, "prefix_rows", lambda rows, *_: rows)
+    _, wide, wide_grads = step()
+    assert int(wide["train.moe.rows_past_prefix"]) == 0
+    assert float(m["loss"]) == float(wide["loss"])
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(wide_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-6 * max(
+                                       1.0, float(np.abs(b).max())))
+
+    monkeypatch.undo()
+    obs.configure(_Sink(), role="miner")
+    try:
+        loop = MinerLoop(engine, InMemoryTransport(), "m0",
+                         send_interval=1e9, check_update_interval=1e9)
+        loop.bootstrap(params=params)
+        loop.run(iter([batch, batch]))
+        # the first step's, at least: a step at 5e-4 against a bias of 10
+        # moves no choice
+        assert obs.registry().peek(
+            "train.moe.rows_past_prefix").value == 2 * 4 * 64
+    finally:
+        obs.reset()
 
 
 def test_the_step_counts_the_block_pairs_its_attention_runs(tiny):
@@ -496,15 +585,8 @@ def test_the_step_counts_the_block_pairs_its_attention_runs(tiny):
     params = model.init_params(jax.random.PRNGKey(0))
     run, causal = lf.ATTN_COUNTERS
 
-    class Sink:
-        def log(self, *_a, **_k):
-            pass
-
-        def close(self):
-            pass
-
     fl.use_interpret(True)
-    obs.configure(Sink(), role="miner")
+    obs.configure(_Sink(), role="miner")
     try:
         _, m = engine.train_step(engine.init_state(params=params), batch)
         loop = MinerLoop(engine, InMemoryTransport(), "m0",

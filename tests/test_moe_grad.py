@@ -7,7 +7,16 @@ The oracle is a dense masked sum: every row through every held expert,
 weighted by what it was routed there with. float32 on `ragged_dot` is
 exact to summation order; the interpreted Pallas grouped matmul takes
 bfloat16 operands, so it is held to a bfloat16 rounding of its own
-operands."""
+operands.
+
+Every case runs the full-width layer and, where the caller states the
+router's expert count, the static PREFIX of the sorted rows: with the held
+rows under the bound, exactly at it, and past it (the overflow's `cond`
+entered), which must change nothing the layer returns and no gradient
+beyond a rounding."""
+
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,14 +26,28 @@ import pytest
 from distributedtraining_tpu.ops import moe, ssm
 
 N, E, F, ROUTER, K = 96, 128, 128, 8, 3
+# a router of 16 for the prefix cases: the 4 held are a quarter, and
+# `moe.prefix_rows` gives 128 of the N * K = 288 sorted rows; 250 held rows
+# fill groups of about 62, so the bound cuts the third group and the fourth
+# lies whole behind it
+WIDE, BOUND = 16, 128
+# (held, the router's experts, held rows forced; None: as routed, and the
+# caller does not state the router's count: the full-width layer)
+LAYERS = [
+    pytest.param((None, ROUTER, None), id="all"),
+    pytest.param(((2, 4), ROUTER, None), id="held"),
+    pytest.param(((2, 4), WIDE, 100), id="prefix-under"),
+    pytest.param(((2, 4), WIDE, BOUND), id="prefix-at"),
+    pytest.param(((2, 4), WIDE, 250), id="prefix-past"),
+]
 
 
-def _setup(seed, dtype, held):
+def _setup(seed, dtype, held, router=ROUTER):
     rng = np.random.default_rng(seed)
-    count = ROUTER if held is None else held[1]
+    count = router if held is None else held[1]
     h = jnp.asarray(rng.standard_normal((N, E)), dtype)
-    w_r = jnp.asarray(rng.standard_normal((E, ROUTER)) * 0.2, jnp.float32)
-    bias = jnp.asarray(rng.standard_normal((ROUTER,)) * 0.05, jnp.float32)
+    w_r = jnp.asarray(rng.standard_normal((E, router)) * 0.2, jnp.float32)
+    bias = jnp.asarray(rng.standard_normal((router,)) * 0.05, jnp.float32)
     w_in = jnp.asarray(rng.standard_normal((count, E, 2 * F)) * 0.1, dtype)
     w_down = jnp.asarray(rng.standard_normal((count, F, E)) * 0.1, dtype)
     return h, w_r, bias, w_in, w_down
@@ -44,26 +67,65 @@ def _dense(h, weights, choice, w_in, w_down, held):
     return out
 
 
+def _hold(choice, held, rows):
+    """`choice` with exactly `rows` of its flat rows held (None: as it
+    is): held rows past that many go to expert 0, which no case holds, or
+    the first rows held elsewhere come to the held experts by turns."""
+    if rows is None:
+        return choice
+    first, count = held
+    flat = choice.reshape(-1)
+    here = (flat >= first) & (flat < first + count)
+    nth_here, nth_away = jnp.cumsum(here) - 1, jnp.cumsum(~here) - 1
+    flat = jnp.where(here & (nth_here >= rows), 0, flat)
+    flat = jnp.where(~here & (nth_away < rows - jnp.sum(here)),
+                     first + nth_away % count, flat)
+    return flat.reshape(choice.shape)
+
+
+def _routed(layer, impl):
+    """The layer under test as a function of (h, weights, choice, stacks),
+    with the case's held rows forced into the choice."""
+    held, router, rows = layer
+
+    def fn(h, weights, choice, w_in, w_down):
+        return moe.routed_experts(
+            h, _hold(choice, held, rows), weights, w_in, w_down, held=held,
+            router_experts=None if rows is None else router, impl=impl)[0]
+    return fn
+
+
 def _loss(fn, h, w_r, bias, w_in, w_down, target):
     choice, weights = moe.route(h, w_r, bias, K, 1.0, norm_eps=1e-6)
     return jnp.sum(fn(h, weights, choice, w_in, w_down).astype(jnp.float32)
                    * target)
 
 
+def test_the_cases_lie_where_their_names_say():
+    assert moe.prefix_rows(N * K, 4, WIDE) == BOUND < N * K
+    h, w_r, bias, _, _ = _setup(11, jnp.float32, (2, 4), WIDE)
+    choice, _ = moe.route(h, w_r, bias, K, 1.0)
+    for rows in (100, BOUND, 250):
+        flat = np.asarray(_hold(choice, (2, 4), rows)).reshape(-1)
+        sizes = np.bincount(flat, minlength=WIDE)[2:6]
+        assert sizes.sum() == rows
+    # past the bound: a group cut by it and a whole group behind it
+    assert sizes[:2].sum() < BOUND < sizes[:3].sum() and sizes[3] > 0
+
+
 @pytest.mark.parametrize("impl,dtype,tol", [
     ("ragged_dot", jnp.float32, 2e-4), ("gmm_interpret", jnp.bfloat16, 0.06)])
-@pytest.mark.parametrize("held", [None, (2, 4)])
-def test_gradient_matches_the_dense_masked_sum(impl, dtype, tol, held):
-    h, w_r, bias, w_in, w_down = _setup(11, dtype, held)
+@pytest.mark.parametrize("layer", LAYERS)
+def test_gradient_matches_the_dense_masked_sum(impl, dtype, tol, layer):
+    held, router, rows = layer
+    h, w_r, bias, w_in, w_down = _setup(11, dtype, held, router)
     target = jnp.asarray(np.random.default_rng(5).standard_normal((N, E)),
                          jnp.float32)
-
-    def routed(h, weights, choice, w_in, w_down):
-        return moe.routed_experts(h, choice, weights, w_in, w_down,
-                                  held=held, impl=impl)[0]
+    routed = _routed(layer, impl)
 
     def dense(h, weights, choice, w_in, w_down):
-        return _dense(h, weights, choice, w_in, w_down, held)
+        return _dense(h, weights, _hold(choice, held, rows), w_in, w_down,
+                      held)
 
     args = (h, w_r, bias, w_in, w_down)
     got = jax.grad(lambda *a: _loss(routed, *a, target), (0, 1, 3, 4))(*args)
@@ -109,21 +171,24 @@ def _poisoned(real):
 
 @pytest.mark.parametrize("impl,dtype", [("ragged_dot", jnp.float32),
                                         ("gmm_interpret", jnp.bfloat16)])
+@pytest.mark.parametrize("rows", [
+    pytest.param(None, id="full-width"), pytest.param(100, id="prefix-under"),
+    pytest.param(BOUND, id="prefix-at"), pytest.param(200, id="prefix-past")])
 def test_rows_held_elsewhere_give_exactly_zero_whatever_the_product_left(
-        monkeypatch, impl, dtype):
+        monkeypatch, impl, dtype, rows):
     """Three quarters of a share's rows belong to no held group. With NaN
     in every one of them after each product, forward and backward, the
     gradients stay finite and are those of the clean product: the masks
-    stand before anything can read such a row."""
+    stand before anything can read such a row. With the prefix (2 of 8
+    held: 128 of the 288 sorted rows) the rows of no group lie inside it
+    behind the held ones, and behind the overflow's."""
     held = (2, 2)
+    assert moe.prefix_rows(N * K, 2, ROUTER) == BOUND
     h, w_r, bias, w_in, w_down = _setup(13, dtype, held)
     target = jnp.asarray(np.random.default_rng(6).standard_normal((N, E)),
                          jnp.float32)
     real = moe.grouped_matmul
-
-    def routed(h, weights, choice, w_in, w_down):
-        return moe.routed_experts(h, choice, weights, w_in, w_down,
-                                  held=held, impl=impl)[0]
+    routed = _routed((held, ROUTER, rows), impl)
 
     args = (h, w_r, bias, w_in, w_down)
     grad = jax.grad(lambda *a: _loss(routed, *a, target), (0, 1, 3, 4))
@@ -133,8 +198,9 @@ def test_rows_held_elsewhere_give_exactly_zero_whatever_the_product_left(
         lambda lhs, rhs, sizes, impl=None: _poisoned(
             lambda a, b, s: real(a, b, s, impl=impl))(lhs, rhs, sizes))
     got = grad(*args)
-    choice, _ = moe.route(h, w_r, bias, K, 1.0)
-    assert float(jnp.mean((choice < 2) | (choice >= 4))) > 0.6
+    choice = _hold(moe.route(h, w_r, bias, K, 1.0)[0], held, rows)
+    assert float(jnp.mean((choice < 2) | (choice >= 4))) > (
+        0.6 if rows is None else 0.3)
     for g, w in zip(got, want):
         assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
         np.testing.assert_array_equal(np.asarray(g, np.float32),
@@ -158,20 +224,22 @@ def scatters(jaxpr, scope=""):
 
 @pytest.mark.parametrize("impl,dtype", [("ragged_dot", jnp.float32),
                                         ("gmm_interpret", jnp.bfloat16)])
-@pytest.mark.parametrize("held", [None, (2, 4)])
-def test_the_gradient_moves_rows_by_gathers_alone(impl, dtype, held):
+@pytest.mark.parametrize("layer", LAYERS)
+def test_the_gradient_moves_rows_by_gathers_alone(impl, dtype, layer):
     """Both row moves own their transpose (`_dispatch`, `_combine`), so
     the gradient with respect to `h`, the weights and both stacks holds no
     scatter into anything a row wide: what stays is the `bincount` over
     the groups, the kernel's small int32 tables and the router's
-    `[N, ROUTER]` scores."""
-    h, w_r, bias, w_in, w_down = _setup(17, dtype, held)
+    `[N, ROUTER]` scores. The prefix brings none back, in its own path or
+    in the overflow's branch."""
+    held, router, _ = layer
+    h, w_r, bias, w_in, w_down = _setup(17, dtype, held, router)
     choice, weights = moe.route(h, w_r, bias, K, 1.0)
+    routed = _routed(layer, impl)
 
     def loss(h, weights, w_in, w_down):
-        out, _ = moe.routed_experts(h, choice, weights, w_in, w_down,
-                                    held=held, impl=impl)
-        return jnp.sum(out.astype(jnp.float32))
+        return jnp.sum(routed(h, weights, choice, w_in, w_down).astype(
+            jnp.float32))
 
     grad = jax.grad(loss, (0, 1, 2, 3))
     found = list(scatters(jax.make_jaxpr(grad)(h, weights, w_in,
@@ -182,8 +250,7 @@ def test_the_gradient_moves_rows_by_gathers_alone(impl, dtype, held):
     assert not wide, wide
     # and the whole of it, router included: [N, ROUTER] is the widest
     found = list(scatters(jax.make_jaxpr(jax.grad(
-        lambda *a: _loss(lambda h, w, c, a, b: moe.routed_experts(
-            h, c, w, a, b, held=held, impl=impl)[0], *a, 1.0),
+        lambda *a: _loss(routed, *a, 1.0),
         (0, 1, 3, 4)))(h, w_r, bias, w_in, w_down).jaxpr))
     assert max(shape[-1] for _, shape, _ in found if len(shape) >= 2) < E
 
@@ -191,13 +258,16 @@ def test_the_gradient_moves_rows_by_gathers_alone(impl, dtype, held):
 @pytest.mark.parametrize("impl,dtype", [("ragged_dot", jnp.float32),
                                         ("ragged_dot", jnp.bfloat16),
                                         ("gmm_interpret", jnp.bfloat16)])
-@pytest.mark.parametrize("held", [None, (2, 4)])
-def test_the_forward_is_the_plain_formula_to_the_bit(impl, dtype, held):
+@pytest.mark.parametrize("layer", LAYERS)
+def test_the_forward_is_the_plain_formula_to_the_bit(impl, dtype, layer):
     """take, the products, a float32 weigh, the mask, take by
     argsort(order), the sum over k: the rules change what `jax.grad`
-    builds and nothing of what the layer returns."""
-    h, w_r, bias, w_in, w_down = _setup(19, dtype, held)
+    builds and nothing of what the layer returns, and neither does the
+    prefix, whether it holds every held row or the overflow runs."""
+    held, router, rows = layer
+    h, w_r, bias, w_in, w_down = _setup(19, dtype, held, router)
     choice, weights = moe.route(h, w_r, bias, K, 1.0)
+    choice = _hold(choice, held, rows)
     flat = choice.reshape(-1)
     G = w_in.shape[0]
     if held is not None:
@@ -212,31 +282,42 @@ def test_the_forward_is_the_plain_formula_to_the_bit(impl, dtype, held):
         y = jnp.where(jnp.take(here, order)[:, None], y, 0.0)
     y = jnp.take(y, jnp.argsort(order), axis=0)
     want = jnp.sum(y.reshape(N, K, -1), axis=1).astype(dtype)
-    got, _ = jax.jit(lambda *a: moe.routed_experts(
-        *a, held=held, impl=impl))(h, choice, weights, w_in, w_down)
+    got, stats = jax.jit(lambda *a: moe.routed_experts(
+        *a, held=held, router_experts=None if rows is None else router,
+        impl=impl))(h, choice, weights, w_in, w_down)
     assert got.dtype == want.dtype
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
+    if rows is not None:
+        assert int(stats["moe_rows_past_prefix"]) == max(rows - BOUND, 0)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
                                        (jnp.bfloat16, 0.02)])
-def test_a_tokens_k_held_rows_sum_into_its_gradient(dtype, tol):
+@pytest.mark.parametrize("rows", [
+    pytest.param(None, id="full-width"), pytest.param(200, id="prefix-under"),
+    pytest.param(256, id="prefix-at"), pytest.param(280, id="prefix-past")])
+def test_a_tokens_k_held_rows_sum_into_its_gradient(dtype, tol, rows):
     """The dispatch's backward gathers a token's k sorted rows and sums
     them. Token 0 has all three of its experts held, token 1 the same
     held expert THREE times (k colliding rows of one group), token 2 none,
-    the rest as routed: `dh` is the dense masked sum's, row by row."""
+    the rest as routed (or, with the prefix, 4 of 8 held: 256 of the 288
+    sorted rows, forced to the case's count): `dh` is the dense masked
+    sum's, row by row."""
     held = (2, 4)
+    assert moe.prefix_rows(N * K, 4, ROUTER) == 256
     h, w_r, bias, w_in, w_down = _setup(23, dtype, held)
     choice, weights = moe.route(h, w_r, bias, K, 1.0)
-    choice = choice.at[0].set(jnp.asarray([2, 3, 5])).at[1].set(3).at[
-        2].set(jnp.asarray([0, 1, 7]))
+    three = jnp.asarray([[2, 3, 5], [3, 3, 3], [0, 1, 7]], choice.dtype)
+    choice = jnp.concatenate([three, _hold(
+        choice[3:], held, None if rows is None else rows - 6)])
     target = jnp.asarray(np.random.default_rng(7).standard_normal((N, E)),
                          jnp.float32)
 
     def routed(h, weights):
         return jnp.sum(moe.routed_experts(
             h, choice, weights, w_in, w_down, held=held,
+            router_experts=None if rows is None else ROUTER,
             impl="ragged_dot")[0].astype(jnp.float32) * target)
 
     def dense(h, weights):
@@ -250,6 +331,188 @@ def test_a_tokens_k_held_rows_sum_into_its_gradient(dtype, tol):
         assert np.abs(g - w).max() <= tol * np.abs(w).max()
         assert np.abs(g[:2] - w[:2]).max() <= tol * np.abs(w[:2]).max()
         assert w[:2].any() and not g[2].any()
+
+
+def test_the_bound_follows_from_what_the_caller_states():
+    # the train cell: 16,384 tokens x 4, 8 of 32 held: a quarter and a
+    # quarter of it more
+    assert moe.prefix_rows(65536, 8, 32) == 20480
+    # whole row tiles of the grouped product, rounded UP
+    assert moe.prefix_rows(1000, 1, 8) == 256 == 2 * moe.GMM_TILE_M
+    # never more than the rows there are: a decode step, all held
+    assert moe.prefix_rows(384, 16, 256) == 128
+    assert moe.prefix_rows(96, 4, 8) == 96
+    assert moe.prefix_rows(65536, 32, 32) == 65536
+    # a share that is not one of the router's is refused
+    h, _, _, w_in, w_down = _setup(3, jnp.float32, (2, 4))
+    choice = jnp.zeros((N, K), jnp.int32)
+    with pytest.raises(ValueError, match="held"):
+        moe.routed_experts(h, choice, jnp.ones((N, K)), w_in, w_down,
+                           held=(2, 4), router_experts=4)
+
+
+def test_clipped_group_sizes_against_a_hand_count():
+    sizes = jnp.asarray([50, 0, 60, 30, 40], jnp.int32)
+    for bound, inside in ((128, [50, 0, 60, 18, 0]),      # cuts group 3
+                          (110, [50, 0, 60, 0, 0]),       # between two
+                          (40, [40, 0, 0, 0, 0]),
+                          (180, [50, 0, 60, 30, 40]),     # all of them
+                          (256, [50, 0, 60, 30, 40])):
+        got = moe._clip_sizes(sizes, bound)
+        assert np.asarray(got[0]).tolist() == inside, bound
+        assert np.asarray(got[0] + got[1]).tolist() == [50, 0, 60, 30, 40]
+        assert int(jnp.sum(got[0])) == min(bound, 180)
+
+
+@pytest.mark.parametrize("rows", [0, 1, 100, BOUND, BOUND + 1, 250, N * K])
+def test_rows_past_the_prefix_are_counted(rows):
+    """`moe_rows_past_prefix` against a count made row by row: the sorted
+    positions at or past the bound whose row is held."""
+    held = (2, 4)
+    h, w_r, bias, w_in, w_down = _setup(29, jnp.float32, held, WIDE)
+    choice, weights = moe.route(h, w_r, bias, K, 1.0)
+    choice = _hold(choice, held, rows)
+    _, stats = moe.routed_experts(h, choice, weights, w_in, w_down,
+                                  held=held, router_experts=WIDE,
+                                  impl="ragged_dot")
+    flat = np.asarray(choice).reshape(-1)
+    here = (flat >= 2) & (flat < 6)
+    order = np.argsort(np.where(here, flat - 2, 4), kind="stable")
+    assert int(stats["moe_rows_past_prefix"]) == int(
+        here[order][BOUND:].sum()) == max(rows - BOUND, 0)
+    assert int(stats["moe_rows"]) == rows
+    # a caller that states no count has no prefix and no such counter; one
+    # whose share is all the router's experts has it, and it reads zero
+    _, plain = moe.routed_experts(h, choice, weights, w_in, w_down,
+                                  held=held, impl="ragged_dot")
+    assert "moe_rows_past_prefix" not in plain
+    _, whole = moe.routed_experts(h, choice, weights, w_in, w_down,
+                                  held=(0, 4), router_experts=4,
+                                  impl="ragged_dot")
+    assert int(whole["moe_rows_past_prefix"]) == 0
+
+
+# (held, under jax.grad): `signature` of the layer at 04ef3cb, the commit
+# before the prefix
+PARENTS = {(None, False): "dccbc5c216fee36f", (None, True): "f9983ed7e3f2a58b",
+           ((2, 4), False): "e39f639e3428dc40",
+           ((2, 4), True): "7311fdc19ed6f7ba"}
+
+
+GROUPED = ("ragged_dot", "ragged_dot_general", "pallas_call")
+
+
+def grouped_products(jaxpr, wrapped=False):
+    """Of every grouped product of a jaxpr and its sub-jaxprs: whether a
+    `cond` or a `while` lies around it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in GROUPED:
+            yield wrapped
+        inside = wrapped or eqn.primitive.name in ("cond", "while")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from grouped_products(sub, inside)
+
+
+def mosaic_calls_outside_conditionals(hlo: str) -> list[str]:
+    """The names of a compiled program's Mosaic custom calls that no
+    `conditional` can reach: those of the computations that are neither a
+    conditional's branch nor called from one. A device trace's readers tell
+    a kernel by this name (`%gmm.3`)."""
+    bodies: dict[str, str] = {}
+    name = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = ""
+        elif name is not None:
+            bodies[name] += line + "\n"
+
+    def named(pattern, text):
+        return {n.strip().lstrip("%") for found in re.findall(pattern, text)
+                for n in found.split(",")}
+
+    inside = set().union(*(
+        named(r"branch_computations=\{([^}]+)\}", b)
+        | named(r"(?:true|false)_computation=([^,\s)]+)", b)
+        for b in bodies.values()))
+    grown = True
+    while grown:
+        called = set().union(*(named(
+            r"(?:calls|to_apply|body|condition)=([^,\s)]+)", bodies[n])
+            for n in inside if n in bodies)) - inside
+        grown = bool(called)
+        inside |= called
+    return [n for comp, body in bodies.items() if comp not in inside
+            for n in re.findall(
+                r"(%[\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                body)]
+
+
+def signature(jaxpr, out=None):
+    """Every equation of a jaxpr and its sub-jaxprs: primitive and the
+    shapes it reads and writes, in order."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name,
+                    tuple(str(v.aval) for v in eqn.invars),
+                    tuple(str(v.aval) for v in eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.append("(")
+            signature(sub, out)
+            out.append(")")
+    return out
+
+
+@pytest.mark.parametrize("impl,dtype", [("ragged_dot", jnp.float32),
+                                        ("gmm_interpret", jnp.bfloat16)])
+def test_the_prefix_paths_grouped_products_lie_outside_every_cond(impl,
+                                                                  dtype):
+    """A device trace tells a kernel by its event's own name, and inside a
+    `cond` (under any transformation applied within the layer) the
+    compiler names it from its `op_name`: the prefix path's products, 2
+    forward and 2 + 2 backward, stand at the top level of the gradient,
+    and only the overflow's lie in a branch."""
+    held = (2, 4)
+    h, w_r, bias, w_in, w_down = _setup(31, dtype, held, WIDE)
+    choice, weights = moe.route(h, w_r, bias, K, 1.0)
+
+    def grad_of(router_experts):
+        def loss(h, weights, w_in, w_down):
+            return jnp.sum(moe.routed_experts(
+                h, choice, weights, w_in, w_down, held=held,
+                router_experts=router_experts, impl=impl)[0].astype(
+                    jnp.float32))
+        return jax.make_jaxpr(jax.grad(loss, (0, 1, 2, 3)))(
+            h, weights, w_in, w_down).jaxpr
+
+    found = list(grouped_products(grad_of(WIDE)))
+    assert found.count(False) == 2 + 2 + 2
+    # the overflow: its forward in one branch, forward again and backward
+    # in the other's
+    assert found.count(True) == 2 + 2 + 2 + 2
+    assert list(grouped_products(grad_of(None))) == [False] * 6
+
+
+def test_without_the_routers_count_the_gradient_is_the_parents_jaxpr():
+    """A caller that does not state the router's expert count (every
+    serve family) gets the full-width layer, equation for equation: the
+    hashes are those of the commit before the prefix (04ef3cb), `held`
+    and not, forward and under `jax.grad`."""
+    for (held, grad), want in PARENTS.items():
+        h, w_r, bias, w_in, w_down = _setup(37, jnp.float32, held)
+
+        def loss(h, w_r, w_in, w_down):
+            choice, weights = moe.route(h, w_r, bias, K, 1.0)
+            return jnp.sum(moe.routed_experts(
+                h, choice, weights, w_in, w_down, held=held,
+                impl="ragged_dot")[0])
+
+        fn = jax.grad(loss, (0, 1, 2, 3)) if grad else loss
+        text = repr(signature(jax.make_jaxpr(fn)(h, w_r, w_in,
+                                                 w_down).jaxpr))
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == want, (
+            held, grad)
 
 
 @pytest.mark.parametrize("m", [4096, 1024])

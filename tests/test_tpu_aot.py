@@ -307,6 +307,55 @@ def test_held_expert_layer_gradient_compiles_at_published_widths(
     assert "%gmm" in text and "%tgmm" in text
 
 
+def test_prefix_paths_kernels_keep_their_names_outside_every_conditional(
+        one_chip, monkeypatch):
+    """`train-lfm2-t8192`'s routed layer with the router's expert count
+    stated (8 of 32 held: 20,480 of the 65,536 sorted rows), under remat
+    and `jax.grad` as a block of the step holds it. A device trace's
+    readers tell a grouped product by its instruction's OWN name, and the
+    compiler takes that name from the innermost wrapper of the call's
+    `op_name`: `gmm` / `tgmm` at the top level, `jvp_jit_gmm__` for a call
+    differentiated inside a `cond` (or by a `jax.vjp` of the layer's own).
+    The prefix path's eight calls stand at the top level under the
+    library's names; the overflow's products, in the conditionals'
+    branches, are the compiler's own (`ragged_dot`), so the step holds no
+    second set of Pallas kernels."""
+    from test_moe_grad import mosaic_calls_outside_conditionals
+
+    from distributedtraining_tpu.ops import moe
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    @jax.checkpoint
+    def layer(h, w_r, bias, w_in, w_down):
+        choice, weights = moe.route(h, w_r, bias, 4, 1.0, True, 1e-6)
+        return moe.routed_experts(h, choice, weights, w_in, w_down,
+                                  held=(0, 8), router_experts=32)[0]
+
+    def loss(h, w_r, bias, w_in, w_down, target):
+        return jnp.sum(layer(h, w_r, bias, w_in, w_down).astype(jnp.float32)
+                       * target)
+
+    assert moe.prefix_rows(16384 * 4, 8, 32) == 20480
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 3, 4)), sds((16384, 2048)),
+        sds((2048, 32), jnp.float32), sds((32,), jnp.float32),
+        sds((8, 2048, 3584)), sds((8, 1792, 2048)),
+        sds((16384, 2048), jnp.float32)).as_text()
+    outside = mosaic_calls_outside_conditionals(text)
+    # forward, remat's re-run, the two transposed products; the two tgmm
+    assert sum(bool(re.fullmatch(r"%gmm(\.\d+)?", n)) for n in outside) == 6
+    assert sum(bool(re.fullmatch(r"%tgmm(\.\d+)?", n)) for n in outside) == 2
+    assert len(outside) == 8
+    # the overflow: 2 products in the forward's branch, 2 + 4 in the
+    # backward's, none of them the library's kernel
+    assert len(re.findall(r"%ragged-dot[\w.\-]* = \w+\[", text)) >= 2 + 6
+    assert len(re.findall(r"%\w*gmm[\w.]* = ", text)) == 8
+    assert "[65536,3584]" not in text.split("ENTRY")[1].split("\n}")[0]
+
+
 @pytest.mark.parametrize("preset, mosaic_calls", [("gpt2-774m", 36),
                                                   ("gpt2-1.5b", 0)])
 def test_decode_program_reads_each_matrix_in_the_dtype_it_multiplies_in(
