@@ -81,7 +81,7 @@ StatePool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
 def unheld_cache_reason(cfg) -> str | None:
     """Why the serve engine cannot hold what this model's layers keep for
     a sequence, in one sentence; None for a model it can serve."""
-    unheld = sorted({c for c in (getattr(cfg, "layer_caches", None) or ())
+    unheld = sorted({c for c in (cfg.layer_caches or ())
                      if c not in ("kv", "ssm", None)})
     if not unheld:
         return None
@@ -95,33 +95,33 @@ def unheld_cache_reason(cfg) -> str | None:
 def layer_caches(cfg, n_layers: int) -> tuple[str | None, ...]:
     """What each layer keeps for a sequence: what the model's config
     states (``layer_caches``), else the paged pair in every layer."""
-    stated = getattr(cfg, "layer_caches", None)
+    stated = cfg.layer_caches
     return ("kv",) * n_layers if stated is None else tuple(stated)
 
 
 def has_recurrent_state(cfg) -> bool:
-    return "ssm" in (getattr(cfg, "layer_caches", None) or ())
+    return "ssm" in (cfg.layer_caches or ())
 
 
 def state_name(cfg) -> str:
     """What the per-slot state is called in the registry
-    (``serve.<name>.state_bytes``): what the config states, else
-    ``"ssm"``."""
-    return getattr(cfg, "state_name", "ssm")
+    (``serve.<name>.state_bytes``): what the config states
+    (``models/family.FamilyConfig``: ``"ssm"`` where it states nothing)."""
+    return cfg.state_name
 
 
 def row_widths(cfg) -> tuple[int, int]:
     """The widths of the two rows one layer caches a token: what the
     model's config states (``cache_row_widths``), else one K/V pair of
     ``n_kv_head`` (or ``n_head``) heads of ``head_dim``."""
-    stated = getattr(cfg, "cache_row_widths", None)
+    stated = cfg.cache_row_widths
     if stated is not None:
         # stored in whole 128-lane tiles, the pad lanes zero for ever: a
         # 64-wide row alone in its array makes XLA:TPU lay the PAGES axis
         # minor-most and re-lay the array around every gather, and Mosaic
         # cannot slice a page out of it (AOT for v5e, PERF.md PR 27)
         return tuple(-(-w // LANES) * LANES for w in stated)
-    width = (getattr(cfg, "n_kv_head", None) or cfg.n_head) * cfg.head_dim
+    width = (cfg.n_kv_head or cfg.n_head) * cfg.head_dim
     return width, width
 
 
@@ -130,11 +130,11 @@ def kv_head_geometry(cfg) -> tuple[int, int]:
     heads (the transfer wire's ``[L, P, Hkv, D]`` pages, the drafter's
     pool). A model that caches anything else is refused with the
     reason."""
-    if getattr(cfg, "cache_row_widths", None) is not None:
+    if cfg.cache_row_widths is not None:
         raise ValueError(LATENT_CACHE_REASON)
     if has_recurrent_state(cfg):
         raise ValueError(RECURRENT_STATE_REASON)
-    return getattr(cfg, "n_kv_head", None) or cfg.n_head, cfg.head_dim
+    return cfg.n_kv_head or cfg.n_head, cfg.head_dim
 
 
 def make_pool(n_layers: int, pool_pages: int, page_size: int,

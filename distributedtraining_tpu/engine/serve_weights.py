@@ -45,10 +45,10 @@ def _walk(cfg, base: Params, leaf: Callable) -> Params:
     ``rounded`` where the family says the forward rounds it first; and a
     tied head's operand, rounded from its table, beside it, unless the
     table is in the compute dtype itself."""
-    rounds = getattr(cfg, "rounds_first", lambda path: False)
-    head, tied = getattr(cfg, "serving_head", (None, None))
+    head, tied = cfg.serving_head
     tree = jax.tree_util.tree_map_with_path(
-        lambda path, x: leaf(x, rounds(tuple(k.key for k in path))), base)
+        lambda path, x: leaf(x, cfg.rounds_first(
+            tuple(k.key for k in path))), base)
     if head and head not in tree and tree[tied].dtype != cfg.compute_dtype():
         tree[head] = leaf(tree[tied], True)
     return tree

@@ -84,7 +84,7 @@ def compat_reason(draft_model, target_cfg) -> str | None:
     shared REAL vocabulary: draft proposals are token ids the target
     scores verbatim, so the id spaces must mean the same thing."""
     for cfg in (draft_model.cfg, target_cfg):
-        if getattr(cfg, "cache_row_widths", None) is not None:
+        if cfg.cache_row_widths is not None:
             return kv_pool.LATENT_CACHE_REASON
         if kv_pool.has_recurrent_state(cfg):
             return kv_pool.RECURRENT_STATE_REASON

@@ -1,9 +1,12 @@
 """Model zoo: TPU-first Flax implementations.
 
+- family: what every family HAS, once: the config base (the statements
+  the engine reads), norm, dense, rotation, the gated FFN bodies, the routed
+  half, the attention and per-slot-state layers' cache protocol, the
+  served model's shell.
 - gpt2: the reference's training target (openai-community/gpt2,
   neurons/miner.py:60), in 124M and 355M presets plus tiny test configs.
-- llama: Llama-2-7B / Llama-3-8B presets for the LoRA-delta and multi-host
-  configs in BASELINE.json.
+- llama: Llama-2-7B / Llama-3-8B presets (no cell runs them yet).
 - deepseek_v3: latent attention + routed/shared experts (the kanana-2
   row), on the serving path.
 - nemotron_h: Mamba-2 layers beside attention and latent routed experts,
@@ -22,6 +25,14 @@
   routed + shared SwiGLU experts of which a chip holds its share (the
   Solar-Open2-250B row), on the serving path.
 - lora: low-rank adapter trees whose *parameters are the delta*.
+
+Adding a family (docs/architecture.md, "Model families"): one file with the
+config (published key names on ``family.FamilyConfig``, plus
+``experts_held`` and the like), the per-layer statement (``layer_caches``),
+the mixers (a block called as ``block(x, step)``), the model
+(``family.ServedDecoder`` with ``block(i)``), the presets and
+``make_model = family.make_model(Model, PRESETS)``; then its module in
+``family_of`` below.
 """
 
 from .gpt2 import GPT2, GPT2Config

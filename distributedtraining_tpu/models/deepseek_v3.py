@@ -20,8 +20,9 @@ h = RMSNorm(x) (no biases anywhere):
   paged cache it attends in the ABSORBED form: ``q' = q_nope W_uk^T``,
   scores ``(q' . c + q_rope . k_r)``, ``o = (P c) W_uv``, with
   ``W_uk``/``W_uv`` the two halves of ``W_kv_b``. The two forms are one
-  function (``ops.mla_attention.latent_attention``, which
-  models/gigachat3_5.py calls too; tests/test_deepseek_v3.py).
+  function (``ops.mla_attention.latent_attention``), and the layer
+  around it is ``family.latent_attention_layer``, models/gigachat3_5.py's
+  too (tests/test_deepseek_v3.py).
 * Dense FFN (the first ``first_k_dense_replace`` layers): SwiGLU of
   ``intermediate_size``.
 * Routed FFN (the rest): sigmoid router with a selection bias
@@ -37,21 +38,16 @@ leaves) does not know this family yet (ROADMAP M2).
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import flax.linen as nn
 import jax
-import jax.numpy as jnp
 
-from ..ops import moe
-from ..ops.embed import embed_lookup
-from ..ops.mla_attention import latent_attention
-from .gpt2 import pad_vocab
-from .llama import RMSNorm, _dense, rotary_embedding
+from . import family
+from .family import dense
 
 
 @dataclasses.dataclass(frozen=True)
-class DeepseekV3Config:
+class DeepseekV3Config(family.FamilyConfig):
     # the published keys, under their published names
     vocab_size: int = 128256
     hidden_size: int = 2048
@@ -86,17 +82,11 @@ class DeepseekV3Config:
     rope_scaling: None = None
     max_position_embeddings: int = 32768
     tie_word_embeddings: bool = False
-    # the program's own
-    dtype: str = "bfloat16"
-    param_dtype: str = "bfloat16"
-    logits_dtype: str = "float32"
+    # the program's own, beside family.FamilyConfig's
     attention_impl: str = "dense"
-    vocab_multiple: int = 128
-    remat: bool = False
-    scan_blocks: bool = False
 
     def __post_init__(self):
-        unsupported = {
+        self.refuse({
             "q_lora_rank": self.q_lora_rank is not None,
             "n_group/topk_group": (self.n_group, self.topk_group) != (1, 1),
             "scoring_func": self.scoring_func != "sigmoid",
@@ -109,46 +99,25 @@ class DeepseekV3Config:
             "qk_head_dim": self.qk_head_dim != (self.qk_nope_head_dim
                                                 + self.qk_rope_head_dim),
             "scan_blocks": self.scan_blocks,
-        }
-        bad = [k for k, v in unsupported.items() if v]
-        if bad:
-            raise ValueError(f"DeepseekV3Config: {', '.join(bad)} not "
-                             "supported (this block takes the query from "
-                             "one full-rank matrix, plain rotary "
-                             "frequencies and one sigmoid-scored group; a "
-                             "config that states q_lora_rank and "
-                             "rope_scaling is models/gigachat3_5.py's)")
-
-    @property
-    def padded_vocab(self) -> int:
-        return pad_vocab(self.vocab_size, self.vocab_multiple)
-
-    @property
-    def max_seq_len(self) -> int:
-        return self.max_position_embeddings
+        }, "this block takes the query from one full-rank matrix, plain "
+           "rotary frequencies and one sigmoid-scored group; a config that "
+           "states q_lora_rank and rope_scaling is models/gigachat3_5.py's")
 
     @property
     def cache_row_widths(self) -> tuple[int, int]:
         """What one layer caches a token (engine/kv_pool.row_widths)."""
         return self.kv_lora_rank, self.qk_rope_head_dim
 
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
+    # cast before every use: the ``nn.Dense`` kernels, ``kv_b_proj``, the
+    # experts' two stacks and the head; the lookup's rows straight after
+    # the gather. Not the router and its selection bias (float32 scores,
+    # ops/moe.route), not an RMSNorm's scale
+    cast_first = ("kernel", "kv_b_proj", "experts_gate_up", "experts_down",
+                  "lm_head", "embed_tokens")
 
-    def storage_dtype(self):
-        return jnp.dtype(self.param_dtype)
+    def norm(self, name: str) -> nn.Module:
+        return family.RMSNorm(self.rms_norm_eps, self.param_dtype, name=name)
 
-    def rounds_first(self, path: tuple[str, ...]) -> bool:
-        """See ``GPT2Config.rounds_first``. Cast before every use: the
-        ``nn.Dense`` kernels, ``kv_b_proj``, the experts' two stacks and
-        the head; the lookup's rows straight after the gather. Not the
-        router and its selection bias (float32 scores, ops/moe.route),
-        not an RMSNorm's scale."""
-        return path[-1] in _CAST_FIRST
-
-
-_CAST_FIRST = ("kernel", "kv_b_proj", "experts_gate_up", "experts_down",
-               "lm_head", "embed_tokens")
 
 PRESETS: dict[str, DeepseekV3Config] = {
     # the published sizes: 30.7B parameters, never built on one chip
@@ -168,154 +137,46 @@ PRESETS: dict[str, DeepseekV3Config] = {
 }
 
 
-def _swiglu(h, width: int, names: tuple[str, str, str], cfg):
-    gate = _dense(width, names[0], ("embed", "mlp"), cfg)(h)
-    up = _dense(width, names[1], ("embed", "mlp"), cfg)(h)
-    return _dense(cfg.hidden_size, names[2], ("mlp", "embed"), cfg)(
-        nn.silu(gate) * up)
-
-
 class DeepseekV3Block(nn.Module):
     cfg: DeepseekV3Config
     routed: bool
 
     @nn.compact
-    def __call__(self, x, attention_mask, segment_ids, position_ids,
-                 kv_lens=None, sow_kv=False, kv_pages=None,
-                 page_tables=None, live=None):
+    def __call__(self, x, step: family.Step):
         cfg = self.cfg
         B, T, E = x.shape
-        H, C = cfg.num_attention_heads, cfg.kv_lora_rank
-        Dn, Dr, Dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
-        cdt = cfg.compute_dtype()
-        norm = functools.partial(_norm, cfg)
+        H, D = cfg.num_attention_heads, cfg.qk_head_dim
 
-        h = norm("input_layernorm")(x)
-        q = _dense(H * (Dn + Dr), "q_proj", ("embed", "qkv"), cfg)(h)
-        q = q.reshape(B, T, H, Dn + Dr)
-        q_nope, q_rope = q[..., :Dn], q[..., Dn:]
-        kv_a = _dense(C + Dr, "kv_a_proj_with_mqa", ("embed", None), cfg)(h)
-        c = norm("kv_a_layernorm")(kv_a[..., :C])
-        q_rope = rotary_embedding(q_rope, position_ids, cfg.rope_theta,
-                                  interleaved=cfg.rope_interleave)
-        k_r = rotary_embedding(kv_a[..., None, C:], position_ids,
-                               cfg.rope_theta,
-                               interleaved=cfg.rope_interleave)[:, :, 0]
-        if sow_kv:
-            # the whole cache of this layer: the normed latent and the
-            # one shared rotary key (kv_pool's pair: c first, k_r second)
-            self.sow("intermediates", "kv_cache", (c, k_r))
-        w_kv_b = self.param(
-            "kv_b_proj",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         (None, "qkv")),
-            (C, H * (Dn + Dv)), cfg.storage_dtype())
-        w_kv_b = w_kv_b.astype(cdt).reshape(C, H, Dn + Dv)
-        attn = latent_attention(
-            q_nope, q_rope, c, k_r, w_kv_b, (Dn + Dr) ** -0.5,
-            kv_pages=kv_pages, page_tables=page_tables, kv_lens=kv_lens,
-            attention_mask=attention_mask, segment_ids=segment_ids,
-            impl=cfg.attention_impl)
-        x = x + _dense(E, "o_proj", ("qkv", "embed"), cfg)(
-            attn.reshape(B, T, H * Dv))
+        h = cfg.norm("input_layernorm")(x)
+        q = dense(H * D, "q_proj", ("embed", "qkv"), cfg)(h)
+        attn = family.latent_attention_layer(
+            self, h, q.reshape(B, T, H, D), step, cfg, "kv_a_layernorm",
+            D ** -0.5)
+        x = x + dense(E, "o_proj", ("qkv", "embed"), cfg)(
+            attn.reshape(B, T, -1))
 
-        h = norm("post_attention_layernorm")(x)
+        h = cfg.norm("post_attention_layernorm")(x)
         if not self.routed:
-            return x + _swiglu(h, cfg.intermediate_size,
-                               ("gate_proj", "up_proj", "down_proj"), cfg)
-        G, F = cfg.n_routed_experts, cfg.moe_intermediate_size
-        w_router = self.param(
-            "router", nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), ("embed", None)),
-            (E, G), cfg.storage_dtype())
-        # a buffer in the release: it moves the choice, never the weights
-        bias = self.param("e_score_correction_bias",
-                          nn.initializers.zeros_init(), (G,), jnp.float32)
-        w_gate_up = self.param(
-            "experts_gate_up", nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), (None, "embed", "mlp")),
-            (G, E, 2 * F), cfg.storage_dtype())
-        w_down = self.param(
-            "experts_down", nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), (None, "mlp", "embed")),
-            (G, F, E), cfg.storage_dtype())
-        flat = h.reshape(B * T, E)
-        choice, weights = moe.route(
-            flat, w_router, bias, cfg.num_experts_per_tok,
-            cfg.routed_scaling_factor, cfg.norm_topk_prob)
-        routed, stats = moe.routed_experts(
-            flat, choice, weights, w_gate_up.astype(cdt),
-            w_down.astype(cdt),
-            live=None if live is None else live.reshape(B * T))
-        if sow_kv:
-            self.sow("intermediates", "serve_stats", stats)
+            return x + family.plain_swiglu(
+                h, cfg.intermediate_size,
+                ("gate_proj", "up_proj", "down_proj"), cfg)
+        F = cfg.moe_intermediate_size
+        routed, _ = family.routed_ffn(
+            self, h, cfg, experts=cfg.n_routed_experts, width=2 * F,
+            live=step.live, sow=step.sow_kv,
+            router_dtype=cfg.storage_dtype())
         with jax.named_scope("moe.shared"):
-            shared = _swiglu(
-                h, cfg.n_shared_experts * F,
-                ("shared_gate_proj", "shared_up_proj", "shared_down_proj"),
-                cfg)
-        return x + routed.reshape(B, T, E) + shared
+            shared = family.plain_swiglu(h, cfg.n_shared_experts * F,
+                                         family.SHARED_SWIGLU, cfg)
+        return x + routed.reshape(x.shape) + shared
 
 
-def _norm(cfg, name: str) -> RMSNorm:
-    return RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name=name)
-
-
-class DeepseekV3(nn.Module):
+class DeepseekV3(family.ServedDecoder):
     cfg: DeepseekV3Config
 
-    @nn.compact
-    def __call__(self, input_ids, *, attention_mask=None, segment_ids=None,
-                 position_ids=None, deterministic: bool = True,
-                 return_hidden: bool = False, kv_lens=None,
-                 sow_kv: bool = False, kv_pages=None, page_tables=None):
-        """The serving hooks are gpt2.GPT2.__call__'s: ``sow_kv`` sows
-        each layer's fresh cache rows, ``kv_pages``/``page_tables``/
-        ``kv_lens`` attend over the paged cache."""
-        cfg = self.cfg
-        B, T = input_ids.shape
-        wte = self.param(
-            "embed_tokens",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         ("vocab", "embed")),
-            (cfg.padded_vocab, cfg.hidden_size), cfg.storage_dtype())
-        if position_ids is None:
-            position_ids = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-        # the rows a routed layer counts: not a prefill bucket's padding,
-        # not a decode bucket's empty slots (a live slot holds >= 1 token)
-        if attention_mask is not None:
-            live = attention_mask.astype(bool)
-        elif kv_lens is not None:
-            live = jnp.broadcast_to(kv_lens[:, None] > 0, (B, T))
-        else:
-            live = None
-        x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
-        for i in range(cfg.num_hidden_layers):
-            x = DeepseekV3Block(cfg, i >= cfg.first_k_dense_replace,
-                                name=f"layer_{i}")(
-                x, attention_mask, segment_ids, position_ids, kv_lens,
-                sow_kv, kv_pages[i] if kv_pages is not None else None,
-                page_tables, live)
-        x = _norm(cfg, "norm")(x)
-        if return_hidden:
-            return x
-        lm_head = self.param(
-            "lm_head",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         ("vocab", "embed")),
-            (cfg.padded_vocab, cfg.hidden_size), cfg.storage_dtype())
-        logits = jnp.einsum("bte,ve->btv", x,
-                            lm_head.astype(cfg.compute_dtype()),
-                            preferred_element_type=jnp.float32)
-        return logits.astype(jnp.dtype(cfg.logits_dtype))
-
-    def init_params(self, rng, *, seq_len: int = 8):
-        dummy = jnp.zeros((1, seq_len), jnp.int32)
-        return nn.meta.unbox(self.init(rng, dummy)["params"])
+    def block(self, i: int) -> DeepseekV3Block:
+        return DeepseekV3Block(self.cfg, i >= self.cfg.first_k_dense_replace,
+                               name=f"layer_{i}")
 
 
-def make_model(preset_or_cfg) -> tuple[DeepseekV3, DeepseekV3Config]:
-    cfg = (PRESETS[preset_or_cfg] if isinstance(preset_or_cfg, str)
-           else preset_or_cfg)
-    return DeepseekV3(cfg), cfg
+make_model = family.make_model(DeepseekV3, PRESETS)

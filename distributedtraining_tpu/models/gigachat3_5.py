@@ -35,7 +35,7 @@ which takes the SAME reading.
   ``[c_kv | k_r] = u W_kva``, ``c = N(c_kv)``; interleaved rotary with
   YaRN's blended frequencies on ``q_rope`` and ``k_r``; softmax scale
   ``qk_head_dim^-0.5 m^2``, ``m = 0.1 mscale_all_dim ln(factor) + 1``;
-  both forms are ``ops.mla_attention.latent_attention``, shared with
+  behind the query it is ``family.latent_attention_layer``, shared with
   models/deepseek_v3.py; ``gated_attention``: the heads' concatenated
   values times ``sigmoid(u W_g)`` before ``o_proj`` (assumed). Caches
   ``c`` and ``k_r`` a token (``cache_row_widths``).
@@ -68,12 +68,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import delta_rule, moe, ssm
-from ..ops.embed import embed_lookup
-from ..ops.mla_attention import latent_attention
-from .gpt2 import pad_vocab
-from .llama import _dense, rotary_embedding
-from .nemotron_h import _a_log_init, _conv_init, _dt_bias_init
+from ..ops import delta_rule
+from . import family
+from .family import dense
 
 _YARN = (("beta_fast", 32), ("beta_slow", 1), ("factor", 8), ("mscale", 1),
          ("mscale_all_dim", 1), ("original_max_position_embeddings", 32768),
@@ -81,7 +78,7 @@ _YARN = (("beta_fast", 32), ("beta_slow", 1), ("factor", 8), ("mscale", 1),
 
 
 @dataclasses.dataclass(frozen=True)
-class GigaChat35Config:
+class GigaChat35Config(family.FamilyConfig):
     # the published keys, under their published names
     vocab_size: int = 128256
     max_position_embeddings: int = 262144
@@ -130,21 +127,14 @@ class GigaChat35Config:
     swiglu_limit: float = 10.0
     tie_word_embeddings: bool = False
     num_nextn_predict_layers: int = 2
-    # the program's own
+    # the program's own, beside family.FamilyConfig's
     experts_held: tuple[int, int] = (0, 256)   # (first, count) on this chip
     chunk_size: int = delta_rule.CHUNK
-    dtype: str = "bfloat16"
-    param_dtype: str = "bfloat16"
-    logits_dtype: str = "float32"
     attention_impl: str = "dense"
-    vocab_multiple: int = 128
-    remat: bool = False
-    scan_blocks: bool = False
 
     def __post_init__(self):
-        first, count = self.experts_held
         yarn = dict(self.rope_scaling)
-        unsupported = {
+        self.refuse({
             "full_attention_layers": not all(
                 0 <= i < self.num_hidden_layers
                 for i in self.full_attention_layers),
@@ -167,26 +157,14 @@ class GigaChat35Config:
             "linear_num_key_heads": (self.linear_num_value_heads
                                      % self.linear_num_key_heads != 0),
             "n_shared_experts": self.n_shared_experts != 1,
-            "experts_held": not (0 <= first and count >= 1
-                                 and first + count <= self.n_routed_experts),
+            "experts_held": family.held_outside(self.experts_held,
+                                                self.n_routed_experts),
             "tie_word_embeddings": self.tie_word_embeddings,
             "qk_head_dim": self.qk_head_dim != (self.qk_nope_head_dim
                                                 + self.qk_rope_head_dim),
             "scan_blocks": self.scan_blocks,
-        }
-        bad = [k for k, v in unsupported.items() if v]
-        if bad:
-            raise ValueError(f"GigaChat35Config: {', '.join(bad)} not "
-                             "supported (this block writes one reading of "
-                             "each key: see the module's docstring)")
-
-    @property
-    def padded_vocab(self) -> int:
-        return pad_vocab(self.vocab_size, self.vocab_multiple)
-
-    @property
-    def max_seq_len(self) -> int:
-        return self.max_position_embeddings
+        }, "this block writes one reading of each key: see the module's "
+           "docstring")
 
     @property
     def cache_row_widths(self) -> tuple[int, int]:
@@ -228,24 +206,18 @@ class GigaChat35Config:
         m = 0.1 * yarn["mscale_all_dim"] * math.log(yarn["factor"]) + 1.0
         return self.qk_head_dim ** -0.5 * m * m
 
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
+    # cast before every use: the ``nn.Dense`` kernels, ``kv_b_proj``, the
+    # experts' two stacks, the head; the lookup's rows straight after the
+    # gather. Not ``A_log``, ``dt_bias``, the convolution (they enter the
+    # float32 recurrence), a norm's parameter, the router or its selection
+    # bias (float32 scores): those leaves are float32 in the tree and stay so
+    cast_first = ("kernel", "kv_b_proj", "experts_gate_up", "experts_down",
+                  "lm_head", "embed_tokens")
 
-    def storage_dtype(self):
-        return jnp.dtype(self.param_dtype)
+    def norm(self, name: str) -> nn.Module:
+        return ZeroCentredNorm(self.rms_norm_eps,
+                               self.layernorm_gating_weight, name=name)
 
-    def rounds_first(self, path: tuple[str, ...]) -> bool:
-        """See ``GPT2Config.rounds_first``. Cast before every use: the
-        ``nn.Dense`` kernels, ``kv_b_proj``, the experts' two stacks, the
-        head; the lookup's rows straight after the gather. Not ``A_log``,
-        ``dt_bias``, the convolution (they enter the float32 recurrence),
-        a norm's parameter, the router or its selection bias (float32
-        scores): those leaves are float32 in the tree and stay so."""
-        return path[-1] in _CAST_FIRST
-
-
-_CAST_FIRST = ("kernel", "kv_b_proj", "experts_gate_up", "experts_down",
-               "lm_head", "embed_tokens")
 
 PRESETS: dict[str, GigaChat35Config] = {
     # the published sizes: 432B parameters, never built on one chip
@@ -279,27 +251,6 @@ PRESETS: dict[str, GigaChat35Config] = {
 }
 
 
-def yarn_inv_freq(dim: int, theta: float, scaling: dict) -> jax.Array:
-    """YaRN's blended rotary frequencies [dim / 2] (DeepSeek-V3's
-    ``DeepseekV3YarnRotaryEmbedding``): pairs that turn more than
-    ``beta_fast`` times over the original context keep their frequency,
-    those that turn fewer than ``beta_slow`` times are divided by
-    ``factor``, a linear ramp between."""
-    factor = scaling["factor"]
-    original = scaling["original_max_position_embeddings"]
-
-    def correction(turns):
-        return (dim * math.log(original / (turns * 2 * math.pi))
-                / (2 * math.log(theta)))
-
-    low = max(math.floor(correction(scaling["beta_fast"])), 0)
-    high = min(math.ceil(correction(scaling["beta_slow"])), dim - 1)
-    plain = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
-                    / max(high - low, 1e-3), 0.0, 1.0)
-    return plain / factor * ramp + plain * (1.0 - ramp)
-
-
 def zero_centred_gain(w, weight: float):
     return weight * jax.nn.sigmoid(w.astype(jnp.float32))
 
@@ -326,60 +277,41 @@ class ZeroCentredNorm(nn.Module):
         return (norm * zero_centred_gain(w, self.weight)).astype(x.dtype)
 
 
-def _norm(cfg, name: str) -> ZeroCentredNorm:
-    return ZeroCentredNorm(cfg.rms_norm_eps, cfg.layernorm_gating_weight,
-                           name=name)
-
-
-def _swiglu(h, width: int, names: tuple[str, str, str], cfg):
-    gate = _dense(width, names[0], ("embed", "mlp"), cfg)(h)
-    up = _dense(width, names[1], ("embed", "mlp"), cfg)(h)
-    act = moe.clamped_swiglu(gate, up, cfg.swiglu_limit).astype(gate.dtype)
-    return _dense(cfg.hidden_size, names[2], ("mlp", "embed"), cfg)(act)
-
-
 class GigaChat35Block(nn.Module):
     cfg: GigaChat35Config
     full_attention: bool
     routed: bool
 
     @nn.compact
-    def __call__(self, x, attention_mask, segment_ids, position_ids, live,
-                 live_len, kv_lens=None, sow_kv=False, kv_pages=None,
-                 page_tables=None, ssm_pools=None, slots=None,
-                 ssm_init=None):
+    def __call__(self, x, step: family.Step):
         cfg = self.cfg
-        h = _norm(cfg, "pre_mixer_norm")(x)
-        if self.full_attention:
-            y = self._latent(h, attention_mask, segment_ids, position_ids,
-                             kv_lens, sow_kv, kv_pages, page_tables)
-        else:
-            y = self._delta(h, live_len, kv_lens, sow_kv, ssm_pools, slots,
-                            ssm_init)
-        x = x + _norm(cfg, "post_mixer_norm")(y)
-        h = _norm(cfg, "pre_ffn_norm")(x)
+        h = cfg.norm("pre_mixer_norm")(x)
+        y = (self._latent if self.full_attention else self._delta)(h, step)
+        x = x + cfg.norm("post_mixer_norm")(y)
+        h = cfg.norm("pre_ffn_norm")(x)
         if self.routed:
-            y = self._experts(h, live, sow_kv)
+            y = self._experts(h, step)
         else:
-            y = _swiglu(h, cfg.intermediate_size,
-                        ("gate_proj", "up_proj", "down_proj"), cfg)
-        return x + _norm(cfg, "post_ffn_norm")(y)
+            y = family.swiglu(h, cfg.intermediate_size,
+                              ("gate_proj", "up_proj", "down_proj"), cfg,
+                              cfg.swiglu_limit)
+        return x + cfg.norm("post_ffn_norm")(y)
 
-    def _delta(self, u, live_len, kv_lens, sow_kv, ssm_pools, slots,
-               ssm_init=None):
+    def _delta(self, u, step):
         cfg = self.cfg
         B, T, E = u.shape
         Hv, dk, dv = cfg.ssm_state_shape
         Hk, K = cfg.linear_num_key_heads, cfg.linear_conv_kernel_dim
         conv_dim = cfg.conv_dim
         cdt, f32 = cfg.compute_dtype(), jnp.float32
-        qkvz = _dense(conv_dim + Hv * dv, "in_proj_qkvz", ("embed", "mlp"),
+        qkvz = dense(conv_dim + Hv * dv, "in_proj_qkvz", ("embed", "mlp"),
                       cfg)(u)
-        ba = _dense(2 * Hv, "in_proj_ba", ("embed", None), cfg)(u)
+        ba = dense(2 * Hv, "in_proj_ba", ("embed", None), cfg)(u)
         qkv, z = qkvz[..., :conv_dim], qkvz[..., conv_dim:]
-        conv_w = self.param("conv1d_weight", _conv_init, (K, conv_dim), f32)
-        a_log = self.param("A_log", _a_log_init, (Hv,), f32)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (Hv,), f32)
+        conv_w = self.param("conv1d_weight", family.conv_init,
+                            (K, conv_dim), f32)
+        a_log = self.param("A_log", family.a_log_init, (Hv,), f32)
+        dt_bias = self.param("dt_bias", family.dt_bias_init, (Hv,), f32)
         beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
         g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:].astype(f32)
                                               + dt_bias)
@@ -399,37 +331,8 @@ class GigaChat35Block(nn.Module):
             return (unit(q) * dk ** -0.5, unit(k),
                     act[..., 2 * Hk * dk:].reshape(*lead, Hv, dv))
 
-        if ssm_pools is None:
-            with jax.named_scope("gdn.prefill"):
-                # from zero, or from what the sequence's earlier part left
-                s0, tail0 = (None, None) if ssm_init is None else ssm_init
-                # `tail0` is named only when there is one: the fault
-                # injectors of benchmarks/tools swap in a
-                # `causal_conv1d` of the older signature
-                conv, tail = ssm.causal_conv1d(
-                    qkv, conv_w, None, live_len,
-                    **({} if tail0 is None else {"tail0": tail0}))
-                q, k, v = split(conv)
-                o, state = delta_rule.delta_rule_prefill(
-                    q, k, v, g, beta, live_len, s0, chunk=cfg.chunk_size)
-            if sow_kv:
-                # the whole of what this layer keeps for the sequence
-                self.sow("intermediates", "ssm_cache", (state, tail))
-        else:
-            with jax.named_scope("gdn.decode"):
-                states, tails = ssm_pools
-                conv, tails = ssm.conv_decode_update(
-                    tails, slots, qkv[:, 0], conv_w, None)
-                q, k, v = split(conv)
-                # a bucket's padding rows (no sequence: length 0) cost
-                # no arithmetic and leave the row they name as it was
-                o, states = delta_rule.gdn_decode_update(
-                    states, slots, q, k, v, g[:, 0], beta[:, 0],
-                    kv_lens > 0)
-                o = o[:, None]
-            self.sow("intermediates", "ssm_cache", (states, tails))
-            self.sow("intermediates", "serve_stats", {
-                "gdn_slot_steps": jnp.sum(kv_lens > 0).astype(jnp.int32)})
+        o = family.delta_rule_layer(self, qkv, conv_w, split, g, beta, step,
+                                    cfg)
         # the gated norm a head: the norm's gain and the gate are both
         # scaled sigmoids, 1 at zero
         w_o = self.param("o_norm", nn.initializers.zeros_init(), (dv,), f32)
@@ -438,162 +341,45 @@ class GigaChat35Block(nn.Module):
         o = (o * zero_centred_gain(w_o, cfg.layernorm_gating_weight)
              * output_gate(z.reshape(B, T, Hv, dv),
                            cfg.linear_sigmoid_gate_scale))
-        return _dense(E, "out_proj", ("mlp", "embed"), cfg)(
+        return dense(E, "out_proj", ("mlp", "embed"), cfg)(
             o.reshape(B, T, Hv * dv).astype(cdt))
 
-    def _latent(self, h, attention_mask, segment_ids, position_ids, kv_lens,
-                sow_kv, kv_pages, page_tables):
+    def _latent(self, h, step):
         cfg = self.cfg
         B, T, E = h.shape
-        H, C = cfg.num_attention_heads, cfg.kv_lora_rank
-        Dn, Dr, Dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
-        cdt = cfg.compute_dtype()
-        c_q = _norm(cfg, "q_a_norm")(
-            _dense(cfg.q_lora_rank, "q_a_proj", ("embed", None), cfg)(h))
-        q = _dense(H * (Dn + Dr), "q_b_proj", (None, "qkv"), cfg)(c_q)
-        q = q.reshape(B, T, H, Dn + Dr)
-        q_nope, q_rope = q[..., :Dn], q[..., Dn:]
-        kv_a = _dense(C + Dr, "kv_a_proj_with_mqa", ("embed", None), cfg)(h)
-        c = _norm(cfg, "kv_a_norm")(kv_a[..., :C])
-        inv_freq = yarn_inv_freq(Dr, cfg.rope_theta, dict(cfg.rope_scaling))
-        q_rope = rotary_embedding(q_rope, position_ids, cfg.rope_theta,
-                                  interleaved=cfg.rope_interleave,
-                                  inv_freq=inv_freq)
-        k_r = rotary_embedding(kv_a[..., None, C:], position_ids,
-                               cfg.rope_theta,
-                               interleaved=cfg.rope_interleave,
-                               inv_freq=inv_freq)[:, :, 0]
-        if sow_kv:
-            # the whole cache of this layer: the normed latent and the
-            # one shared rotary key (kv_pool's pair: c first, k_r second)
-            self.sow("intermediates", "kv_cache", (c, k_r))
-        w_kv_b = self.param(
-            "kv_b_proj",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         (None, "qkv")),
-            (C, H * (Dn + Dv)), cfg.storage_dtype())
-        attn = latent_attention(
-            q_nope, q_rope, c, k_r,
-            w_kv_b.astype(cdt).reshape(C, H, Dn + Dv), cfg.softmax_scale,
-            kv_pages=kv_pages, page_tables=page_tables, kv_lens=kv_lens,
-            attention_mask=attention_mask, segment_ids=segment_ids,
-            impl=cfg.attention_impl)
-        gate = _dense(H * Dv, "o_gate_proj", ("embed", "qkv"), cfg)(h)
+        H, Dv = cfg.num_attention_heads, cfg.v_head_dim
+        c_q = cfg.norm("q_a_norm")(
+            dense(cfg.q_lora_rank, "q_a_proj", ("embed", None), cfg)(h))
+        q = dense(H * cfg.qk_head_dim, "q_b_proj", (None, "qkv"), cfg)(c_q)
+        attn = family.latent_attention_layer(
+            self, h, q.reshape(B, T, H, cfg.qk_head_dim), step, cfg,
+            "kv_a_norm", cfg.softmax_scale)
+        gate = dense(H * Dv, "o_gate_proj", ("embed", "qkv"), cfg)(h)
         attn = (attn.reshape(B, T, H * Dv).astype(jnp.float32)
-                * output_gate(gate, 1.0)).astype(cdt)
-        return _dense(E, "o_proj", ("qkv", "embed"), cfg)(attn)
+                * output_gate(gate, 1.0)).astype(cfg.compute_dtype())
+        return dense(E, "o_proj", ("qkv", "embed"), cfg)(attn)
 
-    def _experts(self, h, live, sow_kv):
+    def _experts(self, h, step):
         cfg = self.cfg
-        B, T, E = h.shape
-        cdt = cfg.compute_dtype()
-        G, F, held = (cfg.n_routed_experts, cfg.moe_intermediate_size,
-                      cfg.experts_held)
-        normal = nn.initializers.normal(0.02)
-        w_router = self.param("router", normal, (E, G), jnp.float32)
-        # a buffer in the release: it moves the choice, never the weights
-        bias = self.param("e_score_correction_bias",
-                          nn.initializers.zeros_init(), (G,), jnp.float32)
-        w_gate_up = self.param("experts_gate_up", normal,
-                               (held[1], E, 2 * F), cfg.storage_dtype())
-        w_down = self.param("experts_down", normal, (held[1], F, E),
-                            cfg.storage_dtype())
-        flat = h.reshape(B * T, E)
-        choice, weights = moe.route(
-            flat, w_router, bias, cfg.num_experts_per_tok,
-            cfg.routed_scaling_factor, cfg.norm_topk_prob)
-        routed, stats = moe.routed_experts(
-            flat, choice, weights, w_gate_up.astype(cdt),
-            w_down.astype(cdt), held=held,
-            live=None if live is None else live.reshape(B * T),
-            swiglu_limit=cfg.swiglu_limit)
-        if sow_kv:
-            self.sow("intermediates", "serve_stats", stats)
+        F, limit = cfg.moe_intermediate_size, cfg.swiglu_limit
+        routed, _ = family.routed_ffn(
+            self, h, cfg, experts=cfg.n_routed_experts, width=2 * F,
+            live=step.live, sow=step.sow_kv, swiglu_limit=limit)
         with jax.named_scope("moe.shared"):
-            shared = _swiglu(
-                h, cfg.n_shared_experts * F,
-                ("shared_gate_proj", "shared_up_proj", "shared_down_proj"),
-                cfg)
-        return routed.reshape(B, T, E) + shared
+            shared = family.swiglu(h, cfg.n_shared_experts * F,
+                                   family.SHARED_SWIGLU, cfg, limit)
+        return routed.reshape(h.shape) + shared
 
 
-class GigaChat35(nn.Module):
+class GigaChat35(family.ServedDecoder):
+    """``kv_pages`` holds the LATENT pair of each full-attention layer."""
     cfg: GigaChat35Config
 
-    @nn.compact
-    def __call__(self, input_ids, *, attention_mask=None, segment_ids=None,
-                 position_ids=None, deterministic: bool = True,
-                 return_hidden: bool = False, kv_lens=None,
-                 sow_kv: bool = False, kv_pages=None, page_tables=None,
-                 ssm_pools=None, slots=None, ssm_init=None):
-        """The serving hooks are nemotron_h.NemotronH.__call__'s:
-        ``kv_pages`` one pair for each full-attention layer, in layer
-        order (here the LATENT pair), ``ssm_pools`` one ``(states,
-        tails)`` pair for each linear layer, ``slots`` [B] the pools' rows
-        this step moves on by one token; the moved pools are sown back
-        under ``ssm_cache``. Without them a linear layer starts from a
-        zero state, or from ``ssm_init`` (one ``(state, tail)`` pair a
-        linear layer: what the sequence's earlier part left), and sows the
-        state after the last live position (``attention_mask`` says which
-        are live)."""
-        del deterministic
+    def block(self, i: int) -> GigaChat35Block:
         cfg = self.cfg
-        B, T = input_ids.shape
-        wte = self.param(
-            "embed_tokens",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         ("vocab", "embed")),
-            (cfg.padded_vocab, cfg.hidden_size), cfg.storage_dtype())
-        if position_ids is None:
-            position_ids = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-        # the rows a routed layer counts and a linear layer feeds on: not
-        # a prefill bucket's padding, not a decode bucket's empty slots
-        if attention_mask is not None:
-            live = attention_mask.astype(bool)
-        elif kv_lens is not None:
-            live = jnp.broadcast_to(kv_lens[:, None] > 0, (B, T))
-        else:
-            live = None
-        live_len = (jnp.full((B,), T, jnp.int32) if attention_mask is None
-                    else jnp.sum(attention_mask.astype(jnp.int32), axis=1))
-        x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
-        n_kv = n_ssm = 0
-        for i in range(cfg.num_hidden_layers):
-            full = i in cfg.full_attention_layers
-            pages = pools = init = None
-            if full and kv_pages is not None:
-                pages, n_kv = kv_pages[n_kv], n_kv + 1
-            if not full:
-                if ssm_pools is not None:
-                    pools = ssm_pools[n_ssm]
-                if ssm_init is not None:
-                    init = ssm_init[n_ssm]
-                n_ssm += 1
-            x = GigaChat35Block(cfg, full, i >= cfg.first_k_dense_replace,
-                                name=f"layer_{i}")(
-                x, attention_mask, segment_ids, position_ids, live,
-                live_len, kv_lens, sow_kv, pages, page_tables, pools, slots,
-                init)
-        x = _norm(cfg, "norm")(x)
-        if return_hidden:
-            return x
-        lm_head = self.param(
-            "lm_head",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         ("vocab", "embed")),
-            (cfg.padded_vocab, cfg.hidden_size), cfg.storage_dtype())
-        logits = jnp.einsum("bte,ve->btv", x,
-                            lm_head.astype(cfg.compute_dtype()),
-                            preferred_element_type=jnp.float32)
-        return logits.astype(jnp.dtype(cfg.logits_dtype))
-
-    def init_params(self, rng, *, seq_len: int = 8):
-        dummy = jnp.zeros((1, seq_len), jnp.int32)
-        return nn.meta.unbox(self.init(rng, dummy)["params"])
+        return GigaChat35Block(cfg, i in cfg.full_attention_layers,
+                               i >= cfg.first_k_dense_replace,
+                               name=f"layer_{i}")
 
 
-def make_model(preset_or_cfg) -> tuple[GigaChat35, GigaChat35Config]:
-    cfg = (PRESETS[preset_or_cfg] if isinstance(preset_or_cfg, str)
-           else preset_or_cfg)
-    return GigaChat35(cfg), cfg
+make_model = family.make_model(GigaChat35, PRESETS)

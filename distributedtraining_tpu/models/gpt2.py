@@ -32,14 +32,11 @@ import numpy as np
 from ..ops.attention import (
     cached_attention, causal_attention, causal_attention_qkv, remat_policy)
 from ..ops.embed import embed_lookup
-
-
-def pad_vocab(n: int, multiple: int) -> int:
-    return ((n + multiple - 1) // multiple) * multiple
+from .family import FamilyConfig
 
 
 @dataclasses.dataclass(frozen=True)
-class GPT2Config:
+class GPT2Config(FamilyConfig):
     vocab_size: int = 50257
     n_positions: int = 1024
     n_embd: int = 768
@@ -68,19 +65,15 @@ class GPT2Config:
     # on-chip measurement.
     logits_dtype: str = "float32"
 
+    n_kv_head = None               # as many K/V heads as query heads
+
     @property
-    def padded_vocab(self) -> int:
-        return pad_vocab(self.vocab_size, self.vocab_multiple)
+    def max_seq_len(self) -> int:
+        return self.n_positions
 
     @property
     def head_dim(self) -> int:
         return self.n_embd // self.n_head
-
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    def storage_dtype(self):
-        return jnp.dtype(self.param_dtype)
 
     def rounds_first(self, path: tuple[str, ...]) -> bool:
         """Whether EVERY use of the leaf at ``path`` of an unrolled base

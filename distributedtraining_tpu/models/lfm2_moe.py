@@ -54,11 +54,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import flash_attention, moe, ssm
+from ..ops import flash_attention, ssm
 from ..ops.attention import causal_attention, remat_policy
 from ..ops.embed import embed_lookup
-from .gpt2 import pad_vocab
-from .llama import RMSNorm, _dense, rotary_embedding
+from . import family
+from .family import dense, rotary_embedding
 
 _PUBLISHED_LAYERS = tuple(
     "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv"
@@ -79,7 +79,7 @@ ATTN_COUNTERS = flash_attention.BLOCK_PAIR_COUNTERS
 
 
 @dataclasses.dataclass(frozen=True)
-class Lfm2MoeConfig:
+class Lfm2MoeConfig(family.FamilyConfig):
     # the published keys, under their published names
     vocab_size: int = 65536
     hidden_size: int = 2048
@@ -101,20 +101,15 @@ class Lfm2MoeConfig:
     rope_theta: float = 1000000.0
     max_position_embeddings: int = 128000
     tie_word_embeddings: bool = True
-    # the program's own
+    # the program's own, beside family.FamilyConfig's
     experts_held: tuple[int, int] = (0, 32)    # (first, count) on this chip
     vocab_held: tuple[int, int] = (0, 65536)   # (first, count) of the ids
     route_norm_eps: float = 1e-6
-    dtype: str = "bfloat16"
     param_dtype: str = "float32"
-    logits_dtype: str = "float32"
-    vocab_multiple: int = 128
     remat: bool = True
-    scan_blocks: bool = False
 
     def __post_init__(self):
-        first, count = self.experts_held
-        unsupported = {
+        self.refuse({
             "layer_types": (len(self.layer_types) != self.num_hidden_layers
                             or set(self.layer_types)
                             - {"conv", "full_attention"}),
@@ -125,25 +120,13 @@ class Lfm2MoeConfig:
             "head_dim": self.hidden_size % self.num_attention_heads != 0,
             "conv_bias": self.conv_bias,
             "use_expert_bias": not self.use_expert_bias,
-            "experts_held": not (0 <= first and count >= 1
-                                 and first + count <= self.num_experts),
+            "experts_held": family.held_outside(self.experts_held,
+                                                self.num_experts),
             "vocab_held": self.vocab_held[1] != self.vocab_size,
             "tie_word_embeddings": not self.tie_word_embeddings,
             "scan_blocks": self.scan_blocks,
-        }
-        bad = [k for k, v in unsupported.items() if v]
-        if bad:
-            raise ValueError(f"Lfm2MoeConfig: {', '.join(bad)} not "
-                             "supported (models/lfm2_moe.py writes the "
-                             "equations of the LFM2-8B-A1B row only)")
-
-    @property
-    def padded_vocab(self) -> int:
-        return pad_vocab(self.vocab_size, self.vocab_multiple)
-
-    @property
-    def max_seq_len(self) -> int:
-        return self.max_position_embeddings
+        }, "models/lfm2_moe.py writes the equations of the LFM2-8B-A1B row "
+           "only")
 
     @property
     def head_dim(self) -> int:
@@ -158,20 +141,14 @@ class Lfm2MoeConfig:
         return tuple("kv" if t == "full_attention" else "conv"
                      for t in self.layer_types)
 
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
+    # cast before every use: the ``nn.Dense`` kernels, the experts' two
+    # stacks, the lookup's rows (and the tied head). Not the convolution's
+    # taps, a norm's gain, the router or ``expert_bias``: float32 in the
+    # tree, float32 where they are used
+    cast_first = ("kernel", "experts_in", "experts_down", "embed_tokens")
 
-    def storage_dtype(self):
-        return jnp.dtype(self.param_dtype)
-
-    def rounds_first(self, path: tuple[str, ...]) -> bool:
-        """See ``GPT2Config.rounds_first``. Cast before every use: the
-        ``nn.Dense`` kernels, the experts' two stacks, the lookup's rows
-        (and the tied head). Not the convolution's taps, a norm's gain,
-        the router or ``expert_bias``: float32 in the tree, float32 where
-        they are used."""
-        return path[-1] in ("kernel", "experts_in", "experts_down",
-                            "embed_tokens")
+    def norm(self, name: str) -> nn.Module:
+        return family.RMSNorm(self.norm_eps, "float32", name=name)
 
     def is_buffer(self, path: tuple[str, ...]) -> bool:
         """A leaf of the parameter tree that is no parameter: the
@@ -210,17 +187,6 @@ PRESETS: dict[str, Lfm2MoeConfig] = {
 }
 
 
-def _norm(cfg, name: str) -> RMSNorm:
-    return RMSNorm(cfg.norm_eps, "float32", name=name)
-
-
-def _conv_init(key, shape, dtype):
-    """PyTorch's default for a depthwise fan-in of K: U(-1/sqrt(K),
-    1/sqrt(K))."""
-    bound = shape[0] ** -0.5
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
 class Lfm2MoeBlock(nn.Module):
     cfg: Lfm2MoeConfig
     mixer: str                  # conv or full_attention
@@ -236,40 +202,42 @@ class Lfm2MoeBlock(nn.Module):
         # stacks' casts, the sort and the counters
         conv = self.mixer == "conv"
         with jax.named_scope("lfm2.conv" if conv else "lfm2.attn"):
-            h = _norm(self.cfg, "operator_norm")(x)
+            h = self.cfg.norm("operator_norm")(x)
             mix = self._conv if conv else self._attention
             out, stats = mix(h, attention_mask, segment_ids, position_ids)
             x = x + out
         with jax.named_scope("lfm2.moe_ffn" if self.routed
                              else "lfm2.dense_ffn"):
-            h = _norm(self.cfg, "ffn_norm")(x)
+            h = self.cfg.norm("ffn_norm")(x)
             if not self.routed:
-                return x + self._dense_ffn(h), stats
+                return x + family.plain_swiglu(
+                    h, self.cfg.intermediate_size, ("w1", "w3", "w2"),
+                    self.cfg), stats
             out, rows = self._experts(h)
             return x + out, {**stats, **rows}
 
     def _conv(self, h, _mask, segment_ids, _pos):
         cfg = self.cfg
         E = cfg.hidden_size
-        bcu = _dense(3 * E, "in_proj", ("embed", "mlp"), cfg)(h)
+        bcu = dense(3 * E, "in_proj", ("embed", "mlp"), cfg)(h)
         gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
-        taps = self.param("conv_weight", _conv_init,
+        taps = self.param("conv_weight", family.conv_init,
                           (cfg.conv_L_cache, E), jnp.float32)
         conv, _ = ssm.causal_conv1d(gate_b * u, taps, None, None,
                                     segment_ids)
         y = (gate_c.astype(jnp.float32) * conv).astype(cfg.compute_dtype())
-        return _dense(E, "out_proj", ("mlp", "embed"), cfg)(y), {}
+        return dense(E, "out_proj", ("mlp", "embed"), cfg)(y), {}
 
     def _attention(self, h, attention_mask, segment_ids, position_ids):
         cfg = self.cfg
         B, T, E = h.shape
         Hq, Hkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
-        q = _dense(Hq * D, "q_proj", ("embed", "qkv"), cfg)(h)
-        k = _dense(Hkv * D, "k_proj", ("embed", "qkv"), cfg)(h)
-        v = _dense(Hkv * D, "v_proj", ("embed", "qkv"), cfg)(h)
-        q = _norm(cfg, "q_layernorm")(q.reshape(B, T, Hq, D))
-        k = _norm(cfg, "k_layernorm")(k.reshape(B, T, Hkv, D))
+        q = dense(Hq * D, "q_proj", ("embed", "qkv"), cfg)(h)
+        k = dense(Hkv * D, "k_proj", ("embed", "qkv"), cfg)(h)
+        v = dense(Hkv * D, "v_proj", ("embed", "qkv"), cfg)(h)
+        q = cfg.norm("q_layernorm")(q.reshape(B, T, Hq, D))
+        k = cfg.norm("k_layernorm")(k.reshape(B, T, Hkv, D))
         v = v.reshape(B, T, Hkv, D)
         q = rotary_embedding(q, position_ids, cfg.rope_theta)
         k = rotary_embedding(k, position_ids, cfg.rope_theta)
@@ -279,46 +247,23 @@ class Lfm2MoeBlock(nn.Module):
             attention_mask=attention_mask, segment_ids=segment_ids,
             impl="flash")
         pairs = flash_attention.block_pairs(q, attention_mask, segment_ids)
-        return _dense(E, "out_proj", ("qkv", "embed"), cfg)(
+        return dense(E, "out_proj", ("qkv", "embed"), cfg)(
             attn.reshape(B, T, Hq * D)), dict(zip(ATTN_COUNTERS, pairs or ()))
-
-    def _dense_ffn(self, h):
-        cfg = self.cfg
-        F = cfg.intermediate_size
-        gate = _dense(F, "w1", ("embed", "mlp"), cfg)(h)
-        up = _dense(F, "w3", ("embed", "mlp"), cfg)(h)
-        return _dense(cfg.hidden_size, "w2", ("mlp", "embed"), cfg)(
-            nn.silu(gate) * up)
 
     def _experts(self, h):
         cfg = self.cfg
-        B, T, E = h.shape
-        F, held = cfg.moe_intermediate_size, cfg.experts_held
-        cdt = cfg.compute_dtype()
-        normal = nn.initializers.normal(0.02)
-        w_router = self.param("router", normal, (E, cfg.num_experts),
-                              jnp.float32)
-        # a buffer in the release (cfg.is_buffer): it moves the choice
-        bias = self.param("expert_bias", nn.initializers.zeros_init(),
-                          (cfg.num_experts,), jnp.float32)
-        # gate and up fused, gate columns first (ops/moe._experts_sorted)
-        w_in = self.param("experts_in", normal, (held[1], E, 2 * F),
-                          cfg.storage_dtype())
-        w_down = self.param("experts_down", normal, (held[1], F, E),
-                            cfg.storage_dtype())
-        flat = h.reshape(B * T, E)
-        choice, weights = moe.route(
-            flat, w_router, bias, cfg.num_experts_per_tok,
-            cfg.routed_scaling_factor, cfg.norm_topk_prob,
-            cfg.route_norm_eps)
-        out, stats = moe.routed_experts(
-            flat, choice, weights, w_in.astype(cdt), w_down.astype(cdt),
-            held=held, router_experts=cfg.num_experts, count_fullest=True)
-        return out.reshape(B, T, E), {name: stats[k]
+        # gate and up fused, gate columns first (ops/moe._experts_sorted);
+        # ``expert_bias`` is a buffer (cfg.is_buffer)
+        out, stats = family.routed_ffn(
+            self, h, cfg, experts=cfg.num_experts,
+            width=2 * cfg.moe_intermediate_size, bias="expert_bias",
+            first="experts_in", router_experts=cfg.num_experts,
+            count_fullest=True)
+        return out.reshape(h.shape), {name: stats[k]
                                       for k, name in TRAIN_COUNTERS.items()}
 
 
-class Lfm2Moe(nn.Module):
+class Lfm2Moe(family.Decoder):
     cfg: Lfm2MoeConfig
 
     @nn.compact
@@ -332,13 +277,8 @@ class Lfm2Moe(nn.Module):
         del deterministic
         cfg = self.cfg
         B, T = input_ids.shape
-        wte = self.param(
-            "embed_tokens",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         ("vocab", "embed")),
-            (cfg.padded_vocab, cfg.hidden_size), cfg.storage_dtype())
-        if position_ids is None:
-            position_ids = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
+        wte = family.embed_table(self, cfg)
+        position_ids = family.default_positions(position_ids, B, T)
         with jax.named_scope("lfm2.embed"):
             x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
         block = Lfm2MoeBlock
@@ -354,20 +294,10 @@ class Lfm2Moe(nn.Module):
         if counted:
             self.sow("intermediates", "train_counters", counted)
         with jax.named_scope("lfm2.head"):
-            x = _norm(cfg, "norm_f")(x)
+            x = cfg.norm("norm_f")(x)
             if return_hidden:
                 return x
-            logits = jnp.einsum("bte,ve->btv", x,
-                                wte.astype(cfg.compute_dtype()),
-                                preferred_element_type=jnp.float32)
-            return logits.astype(jnp.dtype(cfg.logits_dtype))
-
-    def init_params(self, rng, *, seq_len: int = 8):
-        dummy = jnp.zeros((1, seq_len), jnp.int32)
-        return nn.meta.unbox(self.init(rng, dummy)["params"])
+            return family.logits(x, wte, cfg)
 
 
-def make_model(preset_or_cfg) -> tuple[Lfm2Moe, Lfm2MoeConfig]:
-    cfg = (PRESETS[preset_or_cfg] if isinstance(preset_or_cfg, str)
-           else preset_or_cfg)
-    return Lfm2Moe(cfg), cfg
+make_model = family.make_model(Lfm2Moe, PRESETS)

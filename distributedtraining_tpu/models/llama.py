@@ -16,17 +16,16 @@ from __future__ import annotations
 import dataclasses
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from ..ops.attention import (
     cached_attention, causal_attention, remat_policy)
 from ..ops.embed import embed_lookup
-from .gpt2 import pad_vocab
+from .family import FamilyConfig, RMSNorm, dense, rotary_embedding
 
 
 @dataclasses.dataclass(frozen=True)
-class LlamaConfig:
+class LlamaConfig(FamilyConfig):
     vocab_size: int = 32000
     max_seq_len: int = 4096
     n_embd: int = 4096
@@ -54,26 +53,14 @@ class LlamaConfig:
     logits_dtype: str = "float32"
 
     @property
-    def padded_vocab(self) -> int:
-        return pad_vocab(self.vocab_size, self.vocab_multiple)
-
-    @property
     def head_dim(self) -> int:
         return self.n_embd // self.n_head
 
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    def storage_dtype(self):
-        return jnp.dtype(self.param_dtype)
-
-    def rounds_first(self, path: tuple[str, ...]) -> bool:
-        """See ``GPT2Config.rounds_first``. Every matrix is a bias-free
-        ``nn.Dense`` kernel or the untied head, cast before its product;
-        the lookup's rows are cast straight after the gather, which
-        rounds the same values. Not an RMSNorm's scale: it multiplies in
-        float32."""
-        return path[-1] in ("kernel", "lm_head", "wte")
+    # see ``GPT2Config.rounds_first``. Every matrix is a bias-free
+    # ``nn.Dense`` kernel or the untied head, cast before its product; the
+    # lookup's rows are cast straight after the gather, which rounds the
+    # same values. Not an RMSNorm's scale: it multiplies in float32
+    cast_first = ("kernel", "lm_head", "wte")
 
 
 PRESETS: dict[str, LlamaConfig] = {
@@ -85,56 +72,6 @@ PRESETS: dict[str, LlamaConfig] = {
                               n_layer=2, n_head=4, n_kv_head=2,
                               intermediate_size=128, remat=False),
 }
-
-
-def rotary_embedding(x: jax.Array, position_ids: jax.Array,
-                     theta: float, *, interleaved: bool = False,
-                     inv_freq: jax.Array | None = None) -> jax.Array:
-    """Apply RoPE to [B, T, H, D] given positions [B, T]. Pair i is the
-    lanes ``(i, i + D/2)`` (Llama's halves) or, ``interleaved``, the
-    lanes ``(2i, 2i + 1)`` (DeepSeek-V3's ``rope_interleave``).
-    ``inv_freq`` [D/2] stands in for ``theta``'s plain frequencies (a
-    family whose ``rope_scaling`` blends them)."""
-    D = x.shape[-1]
-    if inv_freq is None:
-        inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32)
-                                    / D))
-    angles = position_ids[..., None].astype(jnp.float32) * inv_freq  # [B,T,D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x32 = x.astype(jnp.float32)
-    if interleaved:
-        x1, x2 = x32[..., 0::2], x32[..., 1::2]
-        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                        axis=-1).reshape(x.shape)
-        return out.astype(x.dtype)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
-
-
-class RMSNorm(nn.Module):
-    eps: float
-    param_dtype: str
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param(
-            "scale",
-            nn.with_logical_partitioning(nn.initializers.ones_init(), ("embed",)),
-            (x.shape[-1],), jnp.dtype(self.param_dtype))
-        x32 = x.astype(jnp.float32)
-        norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                                   + self.eps)
-        return (norm * scale).astype(x.dtype)
-
-
-def _dense(features, name, axes, cfg: LlamaConfig):
-    return nn.Dense(features, use_bias=False, dtype=cfg.compute_dtype(),
-                    param_dtype=cfg.storage_dtype(),
-                    kernel_init=nn.with_logical_partitioning(
-                        nn.initializers.normal(0.02), axes),
-                    name=name)
 
 
 class LlamaBlock(nn.Module):
@@ -157,9 +94,9 @@ class LlamaBlock(nn.Module):
         Hq, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
 
         h = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="attn_norm")(x)
-        q = _dense(Hq * D, "wq", ("embed", "qkv"), cfg)(h).reshape(B, T, Hq, D)
-        k = _dense(Hkv * D, "wk", ("embed", "qkv"), cfg)(h).reshape(B, T, Hkv, D)
-        v = _dense(Hkv * D, "wv", ("embed", "qkv"), cfg)(h).reshape(B, T, Hkv, D)
+        q = dense(Hq * D, "wq", ("embed", "qkv"), cfg)(h).reshape(B, T, Hq, D)
+        k = dense(Hkv * D, "wk", ("embed", "qkv"), cfg)(h).reshape(B, T, Hkv, D)
+        v = dense(Hkv * D, "wv", ("embed", "qkv"), cfg)(h).reshape(B, T, Hkv, D)
         q = rotary_embedding(q, position_ids, cfg.rope_theta)
         k = rotary_embedding(k, position_ids, cfg.rope_theta)
         if sow_kv:
@@ -185,13 +122,13 @@ class LlamaBlock(nn.Module):
             attn = causal_attention(q, k, v, attention_mask=attention_mask,
                                     segment_ids=segment_ids,
                                     impl=cfg.attention_impl)
-        attn = _dense(E, "wo", ("qkv", "embed"), cfg)(attn.reshape(B, T, Hq * D))
+        attn = dense(E, "wo", ("qkv", "embed"), cfg)(attn.reshape(B, T, Hq * D))
         x = x + attn
 
         h = RMSNorm(cfg.rms_norm_eps, cfg.param_dtype, name="mlp_norm")(x)
-        gate = _dense(cfg.intermediate_size, "w_gate", ("embed", "mlp"), cfg)(h)
-        up = _dense(cfg.intermediate_size, "w_up", ("embed", "mlp"), cfg)(h)
-        down = _dense(E, "w_down", ("mlp", "embed"), cfg)(nn.silu(gate) * up)
+        gate = dense(cfg.intermediate_size, "w_gate", ("embed", "mlp"), cfg)(h)
+        up = dense(cfg.intermediate_size, "w_up", ("embed", "mlp"), cfg)(h)
+        down = dense(E, "w_down", ("mlp", "embed"), cfg)(nn.silu(gate) * up)
         # pin the residual stream to batch sharding at the block boundary:
         # with fsdp-sharded params GSPMD otherwise reshards activations
         # off the batch axis (B-fold activation blowup at 8B/seq 8k);

@@ -65,20 +65,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops import delta_rule, moe, ssm
-from ..ops.attention import causal_attention
-from ..ops.embed import embed_lookup
-from ..ops.paged_attention import paged_attention
-from .gpt2 import pad_vocab
-from .llama import RMSNorm, _dense
-from .nemotron_h import _a_log_init, _conv_init, _dt_bias_init
+from ..ops import delta_rule
+from . import family
+from .family import dense
 
 _LINEAR = (("head_dim", 128), ("num_heads", 64), ("num_kv_heads", None),
            ("short_conv_kernel_size", 4))
 
 
 @dataclasses.dataclass(frozen=True)
-class SolarOpen2Config:
+class SolarOpen2Config(family.FamilyConfig):
     # the published keys, under their published names
     vocab_size: int = 196608
     max_position_embeddings: int = 1048576
@@ -106,22 +102,15 @@ class SolarOpen2Config:
     kda_allow_neg_eigval: bool = True
     linear_attn_config: tuple = _LINEAR  # the published group, as pairs
     tie_word_embeddings: bool = False
-    # the program's own
+    # the program's own, beside family.FamilyConfig's
     experts_held: tuple[int, int] = (0, 320)   # (first, count) on this chip
     kda_low_rank: int = 128
     chunk_size: int = delta_rule.CHUNK
-    dtype: str = "bfloat16"
-    param_dtype: str = "bfloat16"
-    logits_dtype: str = "float32"
     attention_impl: str = "dense"
-    vocab_multiple: int = 128
-    remat: bool = False
-    scan_blocks: bool = False
 
     def __post_init__(self):
-        first, count = self.experts_held
         linear = dict(self.linear_attn_config)
-        unsupported = {
+        self.refuse({
             "gqa_layers": not all(0 <= i < self.num_hidden_layers
                                   for i in self.gqa_layers),
             "first_k_dense_replace": self.first_k_dense_replace != 0,
@@ -134,34 +123,12 @@ class SolarOpen2Config:
             "num_key_value_heads": (self.num_attention_heads
                                     % self.num_key_value_heads != 0),
             "n_shared_experts": self.n_shared_experts != 1,
-            "experts_held": not (0 <= first and count >= 1
-                                 and first + count <= self.n_routed_experts),
+            "experts_held": family.held_outside(self.experts_held,
+                                                self.n_routed_experts),
             "tie_word_embeddings": self.tie_word_embeddings,
             "scan_blocks": self.scan_blocks,
-        }
-        bad = [k for k, v in unsupported.items() if v]
-        if bad:
-            raise ValueError(f"SolarOpen2Config: {', '.join(bad)} not "
-                             "supported (this block writes one reading of "
-                             "each key: see the module's docstring)")
-
-    @property
-    def padded_vocab(self) -> int:
-        return pad_vocab(self.vocab_size, self.vocab_multiple)
-
-    @property
-    def max_seq_len(self) -> int:
-        return self.max_position_embeddings
-
-    # the K/V geometry of the attention layers, under the names
-    # engine/kv_pool.row_widths reads
-    @property
-    def n_head(self) -> int:
-        return self.num_attention_heads
-
-    @property
-    def n_kv_head(self) -> int:
-        return self.num_key_value_heads
+        }, "this block writes one reading of each key: see the module's "
+           "docstring")
 
     @property
     def layer_caches(self) -> tuple[str, ...]:
@@ -190,24 +157,13 @@ class SolarOpen2Config:
         return (dict(self.linear_attn_config)["short_conv_kernel_size"] - 1,
                 self.conv_dim)
 
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    def storage_dtype(self):
-        return jnp.dtype(self.param_dtype)
-
-    def rounds_first(self, path: tuple[str, ...]) -> bool:
-        """See ``GPT2Config.rounds_first``. Cast before every use: the
-        ``nn.Dense`` kernels, the experts' two stacks, the head; the
-        lookup's rows straight after the gather. Not ``A_log``,
-        ``dt_bias``, the convolution (they enter the float32 recurrence),
-        a norm's gain, the router or its selection bias (float32 scores):
-        those leaves are float32 in the tree and stay so."""
-        return path[-1] in _CAST_FIRST
-
-
-_CAST_FIRST = ("kernel", "experts_gate_up", "experts_down", "lm_head",
-               "embed_tokens")
+    # cast before every use: the ``nn.Dense`` kernels, the experts' two
+    # stacks, the head; the lookup's rows straight after the gather. Not
+    # ``A_log``, ``dt_bias``, the convolution (they enter the float32
+    # recurrence), a norm's gain, the router or its selection bias (float32
+    # scores): those leaves are float32 in the tree and stay so
+    cast_first = ("kernel", "experts_gate_up", "experts_down", "lm_head",
+                  "embed_tokens")
 
 
 def _linear(**changed) -> tuple:
@@ -246,62 +202,46 @@ def output_gate(z):
     return jax.nn.sigmoid(z.astype(jnp.float32))
 
 
-def _norm(cfg, name: str) -> RMSNorm:
-    return RMSNorm(cfg.rms_norm_eps, "float32", name=name)
-
-
-def _swiglu(h, width: int, names: tuple[str, str, str], cfg):
-    gate = _dense(width, names[0], ("embed", "mlp"), cfg)(h)
-    up = _dense(width, names[1], ("embed", "mlp"), cfg)(h)
-    act = moe.clamped_swiglu(gate, up, None).astype(gate.dtype)
-    return _dense(cfg.hidden_size, names[2], ("mlp", "embed"), cfg)(act)
-
-
 class SolarOpen2Block(nn.Module):
     cfg: SolarOpen2Config
     full_attention: bool
 
     @nn.compact
-    def __call__(self, x, attention_mask, segment_ids, live, live_len,
-                 kv_lens=None, sow_kv=False, kv_pages=None,
-                 page_tables=None, ssm_pools=None, slots=None,
-                 ssm_init=None):
+    def __call__(self, x, step: family.Step):
         cfg = self.cfg
-        h = _norm(cfg, "mixer_norm")(x)
+        h = cfg.norm("mixer_norm")(x)
         if self.full_attention:
             with jax.named_scope("solar.gqa"):
-                y = self._attention(h, attention_mask, segment_ids, kv_lens,
-                                    sow_kv, kv_pages, page_tables)
+                y = self._attention(h, step)
         else:
             with jax.named_scope("solar.kda"):
-                y = self._delta(h, live_len, kv_lens, sow_kv, ssm_pools,
-                                slots, ssm_init)
+                y = self._delta(h, step)
         x = x + y
         with jax.named_scope("solar.moe_ffn"):
-            return x + self._experts(_norm(cfg, "ffn_norm")(x), live, sow_kv)
+            return x + self._experts(cfg.norm("ffn_norm")(x), step)
 
-    def _delta(self, u, live_len, kv_lens, sow_kv, ssm_pools, slots,
-               ssm_init):
+    def _delta(self, u, step):
         cfg = self.cfg
         B, T, E = u.shape
         H, dk, dv = cfg.ssm_state_shape
         K, conv_dim = cfg.ssm_tail_shape[0] + 1, cfg.conv_dim
         r = cfg.kda_low_rank
         cdt, f32 = cfg.compute_dtype(), jnp.float32
-        qkv = _dense(conv_dim, "in_proj_qkv", ("embed", "mlp"), cfg)(u)
-        conv_w = self.param("conv1d_weight", _conv_init, (K, conv_dim), f32)
-        a_log = self.param("A_log", _a_log_init, (H,), f32)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (H * dk,), f32)
-        f = _dense(H * dk, "f_b_proj", (None, "mlp"), cfg)(
-            _dense(r, "f_a_proj", ("embed", None), cfg)(u))
+        qkv = dense(conv_dim, "in_proj_qkv", ("embed", "mlp"), cfg)(u)
+        conv_w = self.param("conv1d_weight", family.conv_init,
+                            (K, conv_dim), f32)
+        a_log = self.param("A_log", family.a_log_init, (H,), f32)
+        dt_bias = self.param("dt_bias", family.dt_bias_init, (H * dk,), f32)
+        f = dense(H * dk, "f_b_proj", (None, "mlp"), cfg)(
+            dense(r, "f_a_proj", ("embed", None), cfg)(u))
         # dk log-decays a head, float32
         g = (-jnp.exp(a_log)[:, None]
              * jax.nn.softplus(f.astype(f32) + dt_bias
                                ).reshape(B, T, H, dk))
         beta = 2.0 * jax.nn.sigmoid(
-            _dense(H, "b_proj", ("embed", None), cfg)(u).astype(f32))
-        gate = _dense(H * dv, "g_b_proj", (None, "mlp"), cfg)(
-            _dense(r, "g_a_proj", ("embed", None), cfg)(u))
+            dense(H, "b_proj", ("embed", None), cfg)(u).astype(f32))
+        gate = dense(H * dv, "g_b_proj", (None, "mlp"), cfg)(
+            dense(r, "g_a_proj", ("embed", None), cfg)(u))
 
         def split(act):
             """silu(conv) -> q, k [.., H, dk] (q scaled, k of unit length,
@@ -318,176 +258,39 @@ class SolarOpen2Block(nn.Module):
                     unit(act[..., H * dk:2 * H * dk].reshape(*lead, H, dk)),
                     act[..., 2 * H * dk:].reshape(*lead, H, dv))
 
-        if ssm_pools is None:
-            with jax.named_scope("kda.prefill"):
-                s0, tail0 = (None, None) if ssm_init is None else ssm_init
-                # `tail0` is named only when there is one: the fault
-                # injectors of benchmarks/tools swap in a
-                # `causal_conv1d` of the older signature
-                conv, tail = ssm.causal_conv1d(
-                    qkv, conv_w, None, live_len,
-                    **({} if tail0 is None else {"tail0": tail0}))
-                q, k, v = split(conv)
-                o, state = delta_rule.delta_rule_prefill(
-                    q, k, v, g, beta, live_len, s0, chunk=cfg.chunk_size)
-            if sow_kv:
-                # the whole of what this layer keeps for the sequence
-                self.sow("intermediates", "ssm_cache", (state, tail))
-        else:
-            with jax.named_scope("kda.decode"):
-                states, tails = ssm_pools
-                conv, tails = ssm.conv_decode_update(
-                    tails, slots, qkv[:, 0], conv_w, None)
-                q, k, v = split(conv)
-                # a bucket's padding rows (no sequence: length 0) cost
-                # no arithmetic and leave the row they name as it was
-                o, states = delta_rule.gdn_decode_update(
-                    states, slots, q, k, v, g[:, 0], beta[:, 0],
-                    kv_lens > 0)
-                o = o[:, None]
-            self.sow("intermediates", "ssm_cache", (states, tails))
-            self.sow("intermediates", "serve_stats", {
-                "kda_slot_steps": jnp.sum(kv_lens > 0).astype(jnp.int32)})
+        o = family.delta_rule_layer(self, qkv, conv_w, split, g, beta, step,
+                                    cfg)
         w_o = self.param("o_norm", nn.initializers.ones_init(), (dv,), f32)
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                               + cfg.rms_norm_eps)
         o = o * w_o * output_gate(gate).reshape(B, T, H, dv)
-        return _dense(E, "out_proj", ("mlp", "embed"), cfg)(
+        return dense(E, "out_proj", ("mlp", "embed"), cfg)(
             o.reshape(B, T, H * dv).astype(cdt))
 
-    def _attention(self, h, attention_mask, segment_ids, kv_lens, sow_kv,
-                   kv_pages, page_tables):
-        cfg = self.cfg
-        B, T, E = h.shape
-        Hq, Hkv, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-        q = _dense(Hq * Dh, "q_proj", ("embed", "qkv"), cfg)(h)
-        k = _dense(Hkv * Dh, "k_proj", ("embed", "qkv"), cfg)(h)
-        v = _dense(Hkv * Dh, "v_proj", ("embed", "qkv"), cfg)(h)
-        gate = _dense(Hq * Dh, "g_proj", ("embed", "qkv"), cfg)(h)
-        q = q.reshape(B, T, Hq, Dh)
-        k, v = k.reshape(B, T, Hkv, Dh), v.reshape(B, T, Hkv, Dh)
-        if sow_kv:
-            self.sow("intermediates", "kv_cache", (k, v))
-        if kv_pages is not None:
-            attn = paged_attention(q, kv_pages[0], kv_pages[1], page_tables,
-                                   kv_lens, k, v)
-        else:
-            rep = Hq // Hkv
-            attn = causal_attention(
-                q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
-                attention_mask=attention_mask, segment_ids=segment_ids,
-                impl=cfg.attention_impl)
-        attn = (attn.reshape(B, T, Hq * Dh).astype(jnp.float32)
-                * output_gate(gate)).astype(cfg.compute_dtype())
-        return _dense(E, "o_proj", ("qkv", "embed"), cfg)(attn)
+    def _attention(self, h, step):
+        return family.grouped_query_attention(
+            self, h, step, self.cfg, self.cfg.attention_impl, output_gate)
 
-    def _experts(self, h, live, sow_kv):
+    def _experts(self, h, step):
         cfg = self.cfg
-        B, T, E = h.shape
-        cdt = cfg.compute_dtype()
-        G, F, held = (cfg.n_routed_experts, cfg.moe_intermediate_size,
-                      cfg.experts_held)
-        normal = nn.initializers.normal(0.02)
-        w_router = self.param("router", normal, (E, G), jnp.float32)
-        # a buffer in the lineage's releases: it moves the choice, never
-        # the weights
-        bias = self.param("e_score_correction_bias",
-                          nn.initializers.zeros_init(), (G,), jnp.float32)
-        w_gate_up = self.param("experts_gate_up", normal,
-                               (held[1], E, 2 * F), cfg.storage_dtype())
-        w_down = self.param("experts_down", normal, (held[1], F, E),
-                            cfg.storage_dtype())
-        flat = h.reshape(B * T, E)
-        choice, weights = moe.route(
-            flat, w_router, bias, cfg.num_experts_per_tok,
-            cfg.routed_scaling_factor, cfg.norm_topk_prob)
-        routed, stats = moe.routed_experts(
-            flat, choice, weights, w_gate_up.astype(cdt),
-            w_down.astype(cdt), held=held,
-            live=None if live is None else live.reshape(B * T))
-        if sow_kv:
-            self.sow("intermediates", "serve_stats", stats)
+        F = cfg.moe_intermediate_size
+        routed, _ = family.routed_ffn(
+            self, h, cfg, experts=cfg.n_routed_experts, width=2 * F,
+            live=step.live, sow=step.sow_kv)
         with jax.named_scope("moe.shared"):
-            shared = _swiglu(
-                h, cfg.n_shared_experts * F,
-                ("shared_gate_proj", "shared_up_proj", "shared_down_proj"),
-                cfg)
-        return routed.reshape(B, T, E) + shared
+            shared = family.swiglu(h, cfg.n_shared_experts * F,
+                                   family.SHARED_SWIGLU, cfg)
+        return routed.reshape(h.shape) + shared
 
 
-class SolarOpen2(nn.Module):
+class SolarOpen2(family.ServedDecoder):
+    """``position_ids`` is taken and not read: nothing here is
+    positional."""
     cfg: SolarOpen2Config
 
-    @nn.compact
-    def __call__(self, input_ids, *, attention_mask=None, segment_ids=None,
-                 position_ids=None, deterministic: bool = True,
-                 return_hidden: bool = False, kv_lens=None,
-                 sow_kv: bool = False, kv_pages=None, page_tables=None,
-                 ssm_pools=None, slots=None, ssm_init=None):
-        """The serving hooks are nemotron_h.NemotronH.__call__'s:
-        ``kv_pages`` one pair for each attention layer, in layer order,
-        ``ssm_pools`` one ``(states, tails)`` pair for each delta-rule
-        layer, ``slots`` [B] the pools' rows this step moves on by one
-        token; the moved pools are sown back under ``ssm_cache``. Without
-        pools a delta-rule layer runs the whole of ``input_ids`` and sows
-        the state after the last live position (``attention_mask`` says
-        which are live): from zero, or from ``ssm_init``, one ``(state
-        [B, ..], tail [B, ..])`` pair a delta-rule layer: what the
-        sequence's earlier part left. ``position_ids`` is taken and not
-        read: nothing here is positional."""
-        del position_ids, deterministic
-        cfg = self.cfg
-        B, T = input_ids.shape
-        wte = self.param(
-            "embed_tokens",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         ("vocab", "embed")),
-            (cfg.padded_vocab, cfg.hidden_size), cfg.storage_dtype())
-        # the rows a routed layer counts and a delta-rule layer feeds on:
-        # not a prefill bucket's padding, not a decode bucket's empty slots
-        if attention_mask is not None:
-            live = attention_mask.astype(bool)
-        elif kv_lens is not None:
-            live = jnp.broadcast_to(kv_lens[:, None] > 0, (B, T))
-        else:
-            live = None
-        live_len = (jnp.full((B,), T, jnp.int32) if attention_mask is None
-                    else jnp.sum(attention_mask.astype(jnp.int32), axis=1))
-        x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
-        n_kv = n_ssm = 0
-        for i in range(cfg.num_hidden_layers):
-            full = i in cfg.gqa_layers
-            pages = pools = init = None
-            if full and kv_pages is not None:
-                pages, n_kv = kv_pages[n_kv], n_kv + 1
-            if not full:
-                if ssm_pools is not None:
-                    pools = ssm_pools[n_ssm]
-                if ssm_init is not None:
-                    init = ssm_init[n_ssm]
-                n_ssm += 1
-            x = SolarOpen2Block(cfg, full, name=f"layer_{i}")(
-                x, attention_mask, segment_ids, live, live_len, kv_lens,
-                sow_kv, pages, page_tables, pools, slots, init)
-        x = _norm(cfg, "norm")(x)
-        if return_hidden:
-            return x
-        lm_head = self.param(
-            "lm_head",
-            nn.with_logical_partitioning(nn.initializers.normal(0.02),
-                                         ("vocab", "embed")),
-            (cfg.padded_vocab, cfg.hidden_size), cfg.storage_dtype())
-        logits = jnp.einsum("bte,ve->btv", x,
-                            lm_head.astype(cfg.compute_dtype()),
-                            preferred_element_type=jnp.float32)
-        return logits.astype(jnp.dtype(cfg.logits_dtype))
-
-    def init_params(self, rng, *, seq_len: int = 8):
-        dummy = jnp.zeros((1, seq_len), jnp.int32)
-        return nn.meta.unbox(self.init(rng, dummy)["params"])
+    def block(self, i: int) -> SolarOpen2Block:
+        return SolarOpen2Block(self.cfg, i in self.cfg.gqa_layers,
+                               name=f"layer_{i}")
 
 
-def make_model(preset_or_cfg) -> tuple[SolarOpen2, SolarOpen2Config]:
-    cfg = (PRESETS[preset_or_cfg] if isinstance(preset_or_cfg, str)
-           else preset_or_cfg)
-    return SolarOpen2(cfg), cfg
+make_model = family.make_model(SolarOpen2, PRESETS)

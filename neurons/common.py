@@ -299,8 +299,7 @@ def build(cfg: RunConfig) -> Components:
                                     grad_clip=cfg.grad_clip,
                                     weight_decay=cfg.weight_decay,
                                     mu_dtype=cfg.mu_dtype,
-                                    is_buffer=getattr(model_cfg, "is_buffer",
-                                                      None)),
+                                    is_buffer=model_cfg.is_buffer),
         mesh=mesh, seq_len=seq, fused_loss=cfg.fused_loss,
         accum_steps=cfg.accum_steps)
 

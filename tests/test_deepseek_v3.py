@@ -21,7 +21,8 @@ import numpy as np
 import pytest
 
 from distributedtraining_tpu.engine import kv_pool, serve, speculative
-from distributedtraining_tpu.models import deepseek_v3 as ds, family_of, gpt2
+from distributedtraining_tpu.models import (
+    deepseek_v3 as ds, family, family_of, gpt2)
 from distributedtraining_tpu.ops import mla_attention as mla, moe
 
 _BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -106,12 +107,12 @@ def test_absorbed_attention_is_the_expanded_attention(tiny):
     pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
     block = ds.DeepseekV3Block(pc, routed=True)
     p = {"params": params["layer_1"]}
-    expanded = block.apply(p, x, None, None, pos)
+    expanded = block.apply(p, x, family.Step(position_ids=pos))
     widths = kv_pool.row_widths(pc)
     pages = tuple(jnp.zeros((3, 8, w)) for w in widths)
-    absorbed = block.apply(
-        p, x, None, None, pos, jnp.zeros((B,), jnp.int32), False, pages,
-        jnp.zeros((B, 2), jnp.int32))
+    absorbed = block.apply(p, x, family.Step(
+        position_ids=pos, kv_lens=jnp.zeros((B,), jnp.int32),
+        kv_pages=pages, page_tables=jnp.zeros((B, 2), jnp.int32)))
     assert float(jnp.max(jnp.abs(expanded - absorbed))) <= 1e-5
 
 

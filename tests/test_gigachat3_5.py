@@ -26,7 +26,7 @@ import pytest
 
 from distributedtraining_tpu.engine import (kv_pool, serve, serve_weights,
                                             speculative)
-from distributedtraining_tpu.models import (deepseek_v3, family_of,
+from distributedtraining_tpu.models import (deepseek_v3, family, family_of,
                                             gigachat3_5 as gc, gpt2)
 from distributedtraining_tpu.ops import moe
 
@@ -219,7 +219,8 @@ def test_parameter_count_of_the_cut_is_the_files():
 
 def test_yarn_frequencies_and_scale_against_a_hand_count():
     pc = gc.PRESETS[CUT]
-    inv = np.asarray(gc.yarn_inv_freq(64, 100000.0, dict(pc.rope_scaling)))
+    inv = np.asarray(family.yarn_inv_freq(64, 100000.0,
+                                          dict(pc.rope_scaling)))
     plain = 1.0 / 100000.0 ** (np.arange(0, 64, 2) / 64)
     # the correction range over 32,768 positions: pairs under 14 turn more
     # than 32 times and keep their frequency, pairs from 24 turn less than
