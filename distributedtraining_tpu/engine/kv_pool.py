@@ -52,15 +52,38 @@ slot's row when a later prompt extends it (:func:`copy_state_row`); the
 suffix's prefill then continues from the row (:func:`slot_state_rows`).
 Nothing of it can be rolled back after a refused draft or shipped page by
 page: :data:`RECURRENT_STATE_REASON`.
+
+A third kind, ``"kv_window"`` (models/afmoe.py: sliding-window layers
+beside global ones), is the paged pair again, kept only while a token is
+one of the ``cfg.sliding_window`` newest: those layers' arrays are a pool of
+their OWN (:func:`make_pool` once more, ``window_pool_pages`` pages), with
+their own table a slot and their own host accounting
+(:class:`WindowPages`, a :class:`PagePool` that also gives pages back).
+The table is SHIFTED, not a ring: a request's :class:`Held` is the list of
+its pages from logical page ``first`` on; entry 0 of the table a program is
+handed is page ``first``, and the program gets ``first * P`` beside it
+(``window_starts``), so a window layer attends and writes at ``position -
+start``. A page wholly behind ``newest - window`` is popped off the front
+and ``first`` moves on (:meth:`WindowPages.release_behind`: at every decode
+step's growth, at every prefill chunk's end). Why shifted: the host builds
+every table anew each step anyway, the attention reads no absolute
+position, so the kernel needs the relative length and one lower bound, and
+the chunks it may skip are a prefix of the table; a ring would keep the
+table still and pay a modulus in every mask. What shares pages by position
+for all layers alike cannot hold for this group:
+:data:`WINDOW_CACHE_REASON`.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..ops.paged_attention import PAGES_PER_CHUNK
 
 Pool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
 LANES = 128
@@ -75,21 +98,277 @@ RECURRENT_STATE_REASON = (
     "cannot be rolled back (the state has moved past it) and a state is no "
     "page to ship, so the speculative lane and the KV transfer plane are "
     "not ported to it")
+WINDOW_CACHE_REASON = (
+    "this model keeps a sliding layer's K/V only while a token is inside "
+    "the window (its config states 'kv_window' in layer_caches), in a page "
+    "group that gives pages back behind the window: a shared prefix's "
+    "window pages are mostly released, a refused draft cannot be rolled "
+    "back over a page already given back, and the transfer wire ships one "
+    "table for all layers, so the prefix cache, the speculative lane and "
+    "the KV transfer plane are not ported to it")
 StatePool = tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]
+KINDS = ("kv", "kv_window", "ssm")
+
+
+class PagePool:
+    """Refcounted page accounting over pool indices ``1..pool_pages-1``
+    (page 0 is the trash page and is never allocated). Every owner of a
+    page — an active slot's page table, or a ``PrefixCache``
+    entry — holds exactly one reference; a page returns to the free
+    list only when its refcount reaches 0, so shared prompt pages
+    survive the slots that mapped them. ``check`` is the debug-flag
+    invariant the accounting contract rests on: free pages + referenced
+    pages == total, and the refcounts exactly match the owners the
+    engine can enumerate. This is the ``"kv"`` kind's accounting: a page
+    is a request's until the request ends."""
+
+    def __init__(self, pool_pages: int):
+        self.total = pool_pages - 1          # trash page excluded
+        self._free: list[int] = list(range(1, pool_pages))
+        self._refs: dict[int, int] = {}
+
+    @property
+    def free(self) -> int:
+        return len(self._free)
+
+    def alloc(self, n: int) -> list[int] | None:
+        if len(self._free) < n:
+            return None
+        out = self._free[:n]
+        del self._free[:n]
+        for p in out:
+            self._refs[p] = 1
+        return out
+
+    def incref(self, page: int) -> None:
+        self._refs[page] += 1
+
+    def decref(self, page: int) -> None:
+        left = self._refs[page] - 1
+        if left:
+            self._refs[page] = left
+        else:
+            del self._refs[page]
+            self._free.append(page)
+
+    def refs(self, page: int) -> int:
+        return self._refs.get(page, 0)
+
+    def check(self, expected: dict[int, int] | None = None) -> None:
+        """The conservation invariant (engine ``debug_invariants``
+        flag): every allocatable page is either free or referenced,
+        never both, never neither — and when the engine passes the
+        refcounts it can derive from its slots + cache, they must
+        match the pool's exactly."""
+        assert len(self._free) + len(self._refs) == self.total, (
+            f"page leak: {len(self._free)} free + {len(self._refs)} "
+            f"referenced != {self.total} total")
+        assert all(r >= 1 for r in self._refs.values()), \
+            f"non-positive refcount in {self._refs}"
+        assert not set(self._free) & set(self._refs), \
+            "page simultaneously free and referenced"
+        if expected is not None:
+            assert expected == self._refs, (
+                f"refcount drift: engine expects {expected}, "
+                f"pool holds {self._refs}")
+
+
+def window_table_pages(window: int, chunk: int, page_size: int) -> int:
+    """The most pages a request ever holds of the window group: the
+    window, one prefill chunk and two page edges."""
+    return -(-(window + chunk) // page_size) + 2
+
+
+@dataclasses.dataclass
+class Held:
+    """What one request holds of the window group: ``pages[i]`` is its
+    logical page ``first + i``; everything before ``first`` went back."""
+    pages: list = dataclasses.field(default_factory=list)
+    first: int = 0
+
+
+class WindowPages(PagePool):
+    """The ``"kv_window"`` kind: a :class:`PagePool` (nothing is shared, so
+    every refcount is 1) whose requests hold pages only for positions a
+    later query can still see, with the group's device arrays (``pools``)
+    and what each request holds of it (:class:`Held`, by request id). The
+    four verbs a kind has: :meth:`admit` (may the group take this prompt),
+    :meth:`extend` (pages up to a position about to be written),
+    :meth:`release_behind` (pages wholly behind ``newest - window`` go back)
+    and :meth:`release` (all of them). The engine drives a family's window
+    group through them and never asks whether it has one: a family without
+    the statement gets :class:`NoWindow`, which answers every verb as a
+    group that holds nothing and is never short. ``table_pages`` bounds
+    what a request ever holds: the window, one prefill chunk and two page
+    edges; ``decode_pages`` is the decode programs' table width (a decoding
+    request holds at most ``window / P + 1`` pages), in whole kernel
+    chunks. ``arity``: the arguments a serve program takes for the group
+    (the two halves of its pool, which it donates and returns, the tables
+    and the position of each table's first row)."""
+
+    arity, donated = 4, 2
+
+    def __init__(self, pool_pages: int, page_size: int, window: int,
+                 chunk: int):
+        super().__init__(pool_pages)
+        self.page_size, self.window = page_size, window
+        self.table_pages = window_table_pages(window, chunk, page_size)
+        # the decode kernel attends PAGES_PER_CHUNK pages a grid step
+        self.decode_pages = -(-(-(-window // page_size) + 1)
+                              // PAGES_PER_CHUNK) * PAGES_PER_CHUNK
+        self.held: dict[int, Held] = {}
+        self.pools: tuple = ()      # (k_pages, v_pages), made with the kv pool
+
+    def admit(self, rid: int, prompt_len: int) -> bool:
+        """An empty holding for request ``rid`` if the free pages cover the
+        most this prompt will hold at once (its chunks then never find the
+        group short)."""
+        peak = min(prompt_len // self.page_size + 1, self.table_pages)
+        if self.free < peak:
+            return False
+        self.held[rid] = Held()
+        return True
+
+    def short(self, rid: int, pos: int) -> int:
+        """Pages still to allocate before position ``pos`` is writable."""
+        held = self.held[rid]
+        return max(0, pos // self.page_size + 1 - held.first
+                   - len(held.pages))
+
+    def extend(self, rid: int, pos: int) -> bool:
+        got = self.alloc(self.short(rid, pos))
+        if got is None:
+            return False
+        self.held[rid].pages.extend(got)
+        return True
+
+    def release_behind(self, rid: int, newest: int) -> int:
+        """Give back the pages no query at position ``newest`` or later
+        sees: those whose last row is at or before ``newest - window``."""
+        held = self.held[rid]
+        keep_from = max(0, newest - self.window + 1) // self.page_size
+        n = min(max(0, keep_from - held.first), len(held.pages))
+        for p in held.pages[:n]:
+            self.decref(p)
+        del held.pages[:n]
+        held.first += n
+        return n
+
+    def release(self, rid: int) -> None:
+        """Everything request ``rid`` holds, and the holding (nothing for
+        a request that holds none: a slot released twice)."""
+        held = self.held.pop(rid, None)
+        for p in held.pages if held else ():
+            self.decref(p)
+
+    def tail(self, rids: Sequence[int], width: int = 0, rows: int = 0
+             ) -> tuple:
+        """A serve program's arguments for the group: its pools, one table
+        row a request (``width`` wide, 0: the widest a request ever holds;
+        ``rows`` rows, the rest the trash page) and the position of each
+        table's first row."""
+        width = width or self.table_pages
+        tables = np.zeros((max(rows, len(rids)), width), np.int32)
+        starts = np.zeros((tables.shape[0],), np.int32)
+        for i, rid in enumerate(rids):
+            held = self.held[rid]
+            row = held.pages[:width]
+            tables[i, :len(row)] = row
+            starts[i] = held.first * self.page_size
+        return (*self.pools, tables, starts)
+
+    def keep(self, moved: list) -> list:
+        """Bind the pools a program returned; what else it returned."""
+        self.pools, *rest = moved
+        return rest
+
+    def holdings(self, rids: Sequence[int], contexts: Sequence[int]
+                 ) -> tuple[int, int]:
+        """(pages these requests hold, tokens a window layer's decode step
+        reads for them: ``min(context, window)`` each)."""
+        return (sum(len(self.held[r].pages) for r in rids),
+                sum(min(c, self.window) for c in contexts))
+
+    def check_held(self, rids: Sequence[int]) -> None:
+        """:meth:`PagePool.check` for the group: the holdings are those of
+        ``rids`` and no other, none past the bound, and the refcounts are
+        theirs."""
+        assert set(self.held) == set(rids), \
+            "a window holding without its slot, or a slot without one"
+        expected: dict[int, int] = {}
+        for held in self.held.values():
+            assert len(held.pages) <= self.table_pages, (
+                f"a request holds {len(held.pages)} window pages, past the "
+                f"bound of {self.table_pages}")
+            for p in held.pages:
+                expected[p] = expected.get(p, 0) + 1
+        self.check(expected)
+
+
+class NoWindow:
+    """The window group of a family that states no ``"kv_window"`` layer:
+    :class:`WindowPages`'s verbs over nothing. Never short, holds nothing,
+    takes no argument of a serve program."""
+
+    arity = donated = free = total = 0
+    held: dict = {}
+    pools: tuple = ()
+
+    def admit(self, rid, prompt_len) -> bool:
+        return True
+
+    def short(self, rid, pos) -> int:
+        return 0
+
+    def extend(self, rid, pos) -> bool:
+        return True
+
+    def release_behind(self, rid, newest) -> int:
+        return 0
+
+    def release(self, rid) -> None:
+        pass
+
+    def tail(self, rids, width=0, rows=0) -> tuple:
+        return ()
+
+    def keep(self, moved: list) -> list:
+        return moved
+
+    def holdings(self, rids, contexts) -> tuple[int, int]:
+        return 0, 0
+
+    def check_held(self, rids) -> None:
+        pass
+
+
+def window_group(cfg, pool_pages: int, slots: int, page_size: int,
+                 chunk: int) -> WindowPages | NoWindow:
+    """The window group for a family: :class:`WindowPages` where it states
+    ``"kv_window"`` (``pool_pages`` 0: every slot may hold the group's
+    bound at once), else :class:`NoWindow`."""
+    if not has_window(cfg):
+        return NoWindow()
+    window = cfg.sliding_window
+    return WindowPages(
+        pool_pages or 1 + slots * window_table_pages(window, chunk,
+                                                     page_size),
+        page_size, window, chunk)
 
 
 def unheld_cache_reason(cfg) -> str | None:
     """Why the serve engine cannot hold what this model's layers keep for
     a sequence, in one sentence; None for a model it can serve."""
     unheld = sorted({c for c in (cfg.layer_caches or ())
-                     if c not in ("kv", "ssm", None)})
+                     if c not in (*KINDS, None)})
     if not unheld:
         return None
     return (f"this model's layers keep {', '.join(map(repr, unheld))} for a "
             "sequence (cfg.layer_caches), for which engine/kv_pool.py has no "
-            "pool: it holds a K/V pair of heads a token ('kv') and a "
-            "recurrent state with its convolution tail a slot ('ssm'), so "
-            "the model trains and is not served")
+            "pool: it holds a K/V pair of heads a token for ever ('kv'), "
+            "the same pair while the token is inside a sliding window "
+            "('kv_window') and a recurrent state with its convolution tail "
+            "a slot ('ssm'), so the model trains and is not served")
 
 
 def layer_caches(cfg, n_layers: int) -> tuple[str | None, ...]:
@@ -101,6 +380,10 @@ def layer_caches(cfg, n_layers: int) -> tuple[str | None, ...]:
 
 def has_recurrent_state(cfg) -> bool:
     return "ssm" in (cfg.layer_caches or ())
+
+
+def has_window(cfg) -> bool:
+    return "kv_window" in (cfg.layer_caches or ())
 
 
 def state_name(cfg) -> str:
@@ -134,6 +417,8 @@ def kv_head_geometry(cfg) -> tuple[int, int]:
         raise ValueError(LATENT_CACHE_REASON)
     if has_recurrent_state(cfg):
         raise ValueError(RECURRENT_STATE_REASON)
+    if has_window(cfg):
+        raise ValueError(WINDOW_CACHE_REASON)
     return cfg.n_kv_head or cfg.n_head, cfg.head_dim
 
 
@@ -244,6 +529,32 @@ def write_pages(k_pages, v_pages, inter, layers, page_row) -> Pool:
 
     k_new, v_new = _sown(inter, layers)
     return put(k_pages, k_new), put(v_pages, v_new)
+
+
+def window_kwargs(win: tuple) -> dict:
+    """A serve program's window group ``(k_pages, v_pages, tables,
+    starts)`` as the model's keywords (models/family.ServedDecoder);
+    nothing for a family without the group."""
+    if not win:
+        return {}
+    k_pages, v_pages, tables, starts = win
+    return dict(window_pages=tuple(zip(k_pages, v_pages)),
+                window_tables=tables, window_starts=starts)
+
+
+def write_window_rows(win: tuple, inter, layers, pos, valid) -> tuple:
+    """A decode step's or a continued prefill's write into the window
+    group: the fresh row at position ``pos[b, t]`` lands in the page its
+    table names ``pos - start`` rows on (``valid`` [B, T] false: the trash
+    page). -> ``((k_pages, v_pages),)`` moved, or ``()``."""
+    if not win:
+        return ()
+    k_pages, v_pages, tables, starts = win
+    P = k_pages[0].shape[1]
+    entry = jnp.clip((pos - starts[:, None]) // P, 0, tables.shape[1] - 1)
+    page_idx = jnp.where(valid, jnp.take_along_axis(tables, entry, axis=1),
+                         0)
+    return (write_rows(k_pages, v_pages, inter, layers, page_idx, pos % P),)
 
 
 def copy_page(k_pages, v_pages, src, dst) -> Pool:

@@ -113,6 +113,17 @@ it (:class:`PrefixCache`). The speculative lane and KV transfer cannot
 roll a state back or ship it and refuse such a family
 (``kv_pool.RECURRENT_STATE_REASON``; docs/serving.md).
 
+A family with sliding-window layers (models/afmoe.py) states
+``"kv_window"`` for them: their pages are a SECOND GROUP with its own pool,
+its own narrow table a slot and its own accounting
+(``kv_pool.WindowPages``), which gives a page back as soon as it lies
+wholly behind the window, at a decode step's growth or a prefill chunk's
+end, while the global layers' group keeps every token. Admission, growth
+and preemption account both groups; the programs take the window group's
+pools, table and the table's first position behind their other arguments,
+ahead of a per-slot state's. The prefix cache, the speculative lane and KV
+transfer refuse such a family (``kv_pool.WINDOW_CACHE_REASON``).
+
 Everything is exposed through the PR-3 obs registry as ``serve.*`` and
 scraped by the PR-5 exporter as ``dt_serve_*`` gauges.
 """
@@ -294,66 +305,9 @@ class BucketLadder:
 # Refcounted page pool + content-addressed prefix cache
 # ---------------------------------------------------------------------------
 
-class PagePool:
-    """Refcounted page accounting over pool indices ``1..pool_pages-1``
-    (page 0 is the trash page and is never allocated). Every owner of a
-    page — an active slot's page table, or a :class:`PrefixCache`
-    entry — holds exactly one reference; a page returns to the free
-    list only when its refcount reaches 0, so shared prompt pages
-    survive the slots that mapped them. ``check`` is the debug-flag
-    invariant the accounting contract rests on: free pages + referenced
-    pages == total, and the refcounts exactly match the owners the
-    engine can enumerate."""
-
-    def __init__(self, pool_pages: int):
-        self.total = pool_pages - 1          # trash page excluded
-        self._free: list[int] = list(range(1, pool_pages))
-        self._refs: dict[int, int] = {}
-
-    @property
-    def free(self) -> int:
-        return len(self._free)
-
-    def alloc(self, n: int) -> list[int] | None:
-        if len(self._free) < n:
-            return None
-        out = self._free[:n]
-        del self._free[:n]
-        for p in out:
-            self._refs[p] = 1
-        return out
-
-    def incref(self, page: int) -> None:
-        self._refs[page] += 1
-
-    def decref(self, page: int) -> None:
-        left = self._refs[page] - 1
-        if left:
-            self._refs[page] = left
-        else:
-            del self._refs[page]
-            self._free.append(page)
-
-    def refs(self, page: int) -> int:
-        return self._refs.get(page, 0)
-
-    def check(self, expected: dict[int, int] | None = None) -> None:
-        """The conservation invariant (engine ``debug_invariants``
-        flag): every allocatable page is either free or referenced,
-        never both, never neither — and when the engine passes the
-        refcounts it can derive from its slots + cache, they must
-        match the pool's exactly."""
-        assert len(self._free) + len(self._refs) == self.total, (
-            f"page leak: {len(self._free)} free + {len(self._refs)} "
-            f"referenced != {self.total} total")
-        assert all(r >= 1 for r in self._refs.values()), \
-            f"non-positive refcount in {self._refs}"
-        assert not set(self._free) & set(self._refs), \
-            "page simultaneously free and referenced"
-        if expected is not None:
-            assert expected == self._refs, (
-                f"refcount drift: engine expects {expected}, "
-                f"pool holds {self._refs}")
+# the "kv" kind's accounting lives beside the pool's layout; the name stays
+# importable from here
+PagePool = kv_pool.PagePool
 
 
 class PrefixCache:
@@ -902,6 +856,7 @@ class GenerationEngine:
                  max_slots: int = 8,
                  page_size: int = DEFAULT_PAGE_SIZE,
                  pool_pages: int = 0,
+                 window_pool_pages: int = 0,
                  max_seq_len: int = 0,
                  max_new_tokens: int = 64,
                  eos_id: int | None = None,
@@ -943,6 +898,12 @@ class GenerationEngine:
                 draft is not None or kv_exporter is not None
                 or kv_adopter is not None):
             raise ValueError(kv_pool.RECURRENT_STATE_REASON)
+        # nor can what shares or ships pages by position for all layers
+        # alike hold for a group that gives pages back behind a window
+        if kv_pool.has_window(cfg) and (
+                prefix_cache or draft is not None or kv_exporter is not None
+                or kv_adopter is not None):
+            raise ValueError(kv_pool.WINDOW_CACHE_REASON)
         self.model = type(model)(cfg)
         self.cfg = cfg
         self.page_size = page_size
@@ -1073,6 +1034,12 @@ class GenerationEngine:
                                if self._recurrent and prefix_cache else 0)
         self._snap: kv_pool.StatePool = ((), ())
         self._state_copy_progs: dict[str, Callable] = {}
+        # the window group (cfg.layer_caches states "kv_window"): its
+        # accounting, its arrays and what each request holds of it; for a
+        # family without the statement the same verbs over nothing
+        self._window = kv_pool.window_group(
+            cfg, window_pool_pages, max_slots, page_size,
+            self._chunk_pages * page_size)
         # the transfer plane's wire is a K/V pair of heads: a model that
         # caches anything else is refused here, with the reason
         self._kv_geom = (kv_pool.kv_head_geometry(cfg)
@@ -1166,6 +1133,10 @@ class GenerationEngine:
             self._state_free = list(range(self.max_slots))
 
     @property
+    def _window_layers(self) -> list[str]:
+        return self._layers_keeping("kv_window")
+
+    @property
     def _kv(self) -> kv_pool.Pool:
         """The pool's device arrays, made when a program first takes
         them: a caller that handed ``install_params`` a float32 base
@@ -1182,6 +1153,10 @@ class GenerationEngine:
                 self._ssm_bytes = float(sum(
                     x.nbytes for half in self._ssm for x in half))
                 obs.gauge(self._state_gauge, self._ssm_bytes)
+            self._window.pools = kv_pool.make_pool(
+                len(self._window_layers), self._window.total + 1,
+                self.page_size, kv_pool.row_widths(cfg),
+                cfg.compute_dtype())
             if self._snapshot_rows:
                 self._snap = kv_pool.make_snapshot_pool(
                     cfg, len(self._ssm_layers), self._snapshot_rows)
@@ -1377,11 +1352,56 @@ class GenerationEngine:
     def _donated(self, pages_at: int, state_at: int) -> tuple:
         """The argument numbers a serve program updates in place: the two
         halves of the page pool and, for a family that keeps them, the
-        two halves of the per-slot state behind the other arguments."""
+        two halves of the window group's pool and of the per-slot state
+        behind the other arguments (the window group's four first)."""
         if not self._donate:
             return ()
+        window = tuple(range(state_at, state_at + self._window.donated))
+        state_at += self._window.arity
         state = (state_at, state_at + 1) if self._recurrent else ()
-        return (pages_at, pages_at + 1, *state)
+        return (pages_at, pages_at + 1, *window, *state)
+
+    def _split_behind(self, behind: tuple) -> tuple[tuple, tuple]:
+        """A program's trailing arguments -> (the window group's four, the
+        per-slot state's three), each empty for a family without it."""
+        n = self._window.arity
+        return behind[:n], behind[n:]
+
+    def _keep(self, k_pages, v_pages, moved: list) -> None:
+        """Bind what a program returned behind its picks: the page pool,
+        then the window group's pool and the per-slot state where the
+        family has them."""
+        self._kv = (k_pages, v_pages)
+        moved = self._window.keep(moved)
+        if moved:
+            self._ssm = moved[0]
+
+    def _window_chunk(self, req: ServeRequest, upto: int, width: int = 0
+                      ) -> tuple:
+        """The window group's tail for a prefill program that writes this
+        request's rows up to position ``upto``: its pages grown that far
+        (admission saw to it that the group has them), its table ``width``
+        wide (0: the group's widest) and the position of the table's first
+        row."""
+        if not self._window.extend(req.rid, upto):
+            raise RuntimeError("the window group ran short inside an "
+                               "admission it had room for")
+        return self._window.tail([req.rid], width)
+
+    def _window_behind(self, rid: int, newest: int) -> None:
+        """Give back this request's window pages that no query at
+        ``newest`` or later sees (a chunk's end, a decode step's growth)."""
+        n = self._window.release_behind(rid, newest)
+        if n:
+            obs.count("serve.kv.window.pages_released", n)
+
+    def kv_holdings(self) -> tuple[int, int, int]:
+        """(pages the active slots hold of the ``"kv"`` group, pages they
+        hold of the window group, the tokens a window layer's decode step
+        reads: ``min(context, window)`` a slot), summed over the slots."""
+        return (sum(len(s.pages) for s in self._active),
+                *self._window.holdings([s.req.rid for s in self._active],
+                                       [s.seq_len for s in self._active]))
 
     def _slot_state(self, rows) -> tuple:
         """A serve program's per-slot state tail: the pools and the
@@ -1396,11 +1416,16 @@ class GenerationEngine:
         model, vocab = self.model, self.cfg.vocab_size
         layers, kv_layers, ssm_layers = (self._layers, self._kv_layers,
                                          self._ssm_layers)
+        window_layers, split = self._window_layers, self._split_behind
 
         def serve_prefill(params, tokens, prompt_len, k_pages, v_pages,
-                          page_row, *slot_state):
-            # slot_state: a family with per-slot state passes (states,
-            # tails, slot) behind and gets the written pools back behind
+                          page_row, *behind):
+            # behind: a family with a window group passes (k_pages,
+            # v_pages, page_row [1, pages], start) of it, one with per-slot
+            # state
+            # (states, tails, slot) after that; each gets its written
+            # pools back behind, in that order
+            win, slot_state = split(behind)
             amask = (jnp.arange(t_bucket)[None, :]
                      < prompt_len).astype(jnp.int32)
             logits, muts = model.apply(
@@ -1408,7 +1433,10 @@ class GenerationEngine:
                 sow_kv=True, mutable=["intermediates"])
             k_pages, v_pages = kv_pool.write_pages(
                 k_pages, v_pages, muts["intermediates"], kv_layers, page_row)
-            moved = (kv_pool.write_slot_state(
+            moved = (kv_pool.write_pages(
+                *win[:2], muts["intermediates"], window_layers, win[2][0]),
+                     ) if win else ()
+            moved += (kv_pool.write_slot_state(
                 *slot_state[:2], muts["intermediates"], ssm_layers,
                 slot_state[2]),) if slot_state else ()
             row = logits[0, prompt_len - 1, :vocab]
@@ -1434,9 +1462,11 @@ class GenerationEngine:
         model, vocab = self.model, self.cfg.vocab_size
         layers, kv_layers, ssm_layers = (self._layers, self._kv_layers,
                                          self._ssm_layers)
+        window_layers, split = self._window_layers, self._split_behind
 
         def serve_decode(params, k_pages, v_pages, page_tables, seq_lens,
-                         tokens, *slot_state):
+                         tokens, *behind):
+            win, slot_state = split(behind)
             # paged attention: each block reads its OWN page-pool slice
             # directly through the table (ops/paged_attention.py — the
             # fused gather+attend kernel on TPU, its XLA twin off-TPU).
@@ -1449,13 +1479,16 @@ class GenerationEngine:
                 kv_pages=kv_pages, page_tables=page_tables,
                 kv_lens=seq_lens,
                 sow_kv=True, mutable=["intermediates"],
-                **_state_kwargs(slot_state))
+                **kv_pool.window_kwargs(win), **_state_kwargs(slot_state))
             k_pages, v_pages = kv_pool.write_next_row(
                 k_pages, v_pages, muts["intermediates"], kv_layers,
                 page_tables, seq_lens)
+            moved = kv_pool.write_window_rows(
+                win, muts["intermediates"], window_layers,
+                seq_lens[:, None], True)
             # the state pools come back moved on by the layers themselves
-            moved = (kv_pool.sown_state(muts["intermediates"], ssm_layers),
-                     ) if slot_state else ()
+            moved += (kv_pool.sown_state(muts["intermediates"], ssm_layers),
+                      ) if slot_state else ()
             nxt = jnp.argmax(logits[:, -1, :vocab], axis=-1)
             return (_with_stats(nxt.astype(jnp.int32),
                                 muts["intermediates"], layers),
@@ -1480,10 +1513,12 @@ class GenerationEngine:
         model, vocab = self.model, self.cfg.vocab_size
         layers, kv_layers, ssm_layers = (self._layers, self._kv_layers,
                                          self._ssm_layers)
+        window_layers, split = self._window_layers, self._split_behind
 
         def serve_decode_sample(params, k_pages, v_pages, page_tables,
                                 seq_lens, tokens, temps, top_ps, seeds,
-                                tok_idx, *slot_state):
+                                tok_idx, *behind):
+            win, slot_state = split(behind)
             kv_pages = tuple(zip(k_pages, v_pages))
             logits, muts = model.apply(
                 {"params": params}, tokens[:, None],
@@ -1491,12 +1526,15 @@ class GenerationEngine:
                 kv_pages=kv_pages, page_tables=page_tables,
                 kv_lens=seq_lens,
                 sow_kv=True, mutable=["intermediates"],
-                **_state_kwargs(slot_state))
+                **kv_pool.window_kwargs(win), **_state_kwargs(slot_state))
             k_pages, v_pages = kv_pool.write_next_row(
                 k_pages, v_pages, muts["intermediates"], kv_layers,
                 page_tables, seq_lens)
-            moved = (kv_pool.sown_state(muts["intermediates"], ssm_layers),
-                     ) if slot_state else ()
+            moved = kv_pool.write_window_rows(
+                win, muts["intermediates"], window_layers,
+                seq_lens[:, None], True)
+            moved += (kv_pool.sown_state(muts["intermediates"], ssm_layers),
+                      ) if slot_state else ()
             nxt = _sample_from_logits(logits[:, -1, :vocab], temps,
                                       top_ps, seeds, tok_idx)
             return (_with_stats(nxt, muts["intermediates"], layers),
@@ -1528,10 +1566,12 @@ class GenerationEngine:
         model, P, vocab = self.model, self.page_size, self.cfg.vocab_size
         layers, kv_layers, ssm_layers = (self._layers, self._kv_layers,
                                          self._ssm_layers)
+        window_layers, split = self._window_layers, self._split_behind
         cap = self.max_seq_len
 
         def serve_prefill_ctx(params, tokens, ctx_len, suffix_len,
-                              k_pages, v_pages, page_table, *slot_state):
+                              k_pages, v_pages, page_table, *behind):
+            win, slot_state = split(behind)
             kv_pages = tuple(zip(k_pages, v_pages))
             pos = ctx_len + jnp.arange(t_bucket)
             valid = jnp.arange(t_bucket) < suffix_len
@@ -1547,13 +1587,17 @@ class GenerationEngine:
                 position_ids=jnp.minimum(pos, cap - 1)[None, :],
                 kv_pages=kv_pages, page_tables=page_table,
                 kv_lens=jnp.reshape(ctx_len, (1,)),
-                sow_kv=True, mutable=["intermediates"], **state)
+                sow_kv=True, mutable=["intermediates"],
+                **kv_pool.window_kwargs(win), **state)
             page_idx = jnp.where(
                 valid, page_table[0, jnp.minimum(pos // P, pb - 1)], 0)
             k_pages, v_pages = kv_pool.write_rows(
                 k_pages, v_pages, muts["intermediates"], kv_layers,
                 page_idx[None, :], (pos % P)[None, :])
-            moved = (kv_pool.write_slot_state(
+            moved = kv_pool.write_window_rows(
+                win, muts["intermediates"], window_layers, pos[None, :],
+                valid[None, :])
+            moved += (kv_pool.write_slot_state(
                 *slot_state[:2], muts["intermediates"], ssm_layers,
                 slot_state[2]),) if slot_state else ()
             row = logits[0, suffix_len - 1, :vocab]
@@ -1746,6 +1790,7 @@ class GenerationEngine:
             self.pool.decref(p)
         slot.pages = []
         slot.released = True
+        self._window.release(slot.req.rid)
         if slot.req.rid in self._state_of:
             # the row is free as it lies: the next prefill overwrites it
             self._state_free.append(self._state_of.pop(slot.req.rid))
@@ -1961,8 +2006,13 @@ class GenerationEngine:
                 self._cache.misses += 1
                 obs.count("serve.prefix_misses")
         need = plen // P + 1 - len(shared)
-        fresh = self._alloc_pages(need)
+        # both groups or neither: the window group says whether it has
+        # the most this prompt will hold of it at once, and hands out
+        # nothing yet
+        fresh = (self._alloc_pages(need)
+                 if self._window.admit(req.rid, plen) else None)
         if fresh is None:
+            self._window.release(req.rid)
             for p in shared:
                 self.pool.decref(p)
             self._requeue_front(req)
@@ -2187,7 +2237,7 @@ class GenerationEngine:
             prog = self._prefill_prog(t_bucket)
             k_pages, v_pages = self._kv
             args = (self._params, toks, np.int32(head), k_pages, v_pages,
-                    page_row)
+                    page_row) + self._window_chunk(req, head, mp)
             if self._recurrent:
                 args += self._slot_state(np.int32(self._state_of[req.rid]))
             self._prefill_ladder.mark(t_bucket // P)
@@ -2197,9 +2247,8 @@ class GenerationEngine:
                     prog, *args)
             else:
                 nxt, logit_row, k_pages, v_pages, *moved = prog(*args)
-            self._kv = (k_pages, v_pages)
-            if moved:
-                self._ssm = moved[0]
+            self._keep(k_pages, v_pages, moved)
+            self._window_behind(req.rid, head)
             if head < plen:
                 _count_chunk(nxt)
                 nxt, logit_row = self._prefill_rest(req, pages, head)
@@ -2228,7 +2277,8 @@ class GenerationEngine:
             prog = self._prefill_ctx_prog(t_bucket, pb)
             k_pages, v_pages = self._kv
             args = (self._params, toks, np.int32(ctx_len), np.int32(suffix),
-                    k_pages, v_pages, table)
+                    k_pages, v_pages, table) + self._window_chunk(
+                        req, ctx_len + suffix)
             if self._recurrent:
                 args += self._slot_state(np.int32(self._state_of[req.rid]))
             if (t_bucket, pb) not in self._pctx_seen:
@@ -2238,10 +2288,9 @@ class GenerationEngine:
                     prog, *args)
             else:
                 nxt, logit_row, k_pages, v_pages, *moved = prog(*args)
-            self._kv = (k_pages, v_pages)
-            if moved:
-                self._ssm = moved[0]
+            self._keep(k_pages, v_pages, moved)
             ctx_len += suffix
+            self._window_behind(req.rid, ctx_len)
             if ctx_len >= plen:
                 return nxt, logit_row
             _count_chunk(nxt)
@@ -2348,6 +2397,11 @@ class GenerationEngine:
         hard to push."""
         P = self.page_size
         first = slot.seq_len + ahead
+        # the window group first: pages behind the window back, then the
+        # page position ``first`` lands in
+        self._window_behind(slot.req.rid, first)
+        if not self._window.extend(slot.req.rid, first):
+            return False
         need = (first + window) // P + 1
         while len(slot.pages) < need:
             got = self._alloc_pages(1)
@@ -2539,6 +2593,10 @@ class GenerationEngine:
             return False
         P = self.page_size
         short = 0
+        if self._window.free < sum(
+                self._window.short(s.req.rid, s.seq_len + 1)
+                for s in active):
+            return False
         for slot, flown in zip(active, flight.slots):
             if slot is not flown or \
                     len(slot.req.tokens) + 1 >= slot.req.max_new_tokens:
@@ -2646,6 +2704,15 @@ class GenerationEngine:
                 seen = self._decode_seen
                 args = (self._params, k_pages, v_pages, tables, seq_lens,
                         tokens)
+            if self._window.arity:
+                args += self._window.tail([s.req.rid for s in active],
+                                          self._window.decode_pages, sb)
+                if obs.enabled():
+                    kv, held, live = self.kv_holdings()
+                    obs.observe("serve.kv.pages_held", kv / len(active))
+                    obs.observe("serve.kv.window.pages_held",
+                                held / len(active))
+                    obs.count("serve.kv.window.live_tokens", live)
             if self._recurrent:
                 # padding rows move the pools' spare row, as their page
                 # writes land on page 0
@@ -2663,9 +2730,7 @@ class GenerationEngine:
                 out, k_pages, v_pages, *moved = _timed_compile(prog, *args)
             else:
                 out, k_pages, v_pages, *moved = prog(*args)
-            self._kv = (k_pages, v_pages)
-            if moved:
-                self._ssm = moved[0]
+            self._keep(k_pages, v_pages, moved)
             # the picks start for the host now, not when it asks
             jax.tree_util.tree_map(lambda x: x.copy_to_host_async(), out)
             self._flight = _Flight(list(active), sb, out)
@@ -2731,6 +2796,7 @@ class GenerationEngine:
         self.pool.check(expected)
         if self._cache is not None:
             self._cache.check()
+        self._window.check_held([s.req.rid for s in self._active])
         assert (len(self._state_free) + len(self._state_of)
                 == (self.max_slots if self._recurrent else 0)), \
             "a row of the per-slot state pools is neither free nor owned"

@@ -24,11 +24,18 @@
   beside gated grouped-query attention with no position term, every FFN
   routed + shared SwiGLU experts of which a chip holds its share (the
   Solar-Open2-250B row), on the serving path.
+- afmoe: sliding-window rotary attention layers beside global ones with no
+  position term, every one gated and with a norm a head of q and k; a
+  leading dense FFN, then routed + shared SwiGLU experts, all held; four
+  norms a block and a muP multiplier on the lookup (the Trinity-Mini row),
+  on the serving path: the window layers' pages are a second group that
+  gives pages back behind the window.
 - lora: low-rank adapter trees whose *parameters are the delta*.
 
 Adding a family (docs/architecture.md, "Model families"): one file with the
 config (published key names on ``family.FamilyConfig``, plus
-``experts_held`` and the like), the per-layer statement (``layer_caches``),
+``experts_held`` and the like), the per-layer statement (``layer_caches``:
+``"kv"``, ``"kv_window"`` with ``sliding_window`` beside it, ``"ssm"``),
 the mixers (a block called as ``block(x, step)``), the model
 (``family.ServedDecoder`` with ``block(i)``), the presets and
 ``make_model = family.make_model(Model, PRESETS)``; then its module in
@@ -45,10 +52,10 @@ def family_of(preset: str):
     """The family (its module: ``PRESETS``, ``make_model``) that owns a
     preset's name; GPT-2's, whose lookup then names the unknown preset,
     where none does."""
-    from . import (deepseek_v3, gigachat3_5, gpt2, lfm2_moe, llama,
+    from . import (afmoe, deepseek_v3, gigachat3_5, gpt2, lfm2_moe, llama,
                    nemotron_h, solar_open2)
     for family in (llama, deepseek_v3, nemotron_h, lfm2_moe, gigachat3_5,
-                   solar_open2):
+                   solar_open2, afmoe):
         if preset in family.PRESETS:
             return family
     return gpt2

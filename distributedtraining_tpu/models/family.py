@@ -41,9 +41,12 @@ class FamilyConfig:
     attribute plainly: a misspelt statement fails, it does not fall back.
 
     - ``layer_caches``: per layer what it keeps for a served sequence,
-      ``"kv"`` two rows a TOKEN (pages), ``"ssm"`` a state with its
-      convolution tail a SLOT, None nothing; None: ``"kv"`` in every layer
-      (engine/kv_pool.py; the shell below deals the caches by it).
+      ``"kv"`` two rows a TOKEN (pages) for ever, ``"kv_window"`` the same
+      rows only while the token is one of the ``sliding_window`` newest
+      (pages of a second group, given back behind the window), ``"ssm"`` a
+      state with its convolution tail a SLOT, None nothing; None: ``"kv"``
+      in every layer (engine/kv_pool.py; the shell below deals the caches
+      by it).
     - ``cache_row_widths``: the widths of a ``"kv"`` layer's two rows where
       they are no K/V pair of heads; None: ``n_kv_head or n_head`` heads of
       ``head_dim``, twice (engine/kv_pool.py, engine/speculative.py).
@@ -251,12 +254,21 @@ def relu2(h, width: int, names: tuple[str, str], cfg):
 
 
 def grouped_query_attention(module: nn.Module, h, step: Step, cfg,
-                            impl: str, gate: Callable | None = None):
+                            impl: str, gate: Callable | None = None, *,
+                            qk_norm: bool = False,
+                            rope_theta: float | None = None,
+                            window: int | None = None):
     """``cfg.n_head`` query heads over ``cfg.n_kv_head`` K/V heads of
-    ``cfg.head_dim``, scale ``head_dim^-0.5``, causal, NO position term;
-    caches one K/V pair of heads a token, in pages. With ``gate``:
-    ``W_o [softmax(q k^T) v * gate(W_g h)]``, elementwise over the heads'
-    concatenated values, float32."""
+    ``cfg.head_dim``, scale ``head_dim^-0.5``, causal; caches one K/V pair
+    of heads a token, in pages. With ``gate``: ``W_o [softmax(q k^T) v *
+    gate(W_g h)]``, elementwise over the heads' concatenated values,
+    float32. What a layer has beside that is an argument's value:
+    ``qk_norm`` the family's norm over each head of q and of k (``q_norm``,
+    ``k_norm``: one gain of ``head_dim`` for all heads); ``rope_theta`` a
+    rotation of the whole head in Llama's halves, after the norm (None: NO
+    position term); ``window`` position ``i`` sees ``j`` with ``i - window
+    < j <= i`` (None: every ``j <= i``). The rows are cached as attended:
+    normed and rotated."""
     B, T, E = h.shape
     Hq, Hkv, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     q = dense(Hq * Dh, "q_proj", ("embed", "qkv"), cfg)(h)
@@ -266,17 +278,23 @@ def grouped_query_attention(module: nn.Module, h, step: Step, cfg,
         z = dense(Hq * Dh, "g_proj", ("embed", "qkv"), cfg)(h)
     q = q.reshape(B, T, Hq, Dh)
     k, v = k.reshape(B, T, Hkv, Dh), v.reshape(B, T, Hkv, Dh)
+    if qk_norm:
+        q, k = cfg.norm("q_norm")(q), cfg.norm("k_norm")(k)
+    if rope_theta is not None:
+        q = rotary_embedding(q, step.position_ids, rope_theta)
+        k = rotary_embedding(k, step.position_ids, rope_theta)
+    windowed = {} if window is None else {"window": window}
     if step.sow_kv:
         module.sow("intermediates", "kv_cache", (k, v))
     if step.kv_pages is not None:
         attn = paged_attention(q, *step.kv_pages, step.page_tables,
-                               step.kv_lens, k, v)
+                               step.kv_lens, k, v, **windowed)
     else:
         rep = Hq // Hkv
         attn = causal_attention(
             q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
             attention_mask=step.attention_mask,
-            segment_ids=step.segment_ids, impl=impl)
+            segment_ids=step.segment_ids, impl=impl, **windowed)
     attn = attn.reshape(B, T, Hq * Dh)
     if gate is not None:
         attn = (attn.astype(jnp.float32) * gate(z)).astype(
@@ -456,7 +474,9 @@ class Step(NamedTuple):
     hooks are gpt2.GPT2.__call__'s: ``sow_kv`` sows the layer's fresh cache
     (``kv_cache`` rows a token, ``ssm_cache`` a state and tail),
     ``kv_pages`` / ``page_tables`` / ``kv_lens`` attend over the paged
-    cache; and a per-slot layer's: ``ssm_pools`` its ``(states, tails)``,
+    cache (a ``"kv_window"`` layer is handed its own group's: the narrow
+    table, and the lengths counted from that table's first row); and a
+    per-slot layer's: ``ssm_pools`` its ``(states, tails)``,
     of which ``slots`` [B] are the rows this step moves on by one token
     (sown back under ``ssm_cache``). Without pools such a layer runs the
     whole of the input, from zero or from ``ssm_init`` (the ``(state,
@@ -491,21 +511,34 @@ class ServedDecoder(Decoder):
     """The shell of a served family: the lookup, the live rows, each
     layer's caches dealt in the order ``cfg.layer_caches`` gives, the final
     norm (``final_norm`` its name), the untied head. A family supplies
-    ``block(i)``, layer ``i``'s module, called as ``block(x, step)``."""
+    ``block(i)``, layer ``i``'s module, called as ``block(x, step)``, and
+    may wrap ``embed`` and ``head`` (a multiplier on the lookup, a scope's
+    name)."""
     final_norm = "norm"
 
     def block(self, i: int) -> nn.Module:
         raise NotImplementedError
+
+    def embed(self, table, input_ids):
+        return embed_lookup(table, input_ids).astype(
+            self.cfg.compute_dtype())
+
+    def head(self, x, table):
+        return logits(x, table, self.cfg)
 
     @nn.compact
     def __call__(self, input_ids, *, attention_mask=None, segment_ids=None,
                  position_ids=None, deterministic: bool = True,
                  return_hidden: bool = False, kv_lens=None,
                  sow_kv: bool = False, kv_pages=None, page_tables=None,
-                 ssm_pools=None, slots=None, ssm_init=None):
+                 ssm_pools=None, slots=None, ssm_init=None,
+                 window_pages=None, window_tables=None, window_starts=None):
         """``kv_pages`` one pair for each ``"kv"`` layer, ``ssm_pools`` /
-        ``ssm_init`` one for each ``"ssm"`` layer, in layer order
-        (:class:`Step` says what each is)."""
+        ``ssm_init`` one for each ``"ssm"`` layer, ``window_pages`` one
+        pair for each ``"kv_window"`` layer, in layer order (:class:`Step`
+        says what each is). ``window_tables`` [B, pages] is the window
+        group's own table and ``window_starts`` [B] the position of its
+        first row (engine/kv_pool.py: the shifted table)."""
         del deterministic
         cfg = self.cfg
         B, T = input_ids.shape
@@ -521,12 +554,20 @@ class ServedDecoder(Decoder):
                     else jnp.sum(attention_mask.astype(jnp.int32), axis=1))
         step = Step(attention_mask, segment_ids, position_ids, live,
                     live_len, kv_lens, sow_kv, page_tables, slots)
-        x = embed_lookup(wte, input_ids).astype(cfg.compute_dtype())
-        pages, pools, inits = (
+        x = self.embed(wte, input_ids)
+        pages, pools, inits, window = (
             itertools.repeat(None) if dealt is None else iter(dealt)
-            for dealt in (kv_pages, ssm_pools, ssm_init))
+            for dealt in (kv_pages, ssm_pools, ssm_init, window_pages))
         caches = cfg.layer_caches or ("kv",) * cfg.num_hidden_layers
+        # a window layer attends its own group: the narrow table, the
+        # lengths counted from its first row
+        in_window = {} if window_pages is None else dict(
+            page_tables=window_tables, kv_lens=kv_lens - window_starts)
         for i, kind in enumerate(caches):
+            if kind == "kv_window":
+                x = self.block(i)(x, step._replace(kv_pages=next(window),
+                                                   **in_window))
+                continue
             x = self.block(i)(x, step._replace(
                 kv_pages=next(pages) if kind == "kv" else None,
                 ssm_pools=next(pools) if kind == "ssm" else None,
@@ -534,7 +575,7 @@ class ServedDecoder(Decoder):
         x = cfg.norm(self.final_norm)(x)
         if return_hidden:
             return x
-        return logits(x, embed_table(self, cfg, "lm_head"), cfg)
+        return self.head(x, embed_table(self, cfg, "lm_head"))
 
 
 def make_model(model: type[Decoder], presets: dict) -> Callable:

@@ -27,16 +27,21 @@ NEG_INF = -1e9  # large-negative in bf16-safe range (bf16 max ~3.4e38, fine)
 
 
 def make_causal_mask(q_len: int, kv_len: int | None = None,
-                     *, q_offset: int = 0) -> jax.Array:
+                     *, q_offset: int = 0,
+                     window: int | None = None) -> jax.Array:
     """Boolean [q_len, kv_len] mask, True = may attend.
 
     ``q_offset`` shifts query positions — used by ring attention where the
     local query block sits at a global offset relative to the key block.
+    ``window``: position ``i`` sees ``j`` with ``i - window < j <= i``
+    (``window`` keys, the token itself among them); None: every ``j <= i``.
     """
     kv_len = q_len if kv_len is None else kv_len
     q_pos = jnp.arange(q_len)[:, None] + q_offset
     kv_pos = jnp.arange(kv_len)[None, :]
-    return q_pos >= kv_pos
+    if window is None:
+        return q_pos >= kv_pos
+    return (q_pos >= kv_pos) & (kv_pos > q_pos - window)
 
 
 def combine_masks(causal: jax.Array,
@@ -89,7 +94,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         attention_mask: Optional[jax.Array] = None,
                         segment_ids: Optional[jax.Array] = None,
                         block_q: int = 512,
-                        block_kv: int = 512) -> jax.Array:
+                        block_kv: int = 512,
+                        window: Optional[int] = None) -> jax.Array:
     """Causal attention as a double lax.scan over query/key blocks with an
     online softmax — the FlashAttention algorithm in portable lax (same
     streaming math as ring_attention._ring_body, but blocks come from a
@@ -103,7 +109,10 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     scale artifacts compile so their XLA memory analysis reflects the
     flash-kernel profile rather than a dense [T, T] blowup the TPU never
     pays. Masking matches combine_masks: causal + optional key padding
-    mask + optional segment equality (packed sequences).
+    mask + optional segment equality (packed sequences). ``window``: a
+    query sees the ``window`` newest keys up to itself
+    (:func:`make_causal_mask`); key blocks wholly behind a query block's
+    window are skipped like the causally dead ones.
     """
     B, T, H, D = q.shape
     bq, bkv = min(block_q, T), min(block_kv, T)
@@ -142,6 +151,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         q_pos = qi * bq + jnp.arange(bq)
         k_pos = ki * bkv + jnp.arange(bkv)
         mask = q_pos[:, None] >= k_pos[None, :]          # causal
+        if window is not None:
+            mask = mask & (k_pos[None, :] > q_pos[:, None] - window)
         mask = mask[None, :, :] & kv_ok[:, None, :]      # key padding
         if q_seg_tile is not None:
             mask = mask & (q_seg_tile[:, :, None] == k_seg_tile[:, None, :])
@@ -168,6 +179,9 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # one branch instead of lowering to a select
         ki = kv[0]
         dead = ki * bkv > qi * bq + (bq - 1)
+        if window is not None:
+            # the block's last key is behind the first query's window
+            dead = dead | (ki * bkv + (bkv - 1) <= qi * bq - window)
         new_carry = jax.lax.cond(
             dead, lambda c, _kv: c,
             lambda c, kv_: kv_tile_update(qi, q_tile, q_seg_tile, c, kv_),
@@ -205,7 +219,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     ctx_lens: jax.Array) -> jax.Array:
+                     ctx_lens: jax.Array,
+                     window: Optional[int] = None) -> jax.Array:
     """Decode-step attention for KV-cache generation (engine/serve.py).
 
     ``q`` is the current step's queries [B, Tq, H, D]; ``k``/``v`` are the
@@ -228,6 +243,9 @@ def cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Identical mask semantics (pinned in tests/test_paged_attention.py):
     context positions valid below ``ctx_lens``, the trailing Tq fresh
     positions causal among themselves and always visible to themselves.
+    ``window``: fresh token ``t`` (position ``ctx_lens[b] + t``) sees only
+    the ``window`` newest positions up to itself, of the context and of
+    the fresh rows alike.
     """
     B, Tq, _, depth = q.shape
     S = k.shape[1] - Tq
@@ -238,6 +256,11 @@ def cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     q_pos = jnp.arange(Tq)[None, :, None]                     # [1, Tq, 1]
     valid = (kv_pos < ctx_lens[:, None, None]) | (
         (kv_pos >= S) & (kv_pos - S <= q_pos))                # [B, Tq, S+Tq]
+    if window is not None:
+        # a key's distance behind the query, over the context's dead tail
+        key_pos = jnp.where(kv_pos >= S,
+                            kv_pos - S + ctx_lens[:, None, None], kv_pos)
+        valid = valid & (key_pos > ctx_lens[:, None, None] + q_pos - window)
     scores = jnp.where(valid[:, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
@@ -247,7 +270,8 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      *,
                      attention_mask: Optional[jax.Array] = None,
                      segment_ids: Optional[jax.Array] = None,
-                     impl: str = "dense") -> jax.Array:
+                     impl: str = "dense",
+                     window: Optional[int] = None) -> jax.Array:
     """Causal self-attention entry point used by the models.
 
     impl: "dense" (XLA), "flash" (the Pallas kernel where
@@ -255,9 +279,15 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     else blockwise at long T / dense at short T),
     "blockwise" (portable lax flash — O(block^2) temps everywhere), "ring"
     (sequence-parallel over the sp mesh axis; needs set_ring_mesh and
-    unmasked/unpacked inputs).
+    unmasked/unpacked inputs). ``window`` (``"dense"`` and ``"blockwise"``
+    only): a query sees the ``window`` newest keys up to itself.
     """
     B, T, H, D = q.shape
+    if window is not None and impl in ("flash", "ring"):
+        raise ValueError(
+            f"causal_attention(impl={impl!r}) has no window: the flash "
+            "kernels' pair list and the ring's block schedule are causal "
+            "only; a window layer runs impl='dense' or 'blockwise'")
     if impl == "ring" and attention_mask is None and segment_ids is None:
         from . import ring_attention as ring
         mesh, _ = ring.get_ring_mesh()
@@ -265,8 +295,9 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             return ring.ring_attention(q, k, v)
         # no mesh installed -> dense fallback below
     if impl == "blockwise":
-        return blockwise_attention(q, k, v, attention_mask=attention_mask,
-                                   segment_ids=segment_ids)
+        return blockwise_attention(
+            q, k, v, attention_mask=attention_mask, segment_ids=segment_ids,
+            **({} if window is None else {"window": window}))
     if impl == "flash":
         from . import flash_attention
         # None = the selection rule said no (off-TPU, padding mask,
@@ -282,7 +313,8 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 q, k, v, attention_mask=attention_mask,
                 segment_ids=segment_ids)
         # short T: dense is faster and the temps are tiny
-    mask = combine_masks(make_causal_mask(T), attention_mask, segment_ids)
+    mask = combine_masks(make_causal_mask(T, window=window), attention_mask,
+                         segment_ids)
     return dot_product_attention(q, k, v, mask)
 
 

@@ -53,6 +53,19 @@ token per slot per step); the page pool is one layer's stored
 the pool (padded rows point at trash page 0); ``seq_lens`` ``[B]`` is
 each slot's REAL context length (the fresh token sits at position
 ``seq_lens[b]``, always visible to itself).
+
+A WINDOW layer (``window=`` on every entry here) sees only the ``window``
+newest positions up to the query itself: keys at positions ``<= newest -
+window`` are masked, and context chunks (kernel) or blocks (XLA) wholly
+behind that bound are never fetched. Nothing in this module reads an
+absolute position (a rotation is applied before a row is cached), so the
+mask is translation invariant: the engine hands a window layer a NARROW
+table whose entry 0 is the first page the window still reaches and
+``seq_lens`` counted from that page's first row (engine/kv_pool.py, the
+shifted table). The windowed kernel is the same body under a Mosaic name
+of its own, ``paged_window_decode_attention``; the float32 parity contract
+above holds for it. ``window=None`` lowers every function to the text it
+lowered to before the argument existed.
 """
 
 from __future__ import annotations
@@ -153,7 +166,7 @@ def _decode_kernel(page_tables_ref, seq_lens_ref,   # scalar prefetch
                    k_buf, v_buf, acc_ref, m_ref, l_ref, sem,
                    *, pages_per_chunk: int, page_size: int,
                    n_chunks: int, group: int, head_dim: int,
-                   scale: float):
+                   scale: float, window: int | None = None):
     """One (batch row, context chunk) grid step of the fused decode.
 
     Grid is ``(B, n_chunks + 1)``: the first ``n_chunks`` steps DMA
@@ -163,7 +176,9 @@ def _decode_kernel(page_tables_ref, seq_lens_ref,   # scalar prefetch
     appends the fresh (k_new, v_new) column — the token being decoded,
     not yet in the pool — and writes ``acc / l``. Chunks wholly past
     ``seq_lens[b]`` skip both the DMA and the math (the bucket-padded
-    tail of a short sequence costs nothing but the grid iteration).
+    tail of a short sequence costs nothing but the grid iteration). With
+    ``window`` the fresh token sees positions ``> seq_len - window`` only:
+    chunks wholly at or behind that bound skip likewise.
     """
     b = pl.program_id(0)
     i = pl.program_id(1)
@@ -179,7 +194,12 @@ def _decode_kernel(page_tables_ref, seq_lens_ref,   # scalar prefetch
 
     q = q_ref[0].astype(jnp.float32) * scale             # [G, Hkv*D]
 
-    @pl.when(jnp.logical_and(i < n_chunks, i * chunk < seq_len))
+    runs = jnp.logical_and(i < n_chunks, i * chunk < seq_len)
+    if window is not None:
+        # the chunk's last position is still inside the window
+        runs = jnp.logical_and(runs, (i + 1) * chunk - 1 > seq_len - window)
+
+    @pl.when(runs)
     def _context_chunk():
         # gather exactly the pages the table names for this chunk
         for j in range(pages_per_chunk):
@@ -201,6 +221,8 @@ def _decode_kernel(page_tables_ref, seq_lens_ref,   # scalar prefetch
         pos = i * chunk + jax.lax.broadcasted_iota(
             jnp.int32, (chunk, _LANES), 0)
         valid = pos < seq_len                            # dead pages masked
+        if window is not None:
+            valid = jnp.logical_and(valid, pos > seq_len - window)
         for g in range(group):
             # s[t, h] = q[g, head h lanes] . k[t, head h lanes]
             s = _dot(k * q[g:g + 1, :], seg)             # [T, 128]
@@ -222,7 +244,8 @@ def _decode_kernel(page_tables_ref, seq_lens_ref,   # scalar prefetch
         o_ref[0] = (acc_ref[...] / l_x)[:group].astype(o_ref.dtype)
 
 
-def _build_call(B, G, HD, D, P, MP, q_dtype, page_dtype, interpret: bool):
+def _build_call(B, G, HD, D, P, MP, q_dtype, page_dtype, interpret: bool,
+                window: int | None = None):
     """Construct the pallas_call for one shape signature (lane-dense
     operands: q/out ``[B, G, HD]``, pages ``[pool, P, HD]``, fresh
     k/v ``[B, 1, HD]``)."""
@@ -251,7 +274,8 @@ def _build_call(B, G, HD, D, P, MP, q_dtype, page_dtype, interpret: bool):
     )
     kernel = functools.partial(
         _decode_kernel, pages_per_chunk=ppc, page_size=P,
-        n_chunks=n_chunks, group=G, head_dim=D, scale=D ** -0.5)
+        n_chunks=n_chunks, group=G, head_dim=D, scale=D ** -0.5,
+        **({} if window is None else {"window": window}))
     return pl.pallas_call(  # devprof: exempt (attributed under serve.decode in-step)
         kernel,
         grid_spec=grid_spec,
@@ -259,7 +283,8 @@ def _build_call(B, G, HD, D, P, MP, q_dtype, page_dtype, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="paged_decode_attention",
+        name=("paged_decode_attention" if window is None
+              else "paged_window_decode_attention"),
     )
 
 
@@ -267,7 +292,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_tables: jax.Array,
                            seq_lens: jax.Array, k_new: jax.Array,
                            v_new: jax.Array, *,
-                           interpret: bool = False) -> jax.Array:
+                           interpret: bool = False,
+                           window: int | None = None) -> jax.Array:
     """The fused kernel. Raises ``ValueError`` on a shape
     :func:`kernel_supports` rejects; build and compile errors propagate.
 
@@ -291,7 +317,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     # query head hq = h * G + g  ->  row g, head-h lanes
     qg = q.reshape(B, Hkv, G, D).transpose(0, 2, 1, 3).reshape(B, G, HD)
     call = _build_call(B, G, HD, D, P, MP, q.dtype, k_pages.dtype,
-                       interpret)
+                       interpret, window)
     out = call(page_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
                qg, k_pages, v_pages,
                k_new.reshape(B, 1, HD), v_new.reshape(B, 1, HD))
@@ -302,7 +328,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
 def paged_decode_reference(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_tables: jax.Array,
                            seq_lens: jax.Array, k_new: jax.Array,
-                           v_new: jax.Array) -> jax.Array:
+                           v_new: jax.Array,
+                           window: int | None = None) -> jax.Array:
     """The XLA spelling the kernel replaces — gather the table's pages
     from the stored ``[pages, P, Hkv*D]`` pool into a padded context
     (only the GATHERED rows are split into heads; the pool never is),
@@ -323,6 +350,8 @@ def paged_decode_reference(q: jax.Array, k_pages: jax.Array,
         rep = Hq // Hkv
         k_full = jnp.repeat(k_full, rep, axis=2)
         v_full = jnp.repeat(v_full, rep, axis=2)
+    if window is not None:
+        return cached_attention(q, k_full, v_full, seq_lens, window)
     return cached_attention(q, k_full, v_full, seq_lens)
 
 
@@ -339,7 +368,8 @@ def paged_suffix_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_tables: jax.Array,
                            seq_lens: jax.Array, k_new: jax.Array,
                            v_new: jax.Array, *,
-                           block: int = CONTEXT_BLOCK) -> jax.Array:
+                           block: int = CONTEXT_BLOCK,
+                           window: int | None = None) -> jax.Array:
     """``Tq`` fresh tokens a row over a LONG paged context, in XLA: the
     mask semantics of :func:`paged_decode_reference` (context positions
     below ``seq_lens[b]``, the fresh rows causal among themselves), with
@@ -348,7 +378,10 @@ def paged_suffix_attention(q: jax.Array, k_pages: jax.Array,
     G, Tq, block]`` float32, GQA never broadcast) and folds them into a
     running float32 online softmax; the fresh rows are the last block.
     The loop runs only as far as the longest row's context, so a page
-    table padded to the top of the ladder costs nothing for a short one."""
+    table padded to the top of the ladder costs nothing for a short one.
+    With ``window`` fresh row ``t`` sees positions ``> seq_lens[b] + t -
+    window`` only, and the loop STARTS at the first block the earliest
+    row's window still reaches: a window layer's blocks alone."""
     B, Tq, Hq, D = q.shape
     _, P, HD = k_pages.shape
     Hkv = HD // D
@@ -374,9 +407,13 @@ def paged_suffix_attention(q: jax.Array, k_pages: jax.Array,
 
     def in_context(pos):
         """pos [S] the positions of a context block -> valid [B, Tq, S]."""
-        return jnp.broadcast_to(
+        ok = jnp.broadcast_to(
             (pos[None, :] < seq_lens[:, None])[:, None, :],
             (B, Tq, pos.shape[0]))
+        if window is None:
+            return ok
+        return ok & (pos[None, None, :] > seq_lens[:, None, None]
+                     + jnp.arange(Tq)[None, :, None] - window)
 
     def context_block(i, carry):
         pages = jax.lax.dynamic_slice_in_dim(page_tables, i * bp, bp, axis=1)
@@ -389,7 +426,9 @@ def paged_suffix_attention(q: jax.Array, k_pages: jax.Array,
              jnp.zeros((B, Hkv, G, Tq), f32),
              jnp.zeros((B, Hkv, G, Tq, D), f32))
     n_blocks = jnp.minimum(-(-jnp.max(seq_lens) // (bp * P)), MP // bp)
-    carry = jax.lax.fori_loop(0, n_blocks, context_block, carry)
+    first = 0 if window is None else jnp.minimum(
+        jnp.maximum(jnp.min(seq_lens) - window + 1, 0) // (bp * P), n_blocks)
+    carry = jax.lax.fori_loop(first, n_blocks, context_block, carry)
     if MP % bp:
         # the table's last pages, where it is no whole number of blocks
         tail = page_tables[:, MP - MP % bp:]
@@ -397,8 +436,10 @@ def paged_suffix_attention(q: jax.Array, k_pages: jax.Array,
             carry, k_pages[tail].reshape(B, -1, Hkv, D),
             v_pages[tail].reshape(B, -1, Hkv, D),
             in_context((MP - MP % bp) * P + jnp.arange((MP % bp) * P)))
-    causal = jnp.broadcast_to(jnp.tril(jnp.ones((Tq, Tq), bool))[None],
-                              (B, Tq, Tq))
+    causal = jnp.tril(jnp.ones((Tq, Tq), bool))
+    if window is not None:
+        causal = causal & ~jnp.tril(jnp.ones((Tq, Tq), bool), -window)
+    causal = jnp.broadcast_to(causal[None], (B, Tq, Tq))
     m, l, acc = fold(carry, k_new, v_new, causal)
     out = (acc / l[..., None]).astype(v_new.dtype)    # [B,Hkv,G,Tq,D]
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Tq, Hq, D)
@@ -406,7 +447,8 @@ def paged_suffix_attention(q: jax.Array, k_pages: jax.Array,
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     page_tables: jax.Array, seq_lens: jax.Array,
-                    k_new: jax.Array, v_new: jax.Array) -> jax.Array:
+                    k_new: jax.Array, v_new: jax.Array,
+                    window: int | None = None) -> jax.Array:
     """Model-facing entry (gpt2/llama decode blocks): the kernel on TPU
     at a shape its layout supports, the XLA reference otherwise —
     identical numerics either way (parity pinned in
@@ -414,7 +456,21 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     tests_tpu/test_paged_attention_tpu.py). A suffix (``Tq > 1``) over a
     table of :data:`BLOCKED_MIN_CONTEXT` positions or more attends it in
     blocks (:func:`paged_suffix_attention`): a rule over the shape, so a
-    short-context engine runs what it ran."""
+    short-context engine runs what it ran. A ``window`` layer (the table
+    and lengths its group's: the module's docstring) runs the windowed
+    kernel, and ANY suffix of it in blocks: its table is narrow, and the
+    gathered spelling would score every row against all of it."""
+    if window is not None:
+        if _on_tpu() and kernel_supports(q, k_pages):
+            return paged_decode_attention(q, k_pages, v_pages, page_tables,
+                                          seq_lens, k_new, v_new,
+                                          window=window)
+        if q.shape[1] > 1:
+            return paged_suffix_attention(q, k_pages, v_pages, page_tables,
+                                          seq_lens, k_new, v_new,
+                                          window=window)
+        return paged_decode_reference(q, k_pages, v_pages, page_tables,
+                                      seq_lens, k_new, v_new, window)
     if _on_tpu() and kernel_supports(q, k_pages):
         return paged_decode_attention(q, k_pages, v_pages, page_tables,
                                       seq_lens, k_new, v_new)

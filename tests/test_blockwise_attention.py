@@ -86,3 +86,60 @@ def test_flash_fallback_routes_by_length(qkv, monkeypatch):
     causal_attention(q, k, v, impl="blockwise")
     assert calls == ["block", "block"]
     assert short.shape == (B, T, H, D)
+
+
+# -- a window -----------------------------------------------------------------
+
+def _windowed_by_definition(q, k, v, window, attention_mask=None):
+    T, D = q.shape[1], q.shape[-1]
+    i, j = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    mask = ((j <= i) & (j > i - window))[None, None]
+    if attention_mask is not None:
+        mask = mask & attention_mask[:, None, None, :].astype(bool)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * D ** -0.5
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("impl", ["dense", "blockwise"])
+@pytest.mark.parametrize("window", [1, 7, 64, 65, 500])
+def test_window_is_the_newest_keys_up_to_the_query(impl, window):
+    """T = 200 in blocks of 512 -> one block; `blockwise_attention` with
+    blocks of 64 is covered below. Position i sees (i - window, i]."""
+    from distributedtraining_tpu.ops.attention import causal_attention
+    rng = np.random.default_rng(window)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, 200, 2, 16)), jnp.float32)
+               for _ in range(3))
+    amask = jnp.asarray(rng.random((2, 200)) > 0.1, jnp.int32
+                        ).at[:, 0].set(1)
+    want = _windowed_by_definition(q, k, v, window, amask)
+    # a row none of whose window's keys is real has no defined output
+    i, j = np.arange(200)[:, None], np.arange(200)[None, :]
+    seen = ((j <= i) & (j > i - window))[None] & np.asarray(
+        amask, bool)[:, None, :]
+    live = seen.any(-1)[..., None, None]
+    got = causal_attention(q, k, v, attention_mask=amask, impl=impl,
+                           window=window)
+    blocks = blockwise_attention(q, k, v, attention_mask=amask, block_q=64,
+                                 block_kv=64, window=window)
+    for out in (got, blocks):
+        np.testing.assert_allclose(np.asarray(out) * live,
+                                   np.asarray(want) * live, atol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["flash", "ring"])
+def test_kernels_with_no_window_refuse_one(impl):
+    from distributedtraining_tpu.ops.attention import causal_attention
+    x = jnp.zeros((1, 8, 1, 8))
+    with pytest.raises(ValueError, match="has no window"):
+        causal_attention(x, x, x, impl=impl, window=4)
+
+
+@pytest.mark.parametrize("impl", ["dense", "blockwise"])
+def test_window_none_lowers_to_what_stood(impl):
+    from distributedtraining_tpu.ops.attention import causal_attention
+    x = jnp.zeros((1, 64, 2, 8))
+    plain = jax.jit(lambda a: causal_attention(a, a, a, impl=impl))
+    none = jax.jit(lambda a: causal_attention(a, a, a, impl=impl,
+                                              window=None))
+    assert plain.lower(x).as_text() == none.lower(x).as_text()

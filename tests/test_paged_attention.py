@@ -280,3 +280,92 @@ def test_blocked_suffix_lowers_no_context_wide_score_tensor():
         q, pages, pages, jax.ShapeDtypeStruct((1, 2048), jnp.int32),
         jax.ShapeDtypeStruct((1,), jnp.int32), new, new).as_text()
     assert "33024" not in text and "32768" not in text
+
+
+# ---------------------------------------------------------------------------
+# A window layer: a lower bound on every path, translation invariant
+# ---------------------------------------------------------------------------
+
+def _dense_window(args, window):
+    """Dense masked attention over the gathered context and the fresh
+    rows: fresh row t at position len + t sees positions in (len + t -
+    window, len + t]. Written with a materialised mask, from the
+    definition."""
+    q, kp, vp, pt, sl, kn, vn = args
+    B, Tq, Hq, D = q.shape
+    P, Hkv = kp.shape[1], kp.shape[2] // D
+    S = pt.shape[1] * P
+    k = jnp.concatenate([kp[pt].reshape(B, S, Hkv, D), kn], axis=1)
+    v = jnp.concatenate([vp[pt].reshape(B, S, Hkv, D), vn], axis=1)
+    k, v = (jnp.repeat(x, Hq // Hkv, axis=2) for x in (k, v))
+    pos = jnp.concatenate([jnp.broadcast_to(jnp.arange(S), (B, S)),
+                           sl[:, None] + jnp.arange(Tq)[None]], axis=1)
+    real = jnp.concatenate([jnp.arange(S)[None] < sl[:, None],
+                            jnp.ones((B, Tq), bool)], axis=1)
+    qpos = sl[:, None] + jnp.arange(Tq)[None]
+    mask = (real[:, None, :] & (pos[:, None, :] <= qpos[:, :, None])
+            & (pos[:, None, :] > qpos[:, :, None] - window))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * D ** -0.5
+    p = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+# window 40 over pages of 8: a context under it, at it, one past it, and
+# many windows long (with the chunk of 8 pages = 64 rows: chunks wholly
+# behind the bound, a chunk the bound cuts, the newest chunk)
+@pytest.mark.parametrize("lens", [[3, 39], [40, 41], [200, 129], [255, 64]])
+def test_window_kernel_is_its_twin_is_dense_masked_attention(lens):
+    args = _case(2, 8, 2, 64, 8, 32, lens, seed=sum(lens))
+    want = _dense_window(args, 40)
+    twin = pa.paged_decode_reference(*args, window=40)
+    out = pa.paged_decode_attention(*args, interpret=True, window=40)
+    assert float(jnp.max(jnp.abs(twin - want))) < 2e-6
+    assert float(jnp.max(jnp.abs(out - twin))) < 1e-6
+    if max(lens) > 41:
+        plain = pa.paged_decode_reference(*args)
+        assert float(jnp.max(jnp.abs(plain - twin))) > 1e-3
+
+
+@pytest.mark.parametrize("lens", [[5, 0], [24, 23], [25, 70], [96, 41]])
+def test_window_suffix_in_blocks_is_dense_masked_attention(lens):
+    """A chunk of 7 fresh rows over a paged context, window 24: the
+    chunk's first row behind, at and past the window's edge."""
+    args = _suffix_case(2, 7, 8, 2, 16, 8, 12, lens, seed=sum(lens))
+    want = _dense_window(args, 24)
+    for got in (pa.paged_suffix_attention(*args, block=16, window=24),
+                pa.paged_decode_reference(*args, window=24),
+                pa.paged_attention(*args, window=24)):
+        assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+
+
+def test_window_paths_read_lengths_from_the_tables_first_row():
+    """Translation invariance, which the shifted table rests on: the same
+    newest pages behind a table that starts LATER, with the lengths counted
+    from its first row, give the same numbers."""
+    q, kp, vp, pt, sl, kn, vn = _case(1, 4, 2, 64, 8, 32, [203], seed=9)
+    whole = pa.paged_decode_attention(q, kp, vp, pt, sl, kn, vn,
+                                      interpret=True, window=40)
+    # positions 160.. are pages 20..: a table of 8 entries from page 20
+    moved = pa.paged_decode_attention(q, kp, vp, pt[:, 20:28], sl - 160, kn,
+                                      vn, interpret=True, window=40)
+    assert float(jnp.max(jnp.abs(moved - whole))) < 1e-6
+
+
+def test_window_none_lowers_to_what_stood():
+    args = _case(2, 8, 2, 64, 8, 16, [50, 9])
+    suffix = _suffix_case(1, 5, 8, 2, 16, 8, 16, [50])
+    for fn, a in ((pa.paged_decode_reference, args),
+                  (pa.paged_suffix_attention, suffix),
+                  (pa.paged_attention, args), (pa.paged_attention, suffix)):
+        assert str(jax.make_jaxpr(fn)(*a)) == str(jax.make_jaxpr(
+            lambda *x, f=fn: f(*x, window=None))(*a))
+    plain = pa._build_call(2, 4, 128, 64, 8, 16, jnp.float32, jnp.float32,
+                           True)
+    windowed = pa._build_call(2, 4, 128, 64, 8, 16, jnp.float32, jnp.float32,
+                              True, 40)
+    text = [str(jax.make_jaxpr(c)(args[3], args[4], jnp.zeros((2, 4, 128)),
+                                  args[1], args[2], jnp.zeros((2, 1, 128)),
+                                  jnp.zeros((2, 1, 128))))
+            for c in (plain, windowed)]
+    assert "name=paged_decode_attention" in text[0]
+    assert "name=paged_window_decode_attention" in text[1]

@@ -550,3 +550,30 @@ def test_paged_decode_kernel_takes_a_table_of_2560_pages_a_slot(one_chip):
     assert pa.kernel_supports(args[0], args[1])
     compiled = _compile(pa.paged_decode_attention, *args)
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("window, pages, name", [
+    (2048, 136, "paged_window_decode_attention"),
+    (None, 2112, "paged_decode_attention")])
+def test_windowed_and_plain_decode_kernels_compile_at_32_over_4_heads(
+        one_chip, window, pages, name):
+    """The two paged decode kernels of `serve-trinity-mixed`: 32 query / 4
+    K/V heads of 128 (512 lanes, a query group of 8), 64 slots, pages of
+    16; the window group's table 136 entries a slot with the lower bound,
+    the global layer's 2,112 without. Each under its own Mosaic name."""
+    from distributedtraining_tpu.ops import paged_attention as pa
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = 12417 if window else 65536
+    args = (sds((64, 1, 32, 128)), sds((pool, 16, 512)),
+            sds((pool, 16, 512)), sds((64, pages), jnp.int32),
+            sds((64,), jnp.int32), sds((64, 1, 4, 128)),
+            sds((64, 1, 4, 128)))
+    assert pa.kernel_supports(args[0], args[1])
+    compiled = _compile(
+        lambda *a: pa.paged_decode_attention(*a, window=window), *args)
+    own = [ln.split(" = ")[0].strip() for ln in compiled.as_text(
+        ).splitlines() if "tpu_custom_call" in ln]
+    assert len(own) == 1 and re.fullmatch(rf"%?{name}(\.\d+)?", own[0]), own
