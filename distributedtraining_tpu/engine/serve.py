@@ -2670,6 +2670,11 @@ class GenerationEngine:
                 row = slot.pages[:pb]
                 tables[i, :len(row)] = row
                 seq_lens[i] = slot.seq_len + ahead
+            if obs.enabled():
+                # the share of (rows x table width) that holds context:
+                # what the paged decode kernel walks of the bucket
+                obs.observe("serve.decode.table_live_pct", 100.0 * int(
+                    np.sum(-(-seq_lens // self.page_size))) / (sb * pb))
             if chain:
                 tokens = _split_pick(earlier.out)[0]
             else:

@@ -17,11 +17,19 @@ with ``seq_lens``.
 
 Why not ``jax.experimental.pallas.ops.tpu.paged_attention``: the
 library kernel downcasts every loaded K/V block to bfloat16 before the
-QK/PV matmuls (``MultiPageAsyncCopyDescriptor._maybe_dequantize``),
-which breaks this repo's greedy-parity contract (engine outputs pinned
-token-identical to the full-recompute oracle at f32 — docs/serving.md).
-This kernel keeps the pool dtype through the loads and accumulates in
-fp32, so parity vs ``ops.attention.cached_attention`` holds to 1e-6.
+QK/PV matmuls (``MultiPageAsyncCopyDescriptor._maybe_dequantize``)
+whatever the pool holds, which breaks this repo's greedy-parity contract
+(engine outputs pinned token-identical to the full-recompute oracle at
+f32 — docs/serving.md). This kernel multiplies in the POOL's dtype and
+sums in float32: a float32 pool at ``Precision.HIGHEST`` (parity vs
+``ops.attention.cached_attention`` to 1e-6), a bfloat16 pool with
+bfloat16 K, V and probabilities into float32 sums, which is what the twin
+(``cached_attention``: ``preferred_element_type=float32``,
+``probs.astype(v.dtype)``), :func:`paged_suffix_attention` and
+``mla_decode_attention`` compute for the same layers; bfloat16 products
+are exact in float32, so the scores lose nothing. The softmax statistics,
+the running max and sum and the accumulator are float32 for every pool,
+and the scale multiplies the float32 scores, never a rounded ``q``.
 
 Selection, not probing: :func:`paged_attention` (the model-facing
 entry) picks the kernel from what it can observe — a TPU backend and a
@@ -39,13 +47,35 @@ lane-dense rows is a copy of the whole array under that tiling, not a
 view. The pool is therefore STORED the way the kernel DMAs it — one
 array per layer of lane-dense ``[pages, P, Hkv*D]`` rows
 (engine/kv_pool.py) — and passed to the ``pallas_call`` as it lies. The
-kernel never splits the ``Hkv*D`` lane axis: per-head reductions and
-broadcasts go through a 0/1 segment matrix ``seg[Hkv*D, 128]`` on the
-MXU (``(q*k) @ seg`` sums each head's D lanes into one score lane;
-``p @ seg.T`` spreads a probability back over its head's lanes) at
-HIGHEST precision, so the f32 parity contract survives. The XLA twin
-gathers the table's pages from the same stored shape and splits
-``Hkv*D`` into heads on the gathered context only.
+kernel never splits the ``Hkv*D`` lane axis. Of the two spellings that
+put the products on the MXU (ISSUE 47), it takes (b): a row's queries
+become ONE block ``qb`` whose row ``(g, h)`` holds query row ``g``'s
+head-``h`` lanes and zeros elsewhere, so a chunk costs two products for
+all query rows of all heads: ``qb k^T`` ``[rows, T]`` (the zeros add
+nothing: the same sums as a product a head) and ``p v`` kept ``[rows,
+Hkv*D]`` float32, of which head ``h``'s lanes of row ``(g, h)`` are
+selected ONCE, at the row's end. The rows' order is a rule over ``G`` and
+``Hkv`` (:func:`_query_block`: the order with the fewer rows). Spelling
+(a), a pair of products a K/V head's lane tile (two heads to a tile at
+D = 64), was built on this body, measured and taken out: slower at all
+four served shapes, by 9% (nemotron's) to 60% (gpt2-large's), 34% at solar's
+(`scripts/ab_paged_decode.py`; PERF.md §6, PR 47: the MXU's time is the K
+and V tiles it latches, the same in both, and a product a tile pays its
+fixed cost eight or ten times a chunk). The XLA twin gathers the table's
+pages from the same stored shape and splits ``Hkv*D`` into heads on the
+gathered context only.
+
+What a call costs follows the live context (PR 47): the grid is the
+bucket's rows; a row walks ``ceil(seq_len / chunk)`` chunks in a loop (a
+window layer from the first page its window reaches), chunk ``c + 1``'s
+page DMAs started before chunk ``c``'s products (two K and two V
+buffers); a chunk is up to :data:`CHUNK_ROWS` positions and
+:data:`CHUNK_BYTES` a buffer, from ``Hkv*D`` and the pool dtype
+(:func:`_chunk_pages`); pages past the context are never read; a row
+with ``seq_len == 0`` (a bucket's padding) writes its fresh V to every
+query head, which is its attention, and runs no product. q, the fresh
+rows and the output lie whole in VMEM for the call, so a grid step moves
+nothing but pages.
 
 Layouts: q / k_new / v_new are ``[B, Tq, H(kv), D]`` (decode is one
 token per slot per step); the page pool is one layer's stored
@@ -79,13 +109,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF, cached_attention
 
-# one decode chunk = this many pages DMA'd + attended per grid step;
-# the (slot, page) buckets ride a power-of-two ladder (engine/serve.py
-# BucketLadder), so any larger MP is divisible and smaller MPs run as
-# a single chunk
+# the unit the engine rounds a table's width to (engine/kv_pool.py): every
+# table this kernel is handed is a multiple of it, or a smaller power of
+# two. The chunk the kernel walks is chosen in :func:`_chunk_pages`
 PAGES_PER_CHUNK = 8
 
-_LANES = 128     # score lanes: one per kv head, zero-padded
+CHUNK_BYTES = 1 << 20    # one K (or V) buffer of a chunk, at most
+CHUNK_ROWS = 1024        # positions a chunk, at most
+
+_LANES = 128
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -93,18 +125,29 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _chunk_pages(mp: int) -> int:
-    """Largest power-of-two divisor of ``mp`` capped at PAGES_PER_CHUNK."""
-    c = 1
-    while c < PAGES_PER_CHUNK and mp % (c * 2) == 0:
-        c *= 2
-    return c
+def _chunk_pages(mp: int, page_size: int, hd: int, dtype) -> int:
+    """Pages DMA'd and attended a loop step: the largest power of two
+    whose K (or V) rows fill at most :data:`CHUNK_BYTES` and
+    :data:`CHUNK_ROWS` positions, from the row's width and the pool's
+    dtype alone (bfloat16 pages of 16: 64 at 256 or 512 lanes, 32 at
+    1,024, 16 at 1,280), and no more than the table holds. Placed on the
+    chip (`scripts/ab_paged_decode.py --chunk-rows`, PERF.md §6, PR 47):
+    1,024 positions against 512 read 8% faster at 512 lanes and the same
+    at 1,024; a 2.6 MB buffer at 1,280 lanes read 12-22% slower than 640 KiB
+    with one or two short rows live, where the first chunk's DMA is
+    exposed."""
+    rows = min(CHUNK_ROWS, CHUNK_BYTES // (hd * jnp.dtype(dtype).itemsize))
+    ppc = 1
+    while ppc * 2 * page_size <= rows:
+        ppc *= 2
+    return min(ppc, mp)
 
 
 def kernel_supports(q: jax.Array, k_pages: jax.Array) -> bool:
     """The shapes the kernel's lane-dense layout handles: one query
-    token, whole query groups per kv head, at most one score lane per
-    kv head, ``Hkv*D`` a whole number of 128-lane tiles, and pages that
+    token, whole query groups per kv head, at most 128 kv heads (a head
+    took a score lane before PR 47; kept, so that selection is what it
+    was), ``Hkv*D`` a whole number of 128-lane tiles, and pages that
     are whole sublane tiles of the pool dtype (8 rows f32, 16 bf16)."""
     B, Tq, Hq, D = q.shape
     _, P, HD = k_pages.shape
@@ -114,178 +157,248 @@ def kernel_supports(q: jax.Array, k_pages: jax.Array) -> bool:
             and Hq % Hkv == 0 and HD % _LANES == 0 and P % sublanes == 0)
 
 
-def _seg(hd: int, d: int, *, transpose: bool = False) -> jax.Array:
-    """``seg[x, h] = 1.0`` where lane ``x`` of the ``Hkv*D`` axis
-    belongs to kv head ``h`` (``x // d == h``, spelled without the
-    integer divide); ``[hd, 128]``, or its ``[128, hd]`` transpose."""
-    shape = (_LANES, hd) if transpose else (hd, _LANES)
-    x = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transpose else 0)
-    h = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transpose else 1)
-    return jnp.logical_and(x >= h * d, x < (h + 1) * d).astype(jnp.float32)
+def _precision(dtype):
+    return _HIGHEST if jnp.dtype(dtype) == jnp.float32 else None
 
 
-def _dot(a, b):
-    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
-                               precision=_HIGHEST,
-                               preferred_element_type=jnp.float32)
+def _query_block(G: int, Hkv: int) -> tuple[bool, int, int]:
+    """How a row's queries lie in the query block ``qb``: ``(heads_major,
+    blocks, rows a block)``. Row ``(g, h)`` holds query row ``g``'s
+    head-``h`` lanes; a block is a sublane tile or more, so either every
+    query row brings a block of its K/V heads (``G`` blocks of ``Hkv``
+    rounded up to 8: gpt2-large's 1 x 24) or every K/V head a block of its
+    query rows (``Hkv`` blocks of ``G`` rounded up to 8: nemotron's 2 x 16,
+    trinity's 4 x 8). The fewer rows win: they are what the MXU streams
+    past every latched K and V tile, and what the softmax runs over."""
+    by_query, by_head = G * (-(-Hkv // 8) * 8), Hkv * (-(-G // 8) * 8)
+    if by_head < by_query:
+        return True, Hkv, -(-G // 8) * 8
+    return False, G, -(-Hkv // 8) * 8
 
 
-def _rows8(x):
-    """Broadcast a ``[1, n]`` row to the 8-sublane tile the MXU wants."""
-    return jnp.broadcast_to(x, (8, x.shape[1]))
-
-
-def _online_update(g, s, v, valid, seg_t, acc_ref, m_ref, l_ref):
-    """Streaming-softmax accumulate for query-group row ``g``: ``s``
-    ``[T, 128]`` scores (one lane per kv head), ``v`` ``[T, Hkv*D]``.
-    ``acc`` holds the UNNORMALIZED weighted sum (the division by ``l``
-    happens once, at finalize), ``m``/``l`` the running max / normalizer
-    per head lane. ``p`` is re-zeroed under the mask so a fully-dead
-    chunk contributes exact zeros — the blockwise_attention
-    convention."""
-    m_prev = m_ref[g:g + 1, :]                           # [1, 128]
-    l_prev = l_ref[g:g + 1, :]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)        # [T, 128]
-    alpha = jnp.exp(m_prev - m_new)
-    m_ref[g:g + 1, :] = m_new
-    l_ref[g:g + 1, :] = l_prev * alpha + jnp.sum(p, axis=0, keepdims=True)
-    t = p.shape[0]
-    if t % 8:                                            # the fresh column
-        p = _rows8(p)
-        pv = (_dot(p, seg_t) * v)[:1]
-    else:
-        pv = jnp.sum(_dot(p, seg_t) * v, axis=0, keepdims=True)
-    alpha_x = _dot(_rows8(alpha), seg_t)[:1]             # [1, Hkv*D]
-    acc_ref[g:g + 1, :] = acc_ref[g:g + 1, :] * alpha_x + pv
+def _head_lanes(shape: tuple[int, int], d: int, head=None) -> jax.Array:
+    """``shape`` bool: lane ``x`` belongs to K/V head ``head`` (``x // d ==
+    head``, spelled without the integer divide); ``head=None``: to the
+    head of the ROW's own number, and rows past the last head own no
+    lane."""
+    x = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    h = jax.lax.broadcasted_iota(jnp.int32, shape, 0) if head is None \
+        else head
+    return jnp.logical_and(x >= h * d, x < (h + 1) * d)
 
 
 def _decode_kernel(page_tables_ref, seq_lens_ref,   # scalar prefetch
                    q_ref, k_pages_ref, v_pages_ref, k_new_ref, v_new_ref,
                    o_ref,
-                   k_buf, v_buf, acc_ref, m_ref, l_ref, sem,
-                   *, pages_per_chunk: int, page_size: int,
-                   n_chunks: int, group: int, head_dim: int,
-                   scale: float, window: int | None = None):
-    """One (batch row, context chunk) grid step of the fused decode.
+                   k_buf, v_buf, qb_ref, acc_ref, m_ref, l_ref, sem,
+                   *, pages_per_chunk: int, page_size: int, table_pages: int,
+                   group: int, head_dim: int, scale: float,
+                   window: int | None = None):
+    """One batch row of the fused decode (grid ``(B,)``; q, the fresh
+    rows and the output lie whole in VMEM, so a step moves nothing but
+    the pages it attends).
 
-    Grid is ``(B, n_chunks + 1)``: the first ``n_chunks`` steps DMA
-    ``pages_per_chunk`` pages of this row's table and fold them into
-    the running online softmax (f32 ``m``/``l``/unnormalized ``acc``
-    persist in VMEM scratch across the sequential grid); the FINAL step
-    appends the fresh (k_new, v_new) column — the token being decoded,
-    not yet in the pool — and writes ``acc / l``. Chunks wholly past
-    ``seq_lens[b]`` skip both the DMA and the math (the bucket-padded
-    tail of a short sequence costs nothing but the grid iteration). With
-    ``window`` the fresh token sees positions ``> seq_len - window`` only:
-    chunks wholly at or behind that bound skip likewise.
+    A row with no context writes its fresh V to every query head and is
+    done. A live row builds its query block ``qb`` (row ``(g, h)``: query
+    row ``g``'s head-``h`` lanes, zeros elsewhere; the rows' order is
+    :func:`_query_block`'s), starts from the fresh token (``m`` its score,
+    ``l`` 1, ``acc`` its V: the token being decoded is not in the pool yet),
+    then
+    walks the chunks its context fills, from the first page a window
+    still reaches to the last live page: chunk ``c + 1``'s page DMAs are
+    started before chunk ``c``'s two products (``qb k^T`` and ``p v`` on
+    the MXU in the operands' dtype, float32 sums; ``m`` / ``l`` / ``acc``
+    float32 in VMEM scratch). Pages past the context are never read: a
+    last chunk's dead pages are zeroed in the V buffer (a masked score's
+    ``p`` is exactly 0, and 0 x stale VMEM must stay 0) and masked in the
+    scores. The finalize divides by ``l`` and takes head ``h``'s lanes of
+    row ``(g, h)``, once.
     """
     b = pl.program_id(0)
-    i = pl.program_id(1)
     seq_len = seq_lens_ref[b]
-    chunk = pages_per_chunk * page_size
-    hd = q_ref.shape[-1]
+    P, ppc, G, D = page_size, pages_per_chunk, group, head_dim
+    chunk = ppc * P
+    rows, hd = qb_ref.shape
+    heads_major, n_blocks, block = _query_block(G, hd // D)
+    f32 = jnp.float32
+    prec = _precision(qb_ref.dtype)
+    vn = v_new_ref[pl.ds(b, 1), :]         # [1, Hkv*D] f32
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(seq_len == 0)
+    def _fresh_token_alone():
+        o_ref[b] = jnp.broadcast_to(vn, (G, hd)).astype(o_ref.dtype)
 
-    q = q_ref[0].astype(jnp.float32) * scale             # [G, Hkv*D]
+    @pl.when(seq_len > 0)
+    def _live_row():
+        # a block's lanes, built once a row and not once a chunk: the K/V
+        # head of each row's number (a query row's block), or head i's
+        lanes = ([_head_lanes((block, hd), D, i) for i in range(n_blocks)]
+                 if heads_major
+                 else [_head_lanes((block, hd), D)] * n_blocks)
+        # q_ref[b]: the row's queries, zero rows up to a sublane tile
+        blocks = [jnp.where(lanes[i], q_ref[b] if heads_major
+                            else q_ref[b, i:i + 1, :], 0.0)
+                  for i in range(n_blocks)]
+        if rows > n_blocks * block:
+            blocks.append(jnp.zeros((rows - n_blocks * block, hd), f32))
+        qblk = jnp.concatenate(blocks, axis=0)           # [rows, Hkv*D] f32
+        qb_ref[...] = qblk.astype(qb_ref.dtype)
+        s_new = jnp.sum(qblk * k_new_ref[pl.ds(b, 1), :], axis=1,
+                        keepdims=True) * scale           # [rows, 1]
+        m_ref[...] = jnp.broadcast_to(s_new, m_ref.shape)
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(vn, acc_ref.shape)
 
-    runs = jnp.logical_and(i < n_chunks, i * chunk < seq_len)
-    if window is not None:
-        # the chunk's last position is still inside the window
-        runs = jnp.logical_and(runs, (i + 1) * chunk - 1 > seq_len - window)
+        n_pages = jnp.minimum((seq_len + P - 1) // P, table_pages)
+        first = (0 if window is None
+                 else jnp.maximum(seq_len - window + 1, 0) // P)
+        n_chunks = (n_pages - first + ppc - 1) // ppc
 
-    @pl.when(runs)
-    def _context_chunk():
-        # gather exactly the pages the table names for this chunk
-        for j in range(pages_per_chunk):
-            page = page_tables_ref[b, i * pages_per_chunk + j]
-            rows = pl.ds(j * page_size, page_size)
-            pltpu.make_async_copy(
-                k_pages_ref.at[page], k_buf.at[rows], sem.at[0]).start()
-            pltpu.make_async_copy(
-                v_pages_ref.at[page], v_buf.at[rows], sem.at[1]).start()
-        for j in range(pages_per_chunk):
-            rows = pl.ds(j * page_size, page_size)
-            pltpu.make_async_copy(
-                k_pages_ref.at[0], k_buf.at[rows], sem.at[0]).wait()
-            pltpu.make_async_copy(
-                v_pages_ref.at[0], v_buf.at[rows], sem.at[1]).wait()
-        k = k_buf[...].astype(jnp.float32)               # [T, Hkv*D]
-        v = v_buf[...].astype(jnp.float32)
-        seg, seg_t = _seg(hd, head_dim), _seg(hd, head_dim, transpose=True)
-        pos = i * chunk + jax.lax.broadcasted_iota(
-            jnp.int32, (chunk, _LANES), 0)
-        valid = pos < seq_len                            # dead pages masked
-        if window is not None:
-            valid = jnp.logical_and(valid, pos > seq_len - window)
-        for g in range(group):
-            # s[t, h] = q[g, head h lanes] . k[t, head h lanes]
-            s = _dot(k * q[g:g + 1, :], seg)             # [T, 128]
+        def pages_of(c):
+            """Chunk ``c``'s first table entry and its count of live pages."""
+            base = first + c * ppc
+            return base, jnp.minimum(ppc, n_pages - base)
+
+        def rows_of(j):
+            return pl.ds(pl.multiple_of(j * P, P), P)
+
+        def each_live_page(c, slot, act):
+            """``act`` on the K and the V copy of every live page of chunk
+            ``c`` into buffer ``slot``; -> the count of them."""
+            base, live = pages_of(c)
+
+            def one(j, _):
+                page = page_tables_ref[b, base + j]
+                act(pltpu.make_async_copy(k_pages_ref.at[page],
+                                          k_buf.at[slot, rows_of(j)],
+                                          sem.at[0, slot]))
+                act(pltpu.make_async_copy(v_pages_ref.at[page],
+                                          v_buf.at[slot, rows_of(j)],
+                                          sem.at[1, slot]))
+            jax.lax.fori_loop(0, live, one, None)
+            return live
+
+        def start(c, slot):
+            each_live_page(c, slot, lambda copy: copy.start())
+
+        def wait(c, slot):
+            live = each_live_page(c, slot, lambda copy: copy.wait())
+
+            def dead(j, _):
+                v_buf[slot, rows_of(j), :] = jnp.zeros((P, hd), v_buf.dtype)
+            jax.lax.fori_loop(live, ppc, dead, None)
+
+        @pl.when(n_chunks > 0)
+        def _first_chunk():
+            start(0, 0)
+
+        def attend(c, _):
+            slot = c % 2
+
+            @pl.when(c + 1 < n_chunks)
+            def _next_chunk_in_flight():
+                start(c + 1, 1 - slot)
+
+            wait(c, slot)
+            k = k_buf[slot].astype(qb_ref.dtype)         # [T, Hkv*D]
+            v = v_buf[slot].astype(qb_ref.dtype)
+            s = jax.lax.dot_general(
+                qb_ref[...], k, (((1,), (1,)), ((), ())), precision=prec,
+                preferred_element_type=f32) * scale      # [rows, T]
+            pos = pages_of(c)[0] * P + jax.lax.broadcasted_iota(
+                jnp.int32, (1, chunk), 1)
+            valid = pos < seq_len                        # dead pages masked
+            if window is not None:
+                valid = jnp.logical_and(valid, pos > seq_len - window)
             s = jnp.where(valid, s, NEG_INF)
-            _online_update(g, s, v, valid, seg_t, acc_ref, m_ref, l_ref)
+            m_prev = m_ref[...]                          # [rows, 128]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # m >= the fresh token's score, finite: a masked column's p is
+            # exp(NEG_INF - m) = exactly 0
+            p = jnp.exp(s - m_new[:, :1])
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[...] = m_new
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                precision=prec, preferred_element_type=f32)
+            acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
 
-    @pl.when(i == n_chunks)
-    def _append_fresh_and_finalize():
-        kn = k_new_ref[0].astype(jnp.float32)            # [1, Hkv*D]
-        vn = v_new_ref[0].astype(jnp.float32)
-        seg, seg_t = _seg(hd, head_dim), _seg(hd, head_dim, transpose=True)
-        valid = jnp.ones((1, _LANES), dtype=jnp.bool_)
-        for g in range(group):
-            s = _dot(_rows8(kn * q[g:g + 1, :]), seg)[:1]
-            _online_update(g, s, vn, valid, seg_t, acc_ref, m_ref, l_ref)
-        # l >= exp(0) > 0 on real head lanes; padded lanes never spread
-        # (their seg_t rows are zero), so clamp only guards 0 * inf
-        l_x = _dot(jnp.maximum(l_ref[...], 1e-30), seg_t)   # [G8, Hkv*D]
-        o_ref[0] = (acc_ref[...] / l_x)[:group].astype(o_ref.dtype)
+        jax.lax.fori_loop(0, n_chunks, attend, None)
+
+        out = acc_ref[...] / l_ref[...][:, :1]           # [rows, Hkv*D]
+        g8 = q_ref.shape[1]
+        row = jax.lax.broadcasted_iota(jnp.int32, (g8, hd), 0)
+        res = jnp.zeros((g8, hd), f32)
+        for i in range(n_blocks):
+            mine = jnp.where(lanes[i], out[i * block:(i + 1) * block], 0.0)
+            if heads_major:          # head i's lanes of its G query rows
+                res = res + mine
+            else:                    # query row i: every head's own lanes
+                res = jnp.where(row == i, jnp.sum(mine, axis=0,
+                                                  keepdims=True), res)
+        o_ref[b] = res[:G].astype(o_ref.dtype)
 
 
+@functools.lru_cache(maxsize=None)
 def _build_call(B, G, HD, D, P, MP, q_dtype, page_dtype, interpret: bool,
                 window: int | None = None):
-    """Construct the pallas_call for one shape signature (lane-dense
-    operands: q/out ``[B, G, HD]``, pages ``[pool, P, HD]``, fresh
-    k/v ``[B, 1, HD]``)."""
-    ppc = _chunk_pages(MP)
-    n_chunks = MP // ppc
-    g8 = -(-G // 8) * 8
+    """The jitted pallas_call for one shape signature (lane-dense
+    operands: q ``[B, G8, HD]`` (``G`` rows and zero rows up to a sublane
+    tile) and fresh k/v ``[B, HD]`` float32, pages ``[pool, P, HD]``, out
+    ``[B, G, HD]`` in ``q_dtype``). ONE object a signature, under a
+    ``jit`` of the kernel's own name: a decode program calls the kernel
+    once a layer, and every call but the first finds the kernel traced
+    and, inside one program, lowered (a private function called a layer);
+    without it a program's set-up traces and lowers the body 36 times
+    (PERF.md §6, PR 47: 85 s of a warm `serve-large-chat` set-up)."""
+    ppc = _chunk_pages(MP, P, HD, page_dtype)
+    operand = jnp.promote_types(q_dtype, page_dtype)
+    _, n_blocks, block = _query_block(G, HD // D)
+    rows = -(-n_blocks * block // 16) * 16
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,      # page_tables, seq_lens
-        grid=(B, n_chunks + 1),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, G, HD), lambda b, i, *_: (b, 0, 0)),
+            whole,                                    # q
             pl.BlockSpec(memory_space=pl.ANY),        # k_pages (HBM)
             pl.BlockSpec(memory_space=pl.ANY),        # v_pages (HBM)
-            pl.BlockSpec((1, 1, HD), lambda b, i, *_: (b, 0, 0)),
-            pl.BlockSpec((1, 1, HD), lambda b, i, *_: (b, 0, 0)),
+            whole, whole,                             # fresh k, v
         ],
-        out_specs=pl.BlockSpec((1, G, HD), lambda b, i, *_: (b, 0, 0)),
+        out_specs=whole,
         scratch_shapes=[
-            pltpu.VMEM((ppc * P, HD), page_dtype),        # k chunk
-            pltpu.VMEM((ppc * P, HD), page_dtype),        # v chunk
-            pltpu.VMEM((g8, HD), jnp.float32),            # acc
-            pltpu.VMEM((g8, _LANES), jnp.float32),        # running max
-            pltpu.VMEM((g8, _LANES), jnp.float32),        # running sum
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((2, ppc * P, HD), page_dtype),     # k chunks
+            pltpu.VMEM((2, ppc * P, HD), page_dtype),     # v chunks
+            pltpu.VMEM((rows, HD), operand),              # query block
+            pltpu.VMEM((rows, HD), jnp.float32),          # acc
+            pltpu.VMEM((rows, _LANES), jnp.float32),      # running max
+            pltpu.VMEM((rows, _LANES), jnp.float32),      # running sum
+            pltpu.SemaphoreType.DMA((2, 2)),              # (k | v, slot)
         ],
     )
     kernel = functools.partial(
-        _decode_kernel, pages_per_chunk=ppc, page_size=P,
-        n_chunks=n_chunks, group=G, head_dim=D, scale=D ** -0.5,
+        _decode_kernel, pages_per_chunk=ppc, page_size=P, table_pages=MP,
+        group=G, head_dim=D, scale=D ** -0.5,
         **({} if window is None else {"window": window}))
-    return pl.pallas_call(  # devprof: exempt (attributed under serve.decode in-step)
+    name = ("paged_decode_attention" if window is None
+            else "paged_window_decode_attention")
+    call = pl.pallas_call(  # devprof: exempt (attributed under serve.decode in-step)
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, HD), q_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=("paged_decode_attention" if window is None
-              else "paged_window_decode_attention"),
+        name=name,
     )
+
+    def run(*operands):
+        return call(*operands)
+
+    run.__name__ = run.__qualname__ = name      # what the jit is called
+    return jax.jit(run)  # devprof: exempt (attributed under serve.decode in-step)
 
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
@@ -314,13 +427,16 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     Hkv = HD // D
     G = Hq // Hkv
     MP = page_tables.shape[1]
+    f32 = jnp.float32
     # query head hq = h * G + g  ->  row g, head-h lanes
     qg = q.reshape(B, Hkv, G, D).transpose(0, 2, 1, 3).reshape(B, G, HD)
+    qg = jnp.pad(qg.astype(f32), ((0, 0), (0, -G % 8), (0, 0)))
     call = _build_call(B, G, HD, D, P, MP, q.dtype, k_pages.dtype,
                        interpret, window)
     out = call(page_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
                qg, k_pages, v_pages,
-               k_new.reshape(B, 1, HD), v_new.reshape(B, 1, HD))
+               k_new.reshape(B, HD).astype(f32),
+               v_new.reshape(B, HD).astype(f32))
     out = out.reshape(B, G, Hkv, D).transpose(0, 2, 1, 3)
     return out.reshape(B, 1, Hq, D)
 

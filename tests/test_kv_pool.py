@@ -102,10 +102,14 @@ def _program(family, cfg, eng, draft):
         params, i32(1, T), np.int32(5), k, v, i32(MP)), 3
 
 
-def _equations(jaxpr):
+def _equations(jaxpr, outer_of=None):
     """Every equation, those of nested jaxprs in place of the equation
     that carries them (its outputs are theirs). A ``pallas_call`` is a
-    leaf: its body works on blocks in VMEM, not on arrays in HBM."""
+    leaf: its body works on blocks in VMEM, not on arrays in HBM.
+    ``outer_of``, where given, is filled with the variable of the
+    enclosing jaxpr behind each input of a nested ``jit`` (the paged
+    decode kernel's call is one, ops/paged_attention.py): an argument
+    handed through a ``jit`` is the same array."""
     for eqn in jaxpr.eqns:
         subs = [] if eqn.primitive.name == "pallas_call" else [
             getattr(x, "jaxpr", x)
@@ -114,7 +118,12 @@ def _equations(jaxpr):
             if isinstance(getattr(x, "jaxpr", x), jex_core.Jaxpr)]
         if subs:
             for sub in subs:
-                yield from _equations(sub)
+                if outer_of is not None and eqn.primitive.name in (
+                        "jit", "pjit"):
+                    for inner, outer in zip(sub.invars, eqn.invars):
+                        if isinstance(outer, jex_core.Var):   # no literal
+                            outer_of[inner] = outer_of.get(outer, outer)
+                yield from _equations(sub, outer_of)
         else:
             yield eqn
 
@@ -148,12 +157,14 @@ def test_program_writes_each_layer_in_place(engines, family, monkeypatch):
     layer_elems = int(np.prod(layer))
     updates, kernels = 0, 0
     inputs = set(traced.jaxpr.jaxpr.invars)
-    for eqn in _equations(traced.jaxpr.jaxpr):
+    outer_of = {}
+    for eqn in _equations(traced.jaxpr.jaxpr, outer_of):
         if eqn.primitive.name == "pallas_call":
             kernels += 1
             # the kernel takes a layer as it lies: the program's own
             # argument, not something made from it
-            stored = [x for x in eqn.invars if x.aval.shape == layer]
+            stored = [outer_of.get(x, x) for x in eqn.invars
+                      if x.aval.shape == layer]
             assert len(stored) == 2 and set(stored) <= inputs
         for out in eqn.outvars:
             if out.aval.size < layer_elems:

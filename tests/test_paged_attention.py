@@ -67,10 +67,12 @@ def test_kernel_matches_reference_page_boundary_lengths():
 
 
 def test_kernel_matches_reference_multi_chunk():
-    """MP > PAGES_PER_CHUNK: the online softmax crosses chunk
-    boundaries (the grid's streaming dimension actually streams)."""
-    assert 16 > pa.PAGES_PER_CHUNK
-    _parity(_case(2, 4, 2, 64, 8, 16, [127, 64]))
+    """MP > the pages of a chunk: the online softmax crosses chunk
+    boundaries (the row's loop actually streams, the next chunk in
+    flight)."""
+    ppc = pa._chunk_pages(512, 8, 128, jnp.float32)
+    assert 512 > 2 * ppc
+    _parity(_case(2, 4, 2, 64, 8, 512, [2 * ppc * 8 + 5, ppc * 8]))
 
 
 def test_trash_page_zero_lanes():
@@ -363,9 +365,96 @@ def test_window_none_lowers_to_what_stood():
                            True)
     windowed = pa._build_call(2, 4, 128, 64, 8, 16, jnp.float32, jnp.float32,
                               True, 40)
-    text = [str(jax.make_jaxpr(c)(args[3], args[4], jnp.zeros((2, 4, 128)),
-                                  args[1], args[2], jnp.zeros((2, 1, 128)),
-                                  jnp.zeros((2, 1, 128))))
+    text = [str(jax.make_jaxpr(c)(args[3], args[4], jnp.zeros((2, 8, 128)),
+                                  args[1], args[2], jnp.zeros((2, 128)),
+                                  jnp.zeros((2, 128))))
             for c in (plain, windowed)]
     assert "name=paged_decode_attention" in text[0]
     assert "name=paged_window_decode_attention" in text[1]
+
+
+# ---------------------------------------------------------------------------
+# The four served head shapes, both pool dtypes, the lengths that cut a
+# page, a chunk and a table
+# ---------------------------------------------------------------------------
+
+# Hq, Hkv, D of the cells that run the kernel: gpt2-large (G 1), nemotron
+# (G 16), solar (G 8), trinity (G 8; its sliding layers take a window)
+SERVED = {"large": (20, 20, 64), "nemotron": (32, 2, 128),
+          "solar": (64, 8, 128), "trinity": (32, 4, 128)}
+
+
+def _served_case(name, dtype, MP, lens, seed=0):
+    Hq, Hkv, D = SERVED[name]
+    args = _case(len(lens), Hq, Hkv, D, 16, MP, lens, seed=seed,
+                 pool=1 + sum(-(-n // 16) for n in lens) + 3)
+    q, kp, vp, pt, sl, kn, vn = args
+    return tuple(x.astype(dtype) for x in (q, kp, vp)) + (pt, sl) + tuple(
+        x.astype(dtype) for x in (kn, vn))
+
+
+def _agree(out, ref, dtype):
+    """float32: the module's parity contract. bfloat16: the twin rounds
+    the NORMALISED probabilities to bfloat16, the kernel the unnormalised
+    ones (as every flash kernel), and both round the output: a few
+    bfloat16 steps of a value of order 1."""
+    err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                - ref.astype(jnp.float32))))
+    assert err < (1e-6 if dtype == jnp.float32 else 4e-2), err
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name, window", [
+    ("large", None), ("nemotron", None), ("solar", None), ("trinity", None),
+    ("trinity", 300)])
+def test_kernel_is_its_twin_at_the_served_head_shapes(name, window, dtype):
+    """Lengths: a dead row, 1, a page's edge, a chunk's edge - 1 / at / +
+    1, and a short context under a table far wider; the table (136 pages)
+    is no whole number of chunks of 16 or 32 pages (every case but
+    gpt2-large's float32 pool, 8 pages a chunk)."""
+    HD = SERVED[name][1] * SERVED[name][2]
+    chunk = 16 * pa._chunk_pages(136, 16, HD, dtype)
+    assert 136 * 16 > 2 * chunk + 1
+    lens = [0, 1, 16, chunk - 1, chunk, chunk + 1, 2 * chunk + 1, 40]
+    args = _served_case(name, dtype, 136, lens, seed=len(name))
+    kw = {} if window is None else {"window": window}
+    out = pa.paged_decode_attention(*args, interpret=True, **kw)
+    _agree(out, pa.paged_decode_reference(*args, **kw), dtype)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_pages_the_context_does_not_name_are_never_read(window):
+    """Every page of the pool that no live position lies in holds NaN (the
+    trash page, the tables' dead entries, pages behind a window's reach):
+    the output is finite and bit-equal to a clean pool's. The twin reads
+    them (0 x NaN), so it cannot be the reference here."""
+    lens = [0, 5, 16, 100, 700]
+    args = _served_case("trinity", jnp.float32, 136, lens, seed=7)
+    q, kp, vp, pt, sl, kn, vn = args
+    kw = {} if window is None else {"window": window}
+    clean = pa.paged_decode_attention(*args, interpret=True, **kw)
+    named = np.zeros(kp.shape[0], bool)
+    for row, n in enumerate(lens):
+        first = 0 if window is None else max(n - window + 1, 0) // 16
+        named[np.asarray(pt[row, first:-(-n // 16)])] = True
+    poison = jnp.where(jnp.asarray(named)[:, None, None], 0.0, jnp.nan)
+    # dead table entries point at unnamed pages too
+    pt = jnp.where(jnp.arange(pt.shape[1])[None] * 16 < sl[:, None], pt, 0)
+    dirty = pa.paged_decode_attention(q, kp + poison, vp + poison, pt, sl,
+                                      kn, vn, interpret=True, **kw)
+    assert bool(jnp.all(jnp.isfinite(dirty)))
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+
+
+def test_chunk_is_chosen_from_the_rows_width_and_the_pools_dtype():
+    """No name, no argument: 512 bfloat16 lanes or fewer walk 1,024
+    positions a chunk, 1,024 lanes 512 (1 MiB a buffer), 1,280 lanes 256,
+    a float32 pool half of a bfloat16 one's; never more pages than the
+    table holds."""
+    assert pa._chunk_pages(2560, 16, 1024, jnp.bfloat16) == 32
+    assert pa._chunk_pages(64, 16, 1280, jnp.bfloat16) == 16
+    assert pa._chunk_pages(2112, 16, 512, jnp.bfloat16) == 64
+    assert pa._chunk_pages(128, 16, 256, jnp.bfloat16) == 64
+    assert pa._chunk_pages(64, 16, 1280, jnp.float32) == 8
+    assert pa._chunk_pages(4, 8, 128, jnp.float32) == 4

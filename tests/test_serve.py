@@ -99,6 +99,24 @@ def test_greedy_parity_continuous_batch(setup):
         eng.close()
 
 
+def test_table_live_pct_is_the_share_of_the_bucket_that_holds_context(
+        setup, sink):
+    """`serve.decode.table_live_pct`, once a plain decode step: pages that
+    hold context over rows x table width. A two-row bucket with one dead
+    row and a half-filled four-page table reads 25."""
+    model, cfg, params, _, _ = setup
+    eng = GenerationEngine(model, params, max_slots=2, page_size=8)
+    eng._plain_bucket = lambda ahead=0: (2, 4)
+    try:
+        # contexts of 10..15 tokens: two of the four pages, every step
+        eng.generate([list(range(1, 11))], 5)
+    finally:
+        eng.close()
+    hist = obs.registry().peek("serve.decode.table_live_pct")
+    assert hist is not None and hist.count >= 4
+    assert hist.percentiles((0.0, 100.0)) == {"p0": 25.0, "p100": 25.0}
+
+
 def test_paged_equals_contiguous(setup):
     """Paged KV (small pages, gathered per step) vs a contiguous cache
     (one page holds the whole sequence): identical outputs — paging is a

@@ -552,6 +552,30 @@ def test_paged_decode_kernel_takes_a_table_of_2560_pages_a_slot(one_chip):
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+def test_paged_decode_kernel_at_gpt2_larges_bucket(one_chip):
+    """`serve-large-chat`'s largest decode bucket: 8 rows, a table of 64
+    pages of 16, 20 heads of 64 (1,280 lanes, a query group of 1). The
+    chunk is 16 pages (256 positions, 640 KiB a buffer); the two K and two
+    V buffers stay inside four of `CHUNK_BYTES`, far under the 16 MiB a
+    kernel may hold on this chip, and Mosaic takes the whole of it."""
+    from distributedtraining_tpu.ops import paged_attention as pa
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((8, 1, 20, 64)), sds((513, 16, 1280)), sds((513, 16, 1280)),
+            sds((8, 64), jnp.int32), sds((8,), jnp.int32),
+            sds((8, 1, 20, 64)), sds((8, 1, 20, 64)))
+    assert pa.kernel_supports(args[0], args[1])
+    ppc = pa._chunk_pages(64, 16, 1280, jnp.bfloat16)
+    assert ppc == 16 and 4 * ppc * 16 * 1280 * 2 <= 4 * pa.CHUNK_BYTES
+    compiled = _compile(pa.paged_decode_attention, *args)
+    own = [ln.split(" = ")[0].strip() for ln in compiled.as_text(
+        ).splitlines() if "tpu_custom_call" in ln]
+    assert len(own) == 1 and re.fullmatch(
+        r"%?paged_decode_attention(\.\d+)?", own[0]), own
+
+
 @pytest.mark.parametrize("window, pages, name", [
     (2048, 136, "paged_window_decode_attention"),
     (None, 2112, "paged_decode_attention")])

@@ -76,6 +76,31 @@ def test_kernel_bf16_pages():
                                np.asarray(ref, np.float32), atol=3e-2)
 
 
+# Hq, Hkv, D, window of the cells that run the kernel (gpt2-large, nemotron,
+# solar, trinity's global and sliding layers)
+SERVED = [(20, 20, 64, None), (32, 2, 128, None), (64, 8, 128, None),
+          (32, 4, 128, None), (32, 4, 128, 300)]
+
+
+@pytest.mark.parametrize("Hq, Hkv, D, window", SERVED)
+def test_kernel_is_its_twin_at_the_served_head_shapes_bf16(Hq, Hkv, D,
+                                                           window):
+    """The four served head shapes on bfloat16 pools, as every cell holds
+    them: operands in the pool's dtype, float32 sums; the twin rounds the
+    normalised probabilities, the kernel the unnormalised ones. Lengths: a
+    dead row, 1, a page's edge, a chunk's edge - 1 / at / + 1, two chunks
+    and one, and a short context under a table of 136 pages."""
+    chunk = 16 * pa._chunk_pages(136, 16, Hkv * D, jnp.bfloat16)
+    lens = [0, 1, 16, chunk - 1, chunk, chunk + 1, 2 * chunk + 1, 40]
+    args = _case(len(lens), Hq, Hkv, D, 16, 136, lens, seed=Hq,
+                 dtype=jnp.bfloat16)
+    kw = {} if window is None else {"window": window}
+    out = jax.jit(lambda *a: pa.paged_decode_attention(*a, **kw))(*args)
+    ref = jax.jit(lambda *a: pa.paged_decode_reference(*a, **kw))(*args)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=4e-2)
+
+
 def test_engine_greedy_parity_on_chip():
     """The serving contract on real hardware: engine decode (kernel
     path — Hkv*D = 128 fills a lane tile) token-identical to the
