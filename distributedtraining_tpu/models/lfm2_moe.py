@@ -72,6 +72,7 @@ TRAIN_COUNTERS = {
     "moe_rows_fullest": "train.moe.rows_fullest_expert",
     "moe_experts_touched": "train.moe.experts_touched",
     "moe_rows_past_prefix": "train.moe.rows_past_prefix",
+    "moe_layers_past_prefix": "train.moe.layers_past_prefix",
 }
 # and its attention layers, where the flash kernels run the block pairs the
 # rows' segment ids need (ops/flash_attention.block_pairs)

@@ -35,9 +35,22 @@ them. A caller that also states the router's expert count gets a static
 PREFIX of the sorted rows (:func:`prefix_rows`: the expected share and a
 margin, whole row tiles): the dispatch gathers ``order[:P]``, both grouped
 products, the activation, the weigh, the mask and the casts run on ``[P,
-.]`` arrays with the group sizes clipped to the prefix, and the un-sort
-reads a ``[P, E]`` source (a position at or past ``P`` gives zero),
-forward, in remat's re-run and backward. The prefix path ALWAYS runs, at
+.]`` arrays with the group sizes clipped to the prefix, forward, in remat's
+re-run and backward. The two moves back to token order, the un-sort with
+its sum over ``k`` and the dispatch's backward, read the inverse
+permutation BY TOKEN (``[N, k]``: token ``j``'s ``k`` sorted rows) one
+choice at a time (:func:`_sum_choices`): ``k`` gathers of ``N`` rows out of
+the ``[P, E]`` source in ITS dtype (a position outside the prefix reads no
+row and gives zero), with the weigh, the mask and the cast to float32 made
+from the gathered rows inside the one pass that adds the ``k`` planes in
+the order of the choice. No array of ``N k`` rows is made and nothing is
+re-tiled: the full-width un-sort writes ``f32[N k, E]``, three quarters of
+it the fill, re-tiles it as ``[N, k, E]`` and reduces it, which cost the
+train cell's step 5.3 ms a layer more than this does; and a spelling that
+first sorts the prefix's rows by token and folds each token's adjacent rows
+lost to this one, because a row gather costs by the rows it READS (the
+fold gathers ``P`` and then ``N`` of them, the planes ``P``) and a row it
+only fills costs its bytes (PERF.md, PR 48). The prefix path ALWAYS runs, at
 the top level of the program. Held rows past ``P`` (the OVERFLOW: a router
 further out of balance than the margin) are the one thing behind a
 ``lax.cond``: that branch runs rows ``P .. R - 1`` with the remaining
@@ -184,7 +197,8 @@ def _weigh_held(y: jax.Array, w_sorted: jax.Array, here: jax.Array | None,
 # costs the cell 1.0-1.7% of its rate (a quarter 54,106 tokens/s, three
 # sixteenths 55,051, an eighth 55,590; the parent 47,470: PERF.md, PR 43):
 # an eighth pays only where fewer than one layer-step in 18 lands between
-# the two bounds, which `moe_rows_past_prefix` is there to tell
+# the two bounds, which `moe_layers_past_prefix` is there to tell
+# (`moe_rows_past_prefix` counts the rows, not the layer-steps they fell in)
 PREFIX_MARGIN = 0.25
 
 
@@ -231,7 +245,8 @@ def _dispatch(h: jax.Array, here: jax.Array | None, order: jax.Array,
     """Token order -> expert order: ``h[order // k]``, of all sorted rows
     or of those of ``window`` alone. Backward, a token's ``k`` sorted rows
     are GATHERED along the inverse permutation and summed in float32: no
-    scatter-add into ``[N, E]``. A row of no held group gives ZERO,
+    scatter-add into ``[N, E]`` (with a ``window``, a plane of ``N`` rows a
+    gather: :func:`_sum_choices`). A row of no held group gives ZERO,
     whatever the grouped product's own backward left in an output it never
     wrote (``gmm``'s is unwritten memory): that row's expert is another
     chip's, and nothing of it may reach ``dh``; so does a row outside the
@@ -245,12 +260,33 @@ def _dispatch_fwd(h, here, order, window):
             (here, jnp.argsort(order).reshape(h.shape[0], -1).T))
 
 
+def _sum_choices(src: jax.Array, inverse: jax.Array,
+                 window: tuple[int, int], made) -> jax.Array:
+    """``src`` holds the sorted rows of ``window``, ``inverse`` [k, N] the
+    sorted row of every token's ``i``-th choice: ``made(rows [N, E], i,
+    flat [N])`` float32, of the rows gathered for choice ``i`` (zero
+    outside the window) at flat positions ``flat``, added up in the order
+    of the choice. A token's float32 sum over ``k`` as the full-width layer
+    makes it, to the bit on the CPU; a chip's compiler pairs the terms of
+    the reduce over ``[N, k, E]`` and fuses this pass otherwise, and a few
+    outputs in a million come out one rounding apart (PERF.md, PR 48). A
+    plane of ``N`` rows a gather, nothing ``N k`` rows long."""
+    k, N = inverse.shape
+    return functools.reduce(jnp.add, (
+        made(_take_sorted(src, inverse[i], window), i, jnp.arange(N) * k + i)
+        for i in range(k)))
+
+
 def _dispatch_bwd(window, res, g):
     # inverse [k, N]: the i-th sorted row of every token, so that the
     # gather writes k whole [N, E] planes and the sum re-tiles nothing;
     # plane i's row j is flat row j * k + i
     here, inverse = res
     k, N = inverse.shape
+    if window is not None:
+        return (_sum_choices(g, inverse, window, lambda rows, _, flat: (
+            _held_rows(rows, here, flat).astype(jnp.float32))).astype(
+                g.dtype), None, None)
     g = _take_sorted(g, inverse, window)                  # [k, N, E]
     g = _held_rows(g, here, jnp.arange(N * k).reshape(N, k).T)
     return (jnp.sum(g.astype(jnp.float32), axis=0).astype(g.dtype),
@@ -262,13 +298,21 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 def _combine_fwd(y, weights, here, order, window):
     N, k = weights.shape
-    rows = _window(order, window)
-    out = _weigh_held(y, jnp.take(weights.reshape(-1), rows), here, rows)
-    # un-sort: row r of the sorted order came from flat row order[r]
-    inverse = jnp.argsort(order)
-    out = _take_sorted(out, inverse, window)
-    out = jnp.sum(out.reshape(N, k, -1), axis=1).astype(y.dtype)
-    return out, (y, weights, here, order, inverse)
+    if window is None:
+        out = _weigh_held(y, jnp.take(weights.reshape(-1), order), here,
+                          order)
+        # un-sort: row r of the sorted order came from flat row order[r]
+        inverse = jnp.argsort(order)
+        out = jnp.sum(jnp.take(out, inverse, axis=0).reshape(N, k, -1),
+                      axis=1)
+    else:
+        # by token: choice i of every token out of the window's rows as
+        # they left the experts, weighed where it is held
+        inverse = jnp.argsort(order)
+        out = _sum_choices(
+            y, inverse.reshape(N, k).T, window, lambda rows, i, flat: (
+                _weigh_held(rows, weights[:, i], here, flat)))
+    return out.astype(y.dtype), (y, weights, here, order, inverse)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -276,7 +320,9 @@ def _combine(y: jax.Array, weights: jax.Array, here: jax.Array | None,
              order: jax.Array, window: tuple[int, int] | None) -> jax.Array:
     """Expert order -> token order: the held rows weighed, un-sorted along
     the inverse permutation and summed over ``k``; ``y`` holds all sorted
-    rows, or those of ``window`` alone (the others add zero). Backward,
+    rows, or those of ``window`` alone (the others add zero, and the
+    un-sort gathers ``k`` planes of ``N`` rows out of ``y`` itself, weighed
+    as they are added: :func:`_sum_choices`). Backward,
     the ``[N, E]`` cotangent comes to sorted order by ONE gather
     (``order // k``: no ``[N, k, E]`` broadcast, no scatter-add) and a
     weight's goes back as a gather of ``[N * k]`` scalars. A row held
@@ -488,9 +534,10 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
     (shapes are static) and only live ones are counted. Returns ([N, E]
     in ``h``'s dtype, int32 scalars {"moe_rows": live rows computed here,
     "moe_experts_touched"} and, with ``held``, "moe_rows_elsewhere";
-    with ``router_experts`` "moe_rows_past_prefix"; with
-    ``count_fullest`` also "moe_rows_fullest", the rows of the fullest
-    held expert)."""
+    with ``router_experts`` "moe_rows_past_prefix" and
+    "moe_layers_past_prefix" (1 where this layer has any such row: it left
+    the fast path); with ``count_fullest`` also "moe_rows_fullest", the
+    rows of the fullest held expert)."""
     N, k = choice.shape
     G = w_in.shape[0]
     flat, here = choice.reshape(-1), None                # [N*k]
@@ -544,6 +591,7 @@ def routed_experts(h: jax.Array, choice: jax.Array, weights: jax.Array,
                      moe_rows_elsewhere=(rows - ours).astype(jnp.int32))
     if router_experts is not None:
         stats["moe_rows_past_prefix"] = past
+        stats["moe_layers_past_prefix"] = (past > 0).astype(jnp.int32)
     if count_fullest:
         stats["moe_rows_fullest"] = jnp.max(group_sizes)
     return out, stats

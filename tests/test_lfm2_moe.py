@@ -443,7 +443,7 @@ def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
     batch = _packed_batch(pc, [[48], [48]])
     _, m = engine.train_step(engine.init_state(params=params), batch)
     assert set(m) == {"loss", "tokens", *lf.TRAIN_COUNTERS.values()}
-    rows, away, fullest, touched, past = (
+    rows, away, fullest, touched, past, layers_past = (
         int(m[k]) for k in lf.TRAIN_COUNTERS.values())
     # 4 routed layers x 96 tokens x 2 experts a token
     assert rows + away == 4 * 96 * 2 and 0 < rows < 4 * 96 * 2
@@ -452,7 +452,7 @@ def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
     assert 4 <= touched <= 4 * 4
     # half the experts held: the prefix is 128 of a layer's 192 sorted rows,
     # and a router this even stays inside it
-    assert past == 0 and rows / 4 < 128
+    assert past == 0 == layers_past and rows / 4 < 128
 
     def run_loop():
         loop = MinerLoop(engine, InMemoryTransport(), "m0",
@@ -474,6 +474,7 @@ def test_counters_leave_the_step_beside_the_loss_and_reach_the_registry(
         # every routed layer of every step touched 1..8 of its experts
         assert 3 * 4 <= reg.peek("train.moe.experts_touched").value <= 96
         assert reg.peek("train.moe.rows_past_prefix").value == 0
+        assert reg.peek("train.moe.layers_past_prefix").value == 0
         assert loop._counted_dev == []
     finally:
         obs.reset()
@@ -529,9 +530,11 @@ def test_a_step_past_the_prefix_counts_its_rows_and_loses_none(tiny,
     engine, m, grads = step()
     assert int(m["train.moe.rows"]) == 4 * 192
     assert int(m["train.moe.rows_past_prefix"]) == 4 * (192 - 128)
+    assert int(m["train.moe.layers_past_prefix"]) == 4
     monkeypatch.setattr(moe, "prefix_rows", lambda rows, *_: rows)
     _, wide, wide_grads = step()
     assert int(wide["train.moe.rows_past_prefix"]) == 0
+    assert int(wide["train.moe.layers_past_prefix"]) == 0
     assert float(m["loss"]) == float(wide["loss"])
     for a, b in zip(jax.tree_util.tree_leaves(grads),
                     jax.tree_util.tree_leaves(wide_grads)):
@@ -550,6 +553,9 @@ def test_a_step_past_the_prefix_counts_its_rows_and_loses_none(tiny,
         # moves no choice
         assert obs.registry().peek(
             "train.moe.rows_past_prefix").value == 2 * 4 * 64
+        # and every layer-step of the two left the fast path
+        assert obs.registry().peek(
+            "train.moe.layers_past_prefix").value == 2 * 4
     finally:
         obs.reset()
 

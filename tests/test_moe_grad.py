@@ -13,7 +13,9 @@ Every case runs the full-width layer and, where the caller states the
 router's expert count, the static PREFIX of the sorted rows: with the held
 rows under the bound, exactly at it, and past it (the overflow's `cond`
 entered), which must change nothing the layer returns and no gradient
-beyond a rounding."""
+beyond a rounding. On the prefix the un-sort and the dispatch's backward
+go by token, a choice at a time (`moe._sum_choices`): the routings of
+`HOLDS` give a token none, one, two or all of its rows here."""
 
 import hashlib
 import re
@@ -33,12 +35,23 @@ N, E, F, ROUTER, K = 96, 128, 128, 8, 3
 WIDE, BOUND = 16, 128
 # (held, the router's experts, held rows forced; None: as routed, and the
 # caller does not state the router's count: the full-width layer)
+# or a routing made by hand, named: how many of its K choices each token
+# holds here, by turns (a deployment's share holds 0..K a token), and
+# whether they go to the held experts by turns or all to ONE (the train
+# cell routes so: one row a token, every one in one group). 96 held rows
+# either way, inside the bound, with other chips' rows behind them in the
+# prefix
+ALL = 1 << 10                     # of a token's choices: more than any k
+HOLDS = {"mixed": ((0, 1, 1, 2, ALL, 1, 0, 0), False),
+         "one-group": ((1,), True)}
 LAYERS = [
     pytest.param((None, ROUTER, None), id="all"),
     pytest.param(((2, 4), ROUTER, None), id="held"),
     pytest.param(((2, 4), WIDE, 100), id="prefix-under"),
     pytest.param(((2, 4), WIDE, BOUND), id="prefix-at"),
     pytest.param(((2, 4), WIDE, 250), id="prefix-past"),
+    pytest.param(((2, 4), WIDE, "mixed"), id="prefix-mixed"),
+    pytest.param(((2, 4), WIDE, "one-group"), id="prefix-one-group"),
 ]
 
 
@@ -74,6 +87,15 @@ def _hold(choice, held, rows):
     if rows is None:
         return choice
     first, count = held
+    if rows in HOLDS:
+        # choice i of token j is held while (i + j) % k is under the
+        # token's count, so a token's held rows are not its leading ones
+        counts, one_group = HOLDS[rows]
+        j, i = jnp.indices(choice.shape)
+        ours = (i + j) % choice.shape[1] < jnp.asarray(counts)[j % len(counts)]
+        # any expert past the held ones will do: no case holds the last
+        return jnp.where(ours, first + (0 if one_group else (i + j) % count),
+                         first + count + (i + j) % 2).astype(choice.dtype)
     flat = choice.reshape(-1)
     here = (flat >= first) & (flat < first + count)
     nth_here, nth_away = jnp.cumsum(here) - 1, jnp.cumsum(~here) - 1
@@ -111,6 +133,14 @@ def test_the_cases_lie_where_their_names_say():
         assert sizes.sum() == rows
     # past the bound: a group cut by it and a whole group behind it
     assert sizes[:2].sum() < BOUND < sizes[:3].sum() and sizes[3] > 0
+    # by hand: tokens with none, one, two and all K rows here, inside the
+    # bound; and a row a token, every one in ONE group
+    ours = np.asarray(_hold(choice, (2, 4), "mixed"))
+    ours = (ours >= 2) & (ours < 6)
+    assert set(ours.sum(1)) == {0, 1, 2, K} and ours.sum() == 96 < BOUND
+    assert not ours[:, 0].all() and not ours[ours.sum(1) == 1][:, 0].all()
+    ours = np.asarray(_hold(choice, (2, 4), "one-group"))
+    assert ((ours == 2).sum(1) == 1).all() and not ((ours > 2) & (ours < 6)).any()
 
 
 @pytest.mark.parametrize("impl,dtype,tol", [
@@ -173,7 +203,9 @@ def _poisoned(real):
                                         ("gmm_interpret", jnp.bfloat16)])
 @pytest.mark.parametrize("rows", [
     pytest.param(None, id="full-width"), pytest.param(100, id="prefix-under"),
-    pytest.param(BOUND, id="prefix-at"), pytest.param(200, id="prefix-past")])
+    pytest.param(BOUND, id="prefix-at"), pytest.param(200, id="prefix-past"),
+    pytest.param("mixed", id="prefix-mixed"),
+    pytest.param("one-group", id="prefix-one-group")])
 def test_rows_held_elsewhere_give_exactly_zero_whatever_the_product_left(
         monkeypatch, impl, dtype, rows):
     """Three quarters of a share's rows belong to no held group. With NaN
@@ -181,7 +213,8 @@ def test_rows_held_elsewhere_give_exactly_zero_whatever_the_product_left(
     gradients stay finite and are those of the clean product: the masks
     stand before anything can read such a row. With the prefix (2 of 8
     held: 128 of the 288 sorted rows) the rows of no group lie inside it
-    behind the held ones, and behind the overflow's."""
+    behind the held ones, and behind the overflow's; a token's sum over
+    its choices reads them between its held rows."""
     held = (2, 2)
     assert moe.prefix_rows(N * K, 2, ROUTER) == BOUND
     h, w_r, bias, w_in, w_down = _setup(13, dtype, held)
@@ -207,7 +240,7 @@ def test_rows_held_elsewhere_give_exactly_zero_whatever_the_product_left(
                                       np.asarray(w, np.float32))
     # a token none of whose experts is held gets NOTHING from this layer
     none_here = np.asarray(jnp.all((choice < 2) | (choice >= 4), axis=-1))
-    assert none_here.any()
+    assert none_here.any() == (rows != "one-group")
     assert not np.asarray(got[0], np.float32)[none_here].any()
 
 
@@ -255,6 +288,61 @@ def test_the_gradient_moves_rows_by_gathers_alone(impl, dtype, layer):
     assert max(shape[-1] for _, shape, _ in found if len(shape) >= 2) < E
 
 
+def all_rows_wide(jaxpr):
+    """The shape of every array of `N * K` rows, a row `E` wide or wider,
+    that a jaxpr or a sub-jaxpr of it makes (`[N * K, E]`, `[N, K, E]`,
+    `[K, N, E]`), those inside a `cond`'s branches left out."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            if (len(shape) >= 2 and shape[-1] >= E
+                    and int(np.prod(shape[:-1])) == N * K):
+                yield eqn.primitive.name, shape
+        if eqn.primitive.name != "cond":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from all_rows_wide(sub)
+
+
+@pytest.mark.parametrize("impl,dtype", [("ragged_dot", jnp.float32),
+                                        ("gmm_interpret", jnp.bfloat16)])
+@pytest.mark.parametrize("rows", ["mixed", 250])
+def test_the_prefix_path_makes_no_array_of_all_the_sorted_rows(impl, dtype,
+                                                               rows):
+    """With the router's count the prefix's rows go back to token order a
+    choice at a time, `K` gathers of `N` rows: forward and under
+    `jax.grad`, nothing `N * K` rows long and a row wide is made outside
+    the overflow's conditionals (the un-sort's `[N * K, E]` and its
+    `[N, K, E]`, the dispatch's backward's `[K, N, E]`: the full-width
+    layer's, which the overflow's branch keeps), whether the step would
+    enter them or not, and no row is scattered."""
+    held = (2, 4)
+    h, w_r, bias, w_in, w_down = _setup(41, dtype, held, WIDE)
+    choice, weights = moe.route(h, w_r, bias, K, 1.0)
+    choice = _hold(choice, held, rows)
+
+    def programs(router_experts):
+        def layer(h, weights, w_in, w_down):
+            return moe.routed_experts(
+                h, choice, weights, w_in, w_down, held=held,
+                router_experts=router_experts, impl=impl)[0]
+
+        def loss(*args):
+            return jnp.sum(layer(*args).astype(jnp.float32))
+        args = (h, weights, w_in, w_down)
+        return (jax.make_jaxpr(layer)(*args).jaxpr,
+                jax.make_jaxpr(jax.grad(loss, (0, 1, 2, 3)))(*args).jaxpr)
+
+    for jaxpr in programs(WIDE):
+        assert not list(all_rows_wide(jaxpr))
+        assert not [(name, shape) for name, shape, _ in scatters(jaxpr)
+                    if len(shape) >= 2]
+    # the reader finds them where they are: the full-width layer's un-sort
+    # forward, and its planes under the gradient
+    forward, grad = (list(all_rows_wide(j)) for j in programs(None))
+    assert (N * K, E) in [shape for _, shape in forward]
+    assert (K, N, E) in [shape for _, shape in grad]
+
+
 @pytest.mark.parametrize("impl,dtype", [("ragged_dot", jnp.float32),
                                         ("ragged_dot", jnp.bfloat16),
                                         ("gmm_interpret", jnp.bfloat16)])
@@ -289,7 +377,10 @@ def test_the_forward_is_the_plain_formula_to_the_bit(impl, dtype, layer):
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
     if rows is not None:
-        assert int(stats["moe_rows_past_prefix"]) == max(rows - BOUND, 0)
+        past = max(int(jnp.sum(here)) - BOUND, 0)
+        assert past == (max(rows - BOUND, 0) if rows not in HOLDS else 0)
+        assert int(stats["moe_rows_past_prefix"]) == past
+        assert int(stats["moe_layers_past_prefix"]) == (past > 0)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
@@ -380,16 +471,19 @@ def test_rows_past_the_prefix_are_counted(rows):
     order = np.argsort(np.where(here, flat - 2, 4), kind="stable")
     assert int(stats["moe_rows_past_prefix"]) == int(
         here[order][BOUND:].sum()) == max(rows - BOUND, 0)
+    # and the layer-step that left the fast path, once, however many rows
+    assert int(stats["moe_layers_past_prefix"]) == (rows > BOUND)
     assert int(stats["moe_rows"]) == rows
     # a caller that states no count has no prefix and no such counter; one
     # whose share is all the router's experts has it, and it reads zero
     _, plain = moe.routed_experts(h, choice, weights, w_in, w_down,
                                   held=held, impl="ragged_dot")
-    assert "moe_rows_past_prefix" not in plain
+    assert not {"moe_rows_past_prefix", "moe_layers_past_prefix"} & set(plain)
     _, whole = moe.routed_experts(h, choice, weights, w_in, w_down,
                                   held=(0, 4), router_experts=4,
                                   impl="ragged_dot")
     assert int(whole["moe_rows_past_prefix"]) == 0
+    assert int(whole["moe_layers_past_prefix"]) == 0
 
 
 # (held, under jax.grad): `signature` of the layer at 04ef3cb, the commit

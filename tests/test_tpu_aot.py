@@ -356,6 +356,46 @@ def test_prefix_paths_kernels_keep_their_names_outside_every_conditional(
     assert "[65536,3584]" not in text.split("ENTRY")[1].split("\n}")[0]
 
 
+def test_prefix_paths_step_makes_no_array_of_all_the_sorted_rows(
+        one_chip, monkeypatch):
+    """`train-lfm2-t8192`'s whole step (batch 2 x 8,192, remat, the role's
+    AdamW) for a described v5e: the un-sort and the dispatch's backward go
+    by token, so the top level of the compiled step (what runs whether or
+    not a layer overflows) holds none of the full-width layer's arrays of
+    all 65,536 sorted rows, in either tiling; and the grouped products are
+    where the readers look for them, 6 `gmm` and 2 `tgmm` a routed layer
+    under the library's names outside every conditional."""
+    import dataclasses
+
+    from test_moe_grad import mosaic_calls_outside_conditionals
+
+    from distributedtraining_tpu.engine import TrainEngine
+    from distributedtraining_tpu.models import lfm2_moe
+    from distributedtraining_tpu.ops import flash_attention, moe
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    model, _ = lfm2_moe.make_model(dataclasses.replace(
+        lfm2_moe.PRESETS["lfm2-8b-a1b-l5-e8-v16k"], remat=True))
+    engine = TrainEngine(model)
+
+    def placed(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    batch = {k: placed(jax.ShapeDtypeStruct((2, 8192), jnp.int32))
+             for k in ("input_ids", "segment_ids", "position_ids")}
+    batch["loss_mask"] = placed(jax.ShapeDtypeStruct((2, 8192), jnp.float32))
+    text = engine.train_step.__wrapped__.trace(
+        jax.tree_util.tree_map(placed, engine.abstract_state()),
+        batch).lower(lowering_platforms=("tpu",)).compile().as_text()
+    top = text.split("ENTRY")[1].split("\n}")[0]
+    assert "[20480,2048]" in top
+    for full_width in ("[65536,2048]", "[4,16384,2048]", "[16384,4,2048]"):
+        assert full_width not in top, full_width
+    outside = mosaic_calls_outside_conditionals(text)
+    assert sum(bool(re.fullmatch(r"%gmm(\.\d+)?", n)) for n in outside) == 24
+    assert sum(bool(re.fullmatch(r"%tgmm(\.\d+)?", n)) for n in outside) == 8
+
+
 @pytest.mark.parametrize("preset, mosaic_calls", [("gpt2-774m", 36),
                                                   ("gpt2-1.5b", 0)])
 def test_decode_program_reads_each_matrix_in_the_dtype_it_multiplies_in(

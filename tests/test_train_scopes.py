@@ -60,10 +60,12 @@ BARE_HANDFUL = re.compile(
 # LFM2's since PR 43: the step returns one counter more
 # (`train.moe.rows_past_prefix`); with that entry taken out of
 # `lfm2_moe.TRAIN_COUNTERS` the text is a55be7f's still, 4c8cb1fe5ca0a426 /
-# a1b01fe76df41b10 (the preset holds all 8 experts: the prefix is all rows)
+# a1b01fe76df41b10 (the preset holds all 8 experts: the prefix is all rows).
+# And since PR 48 another (`train.moe.layers_past_prefix`); without that
+# entry the text is PR 43's, f534e1cc394add95 / 42529a818e51f28d
 LOWERED = {
     ("gpt2", False): "acc609dceb884f17", ("gpt2", True): "46c28f222224b48a",
-    ("lfm2", False): "f534e1cc394add95", ("lfm2", True): "42529a818e51f28d",
+    ("lfm2", False): "d0c574f63a036ac4", ("lfm2", True): "bfc0e147d9803eb4",
 }
 
 
